@@ -106,10 +106,15 @@ print(json.dumps({
 
 
 def _cold_child(cache_dir: str, data_dir: str) -> dict:
+    # the XLA cache directory is the environment's to place
+    # (compile/xla_cache.py): a deliberately cold one is handed to the
+    # children through JAX_COMPILATION_CACHE_DIR; aot.cacheDir (argv[2])
+    # only holds the manifest, kept in the same fresh directory
     out = subprocess.run(
         [sys.executable, "-c", _COLD_CHILD, REPO_ROOT, cache_dir,
          data_dir],
-        capture_output=True, text=True, timeout=600, cwd=REPO_ROOT)
+        capture_output=True, text=True, timeout=600, cwd=REPO_ROOT,
+        env=dict(os.environ, JAX_COMPILATION_CACHE_DIR=cache_dir))
     assert out.returncode == 0, \
         f"cold-start child failed:\n{out.stderr[-2000:]}"
     return json.loads(out.stdout.strip().splitlines()[-1])

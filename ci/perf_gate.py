@@ -60,7 +60,10 @@ import sys
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# a host-only gate that spawns bench.py (--run): pin CPU for this
+# process AND its children, whatever the caller exported — a child
+# pointed at the chip its parent holds fails or hangs
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 BASELINE_PATH = os.path.join(REPO_ROOT, "PERF_BASELINE.json")
 

@@ -3,6 +3,13 @@
 # wheel-less package in-place, run the unit suite on the virtual
 # 8-device CPU mesh, compile-check the driver entry points, and run a
 # small end-to-end bench sanity pass.
+#
+# Host-only: every stage runs with JAX_PLATFORMS=cpu and must never be
+# pointed at the chip (the chip's one entry point is chip_smoke.py).
+# Compile cache: the stages use whatever compile/xla_cache.py decides —
+# JAX_COMPILATION_CACHE_DIR if exported, else <checkout>/.jax_cache;
+# compile_smoke.py's cold-start stage hands its children a fresh
+# directory through that same variable.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -63,6 +70,7 @@ echo "== morsel pipeline (parallel drains under stall watchdog) =="
 JAX_PLATFORMS=cpu python ci/pipeline_smoke.py
 
 echo "== superstage compiler (carve smoke, flush budget, determinism, cold start) =="
+# stage 5 seeds and re-reads a cold XLA cache via JAX_COMPILATION_CACHE_DIR
 JAX_PLATFORMS=cpu python ci/compile_smoke.py
 
 echo "== runtime stats plane (attribution, skew stats, zero extra flushes) =="

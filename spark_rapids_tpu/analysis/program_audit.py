@@ -2,9 +2,9 @@
 registered jitted program.
 
 The engine's performance contract is that each registered program — the
-seven ``obs/compile_watch.py`` JIT caches (fused_project,
-staged_compute, hash_aggregate, mesh_join, mesh_sort, mesh_aggregate,
-pallas_hash_partition) plus the join probe/speculative-probe programs
+six ``obs/compile_watch.py`` JIT caches (fused_project,
+staged_compute, hash_aggregate, mesh_join, mesh_sort, mesh_aggregate)
+plus the join probe/speculative-probe programs
 and the exchange stats sketch — runs on device with NO host round
 trips, NO accidental float math in exact-mode programs, and a bounded
 number of fusion-breaking data movements.  Those properties hold by
@@ -138,7 +138,6 @@ _PROVIDER_MODULES = (
     "spark_rapids_tpu.exec.tpu_mesh_join",
     "spark_rapids_tpu.exec.tpu_mesh_sort",
     "spark_rapids_tpu.exec.tpu_mesh_aggregate",
-    "spark_rapids_tpu.kernels.pallas_ops",
     "spark_rapids_tpu.obs.stats",
 )
 
@@ -155,7 +154,6 @@ REQUIRED_PROGRAMS = frozenset({
     "mesh_join",
     "mesh_sort",
     "mesh_aggregate",
-    "pallas_hash_partition",
     "exchange_stats",
 })
 

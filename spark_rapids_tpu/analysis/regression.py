@@ -10,8 +10,9 @@ and gates CI on a committed baseline:
 - :func:`parse_record` / :func:`load_history` — tolerant loader for
   both bench record shapes that exist in-tree: the legacy harness
   wrapper (``{"n", "cmd", "rc", "tail", "parsed"}``) and a bare key
-  set (one ``bench.py`` stdout JSON line).  Early rounds (r01-r05)
-  predate most of the current key set; the loader degrades to
+  set (one ``bench.py`` stdout JSON line).  Early rounds predate
+  most of the current key set (r01-r05 did; they are deleted with the
+  backend they were taken on, tests keep a synthetic one); the loader degrades to
   placeholder ``None`` values instead of crashing, so history tables
   always render every round.
 - ``PERF_BASELINE.json`` — committed per-key baseline: value,
@@ -185,7 +186,7 @@ class BenchRound:
 
     def get(self, key: str):
         """Key value, or ``None`` placeholder when the round predates
-        the key (the r01-r05 gap-handling contract)."""
+        the key (the early-round gap-handling contract)."""
         return self.keys.get(key)
 
 

@@ -6,10 +6,9 @@ The reference plugin earns device residency with cuDF's explicit
 GPU or it is not, and crossing costs a visible copy.  In JAX the
 boundary is implicit — ``np.asarray``, ``float()``, ``len()``,
 ``.tolist()``, branching on an array value, even an f-string all
-silently force a device->host transfer and a dispatch-queue sync.  On
-the remote-dispatch backends this engine targets a hidden pull costs a
-full round trip (~65-100 ms measured), so residency discipline is THE
-precondition for the async device-resident rewrite (ROADMAP item 8):
+silently force a device->host transfer and a dispatch-queue sync.  A
+hidden pull stalls the host until the device drains everything the
+value depends on, so residency discipline is THE precondition for the async device-resident rewrite (ROADMAP item 8):
 it is only safe to overlap aggressively once we can *prove* no
 undeclared sync survives on the drain spine.
 

@@ -8,7 +8,6 @@ GpuDeviceManager), and runs the planner on every action.
 """
 from __future__ import annotations
 
-import os
 import threading
 from typing import Dict, List, Optional, Sequence
 
@@ -21,35 +20,6 @@ from ..memory.arena import DeviceManager
 from ..obs import trace as _obs_trace
 from ..plan import logical as L
 from ..plan.overrides import Planner
-
-
-_CACHE_ENABLED = False
-
-
-def _enable_compilation_cache():
-    """Persistent XLA compilation cache: kernels are compiled per
-
-    (schema, capacity-bucket), so cross-process reuse pays off immediately
-    (first TPU compile is expensive; SURVEY.md §7 compile-cache note)."""
-    global _CACHE_ENABLED
-    if _CACHE_ENABLED:
-        return
-    try:
-        import getpass
-        import tempfile
-        import jax
-        cache_dir = os.environ.get("SPARK_RAPIDS_TPU_XLA_CACHE")
-        if not cache_dir:
-            # computed lazily: getuser() can raise in uid-less containers,
-            # and must not take down an explicitly configured cache
-            cache_dir = os.path.join(
-                tempfile.gettempdir(),
-                f"spark_rapids_tpu_xla_cache_{getpass.getuser()}")
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        _CACHE_ENABLED = True
-    except Exception:
-        pass
 
 
 class TpuSessionBuilder:
@@ -77,7 +47,11 @@ class TpuSession:
     def __init__(self, conf: Optional[TpuConf] = None):
         self.conf = conf or TpuConf()
         set_active(self.conf)
-        _enable_compilation_cache()
+        # persistent XLA compile cache: kernels compile per (schema,
+        # capacity bucket), so cross-process reuse pays off at once;
+        # compile/xla_cache.py is the only place that picks the directory
+        from ..compile import xla_cache as _xla_cache
+        _xla_cache.enable()
         _obs_trace.configure(self.conf)
         from ..obs import flight as _obs_flight
         _obs_flight.configure(self.conf)

@@ -299,8 +299,8 @@ class ColumnarBatch:
         return ColumnarBatch(self.schema, cols, num_rows)
 
     # jitted slice programs keyed by (out_cap,); shapes key the rest.
-    # Eager per-column gathers cost ~7ms of client overhead EACH on the
-    # remote backend; one jit dispatch is ~free (columnar/pending.py doc).
+    # Eager per-column gathers pay one dispatch (and one small compile
+    # per new shape) EACH; one jit dispatch covers them all.
     _SLICE_JIT: dict = {}
 
     def slice(self, start: int, length: int) -> "ColumnarBatch":
@@ -378,8 +378,7 @@ _CONCAT_JIT: dict = {}
 
 def _concat_plain_jit(batches, schema, cap: int, total: int):
     """One jitted program for fixed-width concat (slice+concat+pad per
-    column) — the eager per-column path costs ~7ms/op on the remote
-    backend (columnar/pending.py doc)."""
+    column) — the eager per-column path pays one dispatch per op."""
     import jax
     nrows = tuple(b.num_rows for b in batches)
     key = (nrows, cap, len(schema))

@@ -18,16 +18,17 @@ word and live-row count, so bucketed results are sha-identical to
 unbucketed execution (asserted by tests/test_aot.py across
 pipelineParallelism x superstage).
 
-**Persistent executable cache.**  ``aot.cacheDir`` points the JAX
-persistent compilation cache at a directory so a fresh process
-deserializes prior XLA executables instead of recompiling.  Alongside
-it this module keeps a *manifest*: one JSON entry per first-compile
+**Persistent executable cache.**  JAX's persistent compilation cache
+(directory owned by compile/xla_cache.py: ``JAX_COMPILATION_CACHE_DIR``
+or the fixed in-checkout path) lets a fresh process deserialize prior
+XLA executables instead of recompiling.  ``aot.cacheDir`` names where
+this module keeps its *manifest*: one JSON entry per first-compile
 keyed by ``sha1(program id | signature | conf fingerprint)`` — the
 signature carries the dtype tuple and bucket, the fingerprint hashes
 every program-affecting conf plus the jax version and lattice
 geometry.  When a fresh process's first call of a program finds its
-key in a manifest written by an *earlier* run, the call is a
-persistent-cache load, not a compile: compile_watch counts it under
+key in a manifest written by an *earlier* run against the same XLA
+cache directory, the call is a persistent-cache load, not a compile: compile_watch counts it under
 ``tpu_compile_persistent_hits_total`` and keeps ``tpu_compile_seconds``
 untouched (the cross-process test's "zero new XLA compiles"
 assertion).
@@ -58,6 +59,7 @@ import threading
 import uuid
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
+from . import xla_cache
 from ..obs import flight
 from ..obs.registry import (AOT_BUCKET_DEMAND, AOT_HINT_COMPILES,
                             AOT_WARMUP_COMPILES)
@@ -76,7 +78,6 @@ BUCKETED_PROGRAMS = frozenset({
     "mesh_join",
     "mesh_sort",
     "mesh_aggregate",
-    "pallas_hash_partition",
     "exchange_stats",
 })
 
@@ -120,8 +121,8 @@ class BucketLattice:
 _LOCK = threading.Lock()
 _ENABLED = True
 _LATTICE: Optional[BucketLattice] = None
-_CACHE_DIR = ""
-_XLA_CACHE_WIRED = False
+_CACHE_DIR = ""               #: where the manifest lives (aot.cacheDir)
+_PERSIST_ALL = False          #: every program is written to the XLA cache
 _CONF_FP = ""
 _RUN_ID = uuid.uuid4().hex[:12]     #: distinguishes this process's
                                     #: manifest entries from prior runs
@@ -193,10 +194,10 @@ def conf_fingerprint(conf) -> str:
 def configure(conf) -> None:
     """Apply the ``spark.rapids.tpu.compile.aot.*`` conf group
     (process-wide, last configure wins — the obs-plane discipline)."""
-    global _ENABLED, _LATTICE, _CACHE_DIR, _CONF_FP
+    global _ENABLED, _LATTICE, _CACHE_DIR, _CONF_FP, _PERSIST_ALL
     from ..columnar import column as _col
     from ..config import (AOT_BUCKET_RATIO, AOT_CACHE_DIR, AOT_ENABLED,
-                          AOT_XLA_CACHE)
+                          AOT_PERSIST_EVERY_PROGRAM)
     _ENABLED = bool(conf.get(AOT_ENABLED))
     if not _ENABLED:
         _LATTICE = None
@@ -210,27 +211,13 @@ def configure(conf) -> None:
     if d and d != _CACHE_DIR:
         _CACHE_DIR = d
         os.makedirs(d, exist_ok=True)
-        if bool(conf.get(AOT_XLA_CACHE)):
-            _wire_xla_cache(d)
+        if bool(conf.get(AOT_PERSIST_EVERY_PROGRAM)):
+            # the manifest's "an earlier run compiled this" claim only
+            # holds when every program is persisted, however quick its
+            # compile; the directory stays xla_cache's decision
+            xla_cache.enable(persist_everything=True)
+            _PERSIST_ALL = True
         _load_manifest()
-
-
-def _wire_xla_cache(cache_dir: str) -> None:
-    """Point the JAX persistent compilation cache at ``cache_dir`` with
-    the persistence thresholds dropped so every engine program (CPU
-    test programs compile in milliseconds) is written."""
-    global _XLA_CACHE_WIRED
-    import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_enable_compilation_cache", True)
-        _XLA_CACHE_WIRED = True
-    except Exception:
-        # older jax without a flag: manifest bookkeeping still works,
-        # first-calls just recompile (and are counted as compiles)
-        _XLA_CACHE_WIRED = False
 
 
 def lattice() -> Optional[BucketLattice]:
@@ -261,7 +248,10 @@ def _load_manifest() -> None:
     try:
         with open(path, "r", encoding="utf-8") as f:
             raw = json.load(f)
-        if isinstance(raw, dict):
+        # entries vouch for executables in ONE XLA cache directory; a
+        # manifest written against another directory proves nothing
+        if isinstance(raw, dict) and \
+                raw.get("xla_cache_dir") == xla_cache.cache_dir():
             entries = {k: v for k, v in raw.get("entries", {}).items()
                        if isinstance(v, dict)}
     except (OSError, ValueError):
@@ -279,7 +269,8 @@ def _save_manifest() -> None:
     with _LOCK:
         if not _MANIFEST_DIRTY:
             return
-        payload = {"version": 1, "entries": dict(_MANIFEST)}
+        payload = {"version": 1, "xla_cache_dir": xla_cache.cache_dir(),
+                   "entries": dict(_MANIFEST)}
         _MANIFEST_DIRTY = False
     tmp = _manifest_path() + f".{_RUN_ID}.tmp"
     try:
@@ -308,9 +299,9 @@ def manifest_add(key: str, cache: str, signature, bucket: Optional[int],
 def persistent_ready(key: Optional[str]) -> bool:
     """True when this first-call should be satisfied by the persistent
     cache: the manifest entry was written by an EARLIER process run
-    (same program id, signature and conf fingerprint) and the XLA
-    cache is wired to the same directory."""
-    if key is None or not _XLA_CACHE_WIRED:
+    (same program id, signature and conf fingerprint) against the same
+    XLA cache directory, with every program persisted."""
+    if key is None or not _PERSIST_ALL:
         return False
     with _LOCK:
         e = _MANIFEST.get(key)
@@ -546,7 +537,8 @@ def stats_section() -> Dict:
         "lattice": {"min_rows": lat.min_rows, "ratio": lat.ratio}
         if lat is not None else None,
         "cache_dir": _CACHE_DIR or None,
-        "xla_cache_wired": _XLA_CACHE_WIRED,
+        "xla_cache_dir": xla_cache.cache_dir(),
+        "xla_cache_persist_all": _PERSIST_ALL,
         "conf_fingerprint": _CONF_FP,
         "manifest_entries": manifest_n,
         "demand": demand,
@@ -562,7 +554,7 @@ def stats_section() -> Dict:
 def reset() -> None:
     """Test hook: drop ledger/warmer/manifest state and detach the
     lattice (keeps the process usable for unbucketed baselines)."""
-    global _ENABLED, _LATTICE, _CACHE_DIR, _XLA_CACHE_WIRED, _CONF_FP
+    global _ENABLED, _LATTICE, _CACHE_DIR, _PERSIST_ALL, _CONF_FP
     global _WARMUP_TOTAL, _WARMUP_FAILED, _MANIFEST_DIRTY
     global _HINTS_NOTED, _HINT_COMPILES
     from ..columnar import column as _col
@@ -582,7 +574,7 @@ def reset() -> None:
     _ENABLED = True
     _LATTICE = None
     _CACHE_DIR = ""
-    _XLA_CACHE_WIRED = False
+    _PERSIST_ALL = False
     _CONF_FP = ""
     _col.set_bucket_fn(None)
     _TLS.last = None

@@ -185,9 +185,8 @@ SCAN_CACHE = conf_bool(
     "Keep uploaded file-scan batches device-resident across queries, "
     "keyed on (files, mtimes, columns, pushed filters, batching). "
     "HBM residency makes repeat scans of the same tables skip decode "
-    "AND host->device transfer — the scarce resource on remote-"
-    "dispatch backends (ParquetCachedBatchSerializer role, applied "
-    "at the scan). Entries are dropped LRU past deviceScanCache.bytes "
+    "AND host->device transfer (ParquetCachedBatchSerializer role, "
+    "applied at the scan). Entries are dropped LRU past deviceScanCache.bytes "
     "and on real device-OOM pressure")
 
 SCAN_CACHE_BYTES = conf_bytes(
@@ -668,19 +667,24 @@ OBS_COST_ENABLED = conf_bool(
     "extra device flushes and zero extra backend compiles by "
     "construction")
 OBS_COST_PEAK_TFLOPS = conf_float(
-    "spark.rapids.tpu.obs.cost.peakTeraflops", 275.0,
-    "Declared peak dense compute rate of one accelerator core in "
+    "spark.rapids.tpu.obs.cost.peakTeraflops", 0.0,
+    "Explicit override of the peak dense compute rate of one chip in "
     "TFLOP/s — the roofline ceiling achieved FLOP/s is scored "
-    "against.  The default matches a TPU v4-class part; override per "
-    "deployment (and on the CPU test mesh it is a model constant, "
-    "not a measurement).  With peakHbmGBps it fixes the ridge "
-    "intensity that splits compute_bound from memory_bound verdicts")
+    "against.  0 (default) takes the published figure for the "
+    "running device_kind from spark_rapids_tpu/device_peaks.py (TPU "
+    "v5e: 197 bf16 TFLOP/s); a device missing from that table is an "
+    "error, not a default, and the CPU test mesh uses a named model "
+    "constant, not a measurement.  With peakHbmGBps it fixes the "
+    "ridge intensity that splits compute_bound from memory_bound "
+    "verdicts; every cost block names its peak_source")
 OBS_COST_PEAK_HBM_GBPS = conf_float(
-    "spark.rapids.tpu.obs.cost.peakHbmGBps", 1200.0,
-    "Declared peak HBM bandwidth of one accelerator core in GB/s — "
-    "the roofline memory ceiling.  Programs whose arithmetic "
-    "intensity (flops per byte accessed) falls below "
-    "peakTeraflops*1e3/peakHbmGBps are verdicted memory_bound")
+    "spark.rapids.tpu.obs.cost.peakHbmGBps", 0.0,
+    "Explicit override of the peak HBM bandwidth of one chip in GB/s "
+    "— the roofline memory ceiling.  0 (default) takes the published "
+    "figure for the running device_kind from "
+    "spark_rapids_tpu/device_peaks.py (TPU v5e: 819 GB/s).  Programs "
+    "whose arithmetic intensity (flops per byte accessed) falls "
+    "below peakTeraflops*1e3/peakHbmGBps are verdicted memory_bound")
 OBS_COST_MAX_RECORDS = conf_int(
     "spark.rapids.tpu.obs.cost.maxRecords", 256,
     "Bound on retained (program, bucket) static-cost records and on "
@@ -877,21 +881,25 @@ AOT_BUCKET_RATIO = conf_int(
     "for, trading up to 4x padding waste for executable reuse")
 AOT_CACHE_DIR = conf_str(
     "spark.rapids.tpu.compile.aot.cacheDir", "",
-    "Directory for the persistent executable cache.  When set, the "
-    "JAX persistent compilation cache is pointed here (so a fresh "
-    "process deserializes prior XLA executables instead of "
-    "recompiling) and compile/aot.py keeps a manifest keyed by "
-    "(program id, bucket, dtype tuple, conf fingerprint) so "
-    "first-calls satisfied by the cache are counted as persistent "
-    "hits, not new compiles.  Empty = in-process caching only")
-AOT_XLA_CACHE = conf_bool(
+    "Directory for the AOT manifest.  When set, compile/aot.py keeps "
+    "a manifest here keyed by (program id, bucket, dtype tuple, conf "
+    "fingerprint) so first-calls satisfied by the JAX persistent "
+    "compilation cache are counted as persistent hits, not new "
+    "compiles.  It does NOT place the XLA cache itself: that "
+    "directory is JAX_COMPILATION_CACHE_DIR when set, else the fixed "
+    "<checkout>/.jax_cache (compile/xla_cache.py), and the manifest "
+    "only vouches for the XLA cache directory it was written "
+    "against.  Empty = no manifest")
+AOT_PERSIST_EVERY_PROGRAM = conf_bool(
     "spark.rapids.tpu.compile.aot.xlaCache.enabled", True,
-    "Wire the JAX/XLA persistent compilation cache to aot.cacheDir "
-    "(jax_compilation_cache_dir with the min-compile-time and "
-    "min-entry-size thresholds dropped to zero so every engine "
-    "program persists).  Off keeps the manifest bookkeeping without "
-    "touching the JAX cache config — the escape hatch for platforms "
-    "where cross-process executable deserialization misbehaves")
+    "With aot.cacheDir set, drop the JAX persistent compilation "
+    "cache's min-compile-time and min-entry-size thresholds to zero "
+    "so every engine program persists — what lets a manifest entry "
+    "from an earlier run count the first call as a cache load.  Off "
+    "keeps the manifest bookkeeping without touching the JAX cache "
+    "config (no persistent-hit claims) — the escape hatch for "
+    "platforms where cross-process executable deserialization "
+    "misbehaves")
 AOT_WARMUP_ENABLED = conf_bool(
     "spark.rapids.tpu.compile.aot.warmup.enabled", True,
     "Admission-aware warmup daemon (service/warmup.py): a "

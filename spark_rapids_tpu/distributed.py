@@ -14,6 +14,13 @@ Failure handling (the lineage-recompute role): a dead executor surfaces
 ``ShuffleFetchFailedError`` from the reduce-side iterator; the runner
 recovers by re-planning and re-running the map stage locally — Spark's
 stage-retry semantics with the driver as the only surviving executor.
+
+One process per chip: an accelerator belongs to the process that first
+touched JAX, so a child that asked for it would fail or hang behind its
+parent.  The child executor therefore runs on the CPU backend unless
+``SPARK_RAPIDS_TPU_DIST_PLATFORM`` names another platform — on a
+one-chip host CPU is the only thing that can work — and it prints the
+platform it actually got rather than leaving it implicit.
 """
 from __future__ import annotations
 
@@ -59,9 +66,12 @@ def _child_executor_main(sql: str, tables: Dict[str, str], q_out, q_in):
     until the parent says stop."""
     try:
         import jax
-        if os.environ.get("SPARK_RAPIDS_TPU_DIST_PLATFORM", "cpu") \
-                == "cpu":
-            jax.config.update("jax_platforms", "cpu")
+        jax.config.update(
+            "jax_platforms",
+            os.environ.get("SPARK_RAPIDS_TPU_DIST_PLATFORM", "cpu"))
+        platform = jax.devices()[0].platform
+        print(f"[exec-child pid={os.getpid()}] platform={platform}",
+              flush=True)
         from .shuffle.manager import MapOutputTracker, \
             ShuffleExecutorContext
         from .shuffle.tcp import TcpTransport

@@ -11,6 +11,7 @@ mirroring GpuMetric (GpuExec.scala:27-237).
 """
 from __future__ import annotations
 
+import logging
 import time
 from typing import Dict, Iterator, List
 
@@ -32,6 +33,11 @@ JOIN_TIME = "joinTime"
 BUILD_TIME = "buildTime"
 PARTITION_TIME = "partitionTime"
 SPILL_BYTES = "spillData"
+#: mesh SPMD programs whose receive region overflowed and re-ran on the
+#: in-process path (data-dependent, so not an error — but it must show)
+MESH_OVERFLOW_FALLBACKS = "meshOverflowFallbacks"
+#: distinct devices holding a mesh exec's sharded input
+MESH_INPUT_DEVICES = "meshInputDevices"
 
 
 class Metric:
@@ -105,6 +111,22 @@ class MetricSet:
             if rank[m.level] <= mx:
                 out[name] = m.value
         return out
+
+
+def note_mesh_input(node: "PhysicalPlan", sharded) -> None:
+    """Record on how many distinct devices a mesh exec's re-placed
+    input really landed (shard metadata only — no device sync)."""
+    node.metrics[MESH_INPUT_DEVICES].value = len(
+        {s.device.id for s in sharded.addressable_shards})
+
+
+def note_mesh_overflow(node: "PhysicalPlan") -> None:
+    """A mesh exec's SPMD program reported overflow and is about to
+    re-run in process: count it on the node and say so."""
+    node.metrics[MESH_OVERFLOW_FALLBACKS] += 1
+    logging.getLogger("spark_rapids_tpu.exec.mesh").warning(
+        "%s: receive region overflowed; re-running on the in-process "
+        "path", node.name)
 
 
 class timed:

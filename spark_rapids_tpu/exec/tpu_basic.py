@@ -46,8 +46,7 @@ class TpuLocalScan(TpuExec):
         return self.num_partitions
 
     # host->device uploads dominate repeated queries over the same local
-    # table (remote-dispatch transfer bandwidth is the scarce resource),
-    # so uploaded batches are kept device-resident per source table —
+    # table, so uploaded batches are kept device-resident per source table —
     # a small LRU so HBM stays bounded.
     _DEVICE_CACHE: "OrderedDict" = None
     # concurrent scans (pipelined drains + concurrent service queries)

@@ -35,7 +35,8 @@ from ..obs import compile_watch as _compile_watch
 from ..obs import timeline as _timeline
 from ..obs.registry import compile_cache_event
 from ..parallel.mesh import MIX, _route_to_owners, make_mesh
-from .base import PhysicalPlan, AGG_TIME, NUM_OUTPUT_ROWS, timed
+from .base import (PhysicalPlan, AGG_TIME, NUM_OUTPUT_ROWS, timed,
+                   note_mesh_input, note_mesh_overflow)
 from .tpu_basic import TpuExec
 
 _AXIS = "data"
@@ -253,6 +254,7 @@ class TpuMeshAggregate(TpuExec):
             from ..analysis import residency  # lazy: avoids import cycle
             with residency.declared_transfer(site="mesh_reshard"):
                 flat = [jax.device_put(a, sharding) for a in flat]
+            note_mesh_input(self, flat[0])
 
             program = self._program(mesh, len(key_cols),
                                     [c.dtype for c in key_cols],
@@ -267,6 +269,7 @@ class TpuMeshAggregate(TpuExec):
             if overflow:
                 # receive region overflowed: rerun via the in-process
                 # aggregate on the materialized input (loud fallback)
+                note_mesh_overflow(self)
                 from .tpu_aggregate import TpuHashAggregate
 
                 class _One(PhysicalPlan):

@@ -41,11 +41,13 @@ from ..obs import compile_watch as _compile_watch
 from ..obs import timeline as _timeline
 from ..obs.registry import compile_cache_event
 from ..parallel.mesh import MIX, _route_to_owners, make_mesh
-from .base import PhysicalPlan, JOIN_TIME, NUM_OUTPUT_ROWS, timed
+from .base import (PhysicalPlan, JOIN_TIME, NUM_OUTPUT_ROWS, timed,
+                   note_mesh_input, note_mesh_overflow)
 from .tpu_basic import TpuExec
 from .tpu_mesh_aggregate import _SINGLE_WORD
 
 _AXIS = "data"
+_JOIN_HASH_FINAL = 0xD6E8FEB86659FD93
 
 _MESH_JOIN_TYPES = ("inner", "left", "right", "semi", "anti")
 
@@ -130,6 +132,14 @@ class TpuMeshShuffledJoin(TpuExec):
             h = jnp.zeros_like(words[0])
             for w in words:
                 h = (h ^ w) * jnp.uint64(MIX)
+            # finalized apart from the mesh aggregate's owner hash (the
+            # same fold): a join fed by a mesh aggregate on the same key
+            # would otherwise find every shard's rows bound for ONE
+            # owner, overflow its slack-2 receive region and re-run in
+            # process every time
+            h = h ^ (h >> jnp.uint64(29))
+            h = h * jnp.uint64(_JOIN_HASH_FINAL)
+            h = h ^ (h >> jnp.uint64(32))
             owner = (h >> jnp.uint64(33)) % jnp.uint64(n_dev)
             owner = jnp.where(live, owner.astype(jnp.int32), n_dev)
             payload = list(words) + list(datas) + list(valids)
@@ -308,6 +318,7 @@ class TpuMeshShuffledJoin(TpuExec):
             from ..analysis import residency  # lazy: avoids import cycle
             with residency.declared_transfer(site="mesh_reshard"):
                 flat = [jax.device_put(a, sharding) for a in flat]
+            note_mesh_input(self, flat[0])
 
             program = self._program(mesh, prog_jt, key_groups,
                                     l_dts, r_dts, emit_right)
@@ -319,6 +330,7 @@ class TpuMeshShuffledJoin(TpuExec):
             with residency.declared_transfer(site="mesh_collect"):
                 overflowed = bool(np.asarray(out[-1]).any())
             if overflowed:
+                note_mesh_overflow(self)
                 yield from self._fallback(lbatch, rbatch, swapped)
                 return
             with residency.declared_transfer(site="mesh_collect"):

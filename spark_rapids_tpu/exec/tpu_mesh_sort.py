@@ -41,7 +41,8 @@ from ..kernels import canon
 from ..kernels import join as join_k
 from ..kernels.sort import sort_permutation, sorted_words
 from ..parallel.mesh import _route_to_owners, make_mesh
-from .base import PhysicalPlan, SORT_TIME, NUM_OUTPUT_ROWS, timed
+from .base import (PhysicalPlan, SORT_TIME, NUM_OUTPUT_ROWS, timed,
+                   note_mesh_input, note_mesh_overflow)
 from .tpu_basic import TpuExec
 from .tpu_mesh_aggregate import _SINGLE_WORD
 
@@ -202,6 +203,7 @@ class TpuMeshSort(TpuExec):
             from ..analysis import residency  # lazy: avoids import cycle
             with residency.declared_transfer(site="mesh_reshard"):
                 flat = [jax.device_put(a, sharding) for a in flat]
+            note_mesh_input(self, flat[0])
 
             program = self._program(
                 mesh, len(key_cols), [c.dtype for c in key_cols],
@@ -216,6 +218,7 @@ class TpuMeshSort(TpuExec):
             if overflowed:
                 # skewed splitters overflowed a receive region: loud
                 # fallback to the in-process out-of-core sort
+                note_mesh_overflow(self)
                 from .tpu_sort import TpuSort
 
                 class _One(PhysicalPlan):

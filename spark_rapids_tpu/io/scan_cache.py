@@ -2,9 +2,8 @@
 
 Reference analogue: ParquetCachedBatchSerializer (the reference caches
 columnar batches so repeat reads skip decode) — applied here at the
-scan, and kept ON DEVICE: on a remote-dispatch backend the
-host->device transfer is the scarcest resource, so re-uploading the
-same immutable file data every query dominates short queries.  Batches
+scan, and kept ON DEVICE: re-decoding and re-uploading the same
+immutable file data every query dominates short queries.  Batches
 are immutable (functional JAX arrays), so sharing them across queries
 is safe.
 

@@ -1,102 +1,28 @@
 """Pallas TPU kernels for hot ops XLA doesn't fuse well.
 
-Reference analogue: the hand-written CUDA kernels inside libcudf that the
-plugin leans on for hashing/partitioning (GpuHashPartitioning ->
-murmur3 + contiguousSplit).  Here the fused hash+partition-id kernel is
-written in Pallas so the multi-word mixing chain stays in VMEM in one
-pass instead of N elementwise HLOs round-tripping through HBM.
+Reference analogue: the hand-written CUDA kernels inside libcudf that
+the plugin leans on.  The one kernel here is the bucket-table reduce of
+the sort-free group-by.  (A fused hash+partition-id kernel used to live
+here too: it mixed uint64 lanes, which Mosaic does not lower on TPU
+v5e — the 64-bit multiply constant fails in ``ir_constant`` — so it
+went; XLA fuses the plain ``jnp`` mixing chain in
+shuffle/partitioners.py into one program anyway.)
 
-Falls back to interpret mode off-TPU (CPU tests) and to the plain jnp
-path on any Pallas failure — behavior is identical by construction.
+Every kernel compiles through Mosaic on an accelerator and a lowering
+failure raises.  Interpret mode is for the CPU test backend only, never
+for "anything not called tpu", and there is no silent ``jnp`` fallback.
 """
 from __future__ import annotations
 
 import functools
-from typing import List
 
 import jax
 import jax.numpy as jnp
 
-from ..obs import compile_watch as _compile_watch
-from ..obs.registry import compile_cache_event
-from .basic import M1, M2, mix64, hash_words as _hash_words_jnp
 
-_BLOCK = 1024
-
-
-def _mix_body(h, w):
-    x = h ^ w
-    x = x ^ (x >> jnp.uint64(33))
-    x = x * jnp.uint64(M1)
-    x = x ^ (x >> jnp.uint64(33))
-    x = x * jnp.uint64(M2)
-    x = x ^ (x >> jnp.uint64(33))
-    return x
-
-
-def _make_kernel(num_words: int, num_parts: int):
-    from jax.experimental import pallas as pl
-
-    def kernel(*refs):
-        word_refs = refs[:num_words]
-        out_ref = refs[num_words]
-        h = jnp.full(word_refs[0].shape, jnp.uint64(42))
-        for wr in word_refs:
-            h = _mix_body(h, wr[...])
-        out_ref[...] = (h % jnp.uint64(num_parts)).astype(jnp.int32)
-
-    @functools.partial(jax.jit, static_argnames=())
-    def run(*words):
-        n = words[0].shape[0]
-        grid = (n // _BLOCK,) if n % _BLOCK == 0 and n >= _BLOCK else None
-        interpret = jax.default_backend() != "tpu"
-        if grid is None:
-            return pl.pallas_call(
-                kernel,
-                out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-                interpret=interpret,
-            )(*words)
-        spec = pl.BlockSpec((_BLOCK,), lambda i: (i,))
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[spec] * num_words,
-            out_specs=spec,
-            out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-            interpret=interpret,
-        )(*words)
-
-    return run
-
-
-_KERNEL_CACHE = {}
-
-
-def hash_partition_ids(word_lists: List[jnp.ndarray],
-                       num_parts: int) -> jnp.ndarray:
-    """Fused murmur-mix + mod over N key words -> partition id per row.
-
-    Pallas fast path with jnp fallback (identical math either way).
-    """
-    key = (len(word_lists), num_parts)
-    from ..compile import aot as _aot
-    _aot.note_demand("pallas_hash_partition", word_lists[0].shape[0])
-    try:
-        if key not in _KERNEL_CACHE:
-            compile_cache_event("pallas_hash_partition", False)
-            _KERNEL_CACHE[key] = _compile_watch.wrap_miss(
-                "pallas_hash_partition", _make_kernel(*key), str(key))
-            kfn, nw = _KERNEL_CACHE[key], key[0]
-            def _warm(bucket: int) -> None:
-                kfn(*[jnp.zeros(bucket, jnp.uint64) for _ in range(nw)])
-            _aot.register_warmer("pallas_hash_partition", _warm,
-                                 str(key))
-        else:
-            compile_cache_event("pallas_hash_partition", True)
-        return _KERNEL_CACHE[key](*word_lists)
-    except Exception:
-        h = _hash_words_jnp(word_lists)
-        return (h % jnp.uint64(num_parts)).astype(jnp.int32)
+def interpret_mode() -> bool:
+    """Pallas interpret mode: true only on the CPU (test) backend."""
+    return jax.default_backend() == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -105,8 +31,7 @@ def hash_partition_ids(word_lists: List[jnp.ndarray],
 # values into `table` buckets with a per-row op ('sum' | 'max').
 #
 # Why Pallas: XLA lowers the equivalent one-hot einsum to a convolution
-# that MATERIALIZES the (n, table) one-hot in HBM (measured 39 GB of
-# traffic at n=1M, table=4096).  Here the one-hot tile lives only in
+# that MATERIALIZES the (n, table) one-hot in HBM.  Here the one-hot tile lives only in
 # VMEM: sums ride the MXU as (rows, C) @ (C, Gt) dots, maxes are VPU
 # masked reductions, and HBM traffic is just inputs x (table/Gt) passes.
 # Reference analogue: the hand-rolled cuDF hash-aggregate kernels.
@@ -114,8 +39,8 @@ def hash_partition_ids(word_lists: List[jnp.ndarray],
 
 _TR_C = 512      # chunk columns (x8 chunk-rows = 4096 rows per step)
 _TR_G = 512      # bucket chunk for the in-kernel one-hot loop
-# VMEM budget: the transient one-hot chunk is (4096, 512) f32 = 8 MB,
-# reused across the g-loop; accumulators are (rows, table) f32 = <100 KB.
+# VMEM budget: the transient one-hot tile is (_TR_G, _TR_C) f32 = 1 MB,
+# reused across the loops; accumulators are (rows, table) f32 = <100 KB.
 
 
 def _z(i):
@@ -221,6 +146,7 @@ def _table_reduce_tpu_32(bucket, sums_in, maxs_in, table: int, nsum: int,
             jax.ShapeDtypeStruct((max(nsum, 1), gt), jnp.float32),
             jax.ShapeDtypeStruct((max(nmax, 1), gt), jnp.float32),
         ],
+        interpret=interpret_mode(),
     )(bucket2, sums2, maxs2)
     return sum_out, max_out
 
@@ -235,15 +161,18 @@ def table_reduce(bucket, sum_rows, max_rows, table: int,
     maxs: list of f32[table]).
 
     impl='scatter' (default): one multi-column XLA scatter-add for all
-    sum rows + per-row scatter-max — measured ~80ms/4M rows on v5e, and
-    the multi-column scatter costs the same as a single-column one.
-    impl='pallas': the hand-written one-hot MXU kernel above — currently
-    slower (~150ms/4M: Mosaic's scoped-VMEM limit forces small dot
-    tiles whose loop overhead dominates); kept selectable via
-    spark.rapids.tpu.sql.agg.tableReduceImpl for kernel tuning work.
+    sum rows + per-row scatter-max (the multi-column scatter costs the
+    same as a single-column one).  Smoke timing on one TPU v5e chip at
+    n=4M, table=4096, 2 sum rows + 1 max row: scatter 56 ms, pallas
+    112 ms (one warm call each, PR 22 chip probe; not a benchmark).
+    impl='pallas': the hand-written one-hot MXU kernel above — compiles
+    through Mosaic on v5e and is currently slower (Mosaic's scoped-VMEM
+    limit forces small dot tiles whose loop overhead dominates); kept
+    selectable via spark.rapids.tpu.sql.agg.tableReduceImpl for kernel
+    tuning work.  Interpreted on the CPU test backend only.
     """
     nsum, nmax = len(sum_rows), len(max_rows)
-    if impl == "pallas" and jax.default_backend() == "tpu":
+    if impl == "pallas":
         sums_in = jnp.stack(sum_rows, 0) if nsum else \
             jnp.zeros((1, bucket.shape[0]), jnp.float32)
         maxs_in = jnp.stack(max_rows, 0) if nmax else \
@@ -261,28 +190,3 @@ def table_reduce(bucket, sum_rows, max_rows, table: int,
     maxs = [jax.ops.segment_max(r, bucket, num_segments=table + 1)[:table]
             for r in max_rows]
     return sums, maxs
-
-
-# ---------------------------------------------------------------------------
-# program audit registration (analysis/program_audit.py)
-# ---------------------------------------------------------------------------
-
-def _audit_specs():
-    from ..analysis.program_audit import AuditSpec
-
-    def _build():
-        import jax
-        import numpy as np
-        key = (1, 8)
-        fn = _KERNEL_CACHE.get(key)
-        if fn is None:
-            fn = _compile_watch.wrap_miss(
-                "pallas_hash_partition", _make_kernel(*key), str(key))
-            _KERNEL_CACHE[key] = fn
-        args = (jax.ShapeDtypeStruct((256,), np.uint64),)
-        return fn, args, {}
-
-    return [AuditSpec(
-        "pallas_hash_partition", "pallas_hash_partition", _build,
-        notes="1 key word -> 8 partitions over a 256-row block",
-        budgets={"gather": 2, "scatter": 2, "transpose": 2, "sort": 1})]

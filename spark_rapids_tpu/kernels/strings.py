@@ -139,7 +139,7 @@ def gather_strings(offsets, data, validity, indices, live=None,
 
     Sizing the output byte buffer needs a host-known bound.  The
     default is the exact total — one device sync per gather (a full
-    dispatch-queue round trip on remote backends).  Two SYNC-FREE
+    dispatch-queue drain).  Two SYNC-FREE
     static bounds are used when available:
 
     - ``unique=True``: every live output lane reads a distinct source

@@ -11,8 +11,8 @@ Reference roles:
 TPU adaptation: XLA/PJRT owns the physical HBM allocator, so the arena
 tracks logical live bytes and enforces the budget by spilling catalog
 buffers before admitting new ones (``reserve``).  On real TPU backends the
-HBM size is read from the device; on CPU test backends a configurable
-default is used.
+HBM size is read from the device (a device that does not report it is
+an error); the CPU test mesh budgets against device_peaks.CPU_TEST_MESH.
 """
 from __future__ import annotations
 
@@ -26,6 +26,7 @@ import jax
 from ..config import (TpuConf, get_active, HBM_POOL_FRACTION, HBM_RESERVE,
                       CONCURRENT_TPU_TASKS, HOST_SPILL_LIMIT, SPILL_DIR,
                       SHUFFLE_COMPRESS)
+from ..device_peaks import CPU_TEST_MESH
 from ..obs import flight as _flight
 from ..obs import trace as _trace
 from ..obs.registry import SEM_WAIT_SECONDS
@@ -203,16 +204,22 @@ class DeviceManager:
 
     def __init__(self, conf: Optional[TpuConf] = None):
         conf = conf or get_active()
-        self.device = None
-        hbm_total = 16 << 30  # conservative default (v5e has 16 GiB/chip)
-        try:
-            devs = jax.devices()
-            self.device = devs[0]
-            stats = getattr(self.device, "memory_stats", lambda: None)()
-            if stats and "bytes_limit" in stats:
-                hbm_total = stats["bytes_limit"]
-        except Exception:
-            pass
+        # a failed device query propagates: an engine that carries on
+        # with no device and a guessed budget hides exactly the fault
+        # a bring-up needs to see
+        self.device = jax.devices()[0]
+        stats = self.device.memory_stats()
+        if stats and "bytes_limit" in stats:
+            hbm_total = stats["bytes_limit"]
+        elif self.device.platform == "cpu":
+            # the CPU backend reports no memory stats: the virtual test
+            # mesh budgets against a named stand-in, not a measurement
+            hbm_total = CPU_TEST_MESH.hbm_bytes
+        else:
+            raise RuntimeError(
+                f"{self.device.platform} device {self.device.device_kind!r} "
+                f"reports no bytes_limit in memory_stats() ({stats!r}); "
+                f"refusing to guess its HBM size")
         frac = conf.get(HBM_POOL_FRACTION)
         reserve = conf.get(HBM_RESERVE)
         device_limit = max(int(hbm_total * frac) - reserve, 1 << 30)

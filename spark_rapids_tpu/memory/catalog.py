@@ -90,15 +90,13 @@ class BufferCatalog:
         self.raw_cache_bytes = 0
         from ..shuffle.compression import get_codec
         self.codec = get_codec(compression)
-        # native host slab arena for the HOST tier (pinned-pool role);
-        # graceful fallback to python-heap payloads if the build fails
+        # native host slab arena for the HOST tier (pinned-pool role).
+        # A failed g++ build or load raises: python-heap payloads are
+        # what use_native_arena=False asks for, not a silent downgrade
         self.arena = None
         if use_native_arena:
-            try:
-                from ..native import HostArena
-                self.arena = HostArena(min(host_limit, 2 << 30))
-            except Exception:
-                self.arena = None
+            from ..native import HostArena
+            self.arena = HostArena(min(host_limit, 2 << 30))
 
     @classmethod
     def get(cls) -> "BufferCatalog":

@@ -25,10 +25,18 @@ def _build() -> str:
     if os.path.exists(_SO) and all(
             os.path.getmtime(_SO) >= os.path.getmtime(s) for s in _SRCS):
         return _SO
+    # per-process temp name: two processes building at once (a spawned
+    # executor child next to its parent) must not interleave one file
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", *_SRCS, "-o",
-           _SO + ".tmp"]
-    subprocess.run(cmd, check=True, capture_output=True)
-    os.replace(_SO + ".tmp", _SO)
+           tmp]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"native library build failed ({' '.join(cmd)}):\n"
+            f"{e.stderr[-2000:]}") from e
+    os.replace(tmp, _SO)
     return _SO
 
 
@@ -109,6 +117,13 @@ class HostArena:
         base = self._lib.arena_base(self._h)
         self._np = np
         self._view = (ctypes.c_uint8 * self.capacity).from_address(base)
+        # every numpy view handed out by view() has this ctypes array as
+        # its base, and the array does not own the slab: tie the arena's
+        # lifetime to it, so the slab is freed only after the last view
+        # (or anything aliasing one) is gone — a session re-init used to
+        # free it under a pipeline worker still holding a view (flaky
+        # segfault in the test suite)
+        self._view._owner = self
 
     @property
     def capacity(self) -> int:

@@ -71,10 +71,11 @@ SOURCE_XLA = "xla"
 SOURCE_STATIC = "static"
 
 _ENABLED = True
-#: conf-declared peak rates (roofline ceilings); defaults match the
-#: conf defaults in config.py (a TPU v4-class part)
-_PEAK_FLOPS = 275.0e12
-_PEAK_BYTES = 1200.0e9
+#: explicit conf overrides of the roofline ceilings (FLOP/s, bytes/s);
+#: None = take that ceiling from device_peaks.TABLE by device_kind
+_PEAK_CONF: Tuple[Optional[float], Optional[float]] = (None, None)
+#: resolved (FLOP/s, bytes/s, source) for the current _PEAK_CONF
+_PEAKS: Optional[Tuple[float, float, str]] = None
 _MAX_RECORDS = 256
 
 _LOCK = threading.Lock()
@@ -120,7 +121,7 @@ _CLASS_CACHES = (
     ("join", ("join_probe", "join_spec_probe", "mesh_join")),
     ("aggregate", ("hash_aggregate", "mesh_aggregate")),
     ("agg", ("hash_aggregate", "mesh_aggregate")),
-    ("exchange", ("pallas_hash_partition", "exchange_stats")),
+    ("exchange", ("exchange_stats",)),
     ("filter", ("fused_project",)),
     ("project", ("fused_project",)),
     ("scan", ("fused_project",)),
@@ -318,10 +319,39 @@ def note_dispatch(cache: str, capacity: int,
 # roofline model
 # ---------------------------------------------------------------------------
 
+def peaks() -> Tuple[float, float, str]:
+    """The roofline ceilings as (FLOP/s, bytes/s, source).
+
+    Both conf keys set -> ``"conf"``.  Otherwise the published row for
+    this process's device (device_peaks.TABLE keyed by ``device_kind``;
+    the CPU test mesh borrows a named model constant) fills whichever
+    ceiling the conf leaves open.  An accelerator missing from the
+    table raises ``device_peaks.UnknownDeviceError``: a share against
+    another chip's ceiling is a wrong number that looks right."""
+    global _PEAKS
+    if _PEAKS is None:
+        flops, byts = _PEAK_CONF
+        if flops is not None and byts is not None:
+            _PEAKS = (flops, byts, "conf")
+        else:
+            import jax
+            from .. import device_peaks
+            row = device_peaks.lookup(jax.devices()[0])
+            source = f"device_table:{row.device_kind}"
+            if flops is not None or byts is not None:
+                source += "+conf"
+            _PEAKS = (flops if flops is not None
+                      else row.bf16_tflops * 1e12,
+                      byts if byts is not None else row.hbm_gbps * 1e9,
+                      source)
+    return _PEAKS
+
+
 def ridge_intensity() -> float:
     """flops/byte at the roofline ridge: programs below it cannot
     reach peak FLOP/s no matter how good the kernel is."""
-    return _PEAK_FLOPS / _PEAK_BYTES if _PEAK_BYTES > 0 else 0.0
+    flops, byts, _src = peaks()
+    return flops / byts
 
 
 def roofline_verdict(flops: float, byts: float) -> str:
@@ -338,8 +368,8 @@ def _t_est_s(flops: float, byts: float) -> float:
     """Roofline execution-time estimate: the binding ceiling's wall
     seconds.  Floor keeps zero-cost records from vanishing out of the
     busy apportionment."""
-    t = max(flops / _PEAK_FLOPS if _PEAK_FLOPS > 0 else 0.0,
-            byts / _PEAK_BYTES if _PEAK_BYTES > 0 else 0.0)
+    peak_flops, peak_bytes, _src = peaks()
+    t = max(flops / peak_flops, byts / peak_bytes)
     return t if t > 0.0 else 1e-12
 
 
@@ -466,9 +496,7 @@ def query_summary(marker, busy_ms: Optional[float] = None
         "memory_share_pct": mem_pct,
         "uncosted_dispatches": uncosted,
         "costed_records": len(costs),
-        "peak_tflops": round(_PEAK_FLOPS / 1e12, 3),
-        "peak_gbps": round(_PEAK_BYTES / 1e9, 3),
-        "ridge_intensity": round(ridge_intensity(), 3),
+        **_peaks_section(),
         "model_version": MODEL_VERSION,
         "digest": stable_digest(),
     }
@@ -555,6 +583,16 @@ def coverage_gaps(required=None) -> List[str]:
 # surfaces
 # ---------------------------------------------------------------------------
 
+def _peaks_section() -> Dict[str, Any]:
+    """The ceilings every cost block states, with where they came
+    from (conf override or the device table row)."""
+    flops, byts, source = peaks()
+    return {"peak_tflops": round(flops / 1e12, 3),
+            "peak_gbps": round(byts / 1e9, 3),
+            "peak_source": source,
+            "ridge_intensity": round(flops / byts, 3)}
+
+
 def stable_digest() -> str:
     """sha256 over the timing-independent cost MODEL only: version,
     declared peak rates, ridge, verdict + waste rules.  The captured
@@ -562,11 +600,12 @@ def stable_digest() -> str:
     (superstage on/off compiles different programs) and are excluded —
     same conf x same model -> same digest across pipeline parallelism
     {1,4} x superstage on/off."""
+    peak_flops, peak_bytes, _src = peaks()
     payload = {
         "model_version": MODEL_VERSION,
-        "peak_flops": _PEAK_FLOPS,
-        "peak_bytes": _PEAK_BYTES,
-        "ridge_intensity": ridge_intensity(),
+        "peak_flops": peak_flops,
+        "peak_bytes": peak_bytes,
+        "ridge_intensity": peak_flops / peak_bytes,
         "verdict_rule": "intensity_vs_ridge",
         "waste_rule": "1_minus_rows_over_capacity_rows_known_only",
     }
@@ -623,9 +662,7 @@ def stats_section() -> Dict[str, Any]:
         "captures": captures,
         "programs_costed": costed_programs(),
         "padding_waste_pct": process_waste_pct(),
-        "peak_tflops": round(_PEAK_FLOPS / 1e12, 3),
-        "peak_gbps": round(_PEAK_BYTES / 1e9, 3),
-        "ridge_intensity": round(ridge_intensity(), 3),
+        **_peaks_section(),
         "last_query": last or None,
         "model_version": MODEL_VERSION,
         "digest": stable_digest(),
@@ -643,16 +680,17 @@ def enabled(conf) -> bool:
 
 def configure(conf) -> None:
     """Apply the ``spark.rapids.tpu.obs.cost.*`` conf group."""
-    global _ENABLED, _PEAK_FLOPS, _PEAK_BYTES, _MAX_RECORDS
+    global _ENABLED, _PEAK_CONF, _PEAKS, _MAX_RECORDS
     from ..config import (OBS_COST_ENABLED, OBS_COST_MAX_RECORDS,
                           OBS_COST_PEAK_HBM_GBPS, OBS_COST_PEAK_TFLOPS)
     _ENABLED = bool(conf.get(OBS_COST_ENABLED))
     tflops = float(conf.get(OBS_COST_PEAK_TFLOPS))
     gbps = float(conf.get(OBS_COST_PEAK_HBM_GBPS))
-    if tflops > 0:
-        _PEAK_FLOPS = tflops * 1e12
-    if gbps > 0:
-        _PEAK_BYTES = gbps * 1e9
+    # resolved lazily (peaks()): configure must not be what first
+    # touches the backend
+    _PEAK_CONF = (tflops * 1e12 if tflops > 0 else None,
+                  gbps * 1e9 if gbps > 0 else None)
+    _PEAKS = None
     cap = int(conf.get(OBS_COST_MAX_RECORDS))
     if cap > 0:
         _MAX_RECORDS = cap

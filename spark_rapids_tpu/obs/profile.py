@@ -6,8 +6,8 @@ per-operator ``timed()`` spans blind inside exactly the regions that now
 dominate runtime: one opaque span per stage, nothing per member.  This
 module restores attribution WITHOUT adding dispatches or host syncs:
 
-- every pending-pool flush (columnar/pending.py) — THE unit of device
-  round-trip cost on remote-dispatch backends — reports its wall
+- every pending-pool flush (columnar/pending.py) — the unit of device
+  round-trip cost — reports its wall
   duration through a module observer installed at import time;
 - drain loops that own a flush barrier (the superstage drain, the
   exchange map-side finalize, the session's collect sink) declare

@@ -533,8 +533,8 @@ PIPELINE_DRAINS = _REGISTRY.counter(
 
 
 # -- runtime stats plane (obs/stats.py + obs/profile.py) --------------------
-# Buckets sized to the remote-dispatch cost model: one fused flush is a
-# ~65-100ms round trip, so the interesting resolution is 10ms-10s.
+# Buckets span 1ms-10s: a fused flush waits for all queued device work,
+# so its duration ranges from a bare transfer to a whole stage.
 _DISPATCH_BUCKETS = (0.001, 0.005, 0.01, 0.025, 0.05, 0.075, 0.1,
                      0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 #: per-partition row-count buckets for the exchange skew histogram

@@ -67,10 +67,15 @@ _EV_EXCHANGE = "exchange"
 _EV_SCAN = "scan"
 
 # False after the sketch program failed once on this backend: the
-# exchange keeps rows/bytes stats and drops the sketch (same fallback
-# shape as HashPartitioner._SPLIT_JIT's False sentinel).
+# exchange keeps rows/bytes stats and drops the sketch (observability
+# never fails a query; chip_smoke.py fails if this ever reads False).
 _SKETCH_OK = True
 _SKETCH_LOCK = threading.Lock()
+
+
+def sketch_ok() -> bool:
+    """False once the sketch program has failed in this process."""
+    return _SKETCH_OK
 
 
 def enabled(conf=None) -> bool:

@@ -1,83 +1,27 @@
-"""Version-compat shim layer — the ShimLoader role.
+"""JAX touchpoint shim — the ShimLoader role.
 
 Reference: ShimLoader.scala:26 + shims/ (12 modules): every touchpoint
 with version-unstable Spark internals goes through a SparkShims trait
 selected at runtime.  The TPU build's unstable dependency surface is the
 **JAX API** (modules move between jax.experimental and core across
-releases), so the same pattern applies: all version-sensitive JAX access
-goes through the shim selected by version probe, with an override conf
-(spark.rapids.tpu.shims-provider-override) mirroring
+releases), so version-sensitive JAX access goes through one shim class.
+
+There is exactly one provider, for the one JAX this repo is installed
+with (pyproject.toml pins it); an API the next JAX moves is repaired
+here or at its call site, not probed for at run time.  The override
+conf (spark.rapids.tpu.shims-provider-override) mirrors
 spark.rapids.shims-provider-override.
 """
 from __future__ import annotations
 
 import importlib
-from typing import Callable, List, Optional, Type
+from typing import Optional, Type
 
 import jax
 
 
-class JaxShimBase:
-    """Shim interface: every version-sensitive JAX API in one place."""
-
-    version_prefixes: List[str] = []
-
-    @staticmethod
-    def shard_map():
-        raise NotImplementedError
-
-    @staticmethod
-    def pallas():
-        raise NotImplementedError
-
-    @staticmethod
-    def key_array(seed: int):
-        raise NotImplementedError
-
-    @staticmethod
-    def device_memory_stats(device) -> Optional[dict]:
-        try:
-            return device.memory_stats()
-        except Exception:
-            return None
-
-    # -- additional version-sensitive touchpoints (ShimLoader breadth:
-    # every unstable API the engine uses goes through here) -----------
-    @staticmethod
-    def make_mesh(axis_shapes, axis_names):
-        raise NotImplementedError
-
-    @staticmethod
-    def named_sharding(mesh, *pspec):
-        from jax.sharding import NamedSharding, PartitionSpec
-        return NamedSharding(mesh, PartitionSpec(*pspec))
-
-    @staticmethod
-    def tree_map(fn, tree):
-        raise NotImplementedError
-
-    @staticmethod
-    def compilation_cache_dir(path: str):
-        """Point the persistent executable cache at ``path``."""
-        jax.config.update("jax_compilation_cache_dir", path)
-
-    @staticmethod
-    def live_arrays(backend=None):
-        """Device arrays currently alive (leak triage)."""
-        try:
-            return jax.live_arrays()
-        except Exception:
-            return []
-
-    @staticmethod
-    def donate_argnums_supported() -> bool:
-        return True
-
-
-class JaxShim09(JaxShimBase):
-    """jax >= 0.7: shard_map promoted to jax.shard_map."""
-
-    version_prefixes = ["0.7", "0.8", "0.9", "1."]
+class JaxShim:
+    """Every version-sensitive JAX API the engine uses, for jax 0.9."""
 
     @staticmethod
     def shard_map():
@@ -93,55 +37,13 @@ class JaxShim09(JaxShimBase):
         import jax.random as jr
         return jr.key(seed)
 
-    @staticmethod
-    def make_mesh(axis_shapes, axis_names):
-        # jax.make_mesh picks the best device order for the topology
-        return jax.make_mesh(axis_shapes, axis_names)
 
-    @staticmethod
-    def tree_map(fn, tree):
-        return jax.tree.map(fn, tree)
+_active: Optional[Type[JaxShim]] = None
 
 
-class JaxShimLegacy(JaxShimBase):
-    """jax < 0.7: experimental namespaces."""
-
-    version_prefixes = ["0.4", "0.5", "0.6"]
-
-    @staticmethod
-    def shard_map():
-        from jax.experimental.shard_map import shard_map
-        return shard_map
-
-    @staticmethod
-    def pallas():
-        from jax.experimental import pallas as pl
-        return pl
-
-    @staticmethod
-    def key_array(seed: int):
-        import jax.random as jr
-        return jr.PRNGKey(seed)
-
-    @staticmethod
-    def make_mesh(axis_shapes, axis_names):
-        import numpy as _np
-        from jax.sharding import Mesh
-        devs = _np.array(jax.devices()[:int(_np.prod(axis_shapes))])
-        return Mesh(devs.reshape(axis_shapes), axis_names)
-
-    @staticmethod
-    def tree_map(fn, tree):
-        from jax import tree_util
-        return tree_util.tree_map(fn, tree)
-
-
-_PROVIDERS: List[Type[JaxShimBase]] = [JaxShim09, JaxShimLegacy]
-_active: Optional[Type[JaxShimBase]] = None
-
-
-def detect_shim() -> Type[JaxShimBase]:
-    """ShimLoader.detectShimProvider role: probe the runtime version."""
+def detect_shim() -> Type[JaxShim]:
+    """ShimLoader.detectShimProvider role: the override conf, else the
+    provider for the installed JAX."""
     global _active
     if _active is not None:
         return _active
@@ -150,27 +52,10 @@ def detect_shim() -> Type[JaxShimBase]:
     if override:
         mod, _, cls = override.rpartition(".")
         _active = getattr(importlib.import_module(mod), cls)
-        return _active
-    ver = jax.__version__
-    for p in _PROVIDERS:
-        if any(ver.startswith(v) for v in p.version_prefixes):
-            _active = p
-            return p
-    _active = JaxShim09  # newest as default
+    else:
+        _active = JaxShim
     return _active
 
 
 def get_shard_map():
     return detect_shim().shard_map()
-
-
-def get_pallas():
-    return detect_shim().pallas()
-
-
-def get_make_mesh():
-    return detect_shim().make_mesh
-
-
-def get_tree_map():
-    return detect_shim().tree_map

@@ -2,7 +2,7 @@
 
 The engine memoizes jitted programs at several layers (fused
 expression cores, staged whole-stage programs, join probe/expand
-kernels, aggregate cores, mesh SPMD programs, pallas kernels) keyed on
+kernels, aggregate cores, mesh SPMD programs) keyed on
 (op, schema, capacity bucket).  Long many-query processes on the
 XLA:CPU backend accumulate thousands of live executables; past a
 threshold LLVM's JIT code memory fails hard (segfault on the next
@@ -10,8 +10,8 @@ compile).  ``clear_compile_caches()`` drops every engine-held
 executable reference and JAX's own caches so the arena can be
 reclaimed; subsequent queries simply recompile.
 
-(The TPU path compiles server-side and is not subject to the local
-LLVM arena, but clearing is equally safe there.)
+(The TPU compiler is not subject to the local LLVM arena, but clearing
+is equally safe there.)
 """
 from __future__ import annotations
 
@@ -19,7 +19,6 @@ from __future__ import annotations
 def clear_compile_caches() -> None:
     from ..exec import fused, staged, tpu_aggregate, tpu_join
     from ..exec import tpu_mesh_aggregate, tpu_mesh_join, tpu_mesh_sort
-    from ..kernels import pallas_ops
 
     fused._JIT_CACHE.clear()
     staged.TpuStagedCompute._JIT_CACHE.clear()
@@ -29,7 +28,6 @@ def clear_compile_caches() -> None:
     tpu_mesh_aggregate.TpuMeshAggregate._PROGRAM_CACHE.clear()
     tpu_mesh_join.TpuMeshShuffledJoin._PROGRAM_CACHE.clear()
     tpu_mesh_sort.TpuMeshSort._PROGRAM_CACHE.clear()
-    pallas_ops._KERNEL_CACHE.clear()
 
     import jax
     jax.clear_caches()

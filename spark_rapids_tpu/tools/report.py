@@ -484,6 +484,7 @@ def cost_lines(rec: Dict) -> List[str]:
         f"padding_waste={_fmt(cost.get('padding_waste_pct'))}% "
         f"(peaks {_fmt(cost.get('peak_tflops'))}TF/s,"
         f"{_fmt(cost.get('peak_gbps'))}GB/s "
+        f"from {cost.get('peak_source', 'conf')} "
         f"ridge={_fmt(cost.get('ridge_intensity'))} flop/B)")
     progs = cost.get("programs") or []
     if progs:
@@ -597,8 +598,8 @@ def render_query_report(query_id, story: Dict,
                 f"sem_wait_ms={_fmt(rec.get('sem_wait_ms'))} "
                 f"spill_bytes={_fmt(rec.get('spill_bytes'))}")
         if rec.get("flushes") is not None:
-            # device round trips this query — THE cost model on
-            # remote-dispatch backends (columnar/pending.py)
+            # device round trips this query — the cost model the
+            # planner predicts (columnar/pending.py)
             head += f" flushes={rec.get('flushes')}"
         pred = rec.get("predicted_flushes")
         if pred is not None:

@@ -73,8 +73,6 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     import jax
-    jax.config.update("jax_platforms",
-                      os.environ.get("JAX_PLATFORMS", "cpu"))
     from ..api import TpuSession
     from ..config import TpuConf
     td = tempfile.mkdtemp(prefix="soak_")

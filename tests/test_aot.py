@@ -294,14 +294,14 @@ class TestManifest:
         aot.manifest_add(key, "fused_project", "sig-a", 1024, 12.5)
         assert aot.manifest_entries() == 1
         # same run -> never a persistent hit, even when wired
-        monkeypatch.setattr(aot, "_XLA_CACHE_WIRED", True)
+        monkeypatch.setattr(aot, "_PERSIST_ALL", True)
         assert not aot.persistent_ready(key)
         # simulate a later process: reload manifest under a fresh run id
         monkeypatch.setattr(aot, "_RUN_ID", "another-run")
         aot._load_manifest()
         assert aot.persistent_ready(key)
         # unwired XLA cache -> bookkeeping only, no persistent claims
-        monkeypatch.setattr(aot, "_XLA_CACHE_WIRED", False)
+        monkeypatch.setattr(aot, "_PERSIST_ALL", False)
         assert not aot.persistent_ready(key)
 
     def test_first_call_key_none_without_cache_dir(self):
@@ -316,7 +316,7 @@ class TestManifest:
         }))
         key = aot.manifest_key("fused_project", "sig-p")
         aot.manifest_add(key, "fused_project", "sig-p", 1024, 3.0)
-        monkeypatch.setattr(aot, "_XLA_CACHE_WIRED", True)
+        monkeypatch.setattr(aot, "_PERSIST_ALL", True)
         monkeypatch.setattr(aot, "_RUN_ID", "later-run")
         aot._load_manifest()
         wrapped = compile_watch.wrap_miss(
@@ -478,7 +478,10 @@ class TestPersistentCacheAcrossProcesses:
         import tpcds
         tpcds.generate(data_dir, scale=0.002, seed=11)
         cache_dir = str(tmp_path / "aot_cache")
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        # the children's XLA cache goes where the environment says
+        # (compile/xla_cache.py); aot.cacheDir holds the manifest
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=cache_dir)
 
         def run_child():
             out = subprocess.run(

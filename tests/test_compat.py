@@ -6,7 +6,7 @@ round-trip, and api_validation/ (reflection audit of API parity).
 import numpy as np
 import pytest
 
-from spark_rapids_tpu.shims import detect_shim, get_shard_map, JaxShim09
+from spark_rapids_tpu.shims import detect_shim, get_shard_map, JaxShim
 from spark_rapids_tpu.shuffle.compression import get_codec
 from spark_rapids_tpu.memory.catalog import BufferCatalog, StorageTier
 from spark_rapids_tpu.memory.spillable import SpillableBatch
@@ -14,11 +14,21 @@ from spark_rapids_tpu.columnar import ColumnarBatch
 
 
 class TestShims:
-    def test_detects_current_jax(self):
-        shim = detect_shim()
-        assert shim is not None
-        sm = get_shard_map()
-        assert callable(sm)
+    def test_one_provider_for_the_installed_jax(self):
+        import jax
+        import spark_rapids_tpu.shims as shims
+        assert detect_shim() is JaxShim
+        assert get_shard_map() is jax.shard_map
+        # no provider for a JAX that is not installed
+        providers = [n for n in vars(shims) if n.startswith("JaxShim")]
+        assert providers == ["JaxShim"]
+
+    def test_pyproject_pins_the_installed_jax(self):
+        import os
+        import jax
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "pyproject.toml")) as f:
+            assert f'"jax=={jax.__version__}"' in f.read()
 
     def test_key_array(self):
         k = detect_shim().key_array(7)

@@ -163,8 +163,8 @@ class TestCapture:
 
 class TestRoofline:
     def test_ridge_is_peak_ratio(self):
-        assert costplane.ridge_intensity() == pytest.approx(
-            costplane._PEAK_FLOPS / costplane._PEAK_BYTES)
+        flops, byts, _source = costplane.peaks()
+        assert costplane.ridge_intensity() == pytest.approx(flops / byts)
 
     def test_verdict_boundary_at_ridge(self):
         ridge = costplane.ridge_intensity()

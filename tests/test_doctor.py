@@ -163,8 +163,10 @@ class TestBenchAdapter:
 
     def test_diagnose_bench_none_on_pre_timeline_round(self):
         from spark_rapids_tpu.analysis import regression as R
-        rec = R.load_round(os.path.join(REPO_ROOT,
-                                        "BENCH_r05.json")).keys
+        # synthetic record with the pre-timeline key set (the real
+        # early rounds are gone with the backend they were taken on)
+        rec = R.load_round(os.path.join(
+            REPO_ROOT, "tests", "data", "BENCH_r01.json")).keys
         assert doctor.diagnose_bench(rec) is None
 
 
@@ -254,7 +256,9 @@ class TestEndToEnd:
         df.collect()
         df.collect()
         recs = [json.loads(ln) for ln in open(log)]
-        doc = next(r["doctor"] for r in recs if "doctor" in r)
+        # the LAST record is the query last_query_diagnosis describes
+        # (the first collect's verdict may differ: it paid the compiles)
+        doc = [r["doctor"] for r in recs if "doctor" in r][-1]
         assert doc["primary_cause"] == \
             s.last_query_diagnosis.primary_cause
         assert sum(doc["shares"].values()) == pytest.approx(
