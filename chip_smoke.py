@@ -289,7 +289,7 @@ def leg_kernel(ctx) -> dict:
     import jax.numpy as jnp
     from spark_rapids_tpu.kernels import basic as bk
     from spark_rapids_tpu.kernels import pallas_ops
-    from spark_rapids_tpu.shuffle.partitioners import _hash_partition_ids
+    from spark_rapids_tpu.shuffle.partitioners import partition_hash_ids
     n, table = ctx.kernel_rows, 4096
     rng = np.random.default_rng(ctx.seed)
     out = {"rows": n, "table": table}
@@ -341,7 +341,7 @@ def leg_kernel(ctx) -> dict:
     for nwords in (1, 2):
         words = [rng.integers(0, 2**63, n).astype(np.uint64)
                  for _ in range(nwords)]
-        got = np.asarray(_hash_partition_ids(
+        got = np.asarray(partition_hash_ids(
             tuple(jnp.asarray(w) for w in words), 4))
         h = np.full(n, 42, np.uint64)
         with np.errstate(over="ignore"):

@@ -156,9 +156,9 @@ def main():
     cats = {e["cat"] for e in events}
     assert {"engine", "exec"} <= cats, cats
     names = {e["name"] for e in events}
-    assert "query" in names and "attempt" in names, names
+    assert "srt.query" in names and "srt.attempt" in names, names
     qids = {e["args"].get("query_id") for e in events
-            if e["name"] == "attempt"}
+            if e["name"] == "srt.attempt"}
     # 3 healthy + the shuffle aggregate + the forced failure
     assert len(qids) == 5, qids
     # the TCP fetch's client/server halves join on span_id

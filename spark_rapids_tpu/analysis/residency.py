@@ -349,7 +349,9 @@ def declared_transfer(site: str):
     lifts the device-to-host transfer guard for the region — the ONLY
     sanctioned way to transfer while :func:`guard_scope` is active.
     The guard lift is dynamic (thread-local), so pulls in callees are
-    covered too.
+    covered too.  The region is the coarse span ``srt.pull`` (args:
+    ``site``; obs/trace.py): the host blocked on the device and the
+    copy back.
     """
     spec = SITES.get(site)
     if spec is None:
@@ -362,9 +364,11 @@ def declared_transfer(site: str):
             TRANSFER_COUNT += 1
             _SITE_COUNTS[site] = _SITE_COUNTS.get(site, 0) + 1
     import jax
+    from ..obs import trace as _trace
     _TLS.allow = getattr(_TLS, "allow", 0) + 1
     try:
-        with jax.transfer_guard_device_to_host("allow"):
+        with _trace.span("srt.pull", "pool", True, site=site), \
+                jax.transfer_guard_device_to_host("allow"):
             yield
     finally:
         _TLS.allow -= 1

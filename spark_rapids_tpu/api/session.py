@@ -74,6 +74,13 @@ class TpuSession:
 
     builder = TpuSessionBuilder
 
+    def close(self) -> None:
+        """End of the session's work: write the span file when fine
+        tracing has a path (``obs/trace.py``; nothing is written per
+        query).  The process-wide device state stays up for the next
+        session."""
+        _obs_trace.flush()
+
     @classmethod
     def active(cls) -> "TpuSession":
         s = getattr(cls._active_tls, "session", None)
@@ -142,6 +149,7 @@ class TpuSession:
         plans; standalone, api/sql.py supplies that front end."""
         from .dataframe import DataFrame
         from .sql import sql_to_plan
+        _obs_trace.begin_query(parsed=True)
         plan = sql_to_plan(query, self, self._views)
         return DataFrame(plan, self)
 
@@ -167,6 +175,7 @@ class TpuSession:
         import time as _time
         from ..columnar.arrow import to_arrow, schema_to_arrow
         from ..config import PROFILE_TRACE_DIR
+        _obs_trace.begin_query()
         trace_dir = self.conf.get(PROFILE_TRACE_DIR)
         if trace_dir:
             # xprof trace of the whole query — the NVTX+Nsight role
@@ -192,14 +201,11 @@ class TpuSession:
         explicitly keeps this method thread-safe against session-level
         mutation).  Execution drains through cancellation checkpoints
         and surfaces per-query semaphore-wait and spill-bytes metrics
-        in the event log.  With tracing on, the whole collect is one
-        "query" span (exec-node/kernel/memory spans nest under it) and
-        the span buffer flushes to the configured trace path."""
-        with _obs_trace.span("query", "engine", root=phys.name):
-            out = self._execute_physical_traced(phys, conf, fallbacks)
-        if _obs_trace.is_enabled():
-            _obs_trace.flush()
-        return out
+        in the event log.  The whole collect is the coarse span
+        ``srt.query`` (operator, flush, pull and memory spans nest under
+        it); the span file is written by ``close()``, not here."""
+        with _obs_trace.span("srt.query", "engine", True, root=phys.name):
+            return self._execute_physical_traced(phys, conf, fallbacks)
 
     def _execute_physical_traced(self, phys, conf: Optional[TpuConf] = None,
                                  fallbacks: Optional[List[str]] = None
@@ -458,92 +464,95 @@ class TpuSession:
         # each piece billed to its plane by obs/overhead.py, and the
         # event-log wall_ms no longer pays for its own reporting
         wall_ms = (_time.perf_counter() - t0) * 1000
-        # per-query StatsProfile (obs/stats.py): read-only over resolved
-        # values — built AFTER the final flush, never adds a round trip
-        self.last_stats_profile = None
-        if _stats.enabled(conf):
-            from ..config import OBS_STATS_IN_EVENT_LOG
+        # span srt.obs.assemble: what collect() still does between the
+        # answer being ready and returning it
+        with _obs_trace.span("srt.obs.assemble", "engine", True):
+            # per-query StatsProfile (obs/stats.py): read-only over resolved
+            # values — built AFTER the final flush, never adds a round trip
+            self.last_stats_profile = None
+            if _stats.enabled(conf):
+                from ..config import OBS_STATS_IN_EVENT_LOG
+                try:
+                    prof = _stats.build_profile(
+                        phys,
+                        query_id=token.query_id if token is not None else None,
+                        flushes=int(flushes), dispatch_marker=disp_marker)
+                    self.last_stats_profile = prof
+                    if conf.get(OBS_STATS_IN_EVENT_LOG):
+                        extra["stats_profile"] = prof.to_dict()
+                except Exception:  # noqa: BLE001 — stats never fail a query
+                    import logging
+                    logging.getLogger("spark_rapids_tpu.obs.stats").warning(
+                        "stats profile build failed", exc_info=True)
+            # cross-plane query doctor (obs/doctor.py): joins the summaries
+            # gathered above into one primary-bottleneck verdict — pure
+            # host arithmetic over dicts already in hand, after the final
+            # flush, so the FLUSH_COUNT delta above is unchanged
+            self.last_query_diagnosis = None
+            if _doctor.enabled(conf):
+                try:
+                    diag = _doctor.diagnose(
+                        tl, inline_compile_ms=inline_compile_ms,
+                        netplane=net, memplane=mem, flushes=int(flushes),
+                        predicted_flushes=predicted_flushes,
+                        declared_transfers=declared_sites,
+                        sem_wait_ms=sem_wait_ms,
+                        stats_profile=self.last_stats_profile,
+                        query_id=token.query_id if token is not None
+                        else None,
+                        compiles=extra.get("compiles"),
+                        costplane=cost)
+                    self.last_query_diagnosis = diag
+                    extra["doctor"] = diag.to_dict()
+                except Exception:  # noqa: BLE001 — doctor never fails a query
+                    import logging
+                    logging.getLogger("spark_rapids_tpu.obs.doctor").warning(
+                        "query diagnosis failed", exc_info=True)
+            # longitudinal fleet plane: the stable plan fingerprint groups
+            # this query with every recurrence of its shape
+            # (obs/fingerprint.py), and the engine-side artifacts are
+            # deposited for the history store's terminal join keyed by the
+            # same query_id the service folds at the terminal transition
+            # (obs/history.py).  Pure host arithmetic after the final
+            # flush: the FLUSH_COUNT delta above is unchanged.
+            self.last_query_fingerprint = None
             try:
-                prof = _stats.build_profile(
-                    phys,
-                    query_id=token.query_id if token is not None else None,
-                    flushes=int(flushes), dispatch_marker=disp_marker)
-                self.last_stats_profile = prof
-                if conf.get(OBS_STATS_IN_EVENT_LOG):
-                    extra["stats_profile"] = prof.to_dict()
-            except Exception:  # noqa: BLE001 — stats never fail a query
+                from ..obs import fingerprint as _fingerprint
+                from ..obs import history as _qhistory
+                fp = _fingerprint.plan_fingerprint(phys, conf)
+                self.last_query_fingerprint = fp
+                extra["plan_fingerprint"] = fp
+                if token is not None and _qhistory.enabled():
+                    art = {
+                        "fingerprint": fp,
+                        "flushes": int(flushes),
+                        "flushes_predicted": predicted_flushes,
+                        "device_util_pct": tl["util_pct"],
+                        "gaps": tl["gaps"],
+                    }
+                    if cost is not None:
+                        art["roofline_verdict"] = cost.get("verdict")
+                        art["achieved_GBps"] = cost.get("achieved_gbps")
+                        art["padding_waste_pct"] = \
+                            cost.get("padding_waste_pct")
+                    if self.last_query_diagnosis is not None:
+                        d = self.last_query_diagnosis.to_dict()
+                        art["doctor_cause"] = d.get("primary_cause")
+                        art["doctor_share_pct"] = d.get("primary_share_pct")
+                    _qhistory.note_query(token.query_id, art)
+            except Exception:  # noqa: BLE001 — fleet plane never fails a query
                 import logging
-                logging.getLogger("spark_rapids_tpu.obs.stats").warning(
-                    "stats profile build failed", exc_info=True)
-        # cross-plane query doctor (obs/doctor.py): joins the summaries
-        # gathered above into one primary-bottleneck verdict — pure
-        # host arithmetic over dicts already in hand, after the final
-        # flush, so the FLUSH_COUNT delta above is unchanged
-        self.last_query_diagnosis = None
-        if _doctor.enabled(conf):
-            try:
-                diag = _doctor.diagnose(
-                    tl, inline_compile_ms=inline_compile_ms,
-                    netplane=net, memplane=mem, flushes=int(flushes),
-                    predicted_flushes=predicted_flushes,
-                    declared_transfers=declared_sites,
-                    sem_wait_ms=sem_wait_ms,
-                    stats_profile=self.last_stats_profile,
-                    query_id=token.query_id if token is not None
-                    else None,
-                    compiles=extra.get("compiles"),
-                    costplane=cost)
-                self.last_query_diagnosis = diag
-                extra["doctor"] = diag.to_dict()
-            except Exception:  # noqa: BLE001 — doctor never fails a query
-                import logging
-                logging.getLogger("spark_rapids_tpu.obs.doctor").warning(
-                    "query diagnosis failed", exc_info=True)
-        # longitudinal fleet plane: the stable plan fingerprint groups
-        # this query with every recurrence of its shape
-        # (obs/fingerprint.py), and the engine-side artifacts are
-        # deposited for the history store's terminal join keyed by the
-        # same query_id the service folds at the terminal transition
-        # (obs/history.py).  Pure host arithmetic after the final
-        # flush: the FLUSH_COUNT delta above is unchanged.
-        self.last_query_fingerprint = None
-        try:
-            from ..obs import fingerprint as _fingerprint
-            from ..obs import history as _qhistory
-            fp = _fingerprint.plan_fingerprint(phys, conf)
-            self.last_query_fingerprint = fp
-            extra["plan_fingerprint"] = fp
-            if token is not None and _qhistory.enabled():
-                art = {
-                    "fingerprint": fp,
-                    "flushes": int(flushes),
-                    "flushes_predicted": predicted_flushes,
-                    "device_util_pct": tl["util_pct"],
-                    "gaps": tl["gaps"],
-                }
-                if cost is not None:
-                    art["roofline_verdict"] = cost.get("verdict")
-                    art["achieved_GBps"] = cost.get("achieved_gbps")
-                    art["padding_waste_pct"] = \
-                        cost.get("padding_waste_pct")
-                if self.last_query_diagnosis is not None:
-                    d = self.last_query_diagnosis.to_dict()
-                    art["doctor_cause"] = d.get("primary_cause")
-                    art["doctor_share_pct"] = d.get("primary_share_pct")
-                _qhistory.note_query(token.query_id, art)
-        except Exception:  # noqa: BLE001 — fleet plane never fails a query
-            import logging
-            logging.getLogger("spark_rapids_tpu.obs.history").warning(
-                "fingerprint/history deposit failed", exc_info=True)
-        # the self-meter's verdict on everything the planes above spent
-        # inside this query (including the deferred assembly just run)
-        if _overhead.is_enabled():
-            obs_self = _overhead.delta_ms(obs_marker)
-            extra["obs_self"] = {
-                "total_ms": round(sum(obs_self.values()), 3),
-                "planes": obs_self}
-        self._log_query(phys, wall_ms, conf=conf, fallbacks=fallbacks,
-                        extra=extra)
+                logging.getLogger("spark_rapids_tpu.obs.history").warning(
+                    "fingerprint/history deposit failed", exc_info=True)
+            # the self-meter's verdict on everything the planes above spent
+            # inside this query (including the deferred assembly just run)
+            if _overhead.is_enabled():
+                obs_self = _overhead.delta_ms(obs_marker)
+                extra["obs_self"] = {
+                    "total_ms": round(sum(obs_self.values()), 3),
+                    "planes": obs_self}
+            self._log_query(phys, wall_ms, conf=conf, fallbacks=fallbacks,
+                            extra=extra)
         target = schema_to_arrow(phys.output_schema) if len(
             phys.output_schema) else None
         if not tables:

@@ -2222,7 +2222,9 @@ class _Lowerer:
 
 
 def sql_to_plan(sql: str, session, views) -> L.LogicalPlan:
-    ast = parse_sql(sql)
-    plan = _Lowerer(session, views).lower(ast)
+    from ..obs import trace as _trace
     from ..plan.logical_opt import optimize
-    return optimize(plan)
+    with _trace.span("srt.sql.parse", "front_end", True):
+        ast = parse_sql(sql)
+    with _trace.span("srt.sql.analyze", "front_end", True):
+        return optimize(_Lowerer(session, views).lower(ast))

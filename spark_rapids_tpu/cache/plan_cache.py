@@ -47,6 +47,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ..obs.fingerprint import (conf_fingerprint, logical_shape,
                                plan_fingerprint)
+from ..obs import trace as _trace
 from ..obs.registry import PLAN_CACHE_EVENTS
 
 _LOCK = threading.Lock()
@@ -90,8 +91,18 @@ def plan_with_cache(logical, conf):
       computed once on a miss; ``api/session.py`` prefers this over
       re-running ``predict_flushes``.
     - ``_plan_cache_status``: ``(status, planner_path_ms)`` for the
-      event log and report header (absent when the cache is off).
+      event log and report header (absent when the cache is off): the
+      look-up or planner alone; the coarse span ``srt.plan`` covers the
+      whole call, shape key and conf fingerprint included.
     """
+    with _trace.span("srt.plan", "planner", True) as sp:
+        phys, planner = _plan_with_cache(logical, conf)
+        status = getattr(phys, "_plan_cache_status", None)
+        sp.set(cache=status[0] if status else "off")
+    return phys, planner
+
+
+def _plan_with_cache(logical, conf):
     global _HITS, _MISSES, _VALIDATION_MISSES, _INVALIDATED, _EVICTED
     from ..analysis.flush_budget import FlushPrediction, predict_flushes
     from ..plan.overrides import Planner

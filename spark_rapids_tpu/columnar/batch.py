@@ -309,7 +309,7 @@ class ColumnarBatch:
         if all(type(c) is Column for c in self.columns) and self.columns:
             fn = ColumnarBatch._SLICE_JIT.get(out_cap)
             if fn is None:
-                import jax
+                from ..obs import compile_watch as _cw
 
                 def _slice(datas, valids, start_, nvalid):
                     idx = jnp.arange(out_cap) + start_
@@ -320,7 +320,8 @@ class ColumnarBatch:
                             jnp.take(d, idx, axis=0, mode="clip"),
                             jnp.take(v, idx, axis=0, mode="clip") & live))
                     return outs
-                fn = jax.jit(_slice)
+                fn = _cw.wrap_miss("batch_slice",
+                                   _cw.jit(_slice, "batch_slice"), out_cap)
                 ColumnarBatch._SLICE_JIT[out_cap] = fn
             pairs = fn(tuple(c.data for c in self.columns),
                        tuple(c.validity for c in self.columns),
@@ -379,7 +380,7 @@ _CONCAT_JIT: dict = {}
 def _concat_plain_jit(batches, schema, cap: int, total: int):
     """One jitted program for fixed-width concat (slice+concat+pad per
     column) — the eager per-column path pays one dispatch per op."""
-    import jax
+    from ..obs import compile_watch as _cw
     nrows = tuple(b.num_rows for b in batches)
     key = (nrows, cap, len(schema))
     fn = _CONCAT_JIT.get(key)
@@ -399,7 +400,8 @@ def _concat_plain_jit(batches, schema, cap: int, total: int):
                     v = jnp.pad(v, (0, pad))
                 outs.append((d, v))
             return outs
-        fn = jax.jit(_concat)
+        fn = _cw.wrap_miss("batch_concat", _cw.jit(_concat, "batch_concat"),
+                           key)
         if len(_CONCAT_JIT) < 4096:
             _CONCAT_JIT[key] = fn
     datas = tuple(tuple(b.columns[ci].data for b in batches)

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from . import dtypes as T
+from ..obs import trace as _trace
 
 # Minimum capacity bucket; batches are padded up to powers of two so the
 # jit-cache stays small (SURVEY.md §7 "compile-cache keyed by padded size").
@@ -213,6 +214,7 @@ class Column:
         ``live``/``unique`` are sizing hints for variable-width columns
         (kernels/strings.py gather_strings); fixed-width gathers ignore
         them."""
+        _trace.count_eager("eager.column_gather", indices, 2)
         valid = jnp.take(self.validity, indices, axis=0, mode="clip")
         if live is not None:
             valid = valid & live
@@ -319,6 +321,7 @@ class StringColumn(Column):
         # aggregate's 1000x row reduction never materializes the
         # intermediate gigabytes (and never pays its sizing sync) —
         # the cuDF-style dictionary/gather-map trick.
+        _trace.count_eager("eager.string_gather", indices)
         valid = jnp.take(self.validity, indices, axis=0, mode="clip")
         if live is not None:
             valid = valid & live
@@ -365,6 +368,7 @@ class GatheredStringColumn(StringColumn):
             if src._mat is not None:
                 src = src._mat
                 continue
+            _trace.count_eager("eager.string_gather_compose", idx)
             idx = jnp.take(src.idx, idx, axis=0, mode="clip")
             # a composed map repeats source rows unless EVERY stage was
             # repeat-free
@@ -532,11 +536,11 @@ class ListColumn(Column):
     def gather(self, indices, live=None, unique=False) -> "ListColumn":
         from ..kernels import lists as lkern
         from ..analysis import residency  # lazy: avoids import cycle
-        new_offsets, gvalid, src_starts, total = lkern.gather_list_offsets(
+        new_offsets, gvalid, src_starts, total = lkern.list_gather_offsets(
             self.offsets, self.validity, indices)
         with residency.declared_transfer(site="size_probe"):
             elem_cap = bucket_capacity(max(1, int(total)))
-        src_idx, live = lkern.element_gather_indices(
+        src_idx, live = lkern.list_element_gather_indices(
             new_offsets, src_starts, elem_cap)
         elems = self.elements.gather(src_idx).mask_validity(live)
         return ListColumn(self.dtype, new_offsets, elems, gvalid)
@@ -625,6 +629,7 @@ class StructColumn(Column):
 
     def gather(self, indices, live=None,
                unique=False) -> "StructColumn":
+        _trace.count_eager("eager.struct_gather", indices)
         return StructColumn(
             self.dtype,
             [c.gather(indices, live=live, unique=unique)

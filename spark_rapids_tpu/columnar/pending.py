@@ -31,7 +31,6 @@ at batch boundaries (GpuColumnVector / ColumnarToRow).
 from __future__ import annotations
 
 import threading
-import time
 import weakref
 from typing import List, Optional, Tuple
 
@@ -39,6 +38,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..obs import trace as _trace
 
 # Staged items: weakrefs so abandoned handles are never transferred.
 # The pool is process-wide and queries run concurrently under the query
@@ -101,7 +102,7 @@ def stage(dev) -> Staged:
 # never run eagerly.
 
 @jax.jit
-def _enc_bytes(x):
+def pending_enc_bytes(x):
     """u8-ish[n] -> u32[ceil(n/4)] little-endian (host unpacks via .view)."""
     x = jnp.ravel(x)
     if x.dtype == jnp.bool_:
@@ -117,19 +118,19 @@ def _enc_bytes(x):
 
 
 @jax.jit
-def _enc_wide16(x):
+def pending_enc_wide16(x):
     x = jnp.ravel(x)
     return (x.astype(jnp.int32).view(jnp.uint32)
             if np.dtype(x.dtype).kind == "i" else x.astype(jnp.uint32))
 
 
 @jax.jit
-def _enc_u32(x):
+def pending_enc_u32(x):
     return lax.bitcast_convert_type(jnp.ravel(x), jnp.uint32)
 
 
 @jax.jit
-def _enc_split64(x):
+def pending_enc_split64(x):
     # 64-bit ints: exact shift/mask split (the chip rejects 64-bit
     # bitcasts; masking the arithmetic-shifted high word is exact)
     x = jnp.ravel(x)
@@ -140,7 +141,7 @@ def _enc_split64(x):
 
 
 @jax.jit
-def _enc_f64(x):
+def pending_enc_f64(x):
     return jnp.ravel(x)
 
 
@@ -148,15 +149,15 @@ def _encode(x) -> Tuple[str, list]:
     """Device array -> (layout, [u32 parts] or [f64 parts])."""
     dt = np.dtype(x.dtype)
     if dt == np.bool_ or dt.itemsize == 1:
-        return "u8", [_enc_bytes(x)]
+        return "u8", [pending_enc_bytes(x)]
     if dt.itemsize == 2:
-        return "u32", [_enc_wide16(x)]
+        return "u32", [pending_enc_wide16(x)]
     if dt.itemsize == 4:
-        return "u32", [_enc_u32(x)]
+        return "u32", [pending_enc_u32(x)]
     if dt.kind in "iu":
-        return "split64", list(_enc_split64(x))
+        return "split64", list(pending_enc_split64(x))
     assert dt == np.float64, f"unsupported staged dtype {dt}"
-    return "f64", [_enc_f64(x)]
+    return "f64", [pending_enc_f64(x)]
 
 
 def _decode(layout: str, np_dtype, shape, parts: List[np.ndarray]):
@@ -252,17 +253,19 @@ def flush():
     if not items:
         return
     FLUSH_COUNT += 1
-    obs = _FLUSH_OBSERVER
-    if obs is None:
-        return _flush_items(items)
-    t0 = time.perf_counter_ns()
+    # coarse span srt.flush: the host blocked on the device and the
+    # copy back; the observer takes its duration from the same reads
+    span = _trace.Span("srt.flush", "pool", {"items": len(items)}, True)
     try:
-        return _flush_items(items)
+        with span:
+            return _flush_items(items)
     finally:
-        try:
-            obs(time.perf_counter_ns() - t0, len(items))
-        except Exception:  # noqa: BLE001 — observers never break a flush
-            pass
+        obs = _FLUSH_OBSERVER
+        if obs is not None:
+            try:
+                obs(span.dur_ns, len(items))
+            except Exception:  # noqa: BLE001 — observers never break a flush
+                pass
 
 
 def _flush_items(items: List[Staged]):

@@ -391,13 +391,16 @@ EVENT_LOG_FLUSH_PER_RECORD = conf_bool(
     "high-QPS services.  Env override: SPARK_RAPIDS_TPU_EVENT_LOG_FLUSH")
 OBS_TRACE_ENABLED = conf_bool(
     "spark.rapids.tpu.obs.trace.enabled", False,
-    "Record hierarchical engine spans (service -> exec node -> kernel/"
-    "shuffle/memory; the NvtxRange role) into an in-process buffer.  "
-    "Disabled, the tracer costs one flag read per instrumented site")
+    "Record the fine level of hierarchical engine spans (service -> "
+    "exec node -> shuffle/memory; the NvtxRange role) as Chrome trace "
+    "events in an in-process buffer.  The coarse srt.* spans at layer "
+    "boundaries are recorded whatever this says (obs/trace.py); "
+    "disabled, a fine site costs one flag read")
 OBS_TRACE_PATH = conf_str(
     "spark.rapids.tpu.obs.trace.path", "",
     "Write the Chrome trace-event JSON (Perfetto/chrome://tracing "
-    "loadable) here after each query when tracing is enabled")
+    "loadable) here when the session or the service closes, or on "
+    "trace.flush(), when tracing is enabled; never per query")
 OBS_TRACE_MAX_SPANS = conf_int(
     "spark.rapids.tpu.obs.trace.maxBufferedSpans", 100000,
     "Bound on buffered spans; past it new spans are dropped (and "

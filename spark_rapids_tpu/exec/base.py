@@ -12,7 +12,6 @@ mirroring GpuMetric (GpuExec.scala:27-237).
 from __future__ import annotations
 
 import logging
-import time
 from typing import Dict, Iterator, List
 
 from ..columnar.schema import Schema
@@ -138,43 +137,33 @@ class timed:
     unwinds here instead of running its remaining operators — the
     TaskContext.isInterrupted pattern at columnar granularity.
 
-    Span-aware: with tracing on, each timed region is an "exec" span
-    named after ``node`` (the operator), nesting under the service
-    attempt span and over kernel/shuffle/memory spans.  Disabled, the
-    extra cost is one module-flag read (no allocation)."""
+    Each timed region is the coarse span ``srt.exec.<node>`` (the
+    operator; obs/trace.py), nesting under ``srt.query`` / the service
+    attempt span and over flush, jit-build, shuffle and memory spans.
+    The span's two clock reads are the metric's: one measurement feeds
+    both."""
 
-    __slots__ = ("metric", "node", "t0", "_span")
+    __slots__ = ("metric", "name", "_span")
 
     def __init__(self, metric: Metric, node: "PhysicalPlan" = None):
         self.metric = metric
-        self.node = node
+        self.name = node.name if node is not None else metric.name
 
     def __enter__(self):
         cancel_checkpoint()
         # flight recorder shares this operator boundary (always-on;
         # interned node/metric name only, so the record is
         # allocation-free)
-        _flight.record(_flight.EV_BEGIN,
-                       self.node.name if self.node is not None
-                       else self.metric.name)
-        if _trace._ENABLED:
-            self._span = _trace.Span(
-                self.node.name if self.node is not None
-                else self.metric.name,
-                "exec", {"metric": self.metric.name})
-            self._span.__enter__()
-        else:
-            self._span = None
-        self.t0 = time.perf_counter_ns()
+        _flight.record(_flight.EV_BEGIN, self.name)
+        self._span = _trace.Span("srt.exec." + self.name, "exec",
+                                 {"metric": self.metric.name}, True)
+        self._span.__enter__()
         return self
 
     def __exit__(self, *a):
-        self.metric.add(time.perf_counter_ns() - self.t0)
-        _flight.record(_flight.EV_END,
-                       self.node.name if self.node is not None
-                       else self.metric.name)
-        if self._span is not None:
-            self._span.__exit__(*a)
+        self._span.__exit__(*a)
+        self.metric.add(self._span.dur_ns)
+        _flight.record(_flight.EV_END, self.name)
         return False
 
 

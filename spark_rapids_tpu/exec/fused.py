@@ -135,24 +135,23 @@ class FusedEval:
             # trees with opaque attributes (signature None) get a
             # private jit instead of an unsound id()-keyed entry
             sigs = [expr_signature(self.exprs[i]) for i in self.fused_idx]
-            if any(s is None for s in sigs):
-                self._jitted = _compile_watch.wrap_miss(
-                    "fused_project",
-                    jax.jit(self._eval, static_argnums=(0,)), "opaque")
-            else:
+            key = None
+            if not any(s is None for s in sigs):
                 key = (tuple(sigs),
                        tuple(f.dtype.name for f in self.schema),
                        tuple(self.needed))
                 self._jitted = _JIT_CACHE.get(key)
                 compile_cache_event("fused_project",
                                     self._jitted is not None)
-                if self._jitted is None:
-                    self._jitted = _compile_watch.wrap_miss(
-                        "fused_project",
-                        jax.jit(self._eval, static_argnums=(0,)),
-                        str(key))
-                    if len(_JIT_CACHE) < 4096:
-                        _JIT_CACHE[key] = self._jitted
+            if self._jitted is None:
+                self._jitted = _compile_watch.wrap_miss(
+                    "fused_project",
+                    _compile_watch.jit(self._eval, "fused_project_eval",
+                                       static_argnums=(0,)),
+                    "opaque" if key is None else str(key))
+                if key is not None and len(_JIT_CACHE) < 4096:
+                    _JIT_CACHE[key] = self._jitted
+            if key is not None:
                 self._register_warmer(str(hash(key)))
 
     def _register_warmer(self, variant: str) -> None:

@@ -66,6 +66,7 @@ from collections import deque
 from typing import Callable, Dict, Iterable, List, Optional, Set
 
 from ..obs import flight as _flight
+from ..obs import trace as _trace
 from ..obs.registry import (PIPELINE_BATCHES, PIPELINE_DRAINS,
                             PIPELINE_OVERLAP_RATIO,
                             PIPELINE_WORKER_BUSY_SECONDS)
@@ -269,6 +270,9 @@ class _ParallelDrain:
         self._depth = max(1, depth)
         self._budget = max(1, budget)
         self._token = token
+        # the draining thread's query number, for the workers' spans
+        # and counters (obs/trace.py)
+        self._query = _trace.current_query()
         self._conf = conf
         self._label = label
         n = len(self._parts)
@@ -414,6 +418,7 @@ class _ParallelDrain:
             # token travel to the worker: sinks read the right batch
             # sizes, checkpoints see the right cancellation state
             set_active(self._conf, thread_only=True)
+            _trace.adopt_query(self._query)
             # transfer-guard parity with the collect thread: JAX's
             # guard is thread-local, so every pool worker arms its own
             # scoped disallow (analysis/residency.py)

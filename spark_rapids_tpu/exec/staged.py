@@ -75,7 +75,7 @@ def apply_ops_traced(ops: Sequence[Op], batch) -> "_TracedBatch":
             pred = ec.eval_as_column(payload, batch)
             cap = batch.capacity
             keep = pred.data.astype(bool) & pred.validity
-            order, cnt = bk.compact_indices(keep, n)
+            order, cnt = bk.filter_compact_indices(keep, n)
             live = jnp.arange(cap) < cnt
             cols = [c.gather(order, live=live, unique=True)
                     for c in batch.columns]
@@ -105,7 +105,7 @@ def apply_ops_eager(ops: Sequence[Op], batch: ColumnarBatch,
             if pred is None:
                 pred = ec.eval_as_column(payload, batch)
             keep = pred.data.astype(bool) & pred.validity
-            idx, cnt = bk.compact_indices(keep, batch.rows_dev)
+            idx, cnt = bk.filter_compact_indices(keep, batch.rows_dev)
             n = LazyCount(cnt)
             mask = jnp.arange(batch.capacity) < cnt
             out = batch.gather(idx, n, live=mask, unique=True)
@@ -174,7 +174,7 @@ class TpuStagedCompute(TpuExec):
             return ([(c.data, c.validity) for c in out.columns],
                     out.num_rows)
 
-        fn = jax.jit(_eval, static_argnums=(0,))
+        fn = _compile_watch.jit(_eval, "staged_eval", static_argnums=(0,))
         # compile telemetry: the first call (trace + XLA compile) is
         # wall-timed into the tpu_compile_seconds plane
         fn = _compile_watch.wrap_miss(

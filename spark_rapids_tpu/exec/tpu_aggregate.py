@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import List, Optional
 
+import jax
 import jax.numpy as jnp
 
 from ..columnar import dtypes as T
@@ -25,6 +26,7 @@ from ..compile import aot as _aot
 from ..kernels import canon, aggregate as agg_k
 from ..obs import compile_watch as _compile_watch
 from ..obs import costplane as _costplane
+from ..obs import trace as _obs_trace
 from ..obs.registry import compile_cache_event
 from ..plan.logical import AggExpr
 from .base import PhysicalPlan, AGG_TIME, NUM_OUTPUT_ROWS, timed
@@ -389,25 +391,30 @@ class TpuHashAggregate(TpuExec):
                 kcols = [Column(dt, d, v)
                          for dt, (d, v) in zip(key_dts, key_arrays)]
                 cap = key_arrays[0][0].shape[0]
-                words = canon.batch_key_words(kcols, num_rows)
+                with jax.named_scope("key_words"):
+                    words = canon.batch_key_words(kcols, num_rows)
                 plan = agg_k.groupby_plan(words)
                 agg_buffers = []
                 it = iter(in_arrays)
                 for a, dts in zip(aggs, in_dts):
                     cols = [None if dt is None else
                             Column(dt, *next(it)) for dt in dts] or [None]
-                    bufs = a.func.update(plan, cols) if update_mode \
-                        else a.func.merge(plan, cols)
+                    with jax.named_scope("segment_reduce"):
+                        bufs = a.func.update(plan, cols) if update_mode \
+                            else a.func.merge(plan, cols)
                     agg_buffers.append(bufs)
                 ocap = min(out_cap, cap) if out_cap else cap
                 fit = (plan.num_groups <= ocap).astype(jnp.int32) \
                     if out_cap else jnp.int32(1)
-                ng, outs = _assemble_group_output(plan, kcols, aggs,
-                                                  agg_buffers, ocap,
-                                                  emit_buffers)
+                with jax.named_scope("emit"):
+                    ng, outs = _assemble_group_output(plan, kcols, aggs,
+                                                      agg_buffers, ocap,
+                                                      emit_buffers)
                 return ng, fit, outs
             core = _compile_watch.wrap_miss(
-                "hash_aggregate", jax.jit(_core), str(cache_key))
+                "hash_aggregate",
+                _compile_watch.jit(_core, "agg_grouped_core"),
+                str(cache_key))
             TpuHashAggregate._CORE_CACHE[cache_key] = core
             key_nps = tuple(dt.np_dtype for dt in key_dts)
             in_nps = tuple(dt.np_dtype for dts in in_dts for dt in dts
@@ -576,8 +583,11 @@ class TpuHashAggregate(TpuExec):
         if core is False:
             return None
         if core is None:
-            core = jax.jit(self._build_table_core(
-                batch.schema, bound_keys, bound_inputs, descs, table))
+            core = _compile_watch.wrap_miss(
+                "hash_aggregate",
+                _compile_watch.jit(self._build_table_core(
+                    batch.schema, bound_keys, bound_inputs, descs, table),
+                    "agg_table_core"), str((cache_key, table)))
             TpuHashAggregate._CORE_CACHE[(cache_key, table)] = core
         datas = tuple(c.data for c in batch.columns)
         valids = tuple(c.validity for c in batch.columns)
@@ -845,6 +855,7 @@ class TpuHashAggregate(TpuExec):
             # costs the same as single-column; lane sums < 2^31, exact)
             chunk_out = None
             if chunk_rows:
+                _obs_trace.count_eager("eager.table_chunk_scatter", bucket)
                 chunk_out = jax.ops.segment_sum(
                     jnp.stack(chunk_rows, 1), bucket,
                     num_segments=table + 1)[:table]
@@ -1088,23 +1099,30 @@ class TpuHashAggregate(TpuExec):
                 cols = [Column(f.dtype, d, v)
                         for f, d, v in zip(src_schema, datas, valids)]
                 b = _TracedBatch(src_schema, cols, num_rows, cap)
-                b = apply_ops_traced(pre_ops, b)
-                kcols = [ec.eval_as_column(e, b) for e in bound_keys]
-                words = canon.batch_key_words(kcols, b.num_rows)
+                with jax.named_scope("pre_ops"):
+                    b = apply_ops_traced(pre_ops, b)
+                with jax.named_scope("key_words"):
+                    kcols = [ec.eval_as_column(e, b) for e in bound_keys]
+                    words = canon.batch_key_words(kcols, b.num_rows)
                 plan = agg_k.groupby_plan(words)
                 agg_buffers = []
                 for a, bs in zip(aggs, bound_inputs):
-                    cols2 = [ec.eval_as_column(e, b) for e in bs] or [None]
-                    agg_buffers.append(a.func.update(plan, cols2))
+                    with jax.named_scope("segment_reduce"):
+                        cols2 = [ec.eval_as_column(e, b)
+                                 for e in bs] or [None]
+                        agg_buffers.append(a.func.update(plan, cols2))
                 ocap = min(out_cap, cap) if out_cap else cap
                 fit = (plan.num_groups <= ocap).astype(jnp.int32) \
                     if out_cap else jnp.int32(1)
-                ng, outs = _assemble_group_output(plan, kcols, aggs,
-                                                  agg_buffers, ocap,
-                                                  emit_buffers)
+                with jax.named_scope("emit"):
+                    ng, outs = _assemble_group_output(plan, kcols, aggs,
+                                                      agg_buffers, ocap,
+                                                      emit_buffers)
                 return ng, fit, outs
             core = _compile_watch.wrap_miss(
-                "hash_aggregate", jax.jit(_core), str(cache_key))
+                "hash_aggregate",
+                _compile_watch.jit(_core, "agg_whole_stage_core"),
+                str(cache_key))
             TpuHashAggregate._CORE_CACHE[cache_key] = core
             ws_nps = tuple(f.dtype.np_dtype for f in batch.schema)
             if not any(d is None for d in ws_nps):
@@ -1285,8 +1303,9 @@ class TpuHashAggregate(TpuExec):
             for a, dts in zip(aggs, in_dts):
                 cols = [None if dt is None else Column(dt, *next(it))
                         for dt in dts] or [None]
-                bufs = a.func.update(plan, cols) if update_mode \
-                    else a.func.merge(plan, cols)
+                with jax.named_scope("segment_reduce"):
+                    bufs = a.func.update(plan, cols) if update_mode \
+                        else a.func.merge(plan, cols)
                 cols_out = bufs if emit else [a.func.finalize(bufs)]
                 for o in cols_out:
                     c = o.gather(jnp.zeros(out_cap, jnp.int32))
@@ -1321,7 +1340,9 @@ class TpuHashAggregate(TpuExec):
             if core is not False:
                 if core is None:
                     core = _compile_watch.wrap_miss(
-                        "hash_aggregate", jax.jit(_core), str(cache_key))
+                        "hash_aggregate",
+                        _compile_watch.jit(_core, "agg_global_core"),
+                        str(cache_key))
                     TpuHashAggregate._CORE_CACHE[cache_key] = core
                 _aot.note_demand("hash_aggregate", batch.capacity,
                                  _costplane.rows_if_resolved(batch))

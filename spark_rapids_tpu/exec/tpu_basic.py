@@ -239,7 +239,7 @@ class TpuFilter(TpuExec):
             pred = fcols[0] if fcols is not None else \
                 ec.eval_as_column(bound, batch)
             keep = pred.data.astype(bool) & pred.validity
-            idx, cnt = bk.compact_indices(keep, batch.rows_dev)
+            idx, cnt = bk.filter_compact_indices(keep, batch.rows_dev)
             # keep the count on device: pulling it per batch
             # costs a full dispatch-queue sync (LazyCount doc)
             n = LazyCount(cnt)
@@ -298,12 +298,16 @@ class TpuCoalesceBatches(TpuExec):
                 rows += batch.num_rows
                 nbytes += batch.nbytes()
                 if rows >= self.target_rows or nbytes >= self.target_bytes:
+                    # the region ends before the yield: a span held
+                    # open across it would time the consumer too
                     with timed(self.metrics[CONCAT_TIME], self):
-                        yield concat_batches(pending)
+                        out = concat_batches(pending)
+                    yield out
                     pending, rows, nbytes = [], 0, 0
             if pending:
                 with timed(self.metrics[CONCAT_TIME], self):
-                    yield concat_batches(pending)
+                    out = concat_batches(pending)
+                yield out
         return [run(p) for p in self.children[0].execute()]
 
 
@@ -479,7 +483,8 @@ class RowToColumnar(TpuExec):
         def run(part):
             for t in part:
                 with timed(self.metrics[OP_TIME], self):
-                    yield from_arrow(t)
+                    b = from_arrow(t)
+                yield b
         return [run(p) for p in self.children[0].execute()]
 
 
@@ -502,5 +507,6 @@ class ColumnarToRow(PhysicalPlan):
         def run(part):
             for b in part:
                 with timed(self.metrics[OP_TIME], self):
-                    yield to_arrow(b)
+                    t = to_arrow(b)
+                yield t
         return [run(p) for p in self.children[0].execute()]

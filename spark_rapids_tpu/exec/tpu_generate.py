@@ -2,8 +2,8 @@
 
 Reference: GpuGenerateExec.scala (498 LoC) — explode via cuDF
 ``explode``/``explode_position`` kernels.  TPU-first: the output row plan
-is pure offsets arithmetic (kernels/lists.py explode_offsets/
-explode_indices); the single dynamic scalar (output row count) is pulled
+is pure offsets arithmetic (kernels/lists.py list_explode_offsets/
+list_explode_indices); the single dynamic scalar (output row count) is pulled
 to host to choose the power-of-two output bucket, then one gather per
 column materializes the result — the same two-phase pattern as filter.
 """
@@ -55,13 +55,13 @@ class TpuGenerate(TpuExec):
         if fast is not None:
             return fast
         lcol = ec.eval_as_column(bound, batch)
-        out_offsets, total = lk.explode_offsets(
+        out_offsets, total = lk.list_explode_offsets(
             lcol.offsets, lcol.validity, batch.num_rows, outer)
         from ..analysis import residency  # lazy: avoids import cycle
         with residency.declared_transfer(site="size_probe"):
             n = int(total)
         out_cap = bucket_capacity(max(1, n))
-        row_idx, elem_idx, posv, elem_valid, live = lk.explode_indices(
+        row_idx, elem_idx, posv, elem_valid, live = lk.list_explode_indices(
             lcol.offsets, lcol.validity, out_offsets, out_cap)
         cols = [c.gather(row_idx).mask_validity(live)
                 for c in batch.columns]

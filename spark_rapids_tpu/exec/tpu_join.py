@@ -26,6 +26,7 @@ from ..columnar.batch import ColumnarBatch, concat_batches
 from ..expr import core as ec
 from ..kernels import canon, join as join_k
 from ..kernels import strings as skern
+from ..obs import compile_watch as _compile_watch
 from .base import (PhysicalPlan, BUILD_TIME, JOIN_TIME, NUM_OUTPUT_ROWS,
                    timed)
 from .tpu_basic import TpuExec
@@ -273,7 +274,6 @@ class TpuHashJoinBase(TpuExec):
         c = bkey_cols[0]
         w = canon.value_words(c, build.num_rows)[0]
 
-        @jax.jit
         def _minmax(w, validity, num_rows):
             valid = validity & (jnp.arange(validity.shape[0]) < num_rows)
             any_v = jnp.any(valid)
@@ -286,8 +286,9 @@ class TpuHashJoinBase(TpuExec):
                              jnp.uint64(0))
             nvalid = jnp.sum(valid)
             return wmin, wmax, nvalid
-        wmin, wmax, nvalid = _minmax(w, c.validity,
-                                     jnp.int32(build.num_rows))
+        wmin, wmax, nvalid = _compile_watch.jit(
+            _minmax, "join_direct_minmax")(
+                w, c.validity, jnp.int32(build.num_rows))
         # one host pull per build table (cached on the exec)
         import numpy as _np
         from ..analysis import residency  # lazy: avoids import cycle
@@ -299,7 +300,6 @@ class TpuHashJoinBase(TpuExec):
             return None
         tbl = bucket_capacity(rng)
 
-        @jax.jit
         def _tables(w, validity, num_rows, wmin, nnull):
             valid = validity & (jnp.arange(validity.shape[0]) < num_rows)
             idx = jnp.clip((w - wmin).astype(jnp.int32), 0, tbl - 1)
@@ -308,8 +308,9 @@ class TpuHashJoinBase(TpuExec):
                 .astype(jnp.int32)
             excl = (jnp.cumsum(hist) - hist + nnull).astype(jnp.int32)
             return hist, excl
-        hist, excl = _tables(w, c.validity, jnp.int32(build.num_rows),
-                             wmin, jnp.int32(nnull_h))
+        hist, excl = _compile_watch.jit(_tables, "join_direct_tables")(
+            w, c.validity, jnp.int32(build.num_rows), wmin,
+            jnp.int32(nnull_h))
         return (jnp.uint64(wmin_h), jnp.uint64(wmax_h), hist, excl, tbl)
 
     def _probe_phase(self, sb, skey_cols, bt, str_words, build_matched,
@@ -355,7 +356,8 @@ class TpuHashJoinBase(TpuExec):
                     counts = jnp.where(hit, jnp.take(hist, idx), 0)
                     lo = jnp.take(excl, idx)
                 else:
-                    swords = canon.batch_key_words(kcols, num_rows)
+                    with jax.named_scope("key_words"):
+                        swords = canon.batch_key_words(kcols, num_rows)
                     bt2 = join_k.BuildTable(list(bws), None, None)
                     jc = join_k.probe_counts(bt2, swords, num_rows)
                     counts, lo = jc.counts, jc.lo
@@ -369,9 +371,9 @@ class TpuHashJoinBase(TpuExec):
                     eff = counts
                 total = jnp.sum(eff.astype(jnp.int64))
                 return lo, counts, eff, total
-            from ..obs import costplane as _costplane
-            fn = _costplane.wrap_capture(
-                "join_probe", jax.jit(_core, static_argnames=()))
+            fn = _compile_watch.wrap_miss(
+                "join_probe", _compile_watch.jit(_core, "join_probe_core"),
+                str(key))
             TpuHashJoinBase._PROBE_JIT[key] = fn
         key_arrays = tuple((c.data, c.validity) for c in skey_cols)
         dparams = tuple(direct[:4]) if direct is not None else None
@@ -447,28 +449,34 @@ class TpuHashJoinBase(TpuExec):
                     counts = jnp.where(hit, jnp.take(hist, idx), 0)
                     lo = jnp.take(excl, idx)
                 else:
-                    swords = canon.batch_key_words(kcols, num_rows)
+                    with jax.named_scope("key_words"):
+                        swords = canon.batch_key_words(kcols, num_rows)
                     bt2 = join_k.BuildTable(list(bws), None, None)
                     jc = join_k.probe_counts(bt2, swords, num_rows)
                     counts, lo = jc.counts, jc.lo
                 eff = jnp.where(in_range, counts, 0)
                 fit = (jnp.max(eff) <= 1).astype(jnp.int32)
-                p_idx, cnt = bk.compact_indices(eff > 0, num_rows)
+                p_idx, cnt = bk.filter_compact_indices(eff > 0, num_rows)
                 live = jnp.arange(cap) < cnt
                 b_pos = jnp.clip(jnp.take(lo, p_idx, mode="clip"), 0,
                                  perm.shape[0] - 1)
                 b_idx = jnp.take(perm, b_pos)
-                souts = [(jnp.take(d, p_idx, axis=0, mode="clip"),
-                          jnp.take(v, p_idx, axis=0, mode="clip") & live)
-                         for d, v in zip(sdatas, svalids)]
-                bouts = [(jnp.take(d, b_idx, axis=0, mode="clip"),
-                          jnp.take(v, b_idx, axis=0, mode="clip") & live)
-                         for d, v in zip(bdatas, bvalids)]
+                with jax.named_scope("gather_stream"):
+                    souts = [(jnp.take(d, p_idx, axis=0, mode="clip"),
+                              jnp.take(v, p_idx, axis=0, mode="clip")
+                              & live)
+                             for d, v in zip(sdatas, svalids)]
+                with jax.named_scope("gather_build"):
+                    bouts = [(jnp.take(d, b_idx, axis=0, mode="clip"),
+                              jnp.take(v, b_idx, axis=0, mode="clip")
+                              & live)
+                             for d, v in zip(bdatas, bvalids)]
                 return souts, bouts, p_idx, b_idx, live, \
                     cnt.astype(jnp.int64), fit
-            from ..obs import costplane as _costplane
-            fn = _costplane.wrap_capture("join_spec_probe",
-                                         jax.jit(_core))
+            fn = _compile_watch.wrap_miss(
+                "join_spec_probe",
+                _compile_watch.jit(_core, "join_spec_probe_core"),
+                str(key))
             if len(TpuHashJoinBase._SPEC_JIT) < 4096:
                 TpuHashJoinBase._SPEC_JIT[key] = fn
         key_arrays = tuple((c.data, c.validity) for c in skey_cols)
@@ -619,7 +627,7 @@ class TpuHashJoinBase(TpuExec):
                 sb, "slice_by_mask") else None
             if out is None:
                 from ..kernels import basic as bk
-                idx, _ = bk.compact_indices(eff > 0, sb.rows_dev)
+                idx, _ = bk.filter_compact_indices(eff > 0, sb.rows_dev)
                 out = sb.gather(idx[:out_cap] if out_cap <= sb.capacity
                                 else jnp.pad(idx, (0, out_cap -
                                                    sb.capacity))[:out_cap],
@@ -641,22 +649,27 @@ class TpuHashJoinBase(TpuExec):
         if fn is None:
             def _core(lo, counts, eff, perm, sdatas, svalids, bdatas,
                       bvalids):
-                p_idx, b_idx, live, _ = join_k.expand_matches(
+                p_idx, b_idx, live, _ = join_k.join_expand_matches(
                     lo, eff, perm, out_cap)
-                souts = [(jnp.take(d, p_idx, axis=0, mode="clip"),
-                          jnp.take(v, p_idx, axis=0, mode="clip") & live)
-                         for d, v in zip(sdatas, svalids)]
+                with jax.named_scope("gather_stream"):
+                    souts = [(jnp.take(d, p_idx, axis=0, mode="clip"),
+                              jnp.take(v, p_idx, axis=0, mode="clip")
+                              & live)
+                             for d, v in zip(sdatas, svalids)]
                 bvalid_mask = live
                 if outer_stream:
                     matched = jnp.take(counts > 0, jnp.clip(
                         p_idx, 0, counts.shape[0] - 1))
                     bvalid_mask = live & matched
-                bouts = [(jnp.take(d, b_idx, axis=0, mode="clip"),
-                          jnp.take(v, b_idx, axis=0, mode="clip") &
-                          bvalid_mask)
-                         for d, v in zip(bdatas, bvalids)]
+                with jax.named_scope("gather_build"):
+                    bouts = [(jnp.take(d, b_idx, axis=0, mode="clip"),
+                              jnp.take(v, b_idx, axis=0, mode="clip") &
+                              bvalid_mask)
+                             for d, v in zip(bdatas, bvalids)]
                 return souts, bouts
-            fn = jax.jit(_core)
+            fn = _compile_watch.wrap_miss(
+                "join_expand",
+                _compile_watch.jit(_core, "join_expand_core"), str(key))
             if len(TpuHashJoinBase._EXPAND_JIT) < 4096:
                 TpuHashJoinBase._EXPAND_JIT[key] = fn
         souts, bouts = fn(
@@ -676,7 +689,7 @@ class TpuHashJoinBase(TpuExec):
         """Non-plain columns (strings/nested): the original eager
         expansion."""
         out_cap = bucket_capacity(total)
-        p_idx, b_idx, live, _ = join_k.expand_matches(lo, eff, bt.perm,
+        p_idx, b_idx, live, _ = join_k.join_expand_matches(lo, eff, bt.perm,
                                                       out_cap)
         stream_out = sb.gather(p_idx, total)
         build_out = build.gather(b_idx, total)
@@ -712,7 +725,7 @@ class TpuHashJoinBase(TpuExec):
             in_range = jnp.arange(sb.capacity) < sb.num_rows
             keep = (jc.counts > 0) if jt == "semi" else \
                 ((jc.counts == 0) & in_range)
-            idx, cnt = bk.compact_indices(keep, sb.num_rows)
+            idx, cnt = bk.filter_compact_indices(keep, sb.num_rows)
             n = _host_int(cnt)
             out = sb.gather(idx, n)
             mask = jnp.arange(out.capacity) < n
@@ -733,7 +746,7 @@ class TpuHashJoinBase(TpuExec):
         if total == 0:
             return ColumnarBatch.empty(self.output_schema)
         out_cap = bucket_capacity(total)
-        p_idx, b_idx, live, _ = join_k.expand_matches(
+        p_idx, b_idx, live, _ = join_k.join_expand_matches(
             jc.lo, counts, bt.perm, out_cap)
 
         stream_out = sb.gather(p_idx, total)
@@ -783,7 +796,7 @@ class TpuHashJoinBase(TpuExec):
 
         total = int(join_k.total_matches(jc.counts))
         out_cap = bucket_capacity(max(total, 1))
-        p_idx, b_idx, _live, _ = join_k.expand_matches(
+        p_idx, b_idx, _live, _ = join_k.join_expand_matches(
             jc.lo, jc.counts, bt.perm, out_cap)
         stream_out = sb.gather(p_idx, total)
         build_out = build.gather(b_idx, total)
@@ -805,7 +818,7 @@ class TpuHashJoinBase(TpuExec):
 
         if jt in ("semi", "anti"):
             sel = surv if jt == "semi" else (~surv & in_range)
-            idx, cnt = bk.compact_indices(sel, sb.num_rows)
+            idx, cnt = bk.filter_compact_indices(sel, sb.num_rows)
             n = _host_int(cnt)
             out = sb.gather(idx, n)
             mask = jnp.arange(out.capacity) < n
@@ -823,7 +836,7 @@ class TpuHashJoinBase(TpuExec):
             build_matched |= flags
 
         # surviving pairs
-        pidx2, pcnt = bk.compact_indices(keep, total)
+        pidx2, pcnt = bk.filter_compact_indices(keep, total)
         n_pairs = _host_int(pcnt)
         sp = stream_out.gather(pidx2, n_pairs)
         bp = build_out.gather(pidx2, n_pairs)
@@ -839,7 +852,7 @@ class TpuHashJoinBase(TpuExec):
                         jt == "full")
         if outer_stream:
             un = ~surv & in_range
-            uidx, ucnt = bk.compact_indices(un, sb.num_rows)
+            uidx, ucnt = bk.filter_compact_indices(un, sb.num_rows)
             n_un = _host_int(ucnt)
             if n_un:
                 su = sb.gather(uidx, n_un)
@@ -866,7 +879,7 @@ class TpuHashJoinBase(TpuExec):
         from ..kernels import basic as bk
         in_range = np.arange(build.capacity) < build.num_rows
         keep = jnp.asarray(~build_matched & in_range)
-        idx, cnt = bk.compact_indices(keep, build.num_rows)
+        idx, cnt = bk.filter_compact_indices(keep, build.num_rows)
         n = _host_int(cnt)
         if n == 0:
             return None
@@ -966,7 +979,7 @@ class TpuNestedLoopJoin(TpuExec):
             if jt in ("right", "full") else None
 
         def select_left(lb, sel, n_hint):
-            idx, cnt = bk.compact_indices(sel, n_hint)
+            idx, cnt = bk.filter_compact_indices(sel, n_hint)
             n = _host_int(cnt)
             out = lb.gather(idx, n)
             m = jnp.arange(out.capacity) < n
@@ -1027,7 +1040,7 @@ class TpuNestedLoopJoin(TpuExec):
                 yield out
                 continue
 
-            idx, cnt = bk.compact_indices(keep, total)
+            idx, cnt = bk.filter_compact_indices(keep, total)
             n_pairs = _host_int(cnt)
             parts = []
             if n_pairs:
@@ -1040,7 +1053,7 @@ class TpuNestedLoopJoin(TpuExec):
                 surv = jnp.zeros(lb.capacity, dtype=bool).at[
                     jnp.where(keep, li, 0)].max(keep)
                 un = ~surv & (jnp.arange(lb.capacity) < n_l)
-                uidx, ucnt = bk.compact_indices(un, n_l)
+                uidx, ucnt = bk.filter_compact_indices(un, n_l)
                 n_un = _host_int(ucnt)
                 if n_un:
                     lu = lb.gather(uidx, n_un)
@@ -1058,7 +1071,7 @@ class TpuNestedLoopJoin(TpuExec):
         if right_matched is not None:
             un = jnp.asarray(~right_matched) & \
                 (jnp.arange(rb.capacity) < n_r)
-            uidx, ucnt = bk.compact_indices(un, n_r)
+            uidx, ucnt = bk.filter_compact_indices(un, n_r)
             n_un = _host_int(ucnt)
             if n_un:
                 ru = rb.gather(uidx, n_un)

@@ -200,11 +200,12 @@ class TpuMeshAggregate(TpuExec):
             return tuple(out_flat)
 
         n_out = 2 * nkeys + 2 * len(aggs) + 2
-        fn = jax.jit(shard_map(
+        fn = _compile_watch.jit(shard_map(
             step, mesh=mesh,
             in_specs=tuple(P(_AXIS) for _ in
                            range(2 * (nkeys + sum(in_layout)) + 1)),
-            out_specs=tuple(P(_AXIS) for _ in range(n_out))))
+            out_specs=tuple(P(_AXIS) for _ in range(n_out))),
+            "mesh_aggregate_step")
         # perf plane: per-device busy windows + first-call compile
         # telemetry (signature drops the unstable id(mesh))
         fn = _timeline.device_busy_wrap(

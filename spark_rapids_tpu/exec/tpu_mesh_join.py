@@ -192,7 +192,7 @@ class TpuMeshShuffledJoin(TpuExec):
 
             pcap = lw[0].shape[0]
             out_cap = pcap * 2
-            pc, build_idx, live_out, total = join_k.expand_matches(
+            pc, build_idx, live_out, total = join_k.join_expand_matches(
                 lo, counts_eff, bt.perm, out_cap)
             ovf_out = total > out_cap
             matched_slot = jnp.take(counts, pc) > 0
@@ -215,10 +215,11 @@ class TpuMeshShuffledJoin(TpuExec):
 
         n_in = nw + 2 * len(l_dts) + 1 + nw + 2 * len(r_dts) + 1
         n_out = 2 * len(l_dts) + (2 * len(r_dts) if emit_right else 0) + 2
-        fn = jax.jit(shard_map(
+        fn = _compile_watch.jit(shard_map(
             step, mesh=mesh,
             in_specs=tuple(P(_AXIS) for _ in range(n_in)),
-            out_specs=tuple(P(_AXIS) for _ in range(n_out))))
+            out_specs=tuple(P(_AXIS) for _ in range(n_out))),
+            "mesh_join_step")
         # perf plane: each dispatch window is busy time on every mesh
         # device; the first call (jit compile) lands in compile_watch
         # with the cache key (minus the unstable id(mesh)) as signature

@@ -159,10 +159,11 @@ class TpuMeshSort(TpuExec):
 
         n_in = 2 * nkeys + 2 * len(pay_dts) + 1
         n_out = 2 * len(pay_dts) + 2
-        fn = jax.jit(shard_map(
+        fn = _compile_watch.jit(shard_map(
             step, mesh=mesh,
             in_specs=tuple(P(_AXIS) for _ in range(n_in)),
-            out_specs=tuple(P(_AXIS) for _ in range(n_out))))
+            out_specs=tuple(P(_AXIS) for _ in range(n_out))),
+            "mesh_sort_step")
         # perf plane: per-device busy windows + first-call compile
         # telemetry (signature drops the unstable id(mesh))
         fn = _timeline.device_busy_wrap(

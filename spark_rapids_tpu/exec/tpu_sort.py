@@ -318,7 +318,7 @@ class TpuSort(TpuExec):
         if b_hi is not None:
             lt, _ = cmp_lt(words, unpack(b_hi))
             keep = keep & lt
-        idx, cnt = bk.compact_indices(keep, chunk.num_rows)
+        idx, cnt = bk.filter_compact_indices(keep, chunk.num_rows)
         from ..analysis import residency  # lazy: avoids import cycle
         with residency.declared_transfer(site="sort_ooc"):
             n = int(cnt)

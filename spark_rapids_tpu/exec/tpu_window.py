@@ -280,7 +280,7 @@ class TpuWindow(TpuExec):
         c_hi = jnp.take(cnt, hi_c)
         c_lo = jnp.where(lo_c < 0, 0, jnp.take(cnt, jnp.maximum(lo_c, 0)))
         m_sorted = jnp.where(hi_pos < lo_pos, 0, c_hi - c_lo)
-        vpos, _ = bk.compact_indices(valid, cap)
+        vpos, _ = bk.filter_compact_indices(valid, cap)
         inv = jnp.argsort(perm)
         m_orig = jnp.where(jnp.arange(cap) < n,
                            jnp.take(m_sorted, inv), 0)
@@ -289,7 +289,7 @@ class TpuWindow(TpuExec):
         with residency.declared_transfer(site="size_probe"):
             total = int(jnp.sum(m_orig))
         out_cap = bucket_capacity(max(total, 1))
-        _, elem_pos, live_e, _ = join_k.expand_matches(
+        _, elem_pos, live_e, _ = join_k.join_expand_matches(
             c_lo_orig.astype(jnp.int32), m_orig.astype(jnp.int32),
             vpos.astype(jnp.int32), out_cap)
         elements = sorted_src.gather(elem_pos)

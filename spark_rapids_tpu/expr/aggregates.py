@@ -408,10 +408,10 @@ def _collect_update(plan, c):
     per-group loop, all static shapes.  Nulls drop (Spark collect_list
     semantics); within-group order is input order (stable sort)."""
     from ..columnar.column import ListColumn
-    from ..kernels.basic import compact_indices
+    from ..kernels.basic import filter_compact_indices
     cap = c.capacity
     keep = jnp.take(c.validity, plan.perm) & plan.live_sorted
-    order2, _n = compact_indices(keep, cap)
+    order2, _n = filter_compact_indices(keep, cap)
     take2 = jnp.take(plan.perm, order2)
     elems = c.gather(take2).mask_validity(jnp.take(keep, order2))
     cnt = jax.ops.segment_sum(keep.astype(jnp.int32), plan.seg_id,
@@ -501,8 +501,8 @@ class CollectSet(AggregateFunction):
             (sg[1:] != sg[:-1]) | (sw[1:] != sw[:-1]) |
             (sz[1:] != sz[:-1])]) & slive
         # compact kept elements
-        from ..kernels.basic import compact_indices
-        korder, _n = compact_indices(first, first.shape[0])
+        from ..kernels.basic import filter_compact_indices
+        korder, _n = filter_compact_indices(first, first.shape[0])
         ktake = jnp.take(perm, korder)
         elems = lst.elements.gather(ktake).mask_validity(
             jnp.take(first, korder))

@@ -215,8 +215,8 @@ class ArrayContains(ec.Expression):
         needle, needle_valid = _needle_column(needle, cap, batch.num_rows)
         eq = _segment_equals(col.elements, needle, needle_valid, seg_rows,
                              batch.num_rows)
-        hit = lk.segmented_any(eq & evalid, seg, cap + 1)[:cap]
-        has_null_elem = lk.segmented_any(~evalid & (seg < cap), seg,
+        hit = lk.list_segmented_any(eq & evalid, seg, cap + 1)[:cap]
+        has_null_elem = lk.list_segmented_any(~evalid & (seg < cap), seg,
                                          cap + 1)[:cap]
         valid = col.validity & needle_valid[:cap] & (hit | ~has_null_elem)
         return Column(T.BOOL, hit, valid)
@@ -238,8 +238,8 @@ def _segment_equals(elements: Column, needle: Column, needle_valid,
         from ..kernels import strings as sk
         nw = max(sk.needed_key_words(elements, elements.capacity),
                  sk.needed_key_words(needle, num_rows))
-        ewords = sk._pack_words(elements.offsets, elements.data, nw)
-        nwords = sk._pack_words(needle.offsets, needle.data, nw)
+        ewords = sk.str_pack_words(elements.offsets, elements.data, nw)
+        nwords = sk.str_pack_words(needle.offsets, needle.data, nw)
         eq = jnp.all(ewords == jnp.take(nwords, seg_rows, axis=0), axis=1)
         elens = elements.offsets[1:] - elements.offsets[:-1]
         nlens = needle.offsets[1:] - needle.offsets[:-1]
@@ -279,12 +279,12 @@ class SortArray(ec.Expression):
             else jnp.where(evalid, jnp.uint64(0), jnp.uint64(1))
         # LSD chained pair-sorts (kernels/sort.py rationale): significance
         # order is segment > null rank > value words, so least first
-        from ..kernels.sort import _stable_pair_sort
+        from ..kernels.sort import sort_stable_pair
         perm = jnp.arange(ecap, dtype=jnp.int32)
         passes = list(reversed([seg.astype(jnp.uint64), nk] +
                                [(w if self.asc else ~w) for w in words]))
         for w in passes:
-            perm = _stable_pair_sort(jnp.take(w, perm), perm)
+            perm = sort_stable_pair(jnp.take(w, perm), perm)
         elems = col.elements.gather(perm)
         return ListColumn(col.dtype, col.offsets, elems, col.validity)
 
@@ -337,12 +337,12 @@ def _seg_minmax(arr_e, batch, is_min: bool):
         fn = jax.ops.segment_min if is_min else jax.ops.segment_max
         red = fn(masked, seg, num_segments=cap + 1)[:cap]
         if is_min:
-            has_num = lk.segmented_any(evalid & ~nan, seg, cap + 1)[:cap]
+            has_num = lk.list_segmented_any(evalid & ~nan, seg, cap + 1)[:cap]
             red = jnp.where(has_num, red, jnp.array(jnp.nan, data.dtype))
         else:
-            has_nan = lk.segmented_any(evalid & nan, seg, cap + 1)[:cap]
+            has_nan = lk.list_segmented_any(evalid & nan, seg, cap + 1)[:cap]
             red = jnp.where(has_nan, jnp.array(jnp.nan, data.dtype), red)
-        any_valid = lk.segmented_any(evalid, seg, cap + 1)[:cap]
+        any_valid = lk.list_segmented_any(evalid, seg, cap + 1)[:cap]
         return Column(dt, red, col.validity & any_valid)
     if dt == T.BOOL:
         neutral = is_min  # True for min, False for max
@@ -352,7 +352,7 @@ def _seg_minmax(arr_e, batch, is_min: bool):
     masked = jnp.where(evalid, data, jnp.asarray(neutral, data.dtype))
     fn = jax.ops.segment_min if is_min else jax.ops.segment_max
     red = fn(masked, seg, num_segments=cap + 1)[:cap]
-    any_valid = lk.segmented_any(evalid, seg, cap + 1)[:cap]
+    any_valid = lk.list_segmented_any(evalid, seg, cap + 1)[:cap]
     return Column(dt, red.astype(data.dtype), col.validity & any_valid)
 
 

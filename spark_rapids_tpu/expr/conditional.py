@@ -55,7 +55,7 @@ def _select_columns(branches, batch, out_t):
 
 def _select_strings(conds, cols, cap):
     """Row-wise select among string columns via indexed gather."""
-    from ..kernels.strings import _materialize_bytes
+    from ..kernels.strings import str_materialize_bytes
     from ..columnar.column import bucket_capacity
     sel = jnp.full(cap, len(cols), jnp.int32)
     decided = jnp.zeros(cap, bool)
@@ -91,7 +91,7 @@ def _select_strings(conds, cols, cap):
     # differ per column, so materialize per column then select
     out = jnp.zeros(out_bytes, jnp.uint8)
     for i, c in enumerate(cols):
-        buf_i = _materialize_bytes(c.data, new_offsets, src_start, out_bytes)
+        buf_i = str_materialize_bytes(c.data, new_offsets, src_start, out_bytes)
         j = jnp.arange(out_bytes, dtype=jnp.int32)
         row_of_j = jnp.clip(
             jnp.searchsorted(new_offsets[1:], j, side="right"), 0, cap - 1)

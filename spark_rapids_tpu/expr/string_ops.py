@@ -286,7 +286,7 @@ class ConcatStrings(Expression):
 
     def columnar_eval(self, batch):
         from ..columnar.column import bucket_capacity
-        from ..kernels.strings import _materialize_bytes
+        from ..kernels.strings import str_materialize_bytes
         cols = [_eval_string(c, batch) for c in self.children]
         cap = batch.capacity
         valid = cols[0].validity
@@ -312,7 +312,7 @@ class ConcatStrings(Expression):
             piece_offsets = jnp.concatenate(
                 [jnp.zeros(1, jnp.int32),
                  jnp.cumsum(piece_lens).astype(jnp.int32)])
-            piece_buf = _materialize_bytes(c.data, piece_offsets,
+            piece_buf = str_materialize_bytes(c.data, piece_offsets,
                                            c.offsets[:-1], out_bytes)
             # scatter piece bytes to dst positions
             j = jnp.arange(out_bytes, dtype=jnp.int32)
@@ -382,13 +382,13 @@ class StringTrim(Expression):
         new_lens = jnp.where(col.validity, new_lens, 0)
         src_starts = (starts + new_start_rel).astype(jnp.int32)
         from ..columnar.column import bucket_capacity
-        from ..kernels.strings import _materialize_bytes
+        from ..kernels.strings import str_materialize_bytes
         new_offsets = jnp.concatenate(
             [jnp.zeros(1, jnp.int32), jnp.cumsum(new_lens).astype(jnp.int32)])
         from ..analysis import residency  # lazy: avoids import cycle
         with residency.declared_transfer(site="size_probe"):
             total = int(new_offsets[-1])
-        buf = _materialize_bytes(col.data, new_offsets, src_starts,
+        buf = str_materialize_bytes(col.data, new_offsets, src_starts,
                                  bucket_capacity(max(1, total)))
         return StringColumn(new_offsets, buf, col.validity,
                             max_bytes=col.max_bytes)

@@ -20,6 +20,7 @@ from ..config import (TpuConf, PARQUET_READER_TYPE, MULTITHREAD_READ_THREADS,
 from ..exec.base import PhysicalPlan, NUM_OUTPUT_ROWS
 from ..exec.cpu import CpuExec
 from ..exec.tpu_basic import TpuExec
+from ..obs import trace as _trace
 from ..plan import logical as L
 from .readers import (FilePartitionReader,
                       expand_paths_with_partitions,
@@ -149,7 +150,9 @@ class TpuFileScan(TpuExec):
                 for table in self._reader(files):
                     for chunk in self._chunks(table, max_rows):
                         self.metrics[NUM_OUTPUT_ROWS] += chunk.num_rows
-                        yield from_arrow(chunk)
+                        with _trace.span("srt.scan.upload", "scan", True):
+                            batch = from_arrow(chunk)
+                        yield batch
             parts = [run(files) for files in self._partitions]
         else:
             parts = self._execute_prefetch(max_rows)
@@ -272,7 +275,9 @@ class TpuFileScan(TpuExec):
                         self.metrics[NUM_OUTPUT_ROWS] += chunk.num_rows
                         sem.acquire_if_necessary()
                         try:
-                            batch = from_arrow(chunk)
+                            with _trace.span("srt.scan.upload", "scan",
+                                             True):
+                                batch = from_arrow(chunk)
                         finally:
                             sem.release()
                         yield batch

@@ -31,6 +31,7 @@ except Exception:  # pragma: no cover
     HAVE_ORC = False
 
 from ..columnar.arrow import from_arrow, schema_from_arrow
+from ..obs import trace as _trace
 from ..columnar.schema import Schema
 
 
@@ -203,6 +204,12 @@ class FilePartitionReader:
         self.partition_dtypes = partition_dtypes or {}
 
     def _read(self, pair) -> pa.Table:
+        # coarse span: file read + decode to a host table (set-up time
+        # once the scan cache holds the upload)
+        with _trace.span("srt.scan.read", "scan", True):
+            return self._read_file(pair)
+
+    def _read_file(self, pair) -> pa.Table:
         path, pvals = pair
         # partition-value columns live in the directory layout, not the
         # file: never ask the file reader for them

@@ -16,10 +16,10 @@ from typing import List
 import jax
 import jax.numpy as jnp
 
+from ..obs import trace as _trace
 from . import canon
 from .sort import sorted_words
-from .basic import compact_indices
-from ..obs.trace import traced
+from .basic import filter_compact_indices
 
 
 @dataclasses.dataclass
@@ -33,7 +33,7 @@ class GroupPlan:
     last_pos: jnp.ndarray      # sorted position of each group's LAST row
 
 
-@traced("groupby_plan")
+@jax.named_scope("groupby_plan")
 def groupby_plan(words: List[jnp.ndarray]) -> GroupPlan:
     """Build the sort+segment plan for a set of canonical key words.
 
@@ -53,7 +53,7 @@ def groupby_plan(words: List[jnp.ndarray]) -> GroupPlan:
     seg_id = jnp.cumsum(boundary.astype(jnp.int32)) - 1
     seg_id = jnp.maximum(seg_id, 0)
     num_groups = jnp.sum(boundary)
-    rep_order, _ = compact_indices(boundary, boundary.shape[0])
+    rep_order, _ = filter_compact_indices(boundary, boundary.shape[0])
     rep_indices = jnp.take(perm, rep_order)
     # group g spans sorted rows [head_pos[g], last_pos[g]]; dead rows sort
     # after all live rows, so the last live group ends at live_count-1
@@ -109,6 +109,7 @@ def seg_sum(plan: GroupPlan, values, validity, out_dtype=None):
         # the default is the f64-emulated scatter (error ~(n/G)*2^-48,
         # far inside the engines' 1e-9 comparison tolerance).
         return _seg_sum_f64_pair(plan, acc, ok)
+    _trace.count_eager("eager.seg_sum_scatter", contrib)
     return jax.ops.segment_sum(contrib, plan.seg_id, num_segments=cap)
 
 

@@ -11,11 +11,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..obs.trace import traced
 
 
 @jax.jit
-def compact_indices(keep_mask, num_rows):
+def filter_compact_indices(keep_mask, num_rows):
     """Turn a boolean keep-mask into a stable gather plan.
 
     Returns (indices[cap], new_count).  Rows where keep is True are moved to
@@ -32,7 +31,7 @@ def compact_indices(keep_mask, num_rows):
 
 
 @jax.jit
-def prefix_positions(keep_mask):
+def filter_prefix_positions(keep_mask):
     """positions[i] = output slot of row i if kept (cumsum-1)."""
     return jnp.cumsum(keep_mask.astype(jnp.int32)) - 1
 
@@ -48,7 +47,7 @@ M2 = 0xc4ceb9fe1a85ec53
 
 
 @jax.jit
-def mix64(x):
+def hash_mix64(x):
     x = x.astype(jnp.uint64)
     x = x ^ (x >> jnp.uint64(33))
     x = x * jnp.uint64(M1)
@@ -58,12 +57,12 @@ def mix64(x):
     return x
 
 
-@traced("hash_words")
+@jax.named_scope("hash_words")
 def hash_words(word_lists, seed: int = 42):
     """Combine lists of uint64 word arrays into one 64-bit hash per row."""
     h = jnp.full(word_lists[0].shape, jnp.uint64(seed))
     for w in word_lists:
-        h = mix64(h ^ w)
+        h = hash_mix64(h ^ w)
     return h
 
 

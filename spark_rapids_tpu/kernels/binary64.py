@@ -732,8 +732,8 @@ def _derive_bounds(seg_id, contrib_mask):
                                 seg_id[1:] != seg_id[:-1]])
     else:
         head = jnp.ones(1, bool)
-    from .basic import compact_indices
-    head_pos, num_groups = compact_indices(head, n)
+    from .basic import filter_compact_indices
+    head_pos, num_groups = filter_compact_indices(head, n)
     gi = jnp.arange(n, dtype=jnp.int32)
     nxt = jnp.concatenate([head_pos[1:].astype(jnp.int32),
                            jnp.zeros(1, jnp.int32)])

@@ -21,7 +21,6 @@ from jax import lax
 
 from . import canon
 from .sort import sorted_words
-from ..obs.trace import traced
 
 
 @dataclasses.dataclass
@@ -32,7 +31,7 @@ class BuildTable:
     capacity: int
 
 
-@traced("join_build")
+@jax.named_scope("join_build")
 def build(words: List[jnp.ndarray]) -> BuildTable:
     ws, perm = sorted_words(words)
     return BuildTable(ws, perm, int(perm.shape[0]))
@@ -79,7 +78,7 @@ class JoinCounts:
     matched: jnp.ndarray       # counts > 0 (valid probe rows only)
 
 
-@traced("join_probe_counts")
+@jax.named_scope("join_probe_counts")
 def probe_counts(bt: BuildTable, probe_words: List[jnp.ndarray],
                  probe_num_rows: int,
                  null_equals_null: bool = False) -> JoinCounts:
@@ -100,8 +99,7 @@ def probe_counts(bt: BuildTable, probe_words: List[jnp.ndarray],
 
 
 @functools.partial(jax.jit, static_argnames=("out_cap",))
-@traced("join_expand_matches")
-def expand_matches(lo, counts, perm, out_cap: int):
+def join_expand_matches(lo, counts, perm, out_cap: int):
     """Expand (lo, counts) into flat (probe_idx, build_idx) gather maps.
 
     Output row t belongs to probe row p where exclusive-cumsum[p] <= t <

@@ -28,7 +28,7 @@ def list_lengths(offsets) -> jnp.ndarray:
 
 
 @jax.jit
-def gather_list_offsets(offsets, validity, indices):
+def list_gather_offsets(offsets, validity, indices):
     """Phase 1 of a list-column row gather: new offsets + element total.
 
     Returns (new_offsets[ncap+1], gathered_validity[ncap],
@@ -46,7 +46,7 @@ def gather_list_offsets(offsets, validity, indices):
 
 
 @functools.partial(jax.jit, static_argnames=("elem_cap",))
-def element_gather_indices(new_offsets, src_starts, elem_cap: int):
+def list_element_gather_indices(new_offsets, src_starts, elem_cap: int):
     """Phase 2: for each output element slot, the source element index.
 
     Returns (src_idx[elem_cap], live[elem_cap]): slot j belongs to output
@@ -63,7 +63,7 @@ def element_gather_indices(new_offsets, src_starts, elem_cap: int):
 
 
 @functools.partial(jax.jit, static_argnames=("num_rows", "outer"))
-def explode_offsets(offsets, validity, num_rows: int, outer: bool):
+def list_explode_offsets(offsets, validity, num_rows: int, outer: bool):
     """Per-row output counts for explode (GpuGenerateExec.scala role).
 
     explode emits one output row per element; null/empty lists emit 0 rows
@@ -82,7 +82,7 @@ def explode_offsets(offsets, validity, num_rows: int, outer: bool):
 
 
 @functools.partial(jax.jit, static_argnames=("out_cap",))
-def explode_indices(offsets, validity, out_offsets, out_cap: int):
+def list_explode_indices(offsets, validity, out_offsets, out_cap: int):
     """Row/element/position indices for each exploded output row.
 
     Returns (row_idx, elem_idx, pos, elem_valid, live) each [out_cap]:
@@ -106,11 +106,11 @@ def explode_indices(offsets, validity, out_offsets, out_cap: int):
 
 def segment_ids_for(offsets, elem_cap: int):
     """Row id [elem_cap] of each element; n_lists for dead slots."""
-    return _segment_ids(offsets, elem_cap)
+    return list_segment_ids(offsets, elem_cap)
 
 
 @functools.partial(jax.jit, static_argnames=("elem_cap",))
-def _segment_ids(offsets, elem_cap: int):
+def list_segment_ids(offsets, elem_cap: int):
     j = jnp.arange(elem_cap, dtype=jnp.int32)
     row = jnp.searchsorted(offsets[1:], j, side="right").astype(jnp.int32)
     n_lists = offsets.shape[0] - 1
@@ -120,7 +120,7 @@ def _segment_ids(offsets, elem_cap: int):
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments",))
-def segmented_any(flags, seg_ids, num_segments: int):
+def list_segmented_any(flags, seg_ids, num_segments: int):
     """OR-reduce boolean flags per segment."""
     return jax.ops.segment_max(flags.astype(jnp.int32), seg_ids,
                                num_segments=num_segments) > 0

@@ -97,7 +97,7 @@ def _table_reduce_kernel(nsum: int, nmax: int, gt: int):
 
 
 @functools.partial(jax.jit, static_argnames=("table", "nsum", "nmax"))
-def _table_reduce_tpu(bucket, sums_in, maxs_in, table: int, nsum: int,
+def agg_table_reduce_tpu(bucket, sums_in, maxs_in, table: int, nsum: int,
                       nmax: int):
     # Trace with x64 OFF: every kernel type here is 32-bit, and pallas
     # fori_loop tracing under jax_enable_x64 hits an infinite promotion
@@ -177,7 +177,7 @@ def table_reduce(bucket, sum_rows, max_rows, table: int,
             jnp.zeros((1, bucket.shape[0]), jnp.float32)
         maxs_in = jnp.stack(max_rows, 0) if nmax else \
             jnp.full((1, bucket.shape[0]), -jnp.inf, jnp.float32)
-        sum_out, max_out = _table_reduce_tpu(
+        sum_out, max_out = agg_table_reduce_tpu(
             bucket, sums_in, maxs_in, table, nsum, nmax)
         return ([sum_out[i][:table] for i in range(nsum)],
                 [max_out[i][:table] for i in range(nmax)])

@@ -22,11 +22,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..obs.trace import traced
 
 
 @jax.jit
-def _stable_pair_sort(key, perm):
+def sort_stable_pair(key, perm):
     """The one compiled sort primitive: stable ascending by ``key``,
     carrying ``perm`` — shape-cached per (capacity bucket, key dtype).
 
@@ -37,7 +36,7 @@ def _stable_pair_sort(key, perm):
     return out
 
 
-@traced("sort_permutation")
+@jax.named_scope("sort_permutation")
 def sort_permutation(words: List[jnp.ndarray]) -> jnp.ndarray:
     """Stable ascending sort over word tuples; returns permutation indices."""
     cap = words[0].shape[0]
@@ -46,16 +45,15 @@ def sort_permutation(words: List[jnp.ndarray]) -> jnp.ndarray:
         w = words[0]
         if w.dtype != jnp.dtype(jnp.uint32):
             w = w.astype(jnp.uint64)
-        return _stable_pair_sort(w, perm)
+        return sort_stable_pair(w, perm)
     # LSD: least-significant word first; stability makes later (more
     # significant) passes dominate
     for w in reversed(words):
         k = jnp.take(w.astype(jnp.uint64), perm)
-        perm = _stable_pair_sort(k, perm)
+        perm = sort_stable_pair(k, perm)
     return perm
 
 
-@traced("sorted_words")
 def sorted_words(words: List[jnp.ndarray]):
     """Sort and also return the sorted word arrays (for boundary detection)."""
     perm = sort_permutation(words)

@@ -22,7 +22,7 @@ def string_lengths(offsets) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("num_words",))
-def _pack_words(offsets, data, num_words: int):
+def str_pack_words(offsets, data, num_words: int):
     """[cap, num_words] big-endian uint64 words of each string, zero-padded."""
     cap = offsets.shape[0] - 1
     starts = offsets[:-1]
@@ -94,14 +94,14 @@ def string_key_words(col: StringColumn, num_rows: int,
         # max length is host-known from offsets (one small sync per batch;
         # the reference similarly reads cuDF column metadata host-side).
         num_words = needed_key_words(col, num_rows)
-    words = _pack_words(col.offsets, col.data, num_words)
+    words = str_pack_words(col.offsets, col.data, num_words)
     out = [words[:, i] for i in range(num_words)]
     out.append(string_lengths(col.offsets).astype(jnp.uint64))
     return out
 
 
 @jax.jit
-def _gather_offsets(offsets, validity, indices, live=None):
+def str_gather_offsets(offsets, validity, indices, live=None):
     starts = offsets[:-1]
     lens = offsets[1:] - starts
     ncap = indices.shape[0]
@@ -121,7 +121,7 @@ def _gather_offsets(offsets, validity, indices, live=None):
 
 
 @functools.partial(jax.jit, static_argnames=("out_bytes",))
-def _materialize_bytes(data, new_offsets, src_starts, out_bytes: int):
+def str_materialize_bytes(data, new_offsets, src_starts, out_bytes: int):
     j = jnp.arange(out_bytes, dtype=jnp.int32)
     row = jnp.searchsorted(new_offsets[1:], j, side="right").astype(jnp.int32)
     row = jnp.clip(row, 0, new_offsets.shape[0] - 2)
@@ -150,9 +150,9 @@ def gather_strings(offsets, data, validity, indices, live=None,
     - ``max_bytes``: rows * max-single-string-length, used when that
       bound is not much larger than the source buffer.
     """
-    new_offsets, gvalid, src_starts, total = _gather_offsets(
+    new_offsets, gvalid, src_starts, total = str_gather_offsets(
         offsets, validity, indices, live)
-    # _materialize_bytes does O(out_bytes) device work, so a static
+    # str_materialize_bytes does O(out_bytes) device work, so a static
     # bound only beats the ~0.1-0.2s sync when it is SMALL; large
     # source buffers keep the exact-size sync
     _NOSYNC_MAX = 1 << 22
@@ -168,7 +168,7 @@ def gather_strings(offsets, data, validity, indices, live=None,
         from ..analysis import residency  # lazy: avoids import cycle
         with residency.declared_transfer(site="size_probe"):
             out_bytes = bucket_capacity(max(1, int(total)))
-    buf = _materialize_bytes(data, new_offsets, src_starts, out_bytes)
+    buf = str_materialize_bytes(data, new_offsets, src_starts, out_bytes)
     return new_offsets, buf, gvalid
 
 
@@ -183,27 +183,27 @@ _LOWER_TBL[ord("A"): ord("Z") + 1] += 32
 
 
 @jax.jit
-def upper_bytes(data):
+def str_upper_bytes(data):
     return jnp.take(jnp.asarray(_UPPER_TBL), data.astype(jnp.int32))
 
 
 @jax.jit
-def lower_bytes(data):
+def str_lower_bytes(data):
     return jnp.take(jnp.asarray(_LOWER_TBL), data.astype(jnp.int32))
 
 
 def upper(col: StringColumn) -> StringColumn:
-    return StringColumn(col.offsets, upper_bytes(col.data), col.validity,
+    return StringColumn(col.offsets, str_upper_bytes(col.data), col.validity,
                         max_bytes=col.max_bytes)
 
 
 def lower(col: StringColumn) -> StringColumn:
-    return StringColumn(col.offsets, lower_bytes(col.data), col.validity,
+    return StringColumn(col.offsets, str_lower_bytes(col.data), col.validity,
                         max_bytes=col.max_bytes)
 
 
 @jax.jit
-def _substring_offsets(offsets, start, length):
+def str_substring_offsets(offsets, start, length):
     """Spark substring semantics: 1-based start, negative counts from end."""
     starts = offsets[:-1]
     lens = offsets[1:] - starts
@@ -219,7 +219,7 @@ def substring(col: StringColumn, start: int, length: int) -> StringColumn:
     start_a = jnp.full((cap,), start, jnp.int32)
     len_a = jnp.full((cap,), length if length is not None else 2**31 - 1,
                      jnp.int32)
-    src_starts, new_lens = _substring_offsets(col.offsets, start_a, len_a)
+    src_starts, new_lens = str_substring_offsets(col.offsets, start_a, len_a)
     new_lens = jnp.where(col.validity, new_lens, 0)
     new_offsets = jnp.concatenate(
         [jnp.zeros(1, jnp.int32), jnp.cumsum(new_lens).astype(jnp.int32)])
@@ -227,7 +227,7 @@ def substring(col: StringColumn, start: int, length: int) -> StringColumn:
     with residency.declared_transfer(site="size_probe"):
         total = int(new_offsets[-1])
     out_bytes = bucket_capacity(max(1, total))
-    buf = _materialize_bytes(col.data, new_offsets, src_starts, out_bytes)
+    buf = str_materialize_bytes(col.data, new_offsets, src_starts, out_bytes)
     mb = col.max_bytes
     if mb is not None and length is not None:
         mb = min(mb, max(length, 0))

@@ -143,13 +143,12 @@ class DeviceSemaphore:
 
     @staticmethod
     def _observe_wait(t0_ns: int, waited_ns: int):
-        """One blocked-acquire observation: wait histogram + (when
-        tracing) a retroactive "memory" span covering the blocked
+        """One blocked-acquire observation: wait histogram + the
+        retroactive coarse span ``srt.sem_wait`` covering the blocked
         region.  Only blocked acquires reach here — the immediate-grant
         fast path stays observation-free."""
         SEM_WAIT_SECONDS.observe(waited_ns / 1e9)
-        if _trace._ENABLED:
-            _trace.emit("sem_wait", "memory", t0_ns, waited_ns)
+        _trace.emit("srt.sem_wait", "memory", t0_ns, waited_ns, True)
 
     def release(self):
         count = getattr(self._held, "count", 0)

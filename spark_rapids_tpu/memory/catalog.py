@@ -379,7 +379,8 @@ class BufferCatalog:
     def _spill_entry_to_host(self, e: BufferEntry, rank: int = 0):
         _flight.record(_flight.EV_SPILL, "device_to_host", a=e.nbytes)
         t0 = time.perf_counter_ns()
-        with _trace.span("spill_device_to_host", "memory", bytes=e.nbytes):
+        with _trace.span("srt.spill.device_to_host", "memory", True,
+                         bytes=e.nbytes):
             payload = self._serialize(e.device_obj)
             if self.arena is not None:
                 payload = self._pack_into_arena(payload)
@@ -434,7 +435,8 @@ class BufferCatalog:
     def _spill_entry_to_disk(self, e: BufferEntry, rank: int = 0):
         _flight.record(_flight.EV_SPILL, "host_to_disk", a=e.nbytes)
         t0 = time.perf_counter_ns()
-        with _trace.span("spill_host_to_disk", "memory", bytes=e.nbytes):
+        with _trace.span("srt.spill.host_to_disk", "memory", True,
+                         bytes=e.nbytes):
             self._spill_entry_to_disk_inner(e)
         _memplane.note_spill(
             _memplane.DIR_HOST_TO_DISK, e.buffer_id, e.owner_query,
@@ -475,7 +477,7 @@ class BufferCatalog:
         from .pressure import oom_retry
         _flight.record(_flight.EV_UNSPILL, "host_to_device", a=e.nbytes)
         t0 = time.perf_counter_ns()
-        with _trace.span("unspill_host_to_device", "memory",
+        with _trace.span("srt.unspill.host_to_device", "memory", True,
                          bytes=e.nbytes):
             payload, _ = self._unpack_payload(e.host_payload)
             # the device put can hit the REAL allocator's
@@ -502,7 +504,8 @@ class BufferCatalog:
     def _unspill_disk(self, e: BufferEntry):
         _flight.record(_flight.EV_UNSPILL, "disk_to_host", a=e.nbytes)
         t0 = time.perf_counter_ns()
-        with _trace.span("unspill_disk_to_host", "memory", bytes=e.nbytes):
+        with _trace.span("srt.unspill.disk_to_host", "memory", True,
+                         bytes=e.nbytes):
             self._unspill_disk_inner(e)
         return self._unspill_host(e,
                                   extra_ns=time.perf_counter_ns() - t0)

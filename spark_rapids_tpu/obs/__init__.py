@@ -83,7 +83,7 @@ from . import (trace, registry, prom, flight, timeline,     # noqa: F401
                compile_watch, slo, profile, netplane,       # noqa: F401
                memplane, costplane, doctor, overhead)       # noqa: F401
 from .registry import get_registry  # noqa: F401
-from .trace import span, traced     # noqa: F401
+from .trace import span             # noqa: F401
 
 # install the pending-pool flush observer (idempotent module hook)
 profile.install()

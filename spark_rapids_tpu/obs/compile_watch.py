@@ -39,6 +39,15 @@ call.  Each recorded compile carries:
   ``aot.note_demand`` for that cache), rendered per-bucket by
   ``tools/report.py``.
 
+``jit(fn, name)`` is the engine's one way to build a ``jax.jit`` object
+at run time: it names the program after its operator and role (the
+module XLA compiles is ``jit_<name>``, which is what a device trace
+shows), and counts the construction in ``jit_builds()`` with its site,
+cached site or not.  A warmed engine builds none per query; every one
+it does build is a re-trace and a persistent-cache load (or a compile)
+on some query's path.  The first call of a wrapped closure is the coarse
+span ``srt.jit_build`` (obs/trace.py), and its record carries ``t0_ns``.
+
 Hot-path discipline (this file is on the SYNC001/OBS002 lint scope):
 the warm path is one list-index check; recording happens once per
 compile (seconds-scale events) and allocates one small dict there.
@@ -50,6 +59,7 @@ import time
 from typing import Callable, Dict, List, Optional
 
 from . import flight
+from . import trace as _trace
 from .registry import COMPILE_SECONDS
 
 _SIG_MAX = 160          #: stored signature strings are truncated here
@@ -69,6 +79,33 @@ _WARMUP_NS = 0          #: background warmup compiles (the pseudo-victim)
 _PERSISTENT_NS = 0      #: persistent-cache deserializations (not compiles)
 _PERSISTENT_HITS = 0
 _RECORDS: List[Dict] = []
+_JIT_BUILDS = 0         #: jax.jit objects built through ``jit()``
+_JIT_BUILD_SITES: Dict[str, int] = {}   #: the same, by program name
+
+
+def jit(fn: Callable, name: str, **jit_kwargs) -> Callable:
+    """``jax.jit(fn, **jit_kwargs)`` with the program named ``name``
+    (``<operator>_<role>``, unique across the engine, the same from
+    query to query) and the construction counted.  Naming happens on
+    the python function before ``jax.jit`` sees it: no run-time cost."""
+    global _JIT_BUILDS
+    import jax
+    try:
+        fn.__name__ = fn.__qualname__ = name
+    except AttributeError:
+        # a bound method has no settable name: jit a named forwarder
+        # (traced once per shape, never called on the warm path)
+        inner = fn
+
+        def fn(*args, **kwargs):
+            return inner(*args, **kwargs)
+        fn.__name__ = fn.__qualname__ = name
+    with _LOCK:
+        _JIT_BUILDS += 1
+        _JIT_BUILD_SITES[name] = _JIT_BUILD_SITES.get(name, 0) + 1
+    # and in the building query's counter table (trace.coarse_counts)
+    _trace.count("jit_build." + name)
+    return jax.jit(fn, **jit_kwargs)
 
 
 def _store(rec: Dict) -> None:
@@ -82,7 +119,7 @@ def _store(rec: Dict) -> None:
 
 
 def note_compile(cache: str, dur_ns: int, signature: Optional[str] = None,
-                 ) -> None:
+                 t0_ns: Optional[int] = None) -> None:
     """Record one finished compile: histogram, bounded record store,
     process counters, the victim token's ``inline_compile_ms``, and a
     flight breadcrumb (constant name + plain ints — OBS002).
@@ -108,7 +145,7 @@ def note_compile(cache: str, dur_ns: int, signature: Optional[str] = None,
            "signature": sig, "inline": inline, "origin": origin,
            "bucket": bucket,
            "query_id": tok.query_id if inline else None,
-           "end_ns": time.perf_counter_ns()}
+           "t0_ns": t0_ns, "end_ns": time.perf_counter_ns()}
     with _LOCK:
         _SEQ += 1
         if warmup:
@@ -125,7 +162,8 @@ def note_compile(cache: str, dur_ns: int, signature: Optional[str] = None,
 
 
 def note_persistent_hit(cache: str, dur_ns: int,
-                        signature: Optional[str] = None) -> None:
+                        signature: Optional[str] = None,
+                        t0_ns: Optional[int] = None) -> None:
     """Record a first call satisfied by the persistent executable
     cache: an earlier process compiled this (program, signature, conf
     fingerprint) and this call deserialized it.  Counted under
@@ -142,7 +180,7 @@ def note_persistent_hit(cache: str, dur_ns: int,
     rec = {"cache": cache, "dur_ms": round(dur_ns / 1e6, 3),
            "signature": sig, "inline": False, "origin": "persistent",
            "bucket": aot.last_demand(cache), "query_id": None,
-           "end_ns": time.perf_counter_ns()}
+           "t0_ns": t0_ns, "end_ns": time.perf_counter_ns()}
     with _LOCK:
         _PERSISTENT_NS += dur_ns
         _PERSISTENT_HITS += 1
@@ -173,15 +211,16 @@ def wrap_miss(cache: str, fn: Callable, signature=None) -> Callable:
             return fn(*args, **kwargs)
         from ..compile import aot
         key = aot.first_call_key(cache, signature)
-        t0 = time.perf_counter_ns()
-        out = fn(*args, **kwargs)
+        with _trace.span("srt.jit_build", "compile", True, cache=cache,
+                         site=getattr(fn, "__name__", "")) as sp:
+            out = fn(*args, **kwargs)
         compiled[0] = True
-        dur_ns = time.perf_counter_ns() - t0
+        t0, dur_ns = sp.t0, sp.dur_ns
         persistent = aot.persistent_ready(key)
         if persistent:
-            note_persistent_hit(cache, dur_ns, signature)
+            note_persistent_hit(cache, dur_ns, signature, t0)
         else:
-            note_compile(cache, dur_ns, signature)
+            note_compile(cache, dur_ns, signature, t0)
             if key is not None:
                 aot.manifest_add(key, cache, signature,
                                  aot.last_demand(cache), dur_ns / 1e6)
@@ -212,6 +251,18 @@ def compile_seq() -> int:
     (dispatch_cold routing in obs/profile.py).  An int read is atomic
     under the GIL — no torn values, worst case one late tick."""
     return _SEQ
+
+
+def jit_builds() -> int:
+    """``jax.jit`` objects the engine has built through ``jit()`` since
+    the process started (lock-free int read, like ``compile_seq``)."""
+    return _JIT_BUILDS
+
+
+def jit_build_sites() -> Dict[str, int]:
+    """``{program name: constructions}``: which sites rebuild."""
+    with _LOCK:
+        return dict(_JIT_BUILD_SITES)
 
 
 def total_ns() -> int:

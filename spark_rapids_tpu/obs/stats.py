@@ -108,7 +108,7 @@ def sample_every(conf=None) -> int:
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnums=(5, 6))
-def _stats_prog(h, pids, valid, word0, num_rows, nparts: int, m: int):
+def stats_map_sketch(h, pids, valid, word0, num_rows, nparts: int, m: int):
     """One fused stats program per map batch: HLL registers + null
     counts + key-word min/max, all per partition.
 
@@ -143,9 +143,9 @@ def _stats_prog(h, pids, valid, word0, num_rows, nparts: int, m: int):
 # wrap_miss site (one module-level jit, not a keyed cache), so the
 # plane's own wrapper supplies the static-cost record.  The program
 # auditor keeps lowering the unwrapped jit via _audit_specs below.
-_stats_prog_jit = _stats_prog
+_stats_prog_jit = stats_map_sketch
 from . import costplane as _costplane  # noqa: E402
-_stats_prog = _costplane.wrap_capture("exchange_stats", _stats_prog_jit)
+stats_map_sketch = _costplane.wrap_capture("exchange_stats", _stats_prog_jit)
 
 
 class ExchangeBatchStats:
@@ -236,7 +236,7 @@ def stage_exchange_batch(partitioner, batch, m: int, acc=None,
         from ..compile import aot as _aot
         _aot.note_demand("exchange_stats", batch.capacity,
                          _rows_if_resolved(batch))
-        regs, nulls, wmin, wmax = _stats_prog(
+        regs, nulls, wmin, wmax = stats_map_sketch(
             h, pids, valid, word0, batch.rows_dev,
             partitioner.num_partitions, m)
         st = ExchangeBatchStats(

@@ -2,41 +2,80 @@
 nvtx_profiling.md in the reference, SURVEY.md §5) adapted to a
 multi-tenant serving process.
 
-Spans are thread-local nested regions with query_id/attempt attribution
-pulled from the active :class:`~..service.cancellation.CancelToken`, so
-overlapping queries through the service disentangle by query_id even
-when their spans interleave on the same worker thread.  Finished spans
-buffer in-process and export as Chrome trace-event JSON ("X" complete
-events) loadable in Perfetto / chrome://tracing.
+One ``Span`` class, two levels:
 
-Overhead contract: with tracing disabled (the default) the fast path is
-ONE module-global flag read — ``span()`` returns a shared no-op context
-manager (no allocation), ``traced`` wrappers call straight through, and
-hot call sites additionally guard with ``if trace._ENABLED`` so not even
-an argument dict is built.  Stdlib-only: imported by exec/, memory/,
-shuffle/ and kernels/ layers.
+- **coarse** spans (``span(..., coarse=True)``, names ``srt.*``) sit at
+  the engine's layer boundaries (parse, plan, query, operator region,
+  jit build, flush, pull, scan, semaphore wait, spill) and are ALWAYS
+  recorded, on two clocks at once.  On enter a coarse span opens a
+  ``jax.profiler.TraceAnnotation`` when a profiler session is live (the
+  xplane's host plane, the device trace's clock; one ``is_enabled()``
+  read otherwise) and reads ``time.perf_counter_ns``; on exit it writes
+  one slot of the tracer's bounded ring: id, parent id, name, start,
+  duration, thread, query number.  ``coarse_spans()`` reads the ring
+  back; ``chipbench/span_reduce.py`` turns it into host time by layer.
+- **fine** spans (``span(...)``, today's behaviour) exist only while
+  ``spark.rapids.tpu.obs.trace.enabled`` is set and buffer as Chrome
+  trace events ("X" complete events) loadable in Perfetto /
+  chrome://tracing; coarse spans add the same record then.  With fine
+  tracing off ``span()`` returns a shared no-op (one flag read).
+
+Spans nest per thread (each records its parent's id); every span of one
+query shares a query number: the active
+:class:`~..service.cancellation.CancelToken`'s ``query_id`` under the
+service, else a sequence number taken at ``session.sql()`` /
+``execute_to_arrow`` (``begin_query``) that pool workers adopt
+(``adopt_query``).  ``count()`` keeps per-query counters of the same
+key (the eager one-op launches, ``eager.<site>``).
+
+The ring is the flight recorder's discipline: preallocated slots
+mutated in place, overwrite-oldest, no lock (the slot index comes from
+an ``itertools.count``, atomic under the GIL).  A reader racing a writer
+can see one torn slot; the benchmark reads after its window has closed.
+Stdlib-only at import (jax's profiler is bound at the first coarse
+span): imported by exec/, memory/, shuffle/ and columnar/ layers.
 """
 from __future__ import annotations
 
-import functools
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
 
 from ..service.cancellation import current_token
 
-#: module-level fast-path flag.  Read directly (``trace._ENABLED``) by
-#: hot call sites; everything else goes through enable()/disable().
+#: module-level fast-path flag for the FINE level.  Read directly
+#: (``trace._ENABLED``) by hot call sites; everything else goes through
+#: enable()/disable().  Coarse spans do not consult it to record.
 _ENABLED = False
+
+#: slots in the coarse ring: a benchmark window is 15-20 queries of
+#: under 200 coarse spans each
+RING_SLOTS = 16_384
+#: queries whose ``count()`` tables are kept
+COUNT_QUERIES = 64
 
 _PID = os.getpid()
 _TLS = threading.local()
+_IDS = itertools.count(1)
+_QUERY_SEQ = itertools.count(1)
+#: jax.profiler.TraceAnnotation and jax.core.Tracer, bound at first use
+_ANNOTATION = None
+_JAX_TRACER = None
+
+
+def _bind_jax():
+    global _ANNOTATION, _JAX_TRACER
+    from jax.core import Tracer
+    from jax.profiler import TraceAnnotation
+    _ANNOTATION, _JAX_TRACER = TraceAnnotation, Tracer
 
 
 class _NoopSpan:
-    """Shared do-nothing span: the disabled-path return value of
+    """Shared do-nothing span: the disabled-path return value of a fine
     ``span()``.  A singleton so the disabled fast path allocates
     nothing."""
     __slots__ = ()
@@ -54,51 +93,88 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
-class Span:
-    """One live span; finishes (records) on ``__exit__``."""
-    __slots__ = ("name", "cat", "args", "t0")
+def _query_number(tls: Dict, args: Optional[Dict] = None):
+    """The query a span, emit or count on this thread belongs to; a
+    service query's id also lands in ``args`` (the fine record's
+    attribution)."""
+    tok = current_token()
+    if tok is not None and tok.query_id is not None:
+        if args is not None and "query_id" not in args:
+            args["query_id"] = tok.query_id
+        return tok.query_id
+    return tls.get("qno")
 
-    def __init__(self, name: str, cat: str, args: Dict):
+
+class Span:
+    """One live span; finishes (records) on ``__exit__``.  ``dur_ns``
+    holds the measured duration afterwards, so a site that needs the
+    number (``exec/base.timed``, the flush observer) reads the clock
+    once with the span."""
+    __slots__ = ("name", "cat", "args", "coarse", "t0", "dur_ns", "id",
+                 "parent", "qno", "_ann")
+
+    def __init__(self, name: str, cat: str, args: Dict,
+                 coarse: bool = False):
         self.name = name
         self.cat = cat
         self.args = args
+        self.coarse = coarse
+        self._ann = None
 
     def __enter__(self):
-        tok = current_token()
-        if tok is not None and tok.query_id is not None and \
-                "query_id" not in self.args:
-            self.args["query_id"] = tok.query_id
         d = _TLS.__dict__
+        self.qno = _query_number(d, self.args)
+        self.id = next(_IDS)
+        self.parent = d.get("cur", 0)
+        d["cur"] = self.id
         d["depth"] = d.get("depth", 0) + 1
+        if self.coarse:
+            if _ANNOTATION is None:
+                _bind_jax()
+            if _ANNOTATION.is_enabled():
+                self._ann = _ANNOTATION(self.name, **self.args)
+                self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def set(self, **attrs) -> "Span":
         self.args.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = time.perf_counter_ns() - self.t0
+        dur = self.dur_ns = time.perf_counter_ns() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         d = _TLS.__dict__
         depth = d.get("depth", 1)
         d["depth"] = depth - 1
+        d["cur"] = self.parent
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
-        tr = _TRACER
-        if tr is not None:
-            tr.record(self.name, self.cat, self.t0, dur, depth, self.args)
+        tr = _TRACER or get_tracer()
+        if self.coarse:
+            tr.ring_write(self.id, self.parent, self.name, self.t0, dur,
+                          self.qno, self.args)
+        if _ENABLED:
+            tr.record(self.name, self.cat, self.t0, dur, depth, self.args,
+                      self.id, self.parent)
         return False
 
 
 class SpanTracer:
-    """Process-wide finished-span buffer + Chrome trace export.
+    """Process-wide span store: the coarse ring (always written) and
+    the fine buffer with its Chrome trace export.
 
-    The buffer is bounded (``max_spans``): past it new spans are counted
-    as dropped instead of growing without limit — a long service run
-    with tracing left on must not OOM the host."""
+    Both are bounded.  The ring overwrites its oldest slot; the fine
+    buffer (``max_spans``) counts new spans as dropped past its limit
+    instead of growing — a long service run with tracing left on must
+    not OOM the host."""
 
     def __init__(self, max_spans: int = 100_000,
-                 path: Optional[str] = None):
+                 path: Optional[str] = None,
+                 ring_slots: int = RING_SLOTS):
         self.max_spans = max_spans
         self.path = path
         self.epoch_ns = time.perf_counter_ns()
@@ -106,15 +182,82 @@ class SpanTracer:
         self._events: List[Dict] = []
         self._thread_names: Dict[int, str] = {}
         self.dropped = 0
+        self.ring_slots = ring_slots
+        self.reset_ring()
 
+    # -- coarse ring ---------------------------------------------------------
+    def reset_ring(self):
+        # slot: [seq, id, parent, name, t0_ns, dur_ns, thread, query, args]
+        self._ring = [[-1, 0, 0, "", 0, 0, 0, None, None]
+                      for _ in range(self.ring_slots)]
+        self._ring_seq = itertools.count()
+        self._counts: Dict = {}
+
+    def ring_write(self, sid: int, parent: int, name: str, t0_ns: int,
+                   dur_ns: int, qno, args: Optional[Dict]):
+        k = next(self._ring_seq)
+        s = self._ring[k % self.ring_slots]
+        s[1] = sid
+        s[2] = parent
+        s[3] = name
+        s[4] = t0_ns
+        s[5] = dur_ns
+        s[6] = threading.get_ident()
+        s[7] = qno
+        s[8] = args
+        s[0] = k
+
+    def coarse_spans(self, since_ns: Optional[int] = None
+                     ) -> Optional[List[Dict]]:
+        """The ring's spans in completion order, those that ended
+        before ``since_ns`` left out.  None (and a line on stderr) when
+        the ring has wrapped past ``since_ns``: spans of the asked-for
+        interval may be overwritten, and a partial sum is worse than
+        none."""
+        rows = sorted((list(s) for s in self._ring if s[0] >= 0),
+                      key=lambda s: s[0])
+        if rows and rows[0][0] > 0:
+            oldest_end = rows[0][4] + rows[0][5]
+            if since_ns is None or oldest_end >= since_ns:
+                print(f"obs.trace: the coarse ring ({self.ring_slots} "
+                      f"slots) wrapped past the asked-for start; "
+                      f"{rows[0][0]} spans overwritten", file=sys.stderr,
+                      flush=True)
+                return None
+        return [{"id": s[1], "parent": s[2], "name": s[3], "t0_ns": s[4],
+                 "dur_ns": s[5], "thread": s[6], "query": s[7],
+                 "args": dict(s[8]) if s[8] else {}}
+                for s in rows
+                if since_ns is None or s[4] + s[5] >= since_ns]
+
+    def ring_written(self) -> int:
+        """Spans written to the ring since its last reset (past
+        ``ring_slots`` the oldest are gone)."""
+        return max((s[0] for s in self._ring), default=-1) + 1
+
+    def count(self, qno, name: str, n: int = 1):
+        with self._lock:
+            tbl = self._counts.get(qno)
+            if tbl is None:
+                tbl = self._counts[qno] = {}
+                while len(self._counts) > COUNT_QUERIES:
+                    # dicts keep insertion order: the first is the oldest
+                    del self._counts[next(iter(self._counts))]
+            tbl[name] = tbl.get(name, 0) + n
+
+    def coarse_counts(self) -> Dict:
+        with self._lock:
+            return {q: dict(t) for q, t in self._counts.items()}
+
+    # -- fine buffer ---------------------------------------------------------
     def record(self, name: str, cat: str, t0_ns: int, dur_ns: int,
-               depth: int, args: Dict):
+               depth: int, args: Dict, sid: int = 0, parent: int = 0):
         tid = threading.get_ident()
         ev = {"name": name, "cat": cat, "ph": "X",
               "ts": (t0_ns - self.epoch_ns) / 1e3,
               "dur": dur_ns / 1e3,
               "pid": _PID, "tid": tid,
-              "args": dict(args, depth=depth)}
+              "args": dict(args, depth=depth, id=sid, parent=parent)}
         with self._lock:
             if len(self._events) >= self.max_spans:
                 self.dropped += 1
@@ -151,6 +294,7 @@ class SpanTracer:
         return path
 
     def reset(self):
+        """Drop the fine buffer (the ring has ``reset_ring``)."""
         with self._lock:
             self._events.clear()
             self._thread_names.clear()
@@ -177,8 +321,9 @@ def is_enabled() -> bool:
 
 def enable(path: Optional[str] = None,
            max_spans: Optional[int] = None) -> SpanTracer:
-    """Turn tracing on (fresh buffer).  ``path`` is where ``flush()``
-    writes the Chrome trace JSON."""
+    """Turn fine tracing on (fresh fine buffer; the coarse ring is
+    left as it is).  ``path`` is where ``flush()`` writes the Chrome
+    trace JSON."""
     global _ENABLED
     tr = get_tracer()
     tr.reset()
@@ -206,64 +351,109 @@ def configure(conf) -> None:
                max_spans=conf.get(OBS_TRACE_MAX_SPANS))
 
 
-def span(name: str, cat: str = "engine", **args):
-    """Open a span context.  Disabled-path cost: one flag read + the
-    shared no-op singleton (call sites hotter than per-batch should
-    guard with ``if trace._ENABLED`` to skip the kwargs dict too)."""
-    if not _ENABLED:
+def span(name: str, cat: str = "engine", coarse: bool = False, **args):
+    """Open a span context.  A fine span with tracing off costs one
+    flag read + the shared no-op singleton (call sites hotter than
+    per-batch should guard with ``if trace._ENABLED`` to skip the kwargs
+    dict too); a coarse span is always live."""
+    if not (coarse or _ENABLED):
         return _NOOP
-    return Span(name, cat, args)
+    return Span(name, cat, args, coarse)
 
 
-def emit(name: str, cat: str, start_ns: int, dur_ns: int, **args):
+def emit(name: str, cat: str, start_ns: int, dur_ns: int,
+         coarse: bool = False, **args):
     """Record an already-elapsed region retroactively (e.g. a queue or
-    semaphore wait measured by its own clock).  ``start_ns`` is a
-    time.perf_counter_ns() instant."""
-    if not _ENABLED:
+    semaphore wait measured by its own clock), as a child of the
+    calling thread's open span.  ``start_ns`` is a
+    time.perf_counter_ns() instant.  The profiler has no retroactive
+    annotation: a coarse emit lands in the ring only."""
+    if not (coarse or _ENABLED):
         return
-    tok = current_token()
-    if tok is not None and tok.query_id is not None and \
-            "query_id" not in args:
-        args["query_id"] = tok.query_id
-    tr = _TRACER
-    if tr is not None:
-        depth = _TLS.__dict__.get("depth", 0) + 1
-        tr.record(name, cat, start_ns, dur_ns, depth, args)
+    d = _TLS.__dict__
+    qno = _query_number(d, args)
+    tr = _TRACER or get_tracer()
+    sid, parent = next(_IDS), d.get("cur", 0)
+    if coarse:
+        tr.ring_write(sid, parent, name, start_ns, dur_ns, qno, args)
+    if _ENABLED:
+        tr.record(name, cat, start_ns, dur_ns, d.get("depth", 0) + 1, args,
+                  sid, parent)
 
 
-def traced(name: str, cat: str = "kernel"):
-    """Decorator form for kernel entry points: spans the call when
-    tracing is on, calls nearly straight through (one flag read each
-    for the tracer and the flight recorder) when off.  The flight
-    recorder (obs/flight.py) shares this boundary so the always-on
-    black box and full tracing instrument one code path; its record
-    call passes only the interned ``name`` (OBS002: allocation-free)."""
-    from . import flight as _flight
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*a, **k):
-            _flight.record(_flight.EV_KERNEL, name)
-            try:
-                if not _ENABLED:
-                    return fn(*a, **k)
-                with Span(name, cat, {}):
-                    return fn(*a, **k)
-            finally:
-                _flight.record(_flight.EV_KERNEL_END, name)
-        return wrapper
-    return deco
+# ---------------------------------------------------------------------------
+# query numbers and per-query counters
+# ---------------------------------------------------------------------------
+
+def begin_query(parsed: bool = False) -> int:
+    """Give the calling thread's next query its number.
+    ``session.sql()`` calls this with ``parsed=True``; the
+    ``execute_to_arrow`` that follows keeps that number, so the front
+    end's spans and the execution's share one.  An execution with no
+    ``sql()`` before it (the DataFrame API, a second ``collect()``)
+    takes a new one.  Under the service the token's ``query_id`` wins
+    over this number in every span."""
+    d = _TLS.__dict__
+    if parsed or not d.pop("parsed", False):
+        d["qno"] = next(_QUERY_SEQ)
+    if parsed:
+        d["parsed"] = True
+    return d["qno"]
+
+
+def current_query():
+    """The calling thread's query number (token ``query_id`` first)."""
+    return _query_number(_TLS.__dict__)
+
+
+def adopt_query(qno) -> None:
+    """A pool worker serving another thread's query takes its number."""
+    _TLS.qno = qno
+
+
+def count(name: str, n: int = 1) -> None:
+    """One integer add, under the tracer's lock, into the calling
+    query's counter table (kept for the last ``COUNT_QUERIES`` queries;
+    ``coarse_counts()`` reads them)."""
+    (_TRACER or get_tracer()).count(_query_number(_TLS.__dict__), name, n)
+
+
+def count_eager(name: str, operand, n: int = 1) -> None:
+    """``count(name, n)`` for a site that launches ``n`` one-op jax
+    programs when it runs eagerly (``jit__take``, ``jit_scatter-add``:
+    jax's names, not the engine's to change).  Under a ``jax.jit`` trace
+    ``operand`` is a tracer and the ops join the program being built:
+    nothing is launched and nothing is counted."""
+    if _JAX_TRACER is None:
+        _bind_jax()
+    if not isinstance(operand, _JAX_TRACER):
+        count(name, n)
+
+
+def coarse_spans(since_ns: Optional[int] = None) -> Optional[List[Dict]]:
+    """See :meth:`SpanTracer.coarse_spans`."""
+    return get_tracer().coarse_spans(since_ns)
+
+
+def coarse_counts() -> Dict:
+    """``{query number: {name: count}}`` of the last queries."""
+    return get_tracer().coarse_counts()
 
 
 def flush(path: Optional[str] = None) -> Optional[str]:
-    """Write the current buffer to ``path`` (or the enable()-time path).
-    Returns the written path; None when tracing never started or no
-    output path is configured (in-memory tracing: tests/tools read the
-    buffer through ``get_tracer()`` instead)."""
+    """Write the fine buffer to ``path`` (or the enable()-time path):
+    called when a session or the service closes, or on request — never
+    per query.  Returns the written path; None when tracing never
+    started or no output path is configured (in-memory tracing:
+    tests/tools read the buffer through ``get_tracer()`` instead)."""
     if _TRACER is None or not (path or _TRACER.path):
         return None
     return _TRACER.write(path)
 
 
 def reset():
+    """Test hook: drop the fine buffer, the ring and the counters."""
     if _TRACER is not None:
         _TRACER.reset()
+        _TRACER.reset_ring()
+    _TLS.__dict__.clear()

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs import compile_watch as _compile_watch
+
 MIX = 0x9E3779B97F4A7C15
 SIGN64_BIAS = 0x8000000000000000
 
@@ -151,7 +153,7 @@ def distributed_sum_by_key(mesh: Mesh, axis_name: str = "data"):
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),
         out_specs=(P(axis_name), P(axis_name), P(axis_name),
                    P(axis_name)))
-    return _instrumented(jax.jit(smapped), mesh)
+    return _instrumented(_compile_watch.jit(smapped, "mesh_sum_by_key"), mesh)
 
 
 def distributed_global_sum(mesh: Mesh, axis_name: str = "data"):
@@ -163,9 +165,9 @@ def distributed_global_sum(mesh: Mesh, axis_name: str = "data"):
         local = jnp.sum(jnp.where(valid, vals, 0))
         return jax.lax.psum(local, axis_name)[None]
 
-    return _instrumented(jax.jit(shard_map(
+    return _instrumented(_compile_watch.jit(shard_map(
         step, mesh=mesh, in_specs=(P(axis_name), P(axis_name)),
-        out_specs=P(axis_name))), mesh)
+        out_specs=P(axis_name)), "mesh_global_sum"), mesh)
 
 
 def distributed_join_sum(mesh: Mesh, axis_name: str = "data"):
@@ -232,7 +234,7 @@ def distributed_join_sum(mesh: Mesh, axis_name: str = "data"):
         in_specs=(P(axis_name),) * 6,
         out_specs=(P(axis_name), P(axis_name), P(axis_name),
                    P(axis_name)))
-    return _instrumented(jax.jit(smapped), mesh)
+    return _instrumented(_compile_watch.jit(smapped, "mesh_join_sum"), mesh)
 
 
 def distributed_sort(mesh: Mesh, axis_name: str = "data",
@@ -277,4 +279,4 @@ def distributed_sort(mesh: Mesh, axis_name: str = "data",
         step, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name)),
         out_specs=(P(axis_name), P(axis_name), P(axis_name)))
-    return _instrumented(jax.jit(smapped), mesh)
+    return _instrumented(_compile_watch.jit(smapped, "mesh_sort"), mesh)

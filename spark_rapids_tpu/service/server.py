@@ -277,6 +277,9 @@ class QueryService:
         self.watchdog.stop()
         self.warmup.stop()
         _history.stop()
+        # the span file is written here, once (no-op when fine tracing
+        # is off or has no path), not after every query
+        _trace.flush()
         if self._scrape_server is not None:
             # hardened lifecycle: stop() joins the serving thread and
             # closes the socket so a successor service can rebind the
@@ -433,13 +436,12 @@ class QueryService:
         handle._worker_ident = threading.get_ident()
         m.queue_wait_ms = (time.time() - m.submitted_ts) * 1000.0
         QUEUE_WAIT_SECONDS.observe(m.queue_wait_ms / 1e3)
-        if _trace._ENABLED:
-            # retroactive span: the admission-to-start wait, on the
-            # worker thread's track just before the attempt spans
-            wait_ns = int(m.queue_wait_ms * 1e6)
-            _trace.emit("queue_wait", "service",
-                        time.perf_counter_ns() - wait_ns, wait_ns,
-                        query_id=handle.query_id)
+        # retroactive coarse span: the admission-to-start wait, on the
+        # worker thread's track just before the attempt spans
+        wait_ns = int(m.queue_wait_ms * 1e6)
+        _trace.emit("srt.queue_wait", "service",
+                    time.perf_counter_ns() - wait_ns, wait_ns, True,
+                    query_id=handle.query_id)
         if handle.token.cancelled:
             self._finalize_cancel(handle)
             return
@@ -512,8 +514,9 @@ class QueryService:
         m = handle.metrics
         conf = base_conf.with_overrides(self.retry.overlay(attempt,
                                                            base_conf))
-        with _trace.span("attempt", "service", query_id=handle.query_id,
-                         tenant=handle.tenant, attempt=attempt), \
+        with _trace.span("srt.attempt", "service", True,
+                         query_id=handle.query_id, tenant=handle.tenant,
+                         attempt=attempt), \
                 query_context(handle.token) as token:
             token.observed.clear()
             token.check()
@@ -654,12 +657,6 @@ class QueryService:
     def _forget(self, handle: QueryHandle):
         with self._inflight_lock:
             self._inflight.pop(handle.query_id, None)
-        # the query's "attempt" span closes after the session-level
-        # flush inside execute_physical; re-flush so the trace file on
-        # disk always includes the finished query's full span tree
-        # (no-op when tracing is off or no path is configured)
-        if _trace.is_enabled():
-            _trace.flush()
 
     # -- introspection -----------------------------------------------------
     def stats(self) -> "ServiceStats":

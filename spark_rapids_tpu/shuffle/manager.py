@@ -93,7 +93,7 @@ class ShuffleCatalog:
         # shuffle_serialize in shuffle/meta.py)
         from ..memory.spillable import SpillableBatch
         t0 = time.perf_counter_ns()
-        with _trace.span("shuffle_write", "shuffle"):
+        with _trace.span("srt.shuffle.write", "shuffle", True):
             entries = [SpillableBatch(b, op="TpuShuffleExchange",
                                       site="exchange") for b in batches]
         nbytes = sum(e.nbytes for e in entries)
@@ -111,7 +111,7 @@ class ShuffleCatalog:
         become spillable immediately)."""
         from ..memory.spillable import SpillableBatch
         t0 = time.perf_counter_ns()
-        with _trace.span("shuffle_write", "shuffle"):
+        with _trace.span("srt.shuffle.write", "shuffle", True):
             entries = [SpillableBatch(b, op="TpuShuffleExchange",
                                       site="exchange") for b in batches]
         nbytes = sum(e.nbytes for e in entries)
@@ -129,7 +129,7 @@ class ShuffleCatalog:
         nbytes = sum(e.nbytes for e in entries)
         SHUFFLE_READ_BYTES.inc(nbytes)
         t0 = time.perf_counter_ns()
-        with _trace.span("shuffle_read", "shuffle"):
+        with _trace.span("srt.shuffle.read", "shuffle", True):
             out = [e.materialize() for e in entries]
         if entries:
             _netplane.note_deserialize(block.shuffle_id, block.map_id,

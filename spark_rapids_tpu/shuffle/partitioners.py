@@ -42,7 +42,7 @@ class SplitBatch:
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
-def _split_sort_counts(pids, num_rows, num_partitions: int):
+def partition_sort_counts(pids, num_rows, num_partitions: int):
     """One program: stable u32 sort by partition id (rows past num_rows
     to the end) + per-partition counts via searchsorted boundaries."""
     cap = pids.shape[0]
@@ -56,7 +56,7 @@ def _split_sort_counts(pids, num_rows, num_partitions: int):
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
-def _hash_partition_ids(word_lists, num_partitions: int):
+def partition_hash_ids(word_lists, num_partitions: int):
     """murmur-mix + mod over the key words -> partition id per row, as
     one program (XLA fuses the elementwise chain; 64-bit lanes rule out
     a Mosaic kernel, see kernels/pallas_ops.py)."""
@@ -80,7 +80,7 @@ class Partitioner:
         the sorted ids instead of a scatter (TPU scatters are ~15x the
         cost of a searchsorted at shuffle sizes)."""
         pids = self.partition_ids(batch)
-        perm, counts = _split_sort_counts(
+        perm, counts = partition_sort_counts(
             pids.astype(jnp.uint32), batch.rows_dev, self.num_partitions)
         sorted_batch = batch.gather(perm, batch.rows_lazy)
         return sorted_batch, LazyArray(counts)
@@ -128,7 +128,7 @@ class HashPartitioner(Partitioner):
             for w in canon.value_words(col, nr):
                 word_lists.append(jnp.where(col.validity, w,
                                             jnp.uint64(0x9E3779B97F4A7C15)))
-        return _hash_partition_ids(tuple(word_lists), self.num_partitions)
+        return partition_hash_ids(tuple(word_lists), self.num_partitions)
 
     def split_staged(self, batch: ColumnarBatch):
         """Whole split (key eval + hash + sort + counts + gather of every
@@ -183,8 +183,9 @@ class HashPartitioner(Partitioner):
                           jnp.take(v, perm, axis=0, mode="clip"))
                          for d, v in zip(datas, valids)]
                 return pairs, jnp.diff(bounds)
-            import jax as _jax
-            fn = _jax.jit(_prog)
+            from ..obs import compile_watch as _cw
+            fn = _cw.wrap_miss("partition_split",
+                               _cw.jit(_prog, "partition_split"), key)
             if len(HashPartitioner._SPLIT_JIT) < 4096:
                 HashPartitioner._SPLIT_JIT[key] = fn
         # a failure here is a compile or device error to fix, not a

@@ -252,13 +252,13 @@ class TestPallas:
         import jax.numpy as jnp
         from spark_rapids_tpu.kernels import basic as bk
         from spark_rapids_tpu.shuffle.partitioners import \
-            _hash_partition_ids
+            partition_hash_ids
         rng = np.random.default_rng(0)
         words = tuple(jnp.asarray(rng.integers(0, 2**63, 999).astype(
             np.uint64)) for _ in range(2))
         want = (bk.hash_words(list(words)) % jnp.uint64(7)).astype(
             jnp.int32)
-        got = _hash_partition_ids(words, 7)
+        got = partition_hash_ids(words, 7)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 

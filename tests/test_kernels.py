@@ -107,7 +107,7 @@ class TestJoin:
         assert list(counts) == [2, 0, 1, 2]
         total = join.total_matches(jc.counts)
         assert total == 5
-        p_idx, b_idx, live, tot = join.expand_matches(
+        p_idx, b_idx, live, tot = join.join_expand_matches(
             jc.lo, jc.counts, bt.perm, 8)
         pairs = sorted((int(p), int(bk.to_pylist(4)[b]))
                        for p, b, l in zip(p_idx, b_idx, live) if l)
@@ -181,7 +181,7 @@ class TestStrings:
 class TestBasic:
     def test_compact_indices(self):
         mask = jnp.array([True, False, True, False, True, False, False, False])
-        idx, cnt = basic.compact_indices(mask, 5)
+        idx, cnt = basic.filter_compact_indices(mask, 5)
         assert int(cnt) == 3
         assert list(np.asarray(idx))[:3] == [0, 2, 4]
 

@@ -25,12 +25,21 @@ def _trace_off_after():
 class TestSpanTracer:
     def test_disabled_span_is_shared_noop(self):
         assert trace.span("a") is trace.span("b", "kernel", x=1)
-        # disabled traced functions call straight through
-        @trace.traced("f")
+        assert trace.get_tracer().num_spans() == 0
+
+    def test_coarse_span_records_with_tracing_disabled(self):
+        # what the kernel-entry decorator used to cover: a region that
+        # is instrumented whether or not fine tracing is on.  A coarse
+        # span lands in the ring and leaves the fine buffer alone.
         def f(x):
-            return x + 1
+            with trace.span("srt.exec.f", "exec", coarse=True, rows=x):
+                with trace.span("fine_inner", "kernel"):
+                    return x + 1
         assert f(1) == 2
         assert trace.get_tracer().num_spans() == 0
+        (only,) = trace.coarse_spans()
+        assert only["name"] == "srt.exec.f" and only["parent"] == 0
+        assert only["args"] == {"rows": 1} and only["dur_ns"] >= 0
 
     def test_spans_record_and_nest(self):
         trace.enable()
@@ -44,6 +53,9 @@ class TestSpanTracer:
                    if e.get("ph") == "X"}
         assert by_name["inner"]["args"]["depth"] == \
             by_name["outer"]["args"]["depth"] + 1
+        assert by_name["inner"]["args"]["parent"] == \
+            by_name["outer"]["args"]["id"]
+        assert trace.coarse_spans() == []       # fine spans stay fine
         assert by_name["inner"]["args"]["k"] == "v"
         # inner fully contained in outer on the timeline
         o, i = by_name["outer"], by_name["inner"]
@@ -117,9 +129,10 @@ class TestSpanTracer:
         }))
         df = gen_df(s, {"k": KeyGen(), "v": IntGen()}, 200)
         df.group_by("k").agg(F.sum("v").alias("s")).collect()
+        s.close()           # the span file is written at close
         doc = json.load(open(path))
         names = {e["name"] for e in doc["traceEvents"]}
-        assert "query" in names
+        assert "srt.query" in names
         cats = {e.get("cat") for e in doc["traceEvents"]
                 if e.get("ph") == "X"}
         # engine (query) + exec (operators) at minimum; kernels when the
@@ -279,16 +292,20 @@ class TestTimedSpans:
             pass
         doc = trace.get_tracer().to_chrome_trace()
         evs = [e for e in doc["traceEvents"]
-               if e.get("name") == "TpuFakeOp"]
+               if e.get("name") == "srt.exec.TpuFakeOp"]
         assert evs and evs[0]["cat"] == "exec"
         assert evs[0]["args"]["metric"] == "opTime"
 
-    def test_timed_without_tracing_allocates_no_span(self):
+    def test_timed_without_tracing_writes_the_ring_only(self):
         from spark_rapids_tpu.exec.base import Metric, timed
         m = Metric("opTime")
-        with timed(m) as t:
-            assert t._span is None
+        with timed(m):
+            pass
         assert m.value > 0
+        assert trace.get_tracer().num_spans() == 0
+        (only,) = trace.coarse_spans()
+        assert only["name"] == "srt.exec.opTime"
+        assert only["dur_ns"] == m.value
 
 
 class TestReportTool:
@@ -328,6 +345,7 @@ class TestReportTool:
         }))
         df = gen_df(s, {"k": KeyGen(), "v": IntGen()}, 300)
         df.group_by("k").agg(F.sum("v").alias("s")).collect()
+        s.close()
         assert main([log, "--trace", tp]) == 0
         out = capsys.readouterr().out
         assert "critical-path spans" in out
