@@ -41,8 +41,13 @@ operator the flush its warm execute path is known to force:
 Assumptions (documented, asserted by the quartet cross-check): warm
 caches, the serial single-partition collect regime of ci smoke runs
 (per-partition flush scaling is counted once), single-batch broadcast
-builds, and ``SUPERSTAGE_SPEC_JOIN`` semantics matching
-exec/tpu_join.py's eligibility test.
+builds, ``SUPERSTAGE_SPEC_JOIN`` semantics matching
+exec/tpu_join.py's eligibility test, and stream batches below
+``TpuHashJoinBase._SIZED_MIN_CAPACITY``: an armed join whose partition
+holds a stream batch of that capacity or more sizes its outputs and
+pays the phase-A barrier of an eager join, one flush the prediction
+lacks.  The plan cannot know a later join's stream capacity (it is the
+earlier join's match count), so the predictor does not try.
 """
 from __future__ import annotations
 

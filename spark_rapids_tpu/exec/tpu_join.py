@@ -27,6 +27,7 @@ from ..expr import core as ec
 from ..kernels import canon, join as join_k
 from ..kernels import strings as skern
 from ..obs import compile_watch as _compile_watch
+from ..obs import trace as _trace
 from .base import (PhysicalPlan, BUILD_TIME, JOIN_TIME, NUM_OUTPUT_ROWS,
                    timed)
 from .tpu_basic import TpuExec
@@ -170,11 +171,17 @@ class TpuHashJoinBase(TpuExec):
         # Superstage path (compile/): sync-free speculative unique-match
         # join — no flush barrier at all; the fit flag rides to the next
         # superstage boundary.  Only the carve pass sets _superstage, and
-        # only under a consumer that resolves speculative batches.
+        # only under a consumer that resolves speculative batches.  Its
+        # output keeps the stream's capacity, so a partition holding a
+        # stream batch at or above _SIZED_MIN_CAPACITY pays the phase-A
+        # barrier below instead and sizes its outputs (decided once per
+        # partition: a None from batch k would discard batches 0..k-1).
         if getattr(self, "_superstage", False) and lg.join_type == "inner" \
                 and lg.condition is None and build_matched is None \
                 and all(w is None for w in str_words) \
-                and build.capacity > 0:
+                and build.capacity > 0 \
+                and max(b.capacity for b in stream_batches) \
+                < self._SIZED_MIN_CAPACITY:
             from ..config import get_active, SUPERSTAGE_SPEC_JOIN
             if get_active().get(SUPERSTAGE_SPEC_JOIN):
                 from ..obs import profile
@@ -191,9 +198,9 @@ class TpuHashJoinBase(TpuExec):
                         break
                     spec_outs.append(out)
                 if spec_outs is not None:
+                    _trace.count("join.batches.spec", len(spec_outs))
                     for out in spec_outs:
-                        self.metrics[NUM_OUTPUT_ROWS] += out.rows_lazy
-                        yield out
+                        yield self._note_output(out)
                     return
 
         # Phase A: probe counts for EVERY stream batch first; the output
@@ -209,6 +216,7 @@ class TpuHashJoinBase(TpuExec):
         from ..columnar import pending
         from ..columnar.batch import resolve_speculative
         pending.flush()
+        _trace.count("join.batches.sized", len(stream_batches))
         for (sb, skey_cols), pa in zip(
                 zip(stream_batches, skey_cols_per_batch), phase_a):
             # this flush is a verification barrier: upstream (the FINAL
@@ -234,15 +242,19 @@ class TpuHashJoinBase(TpuExec):
                 outs = self._expand_phases(sb, build, bt, *pa)
             for out in outs:
                 if out is not None:
-                    self.metrics[NUM_OUTPUT_ROWS] += out.rows_lazy
-                    yield out
+                    yield self._note_output(out)
 
         if lg.join_type == "full" and build is not None:
             out = self._unmatched_build_rows(build, build_matched,
                                              stream_schema)
             if out is not None and out.num_rows > 0:
-                self.metrics[NUM_OUTPUT_ROWS] += out.rows_lazy
-                yield out
+                yield self._note_output(out)
+
+    def _note_output(self, out: ColumnarBatch) -> ColumnarBatch:
+        self.metrics[NUM_OUTPUT_ROWS] += out.rows_lazy
+        # the slots every later gather over this batch runs over
+        _trace.count("join.out_capacity_rows", out.capacity)
+        return out
 
     # -- fused probe/expand (one program each; totals via pending pool) --
     _PROBE_JIT: dict = {}
@@ -251,6 +263,12 @@ class TpuHashJoinBase(TpuExec):
 
     # max entries in the direct-address probe table (64 MB of i32 HBM)
     _DIRECT_MAX_RANGE = 1 << 24
+
+    # stream-batch capacity from which a superstage join sizes its
+    # outputs (probe / flush / expand) rather than probing speculatively
+    # at the stream's capacity: where one speculative launch costs the
+    # device what the extra flush costs the query (PERF.md section 5)
+    _SIZED_MIN_CAPACITY = 1 << 14
 
     def _prepare_direct(self, bt, bkey_cols, build):
         """Direct-address probe tables for single fixed-width int keys.
