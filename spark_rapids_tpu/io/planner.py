@@ -36,6 +36,14 @@ def _strategy(fmt: str, conf: TpuConf) -> str:
     return s
 
 
+def _count_batch(how: str, rows: int) -> None:
+    """One batch a scan yields, into the calling query's ``scan.*``
+    counters: ``read`` (decoded and uploaded) or ``cached`` (replayed
+    from the device scan cache)."""
+    _trace.count(f"scan.batches.{how}")
+    _trace.count("scan.rows", rows)
+
+
 class TpuFileScan(TpuExec):
     """Reference: GpuFileSourceScanExec + reader strategies (§2.6)."""
 
@@ -142,6 +150,7 @@ class TpuFileScan(TpuExec):
                 def replay(batches):
                     for b in batches:
                         self.metrics[NUM_OUTPUT_ROWS] += b.num_rows
+                        _count_batch("cached", b.num_rows)
                         yield b
                 return self._stats_wrap([replay(part) for part in cached])
         if not self.conf.get(SCAN_PREFETCH) or \
@@ -152,6 +161,7 @@ class TpuFileScan(TpuExec):
                         self.metrics[NUM_OUTPUT_ROWS] += chunk.num_rows
                         with _trace.span("srt.scan.upload", "scan", True):
                             batch = from_arrow(chunk)
+                        _count_batch("read", chunk.num_rows)
                         yield batch
             parts = [run(files) for files in self._partitions]
         else:
@@ -196,6 +206,7 @@ class TpuFileScan(TpuExec):
                         state["bytes"] += b.nbytes()
                         if state["bytes"] > cap:
                             state["abandoned"] = True
+                            _trace.count("scan.cache.abandoned")
                             for part in collected:
                                 part.clear()
                         else:
@@ -280,6 +291,7 @@ class TpuFileScan(TpuExec):
                                 batch = from_arrow(chunk)
                         finally:
                             sem.release()
+                        _count_batch("read", chunk.num_rows)
                         yield batch
             finally:
                 # abandonment (LIMIT short-circuit, error, GC of the

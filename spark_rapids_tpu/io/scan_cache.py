@@ -17,6 +17,8 @@ import threading
 from collections import OrderedDict
 from typing import List, Optional, Tuple
 
+from ..obs import trace as _trace
+
 
 class DeviceScanCache:
     _instance: Optional["DeviceScanCache"] = None
@@ -55,9 +57,13 @@ class DeviceScanCache:
                 self._bytes -= old[1]
             self._store[key] = (parts, nbytes)
             self._bytes += nbytes
+            evicted = 0
             while self._bytes > cap_bytes and len(self._store) > 1:
                 _, (_, nb) = self._store.popitem(last=False)
                 self._bytes -= nb
+                evicted += 1
+        if evicted:
+            _trace.count("scan.cache.evicted", evicted)
 
     def clear(self):
         with self._lock:

@@ -279,9 +279,11 @@ class TestCoarseSpans:
         assert set(counts) <= set(numbers)
         # every counter belongs to a documented family
         # (docs/observability.md); jit_build.* appears whenever a
-        # neighbour dropped the jit caches, join.* with every hash join
+        # neighbour dropped the jit caches, join.* with every hash join,
+        # scan.* with every file scan
         for tbl in counts.values():
-            assert all(k.startswith(("eager.", "jit_build.", "join."))
+            assert all(k.startswith(("eager.", "jit_build.", "join.",
+                                     "scan."))
                        and v > 0 for k, v in tbl.items())
         assert any(k.startswith("eager.")
                    for tbl in counts.values() for k in tbl)
