@@ -306,7 +306,11 @@ class ColumnarBatch:
     def slice(self, start: int, length: int) -> "ColumnarBatch":
         valid_rows = min(length, max(self.num_rows - start, 0))
         out_cap = bucket_capacity(length)
-        if all(type(c) is Column for c in self.columns) and self.columns:
+        # the fixed-width columns go through ONE jitted program; strings
+        # and nested columns beside them gather lazily, each on its own
+        plain = [i for i, c in enumerate(self.columns) if type(c) is Column]
+        cols = list(self.columns)
+        if plain:
             fn = ColumnarBatch._SLICE_JIT.get(out_cap)
             if fn is None:
                 from ..obs import compile_watch as _cw
@@ -323,17 +327,18 @@ class ColumnarBatch:
                 fn = _cw.wrap_miss("batch_slice",
                                    _cw.jit(_slice, "batch_slice"), out_cap)
                 ColumnarBatch._SLICE_JIT[out_cap] = fn
-            pairs = fn(tuple(c.data for c in self.columns),
-                       tuple(c.validity for c in self.columns),
+            pairs = fn(tuple(self.columns[i].data for i in plain),
+                       tuple(self.columns[i].validity for i in plain),
                        start, valid_rows)
-            cols = [Column(c.dtype, d, v)
-                    for c, (d, v) in zip(self.columns, pairs)]
-            return ColumnarBatch(self.schema, cols, valid_rows)
-        idx = jnp.arange(out_cap) + start
-        b = self.gather(idx, valid_rows)
-        # rows past num_rows must be invalid
-        mask = jnp.arange(b.capacity) < valid_rows
-        cols = [c.mask_validity(mask) for c in b.columns]
+            for i, (d, v) in zip(plain, pairs):
+                cols[i] = Column(cols[i].dtype, d, v)
+        if len(plain) < len(cols):
+            idx = jnp.arange(out_cap) + start
+            # rows past num_rows must be invalid
+            mask = jnp.arange(out_cap) < valid_rows
+            for i, c in enumerate(cols):
+                if type(c) is not Column:
+                    cols[i] = c.gather(idx).mask_validity(mask)
         return ColumnarBatch(self.schema, cols, valid_rows)
 
     def nbytes(self) -> int:

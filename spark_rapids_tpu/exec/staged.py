@@ -11,9 +11,12 @@ the live row count stays a traced scalar throughout.
 
 The planner collapses physical TpuFilter/TpuProject chains into
 ``TpuStagedCompute`` (plan/overrides.py post-pass), and the hash
-aggregate absorbs a leading chain into its own fused core
-(tpu_aggregate._fused_agg_core), so scan -> filter -> project ->
-partial-agg runs as a single program launch per batch.
+aggregate absorbs a leading chain into its own cores
+(tpu_aggregate._fused_whole_stage_core / _fused_table_core), so scan ->
+filter -> project -> partial-agg runs as a single program launch per
+batch.  There a filter does not compact: it clears row liveness
+(``apply_ops_masked``), and the aggregate ranks a dead row past every
+group.
 """
 from __future__ import annotations
 
@@ -85,6 +88,49 @@ def apply_ops_traced(ops: Sequence[Op], batch) -> "_TracedBatch":
             cols = [ec.eval_as_column(e, batch) for e in payload]
             batch = _TracedBatch(out_schema, cols, n, batch.capacity)
     return batch
+
+
+def passthrough_ordinal(expr) -> Optional[int]:
+    """The input ordinal a project expression hands on untouched (a bare
+    column reference, aliased or not); None for anything computed."""
+    while isinstance(expr, ec.Alias):
+        expr = expr.children[0]
+    return expr.ordinal if isinstance(expr, ec.BoundReference) else None
+
+
+def ops_maskable(ops: Sequence[Op]) -> bool:
+    """Can ``apply_ops_masked`` trace this chain?  Predicates and
+    computed projections must be fixed-width and trace-safe; a STRING
+    column may only be handed on untouched (nothing re-orders rows here,
+    so ``ops_fusable``'s whole-row rule does not apply)."""
+    for kind, payload, _ in ops:
+        for e in ([payload] if kind == "filter" else payload):
+            if _tree_fusable(e):
+                continue
+            # _tree_fusable refuses what has no dtype, so a passthrough
+            # that got here has one
+            if kind == "filter" or passthrough_ordinal(e) is None or \
+                    e.dtype() != T.STRING:
+                return False
+    return True
+
+
+def apply_ops_masked(ops: Sequence[Op], batch, live):
+    """Run the chain under trace with every filter folded into the
+    ``live`` row mask instead of compacting: for consumers that do not
+    need contiguous rows (the aggregate's cores: the bucket table never
+    did, the sort path ranks a dead row past every group).  Compaction's
+    argsort and per-column gathers were the dominant map-side cost.
+    Returns (batch at the input's capacity and row count, live)."""
+    for kind, payload, out_schema in ops:
+        if kind == "filter":
+            pred = ec.eval_as_column(payload, batch)
+            live = live & pred.data.astype(bool) & pred.validity
+        else:
+            cols = [ec.eval_as_column(e, batch) for e in payload]
+            batch = _TracedBatch(out_schema, cols, batch.num_rows,
+                                 batch.capacity)
+    return batch, live
 
 
 def apply_ops_eager(ops: Sequence[Op], batch: ColumnarBatch,

@@ -35,30 +35,110 @@ from .tpu_basic import TpuExec
 PARTIAL, FINAL, COMPLETE = "partial", "final", "complete"
 
 
-def _assemble_group_output(plan, key_cols, aggs, agg_buffers, out_cap: int,
+def _assemble_group_output(plan, key_cols, aggs, agg_buffers,
                            emit_buffers: bool):
-    """Traced output assembly: compact keys + agg buffers to rows 0..G-1.
+    """Traced output assembly: keys + agg buffers at rows 0..G-1 of the
+    plan's ``num_slots`` (the core's output capacity).
 
     Runs INSIDE the fused cores — eager per-column gathers/masks after the
-    jitted plan pay one dispatch each, which dominated the reduce side."""
+    jitted plan pay one dispatch each, which dominated the reduce side.
+    The buffers come from the segment kernels at ``num_slots`` already;
+    only the keys are gathered, at their groups' representative rows.  A
+    STRING key (``canon.PackedStringKey``: the core holds its words, not
+    its bytes) yields (representative row, validity) for the caller's
+    lazy gather of the source column (``_output_columns``)."""
     ng = plan.num_groups
-    rep = plan.rep_indices
-    take = jnp.where(jnp.arange(out_cap) < ng,
-                     rep[:out_cap] if out_cap <= rep.shape[0] else
-                     jnp.pad(rep, (0, out_cap - rep.shape[0]))[:out_cap],
-                     0)
-    live = jnp.arange(out_cap) < ng
+    slots = plan.num_slots
+    live = jnp.arange(slots) < ng
+    take = jnp.where(live, plan.rep_indices, 0).astype(jnp.int32)
     outs = []
     for c in key_cols:
+        if type(c) is canon.PackedStringKey:
+            outs.append((take, jnp.take(c.validity, take) & live))
+            continue
         g = c.gather(take, live=live, unique=True).mask_validity(live)
         outs.append((g.data, g.validity))
-    seg_take = jnp.where(live, jnp.arange(out_cap), 0)
     for a, bufs in zip(aggs, agg_buffers):
         cols_out = bufs if emit_buffers else [a.func.finalize(bufs)]
         for o in cols_out:
-            c2 = o.gather(seg_take, live=live, unique=True).mask_validity(live)
-            outs.append((c2.data, c2.validity))
+            assert o.capacity == slots, (o.capacity, slots)
+            outs.append((o.data, o.validity & live))
     return ng, outs
+
+
+def _group_reduce(key_cols, live, num_rows, aggs, agg_cols,
+                  update_mode: bool, out_cap: Optional[int],
+                  emit_buffers: bool):
+    """The traced body both grouped cores share: merged key words ->
+    sort -> ONE row gather of every array the aggregates read in sorted
+    order -> update / merge (the DOUBLE sums as one stacked pass) ->
+    output assembly, all per-group work at the output capacity.
+    ``live`` (or None) marks the rows a folded-in filter kept: a dead
+    row gets rank 2 in the first key field and sorts past every group,
+    so nothing is compacted.  Returns (num_groups, fit, output pairs in
+    schema order)."""
+    from ..expr import aggregates as ea
+    cap = key_cols[0].capacity
+    rows = jnp.arange(cap) < num_rows
+    live = rows if live is None else live & rows
+    with jax.named_scope("key_words"):
+        words = canon.group_key_words(key_cols, num_rows, live)
+    carried = []                # what the aggregates read in sorted order
+    for a, cols in zip(aggs, agg_cols):
+        for c in cols:
+            if c is None:
+                continue
+            carried.append(c.validity)
+            # first / last read one row a group, by index
+            if not isinstance(a.func, (ea.First, ea.Last)):
+                carried.append(c.data)
+    plan = agg_k.groupby_plan(words, num_slots=out_cap, inputs=carried,
+                              live=live)
+    with jax.named_scope("segment_reduce"):
+        agg_k.stack_float_sums(
+            plan, [c for a, cols in zip(aggs, agg_cols)
+                   for c in a.func.float_sum_cols(cols)])
+        agg_buffers = [a.func.update(plan, cols) if update_mode
+                       else a.func.merge(plan, cols)
+                       for a, cols in zip(aggs, agg_cols)]
+    fit = (plan.num_groups <= plan.num_slots).astype(jnp.int32) \
+        if out_cap and out_cap < cap else jnp.int32(1)
+    with jax.named_scope("emit"):
+        ng, outs = _assemble_group_output(plan, key_cols, aggs,
+                                          agg_buffers, emit_buffers)
+    return ng, fit, outs
+
+
+def _pack_string_key(col, num_rows):
+    """(value words, validity) of a STRING key column for a core's
+    ``canon.PackedStringKey``, and the byte bound that sized the words:
+    ``jit_str_pack_words`` on the source column (a lazy gather view
+    gathers its source's words), outside the core because the bound is
+    host-known, not traced.  The bound is rounded up to a power of two:
+    it is part of the core's cache key."""
+    from ..kernels import strings as skern
+    bound = skern.key_byte_bound(col, num_rows)
+    bound = 1 << max(0, bound - 1).bit_length()
+    words = canon.value_words(col, num_rows,
+                              str_words=max(1, -(-bound // 8)))
+    return (tuple(words), col.validity), bound
+
+
+def _output_columns(schema, pairs, string_keys) -> list:
+    """Columns of a core's output pairs; ``string_keys`` maps a key's
+    position to the StringColumn its (row, validity) pair gathers from,
+    lazily (``GatheredStringColumn``: the bytes of at most ``out_cap``
+    representatives are copied when something reads them)."""
+    from ..columnar.column import GatheredStringColumn
+    cols = []
+    for i, (f, (d, v)) in enumerate(zip(schema, pairs)):
+        src = string_keys.get(i)
+        if src is None:
+            cols.append(Column(f.dtype, d, v))
+        else:
+            _obs_trace.count_eager("eager.string_gather", d)
+            cols.append(GatheredStringColumn(src, d, v, unique=True))
+    return cols
 
 
 # -- 32-bit device helpers for exact-float table aggregation ----------------
@@ -340,14 +420,23 @@ class TpuHashAggregate(TpuExec):
                         batch: ColumnarBatch, emit_buffers: bool,
                         out_cap: Optional[int] = None):
         """keys->words->plan->update/merge->output assembly as ONE jitted
-        computation, returning (num_groups, fit, [(data, validity)])
-        output pairs in schema order (``out_cap``/``fit``: speculative
+        computation (``_group_reduce``), returning (num_groups, fit,
+        output columns in schema order) (``out_cap``/``fit``: speculative
         device-side compaction, see _fused_whole_stage_core).
 
+        Takes every grouped update whose pre_ops ran eagerly and every
+        merge (``update_mode=False``): fixed-width keys as (data,
+        validity), STRING keys as their packed words
+        (``_pack_string_key``), fixed-width inputs.  What stays on the
+        eager path of ``_aggregate_batch``: STRING or nested aggregate
+        inputs, functions outside ``_FUSABLE_FUNCS`` (``collect_*``),
+        ``exactDouble``, capacities over 2^22.
+
         The whole grouping pipeline is device-pure (the only host sync is
-        the group count, pulled after); fusing it collapses the ~40 eager
-        dispatches per batch into one — the same rationale as
-        exec/fused.py, applied to the aggregate hot loop
+        the group count, pulled after); fusing it collapses the eager
+        path's launches (for TPC-H Q1 99 capacity-sized takes and 7
+        float64 scatters a batch, PERF.md section 4) into one — the same
+        rationale as exec/fused.py, applied to the aggregate hot loop
         (aggregate.scala:366 computeAggregate role).
         """
         import jax
@@ -363,7 +452,9 @@ class TpuHashAggregate(TpuExec):
                 ea.Last, ea.CentralMoment)
         if batch.capacity > (1 << 22):
             return None
-        if not all(type(c) is Column for c in key_cols):
+        from ..columnar.column import StringColumn
+        if not all(type(c) is Column or isinstance(c, StringColumn)
+                   for c in key_cols):
             return None
         for cols in input_cols:
             if not all(c is None or type(c) is Column for c in cols):
@@ -375,6 +466,11 @@ class TpuHashAggregate(TpuExec):
         in_dts = tuple(tuple(None if c is None else c.dtype for c in cols)
                        for cols in input_cols)
         aggs = self.aggs
+        packed = {i: _pack_string_key(c, batch.rows_dev)
+                  for i, c in enumerate(key_cols) if c.dtype == T.STRING}
+        # a STRING key's dtype stands with its byte bound in the key
+        key_dts = tuple((dt, packed[i][1]) if i in packed else dt
+                        for i, dt in enumerate(key_dts))
         from ..kernels.aggregate import _pair_sum_enabled
         cache_key = (update_mode, emit_buffers, key_dts, in_dts, out_cap,
                      _pair_sum_enabled(),
@@ -388,35 +484,21 @@ class TpuHashAggregate(TpuExec):
 
         if core is None:
             def _core(key_arrays, in_arrays, num_rows):
-                kcols = [Column(dt, d, v)
+                kcols = [canon.PackedStringKey(d, v, dt[1])
+                         if isinstance(dt, tuple) else Column(dt, d, v)
                          for dt, (d, v) in zip(key_dts, key_arrays)]
-                cap = key_arrays[0][0].shape[0]
-                with jax.named_scope("key_words"):
-                    words = canon.batch_key_words(kcols, num_rows)
-                plan = agg_k.groupby_plan(words)
-                agg_buffers = []
                 it = iter(in_arrays)
-                for a, dts in zip(aggs, in_dts):
-                    cols = [None if dt is None else
-                            Column(dt, *next(it)) for dt in dts] or [None]
-                    with jax.named_scope("segment_reduce"):
-                        bufs = a.func.update(plan, cols) if update_mode \
-                            else a.func.merge(plan, cols)
-                    agg_buffers.append(bufs)
-                ocap = min(out_cap, cap) if out_cap else cap
-                fit = (plan.num_groups <= ocap).astype(jnp.int32) \
-                    if out_cap else jnp.int32(1)
-                with jax.named_scope("emit"):
-                    ng, outs = _assemble_group_output(plan, kcols, aggs,
-                                                      agg_buffers, ocap,
-                                                      emit_buffers)
-                return ng, fit, outs
+                agg_cols = [[None if dt is None else Column(dt, *next(it))
+                             for dt in dts] or [None] for dts in in_dts]
+                return _group_reduce(kcols, None, num_rows, aggs, agg_cols,
+                                     update_mode, out_cap, emit_buffers)
             core = _compile_watch.wrap_miss(
                 "hash_aggregate",
                 _compile_watch.jit(_core, "agg_grouped_core"),
                 str(cache_key))
             TpuHashAggregate._CORE_CACHE[cache_key] = core
-            key_nps = tuple(dt.np_dtype for dt in key_dts)
+            key_nps = tuple(None if isinstance(dt, tuple) else dt.np_dtype
+                            for dt in key_dts)
             in_nps = tuple(dt.np_dtype for dts in in_dts for dt in dts
                            if dt is not None)
             if not any(d is None for d in key_nps + in_nps):
@@ -436,11 +518,19 @@ class TpuHashAggregate(TpuExec):
         in_arrays = tuple(
             (c.data, c.validity)
             for cols in input_cols for c in cols if c is not None)
-        key_arrays = tuple((c.data, c.validity) for c in key_cols)
+        key_arrays = tuple(
+            packed[i][0] if i in packed else (c.data, c.validity)
+            for i, c in enumerate(key_cols))
         _aot.note_demand("hash_aggregate", batch.capacity,
                          _costplane.rows_if_resolved(batch))
+        out_schema = buffer_schema(self.group_exprs, aggs) \
+            if emit_buffers else self.output_schema
         try:
-            return core(key_arrays, in_arrays, batch.rows_dev)
+            ng, fit, pairs = core(key_arrays, in_arrays, batch.rows_dev)
+            return ng, fit, _output_columns(
+                out_schema, pairs,
+                {i: c for i, c in enumerate(key_cols)
+                 if c.dtype == T.STRING})
         except Exception:  # noqa: BLE001 - fall back, but loudly
             logging.getLogger("spark_rapids_tpu.exec.aggregate").warning(
                 "fused aggregate core failed; falling back to eager",
@@ -632,26 +722,12 @@ class TpuHashAggregate(TpuExec):
         import jax
         from ..kernels.pallas_ops import table_reduce
         from .fused import _TracedBatch
+        from .staged import apply_ops_masked
         reduce_impl = get_active().get(AGG_TABLE_REDUCE_IMPL)
         pre_ops = self.pre_ops
         SIGN = 0x8000000000000000
         NEG_INF = jnp.float32(-jnp.inf)
         F32_EXACT = jnp.uint64(1 << 24)
-
-        def apply_ops_masked(b, live):
-            # Filters fold into the live mask instead of compacting — the
-            # sort path needs contiguous rows, the bucket table doesn't,
-            # and compaction's argsort + per-column 64-bit gathers were
-            # the dominant map-side cost.
-            for kind, payload, out_schema in (pre_ops or ()):
-                if kind == "filter":
-                    pred = ec.eval_as_column(payload, b)
-                    live = live & pred.data.astype(bool) & pred.validity
-                else:
-                    cols = [ec.eval_as_column(e, b) for e in payload]
-                    b = _TracedBatch(out_schema, cols, b.num_rows,
-                                     b.capacity)
-            return b, live
 
         def decode_word(dtype, word):
             if dtype == T.BOOL:
@@ -665,7 +741,7 @@ class TpuHashAggregate(TpuExec):
                     for f, d, v in zip(src_schema, datas, valids)]
             b = _TracedBatch(src_schema, cols, num_rows, cap)
             live = jnp.arange(cap) < num_rows
-            b, live = apply_ops_masked(b, live)
+            b, live = apply_ops_masked(pre_ops or (), b, live)
             kcols = [ec.eval_as_column(e, b) for e in bound_keys]
             kwords = [canon.value_words(c, b.num_rows)[0] for c in kcols]
             kvalids = [c.validity for c in kcols]
@@ -1010,8 +1086,8 @@ class TpuHashAggregate(TpuExec):
         """One-time guards + signature derivation for the whole-stage
         core; False when this (pre_ops, schema) can never fuse."""
         from .fused import _tree_fusable, expr_signature
-        from .staged import ops_fusable, ops_signature
-        if not ops_fusable(self.pre_ops):
+        from .staged import ops_maskable, ops_signature, passthrough_ordinal
+        if not ops_maskable(self.pre_ops):
             return False
         osig = ops_signature(self.pre_ops)
         if osig is None:
@@ -1023,11 +1099,22 @@ class TpuHashAggregate(TpuExec):
                             for a in self.aggs]
         except KeyError:
             return False
-        if not all(_tree_fusable(e) for e in bound_keys):
-            return False
-        if any(e.dtype() == T.STRING or e.dtype().is_nested
-               for e in bound_keys):
-            return False
+        # a STRING key must be a source column handed through the chain
+        # untouched: the core gets its packed words, the output gathers
+        # its bytes from the source at the groups' representative rows
+        string_keys = {}
+        for i, e in enumerate(bound_keys):
+            if _tree_fusable(e):
+                continue
+            if e.dtype() != T.STRING:
+                return False
+            at = passthrough_ordinal(e)
+            for kind, payload, _ in reversed(self.pre_ops):
+                if at is not None and kind == "project":
+                    at = passthrough_ordinal(payload[at])
+            if at is None:
+                return False
+            string_keys[i] = at
         for bs in bound_inputs:
             if not all(_tree_fusable(e) for e in bs):
                 return False
@@ -1046,24 +1133,28 @@ class TpuHashAggregate(TpuExec):
                      tuple((type(a.func).__name__, repr(a.func),
                             getattr(a.func, "ignore_nulls", None))
                            for a in self.aggs))
-        return cache_key, bound_keys, bound_inputs
+        return cache_key, bound_keys, bound_inputs, string_keys
 
     def _fused_whole_stage_core(self, batch: ColumnarBatch,
                                 emit_buffers: bool = True,
                                 out_cap: Optional[int] = None):
         """scan-side filter/project chain + key eval + grouping + update
         + output assembly as ONE jitted program (whole-stage codegen
-        role, exec/staged.py).
+        role, exec/staged.py).  Filters fold into row liveness
+        (``staged.apply_ops_masked``): nothing is compacted.  A STRING
+        source column enters only as a key, as its packed words; STRING
+        columns nothing reads are not passed in.
 
-        Returns (num_groups, fit, [(data, validity)] output pairs in
-        schema order) or None to fall back (the caller then applies
-        pre_ops eagerly).  ``out_cap`` requests speculative device-side
-        compaction to that capacity; ``fit`` is the device flag that the
-        group count fit (always-1 when uncompacted)."""
+        Returns (num_groups, fit, output columns in schema order) or
+        None to fall back (the caller then applies pre_ops eagerly and
+        tries ``_fused_agg_core``).  ``out_cap`` requests speculative
+        device-side compaction to that capacity; ``fit`` is the device
+        flag that the group count fit (always-1 when uncompacted)."""
         import jax
         import logging
         from .fused import _TracedBatch, _tree_fusable, expr_signature
-        from .staged import ops_fusable, ops_signature, apply_ops_traced
+        from ..columnar.column import StringColumn
+        from .staged import apply_ops_masked
         if TpuHashAggregate._FUSABLE_FUNCS is None:
             from ..expr import aggregates as ea
             TpuHashAggregate._FUSABLE_FUNCS = (
@@ -1071,7 +1162,8 @@ class TpuHashAggregate(TpuExec):
                 ea.Last, ea.CentralMoment)
         if batch.capacity > (1 << 22) or not batch.columns:
             return None
-        if not all(type(c) is Column for c in batch.columns):
+        if not all(type(c) is Column or isinstance(c, StringColumn)
+                   for c in batch.columns):
             return None
         # the guard walks + signature derivation are schema-invariant:
         # compute once per (source dtypes), not per batch
@@ -1083,9 +1175,13 @@ class TpuHashAggregate(TpuExec):
         if prep is False:
             return None
         from ..kernels.aggregate import _pair_sum_enabled
-        cache_key, bound_keys, bound_inputs = prep
+        cache_key, bound_keys, bound_inputs, string_keys = prep
+        # source ordinal -> ((words, validity), byte bound)
+        packed = {at: _pack_string_key(batch.columns[at], batch.rows_dev)
+                  for at in set(string_keys.values())}
+        bounds = tuple(sorted((at, p[1]) for at, p in packed.items()))
         cache_key = cache_key + (emit_buffers, out_cap,
-                                 _pair_sum_enabled())
+                                 _pair_sum_enabled(), bounds)
         core = TpuHashAggregate._CORE_CACHE.get(cache_key)
         if core is False:
             return None
@@ -1095,30 +1191,33 @@ class TpuHashAggregate(TpuExec):
             aggs = self.aggs
 
             def _core(datas, valids, num_rows):
-                cap = datas[0].shape[0]
-                cols = [Column(f.dtype, d, v)
-                        for f, d, v in zip(src_schema, datas, valids)]
+                cap = next(v for v in valids if v is not None).shape[0]
+                # a column the core was not given (None) is a STRING
+                # column nothing reads; a key source holds its words
+                byte_bound = dict(bounds)
+                cols = [None if v is None else
+                        canon.PackedStringKey(d, v, byte_bound[i])
+                        if f.dtype == T.STRING else Column(f.dtype, d, v)
+                        for i, (f, d, v) in enumerate(
+                            zip(src_schema, datas, valids))]
                 b = _TracedBatch(src_schema, cols, num_rows, cap)
                 with jax.named_scope("pre_ops"):
-                    b = apply_ops_traced(pre_ops, b)
-                with jax.named_scope("key_words"):
-                    kcols = [ec.eval_as_column(e, b) for e in bound_keys]
-                    words = canon.batch_key_words(kcols, b.num_rows)
-                plan = agg_k.groupby_plan(words)
-                agg_buffers = []
-                for a, bs in zip(aggs, bound_inputs):
-                    with jax.named_scope("segment_reduce"):
-                        cols2 = [ec.eval_as_column(e, b)
-                                 for e in bs] or [None]
-                        agg_buffers.append(a.func.update(plan, cols2))
-                ocap = min(out_cap, cap) if out_cap else cap
-                fit = (plan.num_groups <= ocap).astype(jnp.int32) \
-                    if out_cap else jnp.int32(1)
-                with jax.named_scope("emit"):
-                    ng, outs = _assemble_group_output(plan, kcols, aggs,
-                                                      agg_buffers, ocap,
-                                                      emit_buffers)
-                return ng, fit, outs
+                    b, live = apply_ops_masked(
+                        pre_ops, b, jnp.arange(cap) < num_rows)
+                kcols = [ec.eval_as_column(e, b) for e in bound_keys]
+                # inputs are keyed by bound expression: sum(x) and
+                # avg(x) read ONE evaluated column, moved once
+                evaluated = {}
+                agg_cols = []
+                for bs in bound_inputs:
+                    for e in bs:
+                        sig = expr_signature(e)
+                        if sig not in evaluated:
+                            evaluated[sig] = ec.eval_as_column(e, b)
+                    agg_cols.append([evaluated[expr_signature(e)]
+                                     for e in bs] or [None])
+                return _group_reduce(kcols, live, num_rows, aggs, agg_cols,
+                                     True, out_cap, emit_buffers)
             core = _compile_watch.wrap_miss(
                 "hash_aggregate",
                 _compile_watch.jit(_core, "agg_whole_stage_core"),
@@ -1133,12 +1232,26 @@ class TpuHashAggregate(TpuExec):
                     core(ds, vs, jnp.int32(0))
                 _aot.register_warmer("hash_aggregate_whole_stage", warm,
                                      str(hash(cache_key)))
-        datas = tuple(c.data for c in batch.columns)
-        valids = tuple(c.validity for c in batch.columns)
+        datas, valids = [], []
+        for i, c in enumerate(batch.columns):
+            if type(c) is Column:
+                d, v = c.data, c.validity
+            elif i in packed:
+                d, v = packed[i][0]
+            else:
+                d = v = None
+            datas.append(d)
+            valids.append(v)
         _aot.note_demand("hash_aggregate", batch.capacity,
                          _costplane.rows_if_resolved(batch))
+        out_schema = buffer_schema(self.group_exprs, self.aggs) \
+            if emit_buffers else self.output_schema
         try:
-            return core(datas, valids, batch.rows_dev)
+            ng, fit, pairs = core(tuple(datas), tuple(valids),
+                                  batch.rows_dev)
+            return ng, fit, _output_columns(
+                out_schema, pairs,
+                {i: batch.columns[at] for i, at in string_keys.items()})
         except Exception:  # noqa: BLE001 - fall back, but loudly
             logging.getLogger("spark_rapids_tpu.exec.aggregate").warning(
                 "whole-stage aggregate core failed; falling back",
@@ -1151,9 +1264,16 @@ class TpuHashAggregate(TpuExec):
                          emit_buffers: bool = False,
                          no_table: bool = False,
                          no_compact: bool = False) -> ColumnarBatch:
+        """One input batch through the first path that admits it: the
+        bucket table (``_fused_table_core``), the whole-stage core
+        (pre_ops traced with the update), the grouped core (pre_ops run
+        eagerly first; every merge), the global core, or the eager
+        grouped fallback.  ``agg.batches.{table,fused,eager}`` count
+        where a grouped batch settled."""
         if not no_table and self.mode == PARTIAL and self.group_exprs:
             t = self._fused_table_core(batch)
             if t is not None:
+                _obs_trace.count("agg.batches.table")
                 return t
         emit = emit_buffers or self.mode == PARTIAL
         out_schema_obj = buffer_schema(self.group_exprs, self.aggs) \
@@ -1187,9 +1307,8 @@ class TpuHashAggregate(TpuExec):
                                               out_cap=compact_cap) \
                 if self.group_exprs else None
             if ws is not None:
-                ng, fit, pairs = ws
-                cols = [Column(f.dtype, d, v)
-                        for f, (d, v) in zip(out_schema_obj, pairs)]
+                ng, fit, cols = ws
+                _obs_trace.count("agg.batches.fused")
                 return _wrap_speculative(
                     ColumnarBatch(out_schema_obj, cols, LazyCount(ng)),
                     fit)
@@ -1225,11 +1344,11 @@ class TpuHashAggregate(TpuExec):
         fused = self._fused_agg_core(key_cols, input_cols, update_mode,
                                      batch, emit, out_cap=compact_cap)
         if fused is not None:
-            ng, fit, pairs = fused
-            cols = [Column(f.dtype, d, v)
-                    for f, (d, v) in zip(out_schema_obj, pairs)]
+            ng, fit, cols = fused
+            _obs_trace.count("agg.batches.fused")
             return _wrap_speculative(
                 ColumnarBatch(out_schema_obj, cols, LazyCount(ng)), fit)
+        _obs_trace.count("agg.batches.eager")
         words = canon.batch_key_words(key_cols, batch.rows_dev)
         plan = agg_k.groupby_plan(words)
         # aggregate buffers (segment-id indexed, 0..G-1, input capacity)
@@ -1355,6 +1474,8 @@ class TpuHashAggregate(TpuExec):
                         exc_info=True)
                     TpuHashAggregate._CORE_CACHE[cache_key] = False
                     pairs = None
+        _obs_trace.count("agg.batches.eager" if pairs is None
+                         else "agg.batches.fused")
         if pairs is None:
             pairs = _core(in_arrays, batch.rows_dev)
         out_cols = [Column(f.dtype, d, v)
@@ -1383,6 +1504,7 @@ def _audit_agg(group=True):
                                                  T.INT64)), "s")]
     agg.group_exprs = [ec.BoundReference(0, T.INT64)] if group else []
     agg.pre_ops = None
+    agg.mode = PARTIAL
     agg._ws_memo = {}
     return agg
 
@@ -1444,7 +1566,7 @@ def _audit_specs():
         assert out is not None, "whole-stage agg core fell back"
         mkey = tuple(f.dtype.name for f in batch.schema)
         prep = agg._ws_memo[mkey]
-        cache_key = prep[0] + (True, None, _pair_sum_enabled())
+        cache_key = prep[0] + (True, None, _pair_sum_enabled(), ())
         core = _cached_core(cache_key, "whole-stage")
         c = batch.capacity
         d = jax.ShapeDtypeStruct((c,), np.int64)
@@ -1454,7 +1576,6 @@ def _audit_specs():
 
     def _global():
         agg = _audit_agg(group=False)
-        agg.mode = PARTIAL
         cap = 16
         val_col = _int_col(cap, 1)
         schema = Schema([Field("v", T.INT64, True)])
@@ -1470,15 +1591,15 @@ def _audit_specs():
     return [
         AuditSpec("hash_aggregate_grouped", "hash_aggregate", _grouped,
                   notes="sum(v) group by k, update mode",
-                  budgets={"gather": 34, "scatter": 4, "transpose": 4,
-                           "sort": 6}),
+                  budgets={"gather": 12, "scatter": 2, "transpose": 4,
+                           "sort": 3}),
         AuditSpec("hash_aggregate_whole_stage", "hash_aggregate",
                   _whole_stage,
                   notes="filter(v>0) chain folded into sum(v) by k",
-                  budgets={"gather": 42, "scatter": 4, "transpose": 4,
-                           "sort": 8}),
+                  budgets={"gather": 12, "scatter": 2, "transpose": 4,
+                           "sort": 3}),
         AuditSpec("hash_aggregate_global", "hash_aggregate", _global,
                   notes="global (no group keys) sum, partial mode",
-                  budgets={"gather": 30, "scatter": 4, "transpose": 4,
-                           "sort": 6}),
+                  budgets={"gather": 14, "scatter": 2, "transpose": 4,
+                           "sort": 4}),
     ]

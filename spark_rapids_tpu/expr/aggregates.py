@@ -56,6 +56,12 @@ class AggregateFunction(Expression):
     def finalize(self, buffers: List[Column]) -> Column:
         return buffers[0]
 
+    def float_sum_cols(self, cols: List[Column]) -> List[Column]:
+        """The inputs (``update``) or buffers (``merge``) this function
+        reduces with a float64 ``agg_k.seg_sum``: the fused cores sum
+        them all in one pass (``agg_k.stack_float_sums``)."""
+        return []
+
     def columnar_eval(self, batch):
         raise AssertionError(
             f"{self.name} must be evaluated by an aggregate exec")
@@ -104,6 +110,9 @@ class Sum(AggregateFunction):
         if isinstance(ct, T.DecimalType):
             return T.DecimalType(min(ct.precision + 10, 18), ct.scale)
         return T.FLOAT64
+
+    def float_sum_cols(self, cols):
+        return cols[:1] if self.dtype() == T.FLOAT64 else []
 
     def update(self, plan, cols):
         c = cols[0]
@@ -198,6 +207,9 @@ class Average(AggregateFunction):
 
     def buffer_dtypes(self):
         return [T.FLOAT64, T.INT64]
+
+    def float_sum_cols(self, cols):
+        return cols[:1]
 
     def update(self, plan, cols):
         c = cols[0]
@@ -311,15 +323,15 @@ class CentralMoment(AggregateFunction):
 
     def update(self, plan, cols):
         c = cols[0]
-        cap = c.capacity
-        x, ok = agg_k._sorted_vals(plan, c.data.astype(jnp.float64),
-                                   c.validity)
+        cap = plan.num_slots
+        x, ok = agg_k._sorted_vals(plan, c.data, c.validity)
+        x = x.astype(jnp.float64)
         cnt = jax.ops.segment_sum(ok.astype(jnp.int64), plan.seg_id,
                                   num_segments=cap)
         s = jax.ops.segment_sum(jnp.where(ok, x, 0.0), plan.seg_id,
                                 num_segments=cap)
         mean = s / jnp.maximum(cnt, 1).astype(jnp.float64)
-        delta = x - jnp.take(mean, plan.seg_id)
+        delta = x - jnp.take(mean, plan.seg_id, mode="clip")
         m2 = jax.ops.segment_sum(jnp.where(ok, delta * delta, 0.0),
                                  plan.seg_id, num_segments=cap)
         always = jnp.ones_like(cnt, dtype=bool)
@@ -328,10 +340,10 @@ class CentralMoment(AggregateFunction):
                 Column(T.FLOAT64, m2, always)]
 
     def merge(self, plan, buffers):
-        cap = buffers[0].capacity
-        n_i, ok = agg_k._sorted_vals(
-            plan, buffers[0].data.astype(jnp.float64),
-            buffers[0].validity)
+        cap = plan.num_slots
+        n_i, ok = agg_k._sorted_vals(plan, buffers[0].data,
+                                     buffers[0].validity)
+        n_i = n_i.astype(jnp.float64)
         mean_i, _ = agg_k._sorted_vals(plan, buffers[1].data,
                                        buffers[1].validity)
         m2_i, _ = agg_k._sorted_vals(plan, buffers[2].data,
@@ -341,7 +353,7 @@ class CentralMoment(AggregateFunction):
         wsum = jax.ops.segment_sum(n_i * mean_i, plan.seg_id,
                                    num_segments=cap)
         mean = wsum / jnp.maximum(n, 1.0)
-        delta = mean_i - jnp.take(mean, plan.seg_id)
+        delta = mean_i - jnp.take(mean, plan.seg_id, mode="clip")
         m2 = jax.ops.segment_sum(
             jnp.where(ok, m2_i + n_i * delta * delta, 0.0),
             plan.seg_id, num_segments=cap)

@@ -3,23 +3,44 @@
 (reference: aggregate.scala:240).
 
 TPU-first: instead of cuDF's open-addressing hash groupby, we sort by
-canonical key words and run segmented reductions (``jax.ops.segment_*``) —
-sort + segment-scan lowers to XLA's native sort and scatter-add, which tile
-onto the VPU far better than data-dependent hash probing (SURVEY.md §7
-"hard parts").  One compiled kernel per (schema, capacity) bucket.
+canonical key words and run segmented reductions over the sorted rows —
+XLA's native sort tiles onto the VPU far better than data-dependent hash
+probing (SURVEY.md §7 "hard parts").  One compiled kernel per (schema,
+capacity) bucket.
+
+Which path moves what (PR 29; on the chip a 1-D take of 2^20 slots costs
+about 11 ms whatever its width, a row gather of eleven 32-bit lanes 8 ms,
+a pair sort 3 ms, a float64 scatter-add 74 ms):
+
+- inside the aggregate's fused cores (``exec/tpu_aggregate.py``
+  ``_fused_agg_core`` / ``_fused_whole_stage_core``) the key words come
+  merged (``canon.group_key_words``: two flag-like string keys are one
+  22-bit word, one sort pass), and ``groupby_plan`` is given every array
+  the aggregates read in sorted order: all of them move in ONE row
+  gather of a 32-bit-lane matrix (``_gather_rows_once``: each distinct
+  data array once, the validities as packed bits); the DOUBLE sums of
+  every aggregate are one stacked segmented scan (``stack_float_sums``:
+  sum(x) and avg(x) share a lane); boundary takes and per-group outputs
+  run at the plan's ``num_slots`` (the core's output capacity), not at
+  the batch's capacity;
+- everywhere else (the eager grouped fallback, the global core, the mesh
+  step) the plan is the chained pair sort of ``kernels/sort.py`` over
+  unmerged words, an input is gathered into sorted order on first use,
+  once per array (``GroupPlan.in_order``), each DOUBLE sum is its own
+  ``segment_sum``, and ``num_slots`` is the batch's capacity.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import List
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..obs import trace as _trace
 from . import canon
 from .sort import sorted_words
-from .basic import filter_compact_indices
 
 
 @dataclasses.dataclass
@@ -31,14 +52,120 @@ class GroupPlan:
     num_groups: jnp.ndarray    # scalar int
     head_pos: jnp.ndarray      # sorted position of each group's FIRST row
     last_pos: jnp.ndarray      # sorted position of each group's LAST row
+    boundary: jnp.ndarray      # sorted-row mask: first row of a live group
+    # id(array) -> (array, the array in sorted order): every row-moving
+    # step happens once per array.  The unsorted array rides along so
+    # its id stays its own for the plan's lifetime.
+    moved: dict = dataclasses.field(default_factory=dict)
+    # (id(data), id(validity)) -> per-group float64 sum (stack_float_sums)
+    sums: dict = dataclasses.field(default_factory=dict)
+
+    @property
+    def num_slots(self) -> int:
+        """Static length of every per-group output (``rep_indices``,
+        ``head_pos``, each buffer the segment kernels return)."""
+        return self.head_pos.shape[0]
+
+    def in_order(self, values):
+        """``values`` in the plan's sorted order: moved there with the
+        other inputs (``_gather_rows_once``), or gathered on first use."""
+        hit = self.moved.get(id(values))
+        if hit is None:
+            hit = self.moved[id(values)] = (values,
+                                            jnp.take(values, self.perm))
+        return hit[1]
+
+
+def _as_lanes(a):
+    """A fixed-width array as uint32 lanes (a list of [rows] arrays) and
+    the function that puts the lanes back together.  Integers and
+    float32 are bit patterns; a float64 is its bit pattern where the
+    backend can bitcast one (CPU), and on the chip, where a float64 IS a
+    pair of float32s, that pair: exact either way."""
+    dt = a.dtype
+    u32 = jnp.uint32
+
+    def bits32(x):
+        return lax.bitcast_convert_type(x, u32)
+    if dt == jnp.float64 and canon._f64_bitcast_supported():
+        pair = lax.bitcast_convert_type(a, u32)          # [rows, 2]
+        return [pair[:, 0], pair[:, 1]], lambda p: \
+            lax.bitcast_convert_type(jnp.stack(p, 1), jnp.float64)
+    if dt == jnp.float64:
+        hi = a.astype(jnp.float32)
+        lo = jnp.where(jnp.isfinite(hi), a - hi.astype(jnp.float64),
+                       0.0).astype(jnp.float32)
+
+        def join(p):
+            h = lax.bitcast_convert_type(p[0], jnp.float32)
+            lw = lax.bitcast_convert_type(p[1], jnp.float32)
+            # h alone when there is no low part: keeps -0.0 and inf
+            return jnp.where(lw == 0, h.astype(jnp.float64),
+                             h.astype(jnp.float64) + lw.astype(jnp.float64))
+        return [bits32(hi), bits32(lo)], join
+    if dt.itemsize == 8:
+        w = a.view(jnp.uint64)
+        return [(w & jnp.uint64(0xFFFFFFFF)).astype(u32),
+                (w >> jnp.uint64(32)).astype(u32)], lambda p: \
+            ((p[1].astype(jnp.uint64) << jnp.uint64(32)) |
+             p[0].astype(jnp.uint64)).view(dt)
+    if dt.itemsize == 4:
+        return [bits32(a)], lambda p: lax.bitcast_convert_type(p[0], dt)
+    # 8- and 16-bit integers ride widened
+    return [bits32(a.astype(jnp.int32))], lambda p: \
+        lax.bitcast_convert_type(p[0], jnp.int32).astype(dt)
+
+
+def _gather_rows_once(perm, arrays):
+    """Every distinct array of ``arrays`` brought into ``perm``'s order
+    by ONE row gather of a ``[rows, lanes]`` uint32 matrix: 64-bit
+    values as two lanes, boolean arrays (validities) 32 to a lane.  On
+    the chip the row gather of ten lanes takes 8.3 ms at 2^20 rows where
+    five 1-D float64 takes in one program take 90 ms (PERF.md section
+    5).  Returns the ``moved`` table of ``GroupPlan``."""
+    distinct = list({id(a): a for a in arrays}.values())
+    flags = [a for a in distinct if a.dtype == jnp.bool_]
+    lanes, joins = [], []
+    for a in distinct:
+        if a.dtype != jnp.bool_:
+            mine, join = _as_lanes(a)
+            joins.append((a, len(lanes), len(mine), join))
+            lanes.extend(mine)
+    flag_lane0 = len(lanes)
+    for at in range(0, len(flags), 32):
+        word = jnp.zeros(perm.shape[0], jnp.uint32)
+        for bit, v in enumerate(flags[at:at + 32]):
+            word = word | (v.astype(jnp.uint32) << jnp.uint32(bit))
+        lanes.append(word)
+    if not lanes:
+        return {}
+    got = jnp.take(jnp.stack(lanes, 1), perm, axis=0)
+    moved = {id(a): (a, join([got[:, at + i] for i in range(n)]))
+             for a, at, n, join in joins}
+    for i, v in enumerate(flags):
+        bit = (got[:, flag_lane0 + i // 32] >> jnp.uint32(i % 32)) \
+            & jnp.uint32(1)
+        moved[id(v)] = (v, bit != jnp.uint32(0))
+    return moved
 
 
 @jax.named_scope("groupby_plan")
-def groupby_plan(words: List[jnp.ndarray]) -> GroupPlan:
+def groupby_plan(words: List[jnp.ndarray], num_slots: Optional[int] = None,
+                 inputs: Optional[list] = None, live=None) -> GroupPlan:
     """Build the sort+segment plan for a set of canonical key words.
 
     ``words`` must come from canon.batch_key_words (first word of each key is
-    the null/range rank; rank 2 == past-num_rows padding).
+    the null/range rank; rank 2 == dead row: past num_rows, or dropped by
+    a folded-in filter).
+
+    ``inputs`` (the fused cores): the arrays the aggregates read in
+    sorted order (input data and validities), all brought there by one
+    row gather (``_gather_rows_once``); without it each is gathered on
+    first use.  ``num_slots`` bounds the groups the per-group outputs
+    have room for (default: one per row); a caller that passes less
+    checks ``num_groups <= num_slots``.  ``live`` (with words from
+    ``canon.group_key_words``, whose first word no longer IS the rank):
+    the rows that are not dead, in input order.
 
     Besides the segment ids, the plan carries each group's first/last
     SORTED position (``head_pos``/``last_pos``): groups are contiguous
@@ -48,29 +175,38 @@ def groupby_plan(words: List[jnp.ndarray]) -> GroupPlan:
     emulates i64 as 32-bit pairs and scatters serialize badly).
     """
     sorted_ws, perm = sorted_words(words)
-    live = sorted_ws[0] != jnp.uint64(2)
+    moved = {} if inputs is None else _gather_rows_once(perm, inputs)
+    n = sorted_ws[0].shape[0]
+    if live is None:
+        live = sorted_ws[0] != jnp.uint64(2)
+    else:
+        # dead rows sort past every live one
+        live = jnp.arange(n) < jnp.sum(live.astype(jnp.int32))
     boundary = canon.words_equal_adjacent(sorted_ws) & live
     seg_id = jnp.cumsum(boundary.astype(jnp.int32)) - 1
     seg_id = jnp.maximum(seg_id, 0)
     num_groups = jnp.sum(boundary)
-    rep_order, _ = filter_compact_indices(boundary, boundary.shape[0])
-    rep_indices = jnp.take(perm, rep_order)
+    slots = n if num_slots is None else min(num_slots, n)
+    # the groups' first sorted rows, in order: a stable sort of the
+    # boundary flags on 32-bit operands (basic.filter_compact_indices
+    # argsorts 64-bit ones, three times the lanes)
+    _, rep_order = lax.sort(
+        (jnp.where(boundary, jnp.uint32(0), jnp.uint32(1)),
+         jnp.arange(n, dtype=jnp.int32)), num_keys=1, is_stable=True)
+    rep_indices = jnp.take(perm, rep_order[:slots])
     # group g spans sorted rows [head_pos[g], last_pos[g]]; dead rows sort
     # after all live rows, so the last live group ends at live_count-1
-    n = boundary.shape[0]
-    head_pos = rep_order.astype(jnp.int32)
+    head_pos = rep_order[:slots]
     live_count = jnp.sum(live.astype(jnp.int32))
-    gi = jnp.arange(n, dtype=jnp.int32)
-    nxt = jnp.concatenate([head_pos[1:], jnp.zeros(1, jnp.int32)])
+    gi = jnp.arange(slots, dtype=jnp.int32)
+    nxt = jnp.concatenate([rep_order, jnp.zeros(1, jnp.int32)])[1:slots + 1]
     last_pos = jnp.where(gi + 1 < num_groups, nxt - 1, live_count - 1)
     return GroupPlan(perm, seg_id, live, rep_indices, num_groups,
-                     head_pos, last_pos)
+                     head_pos, last_pos, boundary, moved)
 
 
 def _sorted_vals(plan: GroupPlan, values, validity):
-    v = jnp.take(values, plan.perm)
-    ok = jnp.take(validity, plan.perm) & plan.live_sorted
-    return v, ok
+    return plan.in_order(values), plan.in_order(validity) & plan.live_sorted
 
 
 def seg_prefix_sum(plan: GroupPlan, contrib):
@@ -86,15 +222,72 @@ def seg_prefix_sum(plan: GroupPlan, contrib):
     hp = jnp.clip(plan.head_pos, 0, cap - 1)
     lp = jnp.clip(plan.last_pos, 0, cap - 1)
     total = jnp.take(cum, lp) - jnp.take(ex, hp)
-    gi = jnp.arange(cap, dtype=jnp.int32)
+    gi = jnp.arange(plan.num_slots, dtype=jnp.int32)
     return jnp.where(gi < plan.num_groups, total,
                      jnp.zeros_like(total))
 
 
+def stack_float_sums(plan: GroupPlan, cols) -> None:
+    """The float64 segment sums of every Column in ``cols`` (Sum and
+    Average inputs, named by ``AggregateFunction.float_sum_cols``) as ONE
+    segmented scan over a ``[rows, k]`` stack, left in ``plan.sums`` for
+    ``seg_sum`` to find.  Each distinct (data, validity) is summed once:
+    sum(x) and avg(x) share a lane.
+
+    The scan restarts at every group's first sorted row and the group's
+    total is the running value at its last row: full float64 adds in
+    tree order, no scatter.  At 2^20 rows and five columns the chip
+    takes 5.9 ms for the scan, 90 ms for one stacked ``segment_sum`` and
+    386 ms for five (PERF.md section 5 has the table)."""
+    if jax.default_backend() != "cpu" and _pair_sum_enabled():
+        return              # the opt-in superaccumulator sums each alone
+    todo = {}
+    for c in cols:
+        key = (id(c.data), id(c.validity))
+        if key not in plan.sums:
+            todo.setdefault(key, c)
+    if not todo:
+        return
+    lanes = []
+    for c in todo.values():
+        v, ok = _sorted_vals(plan, c.data, c.validity)
+        lanes.append(jnp.where(ok, v.astype(jnp.float64), 0.0))
+    stack = jnp.stack(lanes)
+    totals = _segmented_totals(plan, stack)
+    for i, (key, c) in enumerate(todo.items()):
+        # the Column rides along so the ids in the key stay its own
+        plan.sums[key] = (c, totals[i])
+
+
+def _segmented_totals(plan: GroupPlan, stack):
+    """Per-group totals of ``stack`` ([k, rows], sorted order, dead rows
+    zero) by a segmented inclusive scan: log2(rows) shift-and-add steps
+    that stop at each group's first row (``plan.boundary``), then one
+    read at each group's last row.  Full float64 adds in tree order; the
+    steps are elementwise, so the program compiles in seconds where
+    ``lax.associative_scan`` took minutes."""
+    k, n = stack.shape
+    run, stop = stack, plan.boundary
+    d = 1
+    while d < n:
+        prev = jnp.concatenate([jnp.zeros((k, d), run.dtype), run[:, :-d]], 1)
+        run = jnp.where(stop[None, :], run, run + prev)
+        stop = stop | jnp.concatenate([jnp.ones(d, bool), stop[:-d]])
+        d *= 2
+    lp = jnp.clip(plan.last_pos, 0, n - 1)
+    totals = jnp.take(run, lp, axis=1)
+    gi = jnp.arange(plan.num_slots, dtype=jnp.int32)
+    return jnp.where((gi < plan.num_groups)[None, :], totals, 0.0)
+
+
 def seg_sum(plan: GroupPlan, values, validity, out_dtype=None):
-    cap = values.shape[0]
+    acc_dtype = jnp.dtype(out_dtype or values.dtype)
+    if acc_dtype == jnp.float64:
+        hit = plan.sums.get((id(values), id(validity)))
+        if hit is not None:
+            return hit[1]
     v, ok = _sorted_vals(plan, values, validity)
-    acc = v.astype(out_dtype or v.dtype)
+    acc = v.astype(acc_dtype)
     contrib = jnp.where(ok, acc, jnp.zeros_like(acc))
     if jnp.issubdtype(contrib.dtype, jnp.integer) or \
             contrib.dtype == jnp.bool_:
@@ -110,7 +303,8 @@ def seg_sum(plan: GroupPlan, values, validity, out_dtype=None):
         # far inside the engines' 1e-9 comparison tolerance).
         return _seg_sum_f64_pair(plan, acc, ok)
     _trace.count_eager("eager.seg_sum_scatter", contrib)
-    return jax.ops.segment_sum(contrib, plan.seg_id, num_segments=cap)
+    return jax.ops.segment_sum(contrib, plan.seg_id,
+                               num_segments=plan.num_slots)
 
 
 def _pair_sum_enabled() -> bool:
@@ -216,9 +410,10 @@ def _seg_sum_f64_pair(plan: GroupPlan, v, ok):
     lneg, le, lsig, _ = _unpack_f32(lo)
     # per-GROUP anchor: one large-magnitude group must not push other
     # groups' rows below the window (i32 scatter-max is native)
+    slots = plan.num_slots
     emax_g = jax.ops.segment_max(jnp.where(fin_ok, he, jnp.int32(0)),
-                                 plan.seg_id, num_segments=n)
-    emax = jnp.take(emax_g, plan.seg_id)
+                                 plan.seg_id, num_segments=slots)
+    emax = jnp.take(emax_g, plan.seg_id, mode="clip")
     hj, hc0, hc1, hkeep, hlost = _f32_parts(hsig, he, fin_ok, emax, W0)
     lj, lc0, lc1, lkeep, llost = _f32_parts(lsig, le, fin_ok, emax, W0)
     z = jnp.int64(0)
@@ -259,14 +454,14 @@ def _seg_sum_f64_pair(plan: GroupPlan, v, ok):
              (mags[3] << jnp.uint64(32)) | mags[2],
              mags[4]]
     nzs = [w != jnp.uint64(0) for w in words]
-    top = jnp.zeros(n, jnp.int32)
-    any_nz = jnp.zeros(n, bool)
+    top = jnp.zeros(slots, jnp.int32)
+    any_nz = jnp.zeros(slots, bool)
     for i in range(3):
         top = jnp.where(nzs[i], jnp.int32(i), top)
         any_nz = any_nz | nzs[i]
 
     def pick(idx):
-        out = jnp.zeros(n, jnp.uint64)
+        out = jnp.zeros(slots, jnp.uint64)
         for i in range(3):
             out = jnp.where(idx == i, words[i], out)
         return out
@@ -292,12 +487,12 @@ def _seg_sum_f64_pair(plan: GroupPlan, v, ok):
     out = jnp.where(ninf_cnt > 0, jnp.float64(-jnp.inf), out)
     out = jnp.where((nan_cnt > 0) | ((pinf_cnt > 0) & (ninf_cnt > 0)),
                     jnp.float64(jnp.nan), out)
-    gi = jnp.arange(n, dtype=jnp.int32)
+    gi = jnp.arange(slots, dtype=jnp.int32)
     return jnp.where(gi < plan.num_groups, out, 0.0)
 
 
 def seg_count(plan: GroupPlan, validity):
-    _, ok = _sorted_vals(plan, validity, validity)
+    ok = plan.in_order(validity) & plan.live_sorted
     return seg_prefix_sum(plan, ok.astype(jnp.int32)).astype(jnp.int64)
 
 
@@ -318,7 +513,7 @@ def seg_minmax_u64(plan: GroupPlan, words, ok, want_max: bool):
     two u32 scatter passes (hi word, then lo word among hi-winners).
     64-bit scatters are ~5x slower than 32-bit ones on the chip (XLA
     lowers i64 as 32-bit pairs); this keeps the reduction native."""
-    cap = words.shape[0]
+    slots = plan.num_slots
     w = words.astype(jnp.uint64)
     if not want_max:
         w = ~w                               # min == max of complement
@@ -326,10 +521,10 @@ def seg_minmax_u64(plan: GroupPlan, words, ok, want_max: bool):
     lo = (w & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
     z = jnp.uint32(0)
     mhi = jax.ops.segment_max(jnp.where(ok, hi, z), plan.seg_id,
-                              num_segments=cap)
-    on_hi = ok & (hi == jnp.take(mhi, plan.seg_id))
+                              num_segments=slots)
+    on_hi = ok & (hi == jnp.take(mhi, plan.seg_id, mode="clip"))
     mlo = jax.ops.segment_max(jnp.where(on_hi, lo, z), plan.seg_id,
-                              num_segments=cap)
+                              num_segments=slots)
     out = (mhi.astype(jnp.uint64) << jnp.uint64(32)) | \
         mlo.astype(jnp.uint64)
     if not want_max:
@@ -351,7 +546,7 @@ def _seg_minmax_i64(plan, v, ok, want_max: bool):
 
 
 def seg_min(plan: GroupPlan, values, validity):
-    cap = values.shape[0]
+    cap = plan.num_slots
     v, ok = _sorted_vals(plan, values, validity)
     if jnp.issubdtype(v.dtype, jnp.floating):
         # Spark total order: NaN greatest, -0.0 == 0.0.  No bit encoding
@@ -373,7 +568,7 @@ def seg_min(plan: GroupPlan, values, validity):
 
 
 def seg_max(plan: GroupPlan, values, validity):
-    cap = values.shape[0]
+    cap = plan.num_slots
     v, ok = _sorted_vals(plan, values, validity)
     if jnp.issubdtype(v.dtype, jnp.floating):
         # NaN is the greatest value: any NaN in the group wins
@@ -395,11 +590,12 @@ def seg_max(plan: GroupPlan, values, validity):
 def seg_first_index(plan: GroupPlan, validity, ignore_nulls: bool = True):
     """Original-row index of the first (valid) row per group."""
     cap = validity.shape[0]
-    ok = jnp.take(validity, plan.perm) & plan.live_sorted if ignore_nulls \
+    ok = plan.in_order(validity) & plan.live_sorted if ignore_nulls \
         else plan.live_sorted
     pos = jnp.arange(cap, dtype=jnp.int32)
     contrib = jnp.where(ok, pos, jnp.int32(cap))
-    first_pos = jax.ops.segment_min(contrib, plan.seg_id, num_segments=cap)
+    first_pos = jax.ops.segment_min(contrib, plan.seg_id,
+                                    num_segments=plan.num_slots)
     safe = jnp.clip(first_pos, 0, cap - 1).astype(jnp.int32)
     return jnp.take(plan.perm, safe), first_pos < cap
 
@@ -418,15 +614,16 @@ def seg_first_index_by_order(plan: GroupPlan, col, want_min: bool = True,
     words = canon.value_words(col, num_rows)
     if not want_min:
         words = [~w for w in words]
-    ok = jnp.take(col.validity, plan.perm) & plan.live_sorted
+    ok = plan.in_order(col.validity) & plan.live_sorted
     cand = ok
     for w in words:
         ws = jnp.take(w, plan.perm).astype(jnp.uint64)
         m = seg_minmax_u64(plan, ws, cand, want_max=False)
-        cand = cand & (ws == jnp.take(m, plan.seg_id))
+        cand = cand & (ws == jnp.take(m, plan.seg_id, mode="clip"))
     pos = jnp.arange(cap, dtype=jnp.int32)
     contrib = jnp.where(cand, pos, jnp.int32(cap))
-    first_pos = jax.ops.segment_min(contrib, plan.seg_id, num_segments=cap)
+    first_pos = jax.ops.segment_min(contrib, plan.seg_id,
+                                    num_segments=plan.num_slots)
     has = first_pos < cap
     safe = jnp.clip(first_pos, 0, cap - 1).astype(jnp.int32)
     return jnp.take(plan.perm, safe), has
@@ -434,11 +631,12 @@ def seg_first_index_by_order(plan: GroupPlan, col, want_min: bool = True,
 
 def seg_last_index(plan: GroupPlan, validity, ignore_nulls: bool = True):
     cap = validity.shape[0]
-    ok = jnp.take(validity, plan.perm) & plan.live_sorted if ignore_nulls \
+    ok = plan.in_order(validity) & plan.live_sorted if ignore_nulls \
         else plan.live_sorted
     pos = jnp.arange(cap, dtype=jnp.int32)
     contrib = jnp.where(ok, pos, jnp.int32(-1))
-    last_pos = jax.ops.segment_max(contrib, plan.seg_id, num_segments=cap)
+    last_pos = jax.ops.segment_max(contrib, plan.seg_id,
+                                   num_segments=plan.num_slots)
     safe = jnp.clip(last_pos, 0, cap - 1).astype(jnp.int32)
     return jnp.take(plan.perm, safe), last_pos >= 0
 

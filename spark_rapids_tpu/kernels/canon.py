@@ -35,6 +35,25 @@ from ..columnar.column import Column, StringColumn
 SIGN64 = 0x8000000000000000
 
 
+class PackedStringKey:
+    """A STRING key column inside a traced program: the value words
+    ``strings.string_key_words`` packed from the column outside, its
+    validity, and the host-known bound on a string's bytes that sized
+    them (``strings.key_byte_bound``).  Stands where a key Column stands
+    in ``batch_key_words``."""
+
+    dtype = T.STRING
+
+    def __init__(self, words, validity, byte_bound: int):
+        self.words = list(words)
+        self.validity = validity
+        self.byte_bound = byte_bound
+
+    @property
+    def capacity(self) -> int:
+        return int(self.validity.shape[0])
+
+
 def _ints_to_words(data, nbits: int):
     x = data.astype(jnp.int64)
     return (x.view(jnp.uint64) if nbits == 64
@@ -119,6 +138,8 @@ def value_words(col: Column, num_rows: int,
                 str_words: int = None) -> List[jnp.ndarray]:
     """uint64 word list for the column values (no null rank)."""
     dt = col.dtype
+    if type(col) is PackedStringKey:
+        return list(col.words)
     from ..columnar.column import GatheredStringColumn
     if type(col) is GatheredStringColumn and col._mat is None:
         # lazy gather view: gather the SOURCE column's words by index —
@@ -171,6 +192,57 @@ def batch_key_words(cols: List[Column], num_rows: int,
         cap = cols[0].capacity if cols else 16
         out = [jnp.zeros(cap, jnp.uint64)]
     return out
+
+
+def _value_word_bits(col, n_words: int) -> List[Tuple[int, int]]:
+    """How many low bits each of a key's ``value_words`` can occupy, and
+    for a short string's byte word how far it has to come down first:
+    (bits, right shift) per word.  Only what is narrow by construction
+    is told apart; everything else is a full 64-bit word."""
+    if type(col) is PackedStringKey:
+        # byte words are big-endian and zero padded; the last word is
+        # the length, at most the byte bound
+        bound = max(1, min(col.byte_bound, 8 * (n_words - 1)))
+        full = [(64, 0)] * (n_words - 1)
+        if n_words == 2 and bound < 8:
+            used = 1 << (bound - 1).bit_length()         # 1, 2 or 4 bytes
+            full = [(8 * used, 64 - 8 * used)]
+        return full + [(bound.bit_length(), 0)]
+    if col.dtype == T.BOOL:
+        return [(1, 0)]
+    return [(64, 0)] * n_words
+
+
+def group_key_words(cols: List[Column], num_rows, live=None) -> List:
+    """``batch_key_words`` for a group-by inside a traced program, with
+    adjacent narrow words merged: the 2-bit null ranks, a string's
+    length word, the byte word of a string of under 8 bytes, booleans.
+    Merging keeps the lexicographic order, so groups and their order
+    are those of the unmerged words; it exists because every word is a
+    sort pass (two flag-like string keys are six words apart and 22 bits
+    together).  ``live`` (or None) marks rows a filter kept: a dead row
+    gets rank 2 in the first field and sorts past every group.  A
+    single merged word of 32 bits or fewer is returned as uint32."""
+    fields = []                                  # (word, bits)
+    for c in cols:
+        ws = column_key_words(c, num_rows)
+        fields.append((ws[0], 2))
+        for w, (bits, down) in zip(ws[1:],
+                                   _value_word_bits(c, len(ws) - 1)):
+            fields.append((w >> jnp.uint64(down), bits))
+    if live is not None:
+        fields[0] = (jnp.where(live, fields[0][0], jnp.uint64(2)), 2)
+    merged, room = [], 0
+    for w, bits in fields:
+        if merged and bits <= room:
+            merged[-1] = (merged[-1] << jnp.uint64(bits)) | w
+            room -= bits
+        else:
+            merged.append(w)
+            room = 64 - bits
+    if len(merged) == 1 and room >= 32:
+        return [merged[0].astype(jnp.uint32)]
+    return merged
 
 
 def words_equal_adjacent(words: List[jnp.ndarray]) -> jnp.ndarray:

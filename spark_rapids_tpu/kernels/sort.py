@@ -48,13 +48,20 @@ def sort_permutation(words: List[jnp.ndarray]) -> jnp.ndarray:
         return sort_stable_pair(w, perm)
     # LSD: least-significant word first; stability makes later (more
     # significant) passes dominate
-    for w in reversed(words):
-        k = jnp.take(w.astype(jnp.uint64), perm)
-        perm = sort_stable_pair(k, perm)
+    for i, w in enumerate(reversed(words)):
+        k = w.astype(jnp.uint64)
+        # the first pass sorts the rows as they lie
+        perm = sort_stable_pair(k if i == 0 else jnp.take(k, perm), perm)
     return perm
 
 
 def sorted_words(words: List[jnp.ndarray]):
     """Sort and also return the sorted word arrays (for boundary detection)."""
+    if len(words) == 1:
+        # one word: the sort hands back the sorted key beside the rows
+        w = words[0]
+        iota = jnp.arange(w.shape[0], dtype=jnp.int32)
+        key, perm = lax.sort((w, iota), num_keys=1, is_stable=True)
+        return [key], perm
     perm = sort_permutation(words)
     return [jnp.take(w, perm) for w in words], perm

@@ -40,7 +40,15 @@ def str_pack_words(offsets, data, num_words: int):
 
 
 def needed_key_words(col: StringColumn, num_rows: int) -> int:
-    """Bucketed uint64 word count needed to encode this column's strings.
+    """Bucketed uint64 word count needed to encode this column's strings
+    (``key_byte_bound`` in words, rounded up to a power of two)."""
+    num_words = max(1, -(-key_byte_bound(col, num_rows) // 8))
+    return 1 << (num_words - 1).bit_length()
+
+
+def key_byte_bound(col: StringColumn, num_rows: int) -> int:
+    """A host-known upper bound on the byte length of this column's
+    live strings.
 
     Uses the column's host-known ``max_bytes`` bound when present; a
     column derived purely on device pays ONE offsets sync and caches
@@ -57,8 +65,8 @@ def needed_key_words(col: StringColumn, num_rows: int) -> int:
         if src.max_bytes is None:
             cached = getattr(src, "_live_max_bytes", None)
             if cached is not None:
-                return needed_key_words(src, cached[0])
-        return needed_key_words(src, src.capacity)
+                return key_byte_bound(src, cached[0])
+        return key_byte_bound(src, src.capacity)
     max_len = col.max_bytes
     if max_len is None:
         if not isinstance(num_rows, (int, np.integer)):
@@ -79,8 +87,7 @@ def needed_key_words(col: StringColumn, num_rows: int) -> int:
             # shrunk batch) must not inflate the bucket
             max_len = int(lens[:num_rows].max()) if num_rows else 0
             col._live_max_bytes = (num_rows, max_len)
-    num_words = max(1, -(-max_len // 8))
-    return 1 << (num_words - 1).bit_length()
+    return max_len
 
 
 def string_key_words(col: StringColumn, num_rows: int,

@@ -280,10 +280,10 @@ class TestCoarseSpans:
         # every counter belongs to a documented family
         # (docs/observability.md); jit_build.* appears whenever a
         # neighbour dropped the jit caches, join.* with every hash join,
-        # scan.* with every file scan
+        # scan.* with every file scan, agg.* with every aggregate
         for tbl in counts.values():
             assert all(k.startswith(("eager.", "jit_build.", "join.",
-                                     "scan."))
+                                     "scan.", "agg."))
                        and v > 0 for k, v in tbl.items())
         assert any(k.startswith("eager.")
                    for tbl in counts.values() for k in tbl)
