@@ -16,8 +16,8 @@ host.  Legs (docs: README "Running"):
             hit), rows equal to the CPU engine
   service   QueryService, 2 workers, 8 submissions from 2 tenants (the
             resident shape at 4M rows + two of the SQL queries)
-  kernel    every Pallas kernel the tree ships, compiled by Mosaic,
-            against the jnp formulation at the resident leg's shapes
+  kernel    the hash-partition ids program (1 and 2 key words) against
+            a numpy reference at the resident leg's row count
   mesh      only with >= 4 devices: the resident query (and a global
             sort of it) under shuffle.mode=mesh, with per-device
             evidence that the data spread.  64M rows when it runs alone
@@ -285,58 +285,14 @@ def leg_service(ctx) -> dict:
 
 def leg_kernel(ctx) -> dict:
     import numpy as np
-    import jax
     import jax.numpy as jnp
     from spark_rapids_tpu.kernels import basic as bk
-    from spark_rapids_tpu.kernels import pallas_ops
     from spark_rapids_tpu.shuffle.partitioners import partition_hash_ids
-    n, table = ctx.kernel_rows, 4096
+    n = ctx.kernel_rows
     rng = np.random.default_rng(ctx.seed)
-    out = {"rows": n, "table": table}
-    if pallas_ops.interpret_mode() != ctx.rehearsal:
-        raise AssertionError(
-            f"Pallas interpret mode is {pallas_ops.interpret_mode()} on "
-            f"platform {ctx.device.platform}")
-    # -- table_reduce: the one Pallas kernel the tree ships --------------
-    bucket = jnp.asarray(rng.integers(0, table, n).astype(np.int32))
-    s1 = jnp.asarray(rng.random(n).astype(np.float32))
-    s2 = jnp.ones(n, jnp.float32)
-    m1 = jnp.asarray(rng.random(n).astype(np.float32))
-
-    def reduce(impl, b, a1, a2, mx):
-        return pallas_ops.table_reduce(b, [a1, a2], [mx], table, impl=impl)
-
-    def run(impl):
-        return reduce(impl, bucket, s1, s2, m1)
-    if not ctx.rehearsal:
-        hlo = jax.jit(reduce, static_argnums=0).lower(
-            "pallas", bucket, s1, s2, m1).as_text()
-        if "tpu_custom_call" not in hlo:
-            raise AssertionError("table_reduce(impl='pallas') lowered "
-                                 "without a Mosaic tpu_custom_call")
-    t0 = time.perf_counter()
-    psums, pmaxs = jax.block_until_ready(run("pallas"))
-    cold_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    jax.block_until_ready(run("pallas"))
-    warm_s = time.perf_counter() - t0
-    xsums, xmaxs = jax.block_until_ready(run("scatter"))
-    # f32 sums of ~n/table values: reassociation-level tolerance; the
-    # count row (all ones) and the max row are exact
-    np.testing.assert_allclose(np.asarray(psums[0]), np.asarray(xsums[0]),
-                               rtol=1e-5)
-    np.testing.assert_array_equal(np.asarray(psums[1]),
-                                  np.asarray(xsums[1]))
-    np.testing.assert_array_equal(np.asarray(pmaxs[0]),
-                                  np.asarray(xmaxs[0]))
-    out["table_reduce_pallas"] = {"smoke_first_call_s": round(cold_s, 3),
-                                  "smoke_warm_call_s": round(warm_s, 4)}
-    say(f"kernel: table_reduce(impl='pallas') n={n} table={table} "
-        f"compiled by {'the interpreter' if ctx.rehearsal else 'Mosaic'}"
-        f", equal to the scatter formulation (SMOKE TIMINGS first call "
-        f"{cold_s:.2f}s, warm {warm_s * 1e3:.1f}ms)")
-    # -- hash partition ids: XLA program (the Pallas kernel did not pass
-    # Mosaic and is gone) against a numpy murmur-mix reference ----------
+    out = {"rows": n}
+    # hash partition ids: the XLA program against a numpy murmur-mix
+    # reference
     m1c, m2c = np.uint64(bk.M1), np.uint64(bk.M2)
     for nwords in (1, 2):
         words = [rng.integers(0, 2**63, n).astype(np.uint64)
@@ -496,7 +452,7 @@ def main(argv=None) -> int:
                   f"debugs the script on the CPU backend.")
             return 2
         rehearsal = True
-        print("REHEARSAL platform=cpu: tiny sizes, Pallas interpreted; "
+        print("REHEARSAL platform=cpu: tiny sizes; "
               "this debugs the script and proves nothing about the chip",
               flush=True)
         # XLA:CPU AOT results reloaded on another machine can SIGILL

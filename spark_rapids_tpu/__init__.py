@@ -2,7 +2,7 @@
 
 A ground-up re-design of the RAPIDS Accelerator for Apache Spark
 (reference: /root/reference, studied in SURVEY.md) for TPU hardware:
-JAX/XLA/Pallas kernels in place of cuDF, an HBM arena + spill catalog in
+JAX/XLA programs in place of cuDF, an HBM arena + spill catalog in
 place of RMM, and ICI/DCN collectives (jax.sharding over a Mesh) in place
 of UCX shuffle.
 

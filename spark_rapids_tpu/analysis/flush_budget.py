@@ -41,8 +41,8 @@ operator the flush its warm execute path is known to force:
 Assumptions (documented, asserted by the quartet cross-check): warm
 caches, the serial single-partition collect regime of ci smoke runs
 (per-partition flush scaling is counted once), single-batch broadcast
-builds, ``SUPERSTAGE_SPEC_JOIN`` semantics matching
-exec/tpu_join.py's eligibility test, and stream batches below
+builds, the speculative join's eligibility test of
+exec/tpu_join.py, and stream batches below
 ``TpuHashJoinBase._SIZED_MIN_CAPACITY``: an armed join whose partition
 holds a stream batch of that capacity or more sizes its outputs and
 pays the phase-A barrier of an eager join, one flush the prediction
@@ -142,9 +142,6 @@ def _spec_join_eligible(node, conf) -> bool:
     compile/carve.py) of an inner, unconditioned, non-string-key join
     skips the phase-A flush barrier."""
     if not getattr(node, "_superstage", False):
-        return False
-    from ..config import SUPERSTAGE_SPEC_JOIN
-    if not conf.get(SUPERSTAGE_SPEC_JOIN):
         return False
     lg = node.logical
     if lg.join_type != "inner" or \

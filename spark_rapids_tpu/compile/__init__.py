@@ -12,7 +12,7 @@ Planner post-pass (runs after analysis/plan_verify.py):
   carved tree.
 
 Conf: ``spark.rapids.tpu.sql.superstage`` (off switch),
-``...superstage.minOps``, ``...superstage.speculativeJoin``.
+``...superstage.minOps``.
 """
 from .carve import carve_plan
 from .lower import (BARRIER, BOUNDARY, CHAIN, PROGRAM, barrier_count,

@@ -269,21 +269,11 @@ EXACT_DOUBLE = conf_bool(
 AGG_TABLE_SIZE = conf_int(
     "spark.rapids.tpu.sql.agg.tableSize", 4096,
     "Bucket-table size for the sort-free small-domain group-by fast path "
-    "(kernels/aggregate.py table_plan).  Key sets whose combined "
-    "cardinality range fits are aggregated via one-hot MXU matmuls and "
-    "small-output scatters with no sort; a device-side fit flag reruns "
-    "non-fitting batches on the general sort path.")
-AGG_TABLE_ENABLED = conf_bool(
-    "spark.rapids.tpu.sql.agg.tablePath.enabled", True,
-    "Enable the sort-free bucket-table aggregation fast path")
-AGG_PAIR_SUM = conf_bool(
-    "spark.rapids.tpu.sql.agg.pairSum.enabled", False,
-    "Accumulate FLOAT64 sort-path sums with the f32-pair integer "
-    "superaccumulator (kernels/aggregate._seg_sum_f64_pair): "
-    "deterministic, order-independent, correctly rounded to the "
-    "device's 48-bit pair representation.  ~4x slower than the default "
-    "f64-emulated scatter-add on the chip's emulated 64-bit integer "
-    "ALU; enable when reduction determinism matters more than speed.")
+    "(kernels/aggregate.py table_bucket).  Integer-family key sets "
+    "whose combined cardinality range fits are aggregated via "
+    "small-output scatters with no sort, in batches of at least this "
+    "capacity; a device-side fit flag reruns non-fitting batches on the "
+    "general sort path.")
 AGG_COMPACT_ROWS = conf_int(
     "spark.rapids.tpu.sql.agg.speculativeCompactRows", 1 << 16,
     "Sort-path group-by outputs are speculatively compacted on device "
@@ -292,11 +282,6 @@ AGG_COMPACT_ROWS = conf_int(
     "uncompacted).  Without it a 4M-row batch aggregating to 1k groups "
     "hands a 4M-capacity batch to the exchange/join, and every "
     "downstream program pays full-width work for dead rows.")
-AGG_TABLE_REDUCE_IMPL = conf_str(
-    "spark.rapids.tpu.sql.agg.tableReduceImpl", "scatter",
-    "Bucket-table reduction backend: 'scatter' (multi-column XLA "
-    "scatter) or 'pallas' (hand-written one-hot MXU kernel, "
-    "kernels/pallas_ops.table_reduce)")
 INCOMPATIBLE_OPS = conf_bool(
     "spark.rapids.tpu.sql.incompatibleOps.enabled", False,
     "Allow ops whose results can differ from CPU in corner cases "
@@ -858,15 +843,6 @@ SUPERSTAGE_MIN_OPS = conf_int(
     "Minimum member operators before a carved region is wrapped in a "
     "TpuSuperstage (singleton regions gain nothing over the "
     "per-operator fused paths)", internal=True)
-SUPERSTAGE_SPEC_JOIN = conf_bool(
-    "spark.rapids.tpu.sql.superstage.speculativeJoin", True,
-    "Inside a superstage, lower no-condition inner hash-join probes to "
-    "the sync-free speculative unique-match program: output capacity "
-    "is the probe capacity (static), the match count stays on device, "
-    "and a fit flag (max matches per probe row <= 1) rides the "
-    "existing speculative redo machinery to the stage flush barrier; "
-    "a violating batch (duplicate build keys) recomputes on the exact "
-    "path.  Star-schema dimension joins always fit", internal=True)
 AOT_ENABLED = conf_bool(
     "spark.rapids.tpu.compile.aot.enabled", True,
     "AOT compile subsystem (compile/aot.py): shape-bucket batch "

@@ -10,6 +10,7 @@ TPU-first: grouping is the sort+segmented-reduce kernel
 """
 from __future__ import annotations
 
+import logging
 from typing import List, Optional
 
 import jax
@@ -20,8 +21,8 @@ from ..columnar.schema import Field, Schema
 from ..columnar.column import Column, bucket_capacity
 from ..columnar.batch import (ColumnarBatch, LazyCount, SpeculativeResult,
                               concat_batches, resolve_speculative)
+from ..expr import aggregates as ea
 from ..expr import core as ec
-from ..expr.aggregates import AggregateFunction
 from ..compile import aot as _aot
 from ..kernels import canon, aggregate as agg_k
 from ..obs import compile_watch as _compile_watch
@@ -77,7 +78,6 @@ def _group_reduce(key_cols, live, num_rows, aggs, agg_cols,
     row gets rank 2 in the first key field and sorts past every group,
     so nothing is compacted.  Returns (num_groups, fit, output pairs in
     schema order)."""
-    from ..expr import aggregates as ea
     cap = key_cols[0].capacity
     rows = jnp.arange(cap) < num_rows
     live = rows if live is None else live & rows
@@ -155,14 +155,12 @@ CH_W0 = 88        # max chunk position (top term bit 88+23 < 120)
 def _flip32(f):
     """f32 -> u32 whose unsigned order equals the float total order
     (-0.0 handled by callers; NaNs must be masked out)."""
-    import jax
     u = jax.lax.bitcast_convert_type(f, jnp.uint32)
     neg = (u >> jnp.uint32(31)) != jnp.uint32(0)
     return jnp.where(neg, ~u, u | jnp.uint32(0x80000000))
 
 
 def _unflip32(w):
-    import jax
     neg = (w & jnp.uint32(0x80000000)) == jnp.uint32(0)
     u = jnp.where(neg, ~w, w & jnp.uint32(0x7FFFFFFF))
     return jax.lax.bitcast_convert_type(u, jnp.float32)
@@ -170,7 +168,6 @@ def _unflip32(w):
 
 def _pow2f(k):
     """2^k as f32 from a traced i32 scalar, k in [-126, 127]."""
-    import jax
     return jax.lax.bitcast_convert_type(
         ((k + 127).astype(jnp.uint32) << jnp.uint32(23)), jnp.float32)
 
@@ -178,7 +175,6 @@ def _pow2f(k):
 def _f32_exp(f):
     """(biased exponent clamped >=1, 24-bit significand, negative) of an
     f32 array."""
-    import jax
     u = jax.lax.bitcast_convert_type(f, jnp.uint32)
     neg = (u >> jnp.uint32(31)) != jnp.uint32(0)
     e = ((u >> jnp.uint32(23)) & jnp.uint32(0xFF)).astype(jnp.int32)
@@ -228,6 +224,17 @@ def _chunk_recombine(lanes_f64, emax):
     return out
 
 
+# the cores' sort, row gather and scan are sized by the batch: past this
+# many slots a batch takes the eager fallback
+_CORE_MAX_CAPACITY = 1 << 22
+
+
+def _agg_signature(aggs) -> tuple:
+    """The aggregate functions as a core's cache key names them."""
+    return tuple((type(a.func).__name__, repr(a.func),
+                  getattr(a.func, "ignore_nulls", None)) for a in aggs)
+
+
 def buffer_schema(group_exprs, aggs: List[AggExpr]) -> Schema:
     """Schema of partial-aggregation output: keys + flattened buffers."""
     fields = [Field(ec.output_name(e), e.dtype(), True) for e in group_exprs]
@@ -247,8 +254,8 @@ class TpuHashAggregate(TpuExec):
         # whole-stage fusion: a leading filter/project chain folded in by
         # the planner post-pass (exec/staged.py) — applied before keys
         self.pre_ops = None
-        # per-exec memo for whole-stage guards/signatures (shared with
-        # the throwaway inner instances _update_batch builds per batch)
+        # per-exec memo: whole-stage guards / signatures by source
+        # dtypes, the eager pre_ops programs, the compaction's state
         self._ws_memo = {}
 
     @property
@@ -316,7 +323,7 @@ class TpuHashAggregate(TpuExec):
                         ColumnarBatch.empty(child_schema))]
                 # A single PARTIAL passes through unverified/uncompacted
                 # (zero syncs); the exchange downstream holds the flush
-                # barrier that verifies speculative table-path batches
+                # barrier that verifies speculatively compacted batches
                 # and slices them.  Any path that merges/finalizes here
                 # must verify first (the merge would bake garbage in) —
                 # EXCEPT the single-partial deferred path below, which
@@ -383,18 +390,12 @@ class TpuHashAggregate(TpuExec):
         return b.slice(0, max(n, 1))
 
     def _update_batch(self, batch: ColumnarBatch) -> ColumnarBatch:
-        """Partial (update) aggregation of one input batch -> buffer batch."""
-        inner = TpuHashAggregate(self.group_exprs, self.aggs,
-                                 self.children[0], mode=PARTIAL)
-        inner.pre_ops = self.pre_ops
-        inner._ws_memo = self._ws_memo
+        """One input batch -> buffer batch: the update of a PARTIAL or
+        COMPLETE aggregate; a FINAL one's input is buffer-shaped already
+        and merges within the batch."""
         if self.mode == FINAL:
-            # input is already buffer-shaped: merge within the batch
-            inner = TpuHashAggregate(self.group_exprs, self.aggs,
-                                     self.children[0], mode=FINAL)
-            inner_out = inner._aggregate_batch(batch, emit_buffers=True)
-            return inner_out
-        return inner._aggregate_batch(batch)
+            return self._aggregate_batch(batch, FINAL, emit_buffers=True)
+        return self._aggregate_batch(batch, PARTIAL)
 
     def _merge_finalize(self, merged: ColumnarBatch,
                         multiple: bool) -> ColumnarBatch:
@@ -402,19 +403,55 @@ class TpuHashAggregate(TpuExec):
             if not multiple:
                 return merged
             # merge duplicate keys across partials, stay in buffer form
-            inner = TpuHashAggregate(self.group_exprs, self.aggs,
-                                     self.children[0], mode=FINAL)
-            return inner._aggregate_batch(merged, emit_buffers=True)
-        inner = TpuHashAggregate(self.group_exprs, self.aggs,
-                                 self.children[0], mode=FINAL)
-        return inner._aggregate_batch(merged)
+            return self._aggregate_batch(merged, FINAL, emit_buffers=True)
+        return self._aggregate_batch(merged, FINAL)
 
-    # -- fused core (one dispatch per batch) -------------------------------
-    _FUSABLE_FUNCS = None   # populated lazily (class-level allowlist)
-    # class-level jit cache: _update_batch/_merge_finalize build throwaway
-    # TpuHashAggregate instances per batch, so the cache must outlive them
-    # (keyed by everything the traced closure captures)
+    def _out_schema(self, emit_buffers: bool) -> Schema:
+        """What one ``_aggregate_batch`` step yields: buffers, or this
+        node's finalized columns (a PARTIAL node only emits buffers)."""
+        return buffer_schema(self.group_exprs, self.aggs) \
+            if emit_buffers else self.output_schema
+
+    # -- the one-program cores ---------------------------------------------
+    # what a core's update / merge can trace (``collect_*`` cannot)
+    _FUSABLE_FUNCS = (ea.Sum, ea.Count, ea.Min, ea.Max, ea.Average,
+                      ea.First, ea.Last, ea.CentralMoment)
+    # class-level jit cache, keyed by everything the traced closure
+    # captures: plans are rebuilt per query, the programs outlive them
     _CORE_CACHE = {}
+
+    def _run_core(self, cache_key, build, args, batch: ColumnarBatch,
+                  what: str, warmer=None):
+        """The tail the three cores share: look ``cache_key`` up in
+        ``_CORE_CACHE`` (a key whose core failed before stays failed),
+        on a miss ``build()`` the jitted program behind ``wrap_miss``
+        and register ``warmer`` = (AOT program name, ``warm(core,
+        bucket)``) for it, note the demand at the batch's capacity and
+        call.  A core that raises is logged, cached as ``False`` and
+        answered with None: the caller falls back."""
+        cache = TpuHashAggregate._CORE_CACHE
+        core = cache.get(cache_key)
+        compile_cache_event("hash_aggregate", core is not None)
+        if core is False:
+            return None
+        if core is None:
+            core = cache[cache_key] = _compile_watch.wrap_miss(
+                "hash_aggregate", build(), str(cache_key))
+            if warmer is not None:
+                program, warm = warmer
+                _aot.register_warmer(
+                    program, lambda bucket, core=core: warm(core, bucket),
+                    str(hash(cache_key)))
+        _aot.note_demand("hash_aggregate", batch.capacity,
+                         _costplane.rows_if_resolved(batch))
+        try:
+            return core(*args)
+        except Exception:  # noqa: BLE001 - fall back, but loudly
+            logging.getLogger("spark_rapids_tpu.exec.aggregate").warning(
+                "%s aggregate core failed; falling back", what,
+                exc_info=True)
+            cache[cache_key] = False
+            return None
 
     def _fused_agg_core(self, key_cols, input_cols, update_mode: bool,
                         batch: ColumnarBatch, emit_buffers: bool,
@@ -439,80 +476,54 @@ class TpuHashAggregate(TpuExec):
         rationale as exec/fused.py, applied to the aggregate hot loop
         (aggregate.scala:366 computeAggregate role).
         """
-        import jax
-        import logging
         from ..columnar.binary64 import exact_double_enabled
+        from ..columnar.column import StringColumn
         if exact_double_enabled():
             # traced reassembly would strip Binary64Columns
             return None
-        if TpuHashAggregate._FUSABLE_FUNCS is None:
-            from ..expr import aggregates as ea
-            TpuHashAggregate._FUSABLE_FUNCS = (
-                ea.Sum, ea.Count, ea.Min, ea.Max, ea.Average, ea.First,
-                ea.Last, ea.CentralMoment)
-        if batch.capacity > (1 << 22):
+        if batch.capacity > _CORE_MAX_CAPACITY:
             return None
-        from ..columnar.column import StringColumn
         if not all(type(c) is Column or isinstance(c, StringColumn)
                    for c in key_cols):
             return None
         for cols in input_cols:
             if not all(c is None or type(c) is Column for c in cols):
                 return None
-        if not all(isinstance(a.func, TpuHashAggregate._FUSABLE_FUNCS)
+        if not all(isinstance(a.func, self._FUSABLE_FUNCS)
                    for a in self.aggs):
             return None
-        key_dts = tuple(c.dtype for c in key_cols)
         in_dts = tuple(tuple(None if c is None else c.dtype for c in cols)
                        for cols in input_cols)
         aggs = self.aggs
         packed = {i: _pack_string_key(c, batch.rows_dev)
                   for i, c in enumerate(key_cols) if c.dtype == T.STRING}
         # a STRING key's dtype stands with its byte bound in the key
-        key_dts = tuple((dt, packed[i][1]) if i in packed else dt
-                        for i, dt in enumerate(key_dts))
-        from ..kernels.aggregate import _pair_sum_enabled
+        key_dts = tuple((c.dtype, packed[i][1]) if i in packed else c.dtype
+                        for i, c in enumerate(key_cols))
         cache_key = (update_mode, emit_buffers, key_dts, in_dts, out_cap,
-                     _pair_sum_enabled(),
-                     tuple((type(a.func).__name__, repr(a.func),
-                            getattr(a.func, "ignore_nulls", None))
-                           for a in aggs))
-        core = TpuHashAggregate._CORE_CACHE.get(cache_key)
-        compile_cache_event("hash_aggregate", core is not None)
-        if core is False:
-            return None
+                     _agg_signature(aggs))
 
-        if core is None:
-            def _core(key_arrays, in_arrays, num_rows):
-                kcols = [canon.PackedStringKey(d, v, dt[1])
-                         if isinstance(dt, tuple) else Column(dt, d, v)
-                         for dt, (d, v) in zip(key_dts, key_arrays)]
-                it = iter(in_arrays)
-                agg_cols = [[None if dt is None else Column(dt, *next(it))
-                             for dt in dts] or [None] for dts in in_dts]
-                return _group_reduce(kcols, None, num_rows, aggs, agg_cols,
-                                     update_mode, out_cap, emit_buffers)
-            core = _compile_watch.wrap_miss(
-                "hash_aggregate",
-                _compile_watch.jit(_core, "agg_grouped_core"),
-                str(cache_key))
-            TpuHashAggregate._CORE_CACHE[cache_key] = core
-            key_nps = tuple(None if isinstance(dt, tuple) else dt.np_dtype
-                            for dt in key_dts)
-            in_nps = tuple(dt.np_dtype for dts in in_dts for dt in dts
-                           if dt is not None)
-            if not any(d is None for d in key_nps + in_nps):
-                def warm(bucket: int) -> None:
-                    ka = tuple((jnp.zeros(bucket, d),
-                                jnp.zeros(bucket, jnp.bool_))
-                               for d in key_nps)
-                    ia = tuple((jnp.zeros(bucket, d),
-                                jnp.zeros(bucket, jnp.bool_))
-                               for d in in_nps)
-                    core(ka, ia, jnp.int32(0))
-                _aot.register_warmer("hash_aggregate_grouped", warm,
-                                     str(hash(cache_key)))
+        def _core(key_arrays, in_arrays, num_rows):
+            kcols = [canon.PackedStringKey(d, v, dt[1])
+                     if isinstance(dt, tuple) else Column(dt, d, v)
+                     for dt, (d, v) in zip(key_dts, key_arrays)]
+            it = iter(in_arrays)
+            agg_cols = [[None if dt is None else Column(dt, *next(it))
+                         for dt in dts] or [None] for dts in in_dts]
+            return _group_reduce(kcols, None, num_rows, aggs, agg_cols,
+                                 update_mode, out_cap, emit_buffers)
 
+        key_nps = tuple(None if isinstance(dt, tuple) else dt.np_dtype
+                        for dt in key_dts)
+        in_nps = tuple(dt.np_dtype for dts in in_dts for dt in dts
+                       if dt is not None)
+
+        def warm(core, bucket: int) -> None:
+            ka = tuple((jnp.zeros(bucket, d), jnp.zeros(bucket, jnp.bool_))
+                       for d in key_nps)
+            ia = tuple((jnp.zeros(bucket, d), jnp.zeros(bucket, jnp.bool_))
+                       for d in in_nps)
+            core(ka, ia, jnp.int32(0))
         # flat arg list, None inputs omitted (the dtypes tuple encodes
         # which are None — no placeholder transfers)
         in_arrays = tuple(
@@ -521,29 +532,27 @@ class TpuHashAggregate(TpuExec):
         key_arrays = tuple(
             packed[i][0] if i in packed else (c.data, c.validity)
             for i, c in enumerate(key_cols))
-        _aot.note_demand("hash_aggregate", batch.capacity,
-                         _costplane.rows_if_resolved(batch))
-        out_schema = buffer_schema(self.group_exprs, aggs) \
-            if emit_buffers else self.output_schema
-        try:
-            ng, fit, pairs = core(key_arrays, in_arrays, batch.rows_dev)
-            return ng, fit, _output_columns(
-                out_schema, pairs,
-                {i: c for i, c in enumerate(key_cols)
-                 if c.dtype == T.STRING})
-        except Exception:  # noqa: BLE001 - fall back, but loudly
-            logging.getLogger("spark_rapids_tpu.exec.aggregate").warning(
-                "fused aggregate core failed; falling back to eager",
-                exc_info=True)
-            TpuHashAggregate._CORE_CACHE[cache_key] = False
+        out = self._run_core(
+            cache_key,
+            lambda: _compile_watch.jit(_core, "agg_grouped_core"),
+            (key_arrays, in_arrays, batch.rows_dev), batch, "grouped",
+            warmer=None if None in key_nps + in_nps
+            else ("hash_aggregate_grouped", warm))
+        if out is None:
             return None
+        ng, fit, pairs = out
+        return ng, fit, _output_columns(
+            self._out_schema(emit_buffers), pairs,
+            {i: c for i, c in enumerate(key_cols) if c.dtype == T.STRING})
 
-    # -- sort-free bucket-table fast path ----------------------------------
-    # (kernels/aggregate.py table_plan; the cuDF-hash-groupby role done
-    # the TPU way: mixed-radix bucket ids + MXU one-hot matmuls, no sort,
-    # speculative dispatch verified by a device-side fit flag.)
-
-    _TABLE_KEY_DTYPES = None   # int-family key dtypes (lazily built)
+    # -- sort-free bucket-table path ---------------------------------------
+    # (kernels/aggregate.py table_bucket / table_reduce; the cuDF-hash-
+    # groupby role done the TPU way: mixed-radix bucket ids + small-output
+    # scatters, no sort, speculative dispatch verified by a device-side
+    # fit flag.)  Taken by what it admits, not by an option: integer-
+    # family keys over plain columns at a capacity of at least
+    # ``sql.agg.tableSize``.  On the chip it is 4.5-9x ahead of the
+    # grouped core on such a key at 2^20 slots (PERF.md section 5).
 
     @staticmethod
     def _table_key_ok(dt) -> bool:
@@ -637,14 +646,11 @@ class TpuHashAggregate(TpuExec):
 
         Returns a buffer-schema ColumnarBatch (capacity = table size)
         carrying a SpeculativeResult, or None to use the general path."""
-        import jax
-        import logging
-        from ..config import get_active, AGG_TABLE_ENABLED, AGG_TABLE_SIZE
+        from ..config import get_active, AGG_TABLE_SIZE
         from ..columnar.binary64 import exact_double_enabled
-        conf = get_active()
-        if not conf.get(AGG_TABLE_ENABLED) or exact_double_enabled():
+        if exact_double_enabled():
             return None
-        table = int(conf.get(AGG_TABLE_SIZE))
+        table = int(get_active().get(AGG_TABLE_SIZE))
         # capacity cap is 2^24: all reduce rows are f32, so per-group
         # counts and first/last positions are exact only up to 2^24
         # (f32 integer-exact range); a larger batch could silently
@@ -669,26 +675,17 @@ class TpuHashAggregate(TpuExec):
         if batch.capacity > (1 << 22) and \
                 any(d[0] in ("fsum64", "favg64") for d in descs):
             return None
-        core = TpuHashAggregate._CORE_CACHE.get((cache_key, table))
-        if core is False:
-            return None
-        if core is None:
-            core = _compile_watch.wrap_miss(
-                "hash_aggregate",
-                _compile_watch.jit(self._build_table_core(
-                    batch.schema, bound_keys, bound_inputs, descs, table),
-                    "agg_table_core"), str((cache_key, table)))
-            TpuHashAggregate._CORE_CACHE[(cache_key, table)] = core
         datas = tuple(c.data for c in batch.columns)
         valids = tuple(c.validity for c in batch.columns)
-        try:
-            fit, ng, key_pairs, buf_groups = core(datas, valids,
-                                                  batch.rows_dev)
-        except Exception:  # noqa: BLE001 - fall back, but loudly
-            logging.getLogger("spark_rapids_tpu.exec.aggregate").warning(
-                "table aggregate core failed; falling back", exc_info=True)
-            TpuHashAggregate._CORE_CACHE[(cache_key, table)] = False
+        out = self._run_core(
+            (cache_key, table),
+            lambda: _compile_watch.jit(self._build_table_core(
+                batch.schema, bound_keys, bound_inputs, descs, table),
+                "agg_table_core"),
+            (datas, valids, batch.rows_dev), batch, "table")
+        if out is None:
             return None
+        fit, ng, key_pairs, buf_groups = out
         out_cols = [Column(e.dtype(), d, v)
                     for e, (d, v) in zip(bound_keys, key_pairs)]
         for a, pairs in zip(self.aggs, buf_groups):
@@ -700,7 +697,7 @@ class TpuHashAggregate(TpuExec):
 
         def redo():
             self._ws_memo["table_state"] = "off"
-            return self._aggregate_batch(batch, no_table=True)
+            return self._aggregate_batch(batch, PARTIAL, no_table=True)
         out._speculative = SpeculativeResult([LazyCount(fit)], redo)
         return out
 
@@ -709,25 +706,18 @@ class TpuHashAggregate(TpuExec):
         """Build the traced table-aggregation program.
 
         One pass: mixed-radix bucket ids (kernels/aggregate.table_bucket),
-        then a SINGLE fused Pallas table-reduce (pallas_ops.table_reduce)
-        covering every sum/count row (MXU one-hot dots) and every min/max
-        row (VPU masked reductions; mins ride negated).  Exact float mode
+        then ONE table reduce (kernels/aggregate.table_reduce) covering
+        every sum/count row (one stacked scatter-add) and every min/max
+        row (a scatter-max each; mins ride negated).  Exact float mode
         adds 64-bit lanes (fsum64/favg64/fminmax64) reduced by direct
         small-output scatters in the device's full f64 representation.
         All f32 reduce rows; integer min/max and first/last positions are exact
         because the fit flag restricts them to the f32-exact integer
         range (2^24) — non-fitting batches re-run on the sort path."""
-        import jax.numpy as jnp
-        from ..config import get_active, AGG_TABLE_REDUCE_IMPL
-        import jax
-        from ..kernels.pallas_ops import table_reduce
         from .fused import _TracedBatch
         from .staged import apply_ops_masked
-        reduce_impl = get_active().get(AGG_TABLE_REDUCE_IMPL)
         pre_ops = self.pre_ops
         SIGN = 0x8000000000000000
-        NEG_INF = jnp.float32(-jnp.inf)
-        F32_EXACT = jnp.uint64(1 << 24)
 
         def decode_word(dtype, word):
             if dtype == T.BOOL:
@@ -736,6 +726,12 @@ class TpuHashAggregate(TpuExec):
             return v.astype(dtype.np_dtype)
 
         def _core(datas, valids, num_rows):
+            # made under the trace: a device array closed over from
+            # outside is pulled to the host when the program lowers,
+            # which the residency guard refuses (so the core failed and
+            # fell back on every guarded query before PR 30)
+            NEG_INF = jnp.float32(-jnp.inf)
+            F32_EXACT = jnp.uint64(1 << 24)
             cap = datas[0].shape[0]
             cols = [Column(f.dtype, d, v)
                     for f, d, v in zip(src_schema, datas, valids)]
@@ -925,8 +921,8 @@ class TpuHashAggregate(TpuExec):
                                       NEG_INF))
                     agg_meta.append(None)
 
-            sums, maxs = table_reduce(bucket, sum_rows, max_rows, table,
-                                      impl=reduce_impl)
+            sums, maxs = agg_k.table_reduce(bucket, sum_rows, max_rows,
+                                            table)
             # i32 chunk lanes: ONE stacked scatter (multi-column scatter
             # costs the same as single-column; lane sums < 2^31, exact)
             chunk_out = None
@@ -1118,7 +1114,7 @@ class TpuHashAggregate(TpuExec):
         for bs in bound_inputs:
             if not all(_tree_fusable(e) for e in bs):
                 return False
-        if not all(isinstance(a.func, TpuHashAggregate._FUSABLE_FUNCS)
+        if not all(isinstance(a.func, self._FUSABLE_FUNCS)
                    for a in self.aggs):
             return False
         ksigs = [expr_signature(e) for e in bound_keys]
@@ -1130,9 +1126,7 @@ class TpuHashAggregate(TpuExec):
         cache_key = ("ws", osig, tuple(ksigs),
                      tuple(x for t in isigs for x in t),
                      tuple(f.dtype.name for f in src_schema),
-                     tuple((type(a.func).__name__, repr(a.func),
-                            getattr(a.func, "ignore_nulls", None))
-                           for a in self.aggs))
+                     _agg_signature(self.aggs))
         return cache_key, bound_keys, bound_inputs, string_keys
 
     def _fused_whole_stage_core(self, batch: ColumnarBatch,
@@ -1150,17 +1144,10 @@ class TpuHashAggregate(TpuExec):
         tries ``_fused_agg_core``).  ``out_cap`` requests speculative
         device-side compaction to that capacity; ``fit`` is the device
         flag that the group count fit (always-1 when uncompacted)."""
-        import jax
-        import logging
-        from .fused import _TracedBatch, _tree_fusable, expr_signature
+        from .fused import _TracedBatch, expr_signature
         from ..columnar.column import StringColumn
         from .staged import apply_ops_masked
-        if TpuHashAggregate._FUSABLE_FUNCS is None:
-            from ..expr import aggregates as ea
-            TpuHashAggregate._FUSABLE_FUNCS = (
-                ea.Sum, ea.Count, ea.Min, ea.Max, ea.Average, ea.First,
-                ea.Last, ea.CentralMoment)
-        if batch.capacity > (1 << 22) or not batch.columns:
+        if batch.capacity > _CORE_MAX_CAPACITY or not batch.columns:
             return None
         if not all(type(c) is Column or isinstance(c, StringColumn)
                    for c in batch.columns):
@@ -1174,64 +1161,51 @@ class TpuHashAggregate(TpuExec):
             self._ws_memo[mkey] = prep
         if prep is False:
             return None
-        from ..kernels.aggregate import _pair_sum_enabled
         cache_key, bound_keys, bound_inputs, string_keys = prep
         # source ordinal -> ((words, validity), byte bound)
         packed = {at: _pack_string_key(batch.columns[at], batch.rows_dev)
                   for at in set(string_keys.values())}
         bounds = tuple(sorted((at, p[1]) for at, p in packed.items()))
-        cache_key = cache_key + (emit_buffers, out_cap,
-                                 _pair_sum_enabled(), bounds)
-        core = TpuHashAggregate._CORE_CACHE.get(cache_key)
-        if core is False:
-            return None
-        if core is None:
-            src_schema = batch.schema
-            pre_ops = self.pre_ops
-            aggs = self.aggs
+        cache_key = cache_key + (emit_buffers, out_cap, bounds)
+        src_schema = batch.schema
+        pre_ops = self.pre_ops
+        aggs = self.aggs
 
-            def _core(datas, valids, num_rows):
-                cap = next(v for v in valids if v is not None).shape[0]
-                # a column the core was not given (None) is a STRING
-                # column nothing reads; a key source holds its words
-                byte_bound = dict(bounds)
-                cols = [None if v is None else
-                        canon.PackedStringKey(d, v, byte_bound[i])
-                        if f.dtype == T.STRING else Column(f.dtype, d, v)
-                        for i, (f, d, v) in enumerate(
-                            zip(src_schema, datas, valids))]
-                b = _TracedBatch(src_schema, cols, num_rows, cap)
-                with jax.named_scope("pre_ops"):
-                    b, live = apply_ops_masked(
-                        pre_ops, b, jnp.arange(cap) < num_rows)
-                kcols = [ec.eval_as_column(e, b) for e in bound_keys]
-                # inputs are keyed by bound expression: sum(x) and
-                # avg(x) read ONE evaluated column, moved once
-                evaluated = {}
-                agg_cols = []
-                for bs in bound_inputs:
-                    for e in bs:
-                        sig = expr_signature(e)
-                        if sig not in evaluated:
-                            evaluated[sig] = ec.eval_as_column(e, b)
-                    agg_cols.append([evaluated[expr_signature(e)]
-                                     for e in bs] or [None])
-                return _group_reduce(kcols, live, num_rows, aggs, agg_cols,
-                                     True, out_cap, emit_buffers)
-            core = _compile_watch.wrap_miss(
-                "hash_aggregate",
-                _compile_watch.jit(_core, "agg_whole_stage_core"),
-                str(cache_key))
-            TpuHashAggregate._CORE_CACHE[cache_key] = core
-            ws_nps = tuple(f.dtype.np_dtype for f in batch.schema)
-            if not any(d is None for d in ws_nps):
-                def warm(bucket: int) -> None:
-                    ds = tuple(jnp.zeros(bucket, d) for d in ws_nps)
-                    vs = tuple(jnp.zeros(bucket, jnp.bool_)
-                               for _ in ws_nps)
-                    core(ds, vs, jnp.int32(0))
-                _aot.register_warmer("hash_aggregate_whole_stage", warm,
-                                     str(hash(cache_key)))
+        def _core(datas, valids, num_rows):
+            cap = next(v for v in valids if v is not None).shape[0]
+            # a column the core was not given (None) is a STRING
+            # column nothing reads; a key source holds its words
+            byte_bound = dict(bounds)
+            cols = [None if v is None else
+                    canon.PackedStringKey(d, v, byte_bound[i])
+                    if f.dtype == T.STRING else Column(f.dtype, d, v)
+                    for i, (f, d, v) in enumerate(
+                        zip(src_schema, datas, valids))]
+            b = _TracedBatch(src_schema, cols, num_rows, cap)
+            with jax.named_scope("pre_ops"):
+                b, live = apply_ops_masked(
+                    pre_ops, b, jnp.arange(cap) < num_rows)
+            kcols = [ec.eval_as_column(e, b) for e in bound_keys]
+            # inputs are keyed by bound expression: sum(x) and
+            # avg(x) read ONE evaluated column, moved once
+            evaluated = {}
+            agg_cols = []
+            for bs in bound_inputs:
+                for e in bs:
+                    sig = expr_signature(e)
+                    if sig not in evaluated:
+                        evaluated[sig] = ec.eval_as_column(e, b)
+                agg_cols.append([evaluated[expr_signature(e)]
+                                 for e in bs] or [None])
+            return _group_reduce(kcols, live, num_rows, aggs, agg_cols,
+                                 True, out_cap, emit_buffers)
+
+        ws_nps = tuple(f.dtype.np_dtype for f in src_schema)
+
+        def warm(core, bucket: int) -> None:
+            ds = tuple(jnp.zeros(bucket, d) for d in ws_nps)
+            vs = tuple(jnp.zeros(bucket, jnp.bool_) for _ in ws_nps)
+            core(ds, vs, jnp.int32(0))
         datas, valids = [], []
         for i, c in enumerate(batch.columns):
             if type(c) is Column:
@@ -1242,76 +1216,83 @@ class TpuHashAggregate(TpuExec):
                 d = v = None
             datas.append(d)
             valids.append(v)
-        _aot.note_demand("hash_aggregate", batch.capacity,
-                         _costplane.rows_if_resolved(batch))
-        out_schema = buffer_schema(self.group_exprs, self.aggs) \
-            if emit_buffers else self.output_schema
-        try:
-            ng, fit, pairs = core(tuple(datas), tuple(valids),
-                                  batch.rows_dev)
-            return ng, fit, _output_columns(
-                out_schema, pairs,
-                {i: batch.columns[at] for i, at in string_keys.items()})
-        except Exception:  # noqa: BLE001 - fall back, but loudly
-            logging.getLogger("spark_rapids_tpu.exec.aggregate").warning(
-                "whole-stage aggregate core failed; falling back",
-                exc_info=True)
-            TpuHashAggregate._CORE_CACHE[cache_key] = False
+        out = self._run_core(
+            cache_key,
+            lambda: _compile_watch.jit(_core, "agg_whole_stage_core"),
+            (tuple(datas), tuple(valids), batch.rows_dev), batch,
+            "whole-stage",
+            warmer=None if None in ws_nps
+            else ("hash_aggregate_whole_stage", warm))
+        if out is None:
             return None
+        ng, fit, pairs = out
+        return ng, fit, _output_columns(
+            self._out_schema(emit_buffers), pairs,
+            {i: batch.columns[at] for i, at in string_keys.items()})
 
-    # -- core -------------------------------------------------------------------
-    def _aggregate_batch(self, batch: ColumnarBatch,
+    # -- one batch ---------------------------------------------------------
+    def _aggregate_batch(self, batch: ColumnarBatch, mode: str,
                          emit_buffers: bool = False,
                          no_table: bool = False,
                          no_compact: bool = False) -> ColumnarBatch:
-        """One input batch through the first path that admits it: the
-        bucket table (``_fused_table_core``), the whole-stage core
-        (pre_ops traced with the update), the grouped core (pre_ops run
-        eagerly first; every merge), the global core, or the eager
-        grouped fallback.  ``agg.batches.{table,fused,eager}`` count
-        where a grouped batch settled."""
-        if not no_table and self.mode == PARTIAL and self.group_exprs:
+        """One step over one batch: ``mode`` PARTIAL updates from input
+        rows (how a COMPLETE node runs its batches too) and emits
+        buffers; FINAL merges a buffer-shaped batch and finalizes unless
+        ``emit_buffers``.  Top to bottom: a keyed update the bucket
+        table admits -> the table core; no keys -> the global core; an
+        update whose pre_ops trace -> the whole-stage core; else pre_ops
+        run eagerly and the grouped core takes the update, as it takes
+        every merge; else the eager grouped fallback.
+        ``agg.batches.{table,fused,eager}`` count where a batch settled.
+
+        What still lands on the eager fallback: STRING or nested
+        aggregate inputs, functions outside ``_FUSABLE_FUNCS``
+        (``collect_*``), ``exactDouble``, capacities over 2^22."""
+        update = mode == PARTIAL
+        emit = emit_buffers or update
+        if update and self.group_exprs and not no_table:
             t = self._fused_table_core(batch)
             if t is not None:
                 _obs_trace.count("agg.batches.table")
                 return t
-        emit = emit_buffers or self.mode == PARTIAL
-        out_schema_obj = buffer_schema(self.group_exprs, self.aggs) \
-            if emit else self.output_schema
         # speculative device-side compaction: hand downstream a small-
         # capacity batch instead of the input-capacity one (group counts
         # are almost always << rows); the fit flag is verified at the
-        # consumer's flush barrier, a misfit recomputes uncompacted and
-        # turns compaction off for this exec
+        # consumer's flush barrier, a misfit recomputes uncompacted.  An
+        # update's misfit turns compaction off for this exec's later
+        # updates; a merge sees all its partials' groups at once and
+        # says nothing of the next
         compact_cap = None
-        if not no_compact and self.group_exprs and \
-                self._ws_memo.get("compact_state") != "off":
+        if not no_compact and self.group_exprs and not (
+                update and self._ws_memo.get("compact_state") == "off"):
             from ..config import get_active, AGG_COMPACT_ROWS
             cc = int(get_active().get(AGG_COMPACT_ROWS))
             if cc > 0 and batch.capacity > cc:
                 compact_cap = cc
+        source = batch
 
-        def _wrap_speculative(out: ColumnarBatch, fit) -> ColumnarBatch:
+        def fused_out(core_out) -> ColumnarBatch:
+            ng, fit, cols = core_out
+            _obs_trace.count("agg.batches.fused")
+            out = ColumnarBatch(self._out_schema(emit), cols, LazyCount(ng))
             if compact_cap is None:
                 return out
 
             def redo():
-                self._ws_memo["compact_state"] = "off"
+                if update:
+                    self._ws_memo["compact_state"] = "off"
                 return resolve_speculative(self._aggregate_batch(
-                    batch, emit_buffers=emit_buffers, no_table=no_table,
-                    no_compact=True))
+                    source, mode, emit_buffers=emit_buffers,
+                    no_table=no_table, no_compact=True))
             out._speculative = SpeculativeResult([LazyCount(fit)], redo)
             return out
-        if self.pre_ops and self.mode in (PARTIAL, COMPLETE):
+
+        if update and self.pre_ops:
             ws = self._fused_whole_stage_core(batch, emit,
                                               out_cap=compact_cap) \
                 if self.group_exprs else None
             if ws is not None:
-                ng, fit, cols = ws
-                _obs_trace.count("agg.batches.fused")
-                return _wrap_speculative(
-                    ColumnarBatch(out_schema_obj, cols, LazyCount(ng)),
-                    fit)
+                return fused_out(ws)
             from .staged import apply_ops_eager, build_fused_per_op
             fkey = ("fpo", tuple(f.dtype.name for f in batch.schema))
             fpo = self._ws_memo.get(fkey)
@@ -1319,91 +1300,72 @@ class TpuHashAggregate(TpuExec):
                 fpo = build_fused_per_op(self.pre_ops, batch.schema)
                 self._ws_memo[fkey] = fpo
             batch = apply_ops_eager(self.pre_ops, batch, fpo)
-        child_schema = batch.schema
-        if self.mode in (PARTIAL, COMPLETE):
-            key_cols = [ec.eval_as_column(e.bind(child_schema), batch)
+        nkeys = len(self.group_exprs)
+        if update:
+            schema = batch.schema
+            key_cols = [ec.eval_as_column(e.bind(schema), batch)
                         for e in self.group_exprs]
-            input_cols = []
+            input_cols = [
+                [ec.eval_as_column(c.bind(schema), batch)
+                 for c in a.func.children] or [None] for a in self.aggs]
+        else:       # keys + buffers laid out by buffer_schema
+            key_cols = batch.columns[:nkeys]
+            input_cols, pos = [], nkeys
             for a in self.aggs:
-                bound = [c.bind(child_schema) for c in a.func.children]
                 input_cols.append(
-                    [ec.eval_as_column(b, batch) for b in bound] or [None])
-        else:  # FINAL: input is keys + buffers laid out by buffer_schema
-            key_cols = [batch.columns[i] for i in range(len(self.group_exprs))]
-            input_cols = []
-            pos = len(self.group_exprs)
-            for a in self.aggs:
-                nb = a.func.num_buffers
-                input_cols.append(batch.columns[pos: pos + nb])
-                pos += nb
-
-        if not self.group_exprs:
-            return self._global_agg(batch, input_cols, emit_buffers)
-
-        update_mode = self.mode in (PARTIAL, COMPLETE)
-        fused = self._fused_agg_core(key_cols, input_cols, update_mode,
-                                     batch, emit, out_cap=compact_cap)
+                    batch.columns[pos: pos + a.func.num_buffers])
+                pos += a.func.num_buffers
+        if not nkeys:
+            return self._global_agg(batch, input_cols, update, emit)
+        fused = self._fused_agg_core(key_cols, input_cols, update, batch,
+                                     emit, out_cap=compact_cap)
         if fused is not None:
-            ng, fit, cols = fused
-            _obs_trace.count("agg.batches.fused")
-            return _wrap_speculative(
-                ColumnarBatch(out_schema_obj, cols, LazyCount(ng)), fit)
+            return fused_out(fused)
         _obs_trace.count("agg.batches.eager")
+        return self._eager_grouped(batch, key_cols, input_cols, update,
+                                   emit)
+
+    def _eager_grouped(self, batch: ColumnarBatch, key_cols, input_cols,
+                       update: bool, emit: bool) -> ColumnarBatch:
+        """The grouped fallback: the same sort + segmented reduce, one
+        jax launch an operation, output at the input's capacity."""
         words = canon.batch_key_words(key_cols, batch.rows_dev)
         plan = agg_k.groupby_plan(words)
-        # aggregate buffers (segment-id indexed, 0..G-1, input capacity)
-        agg_buffers = []
-        for a, cols in zip(self.aggs, input_cols):
-            bufs = a.func.update(plan, cols) if update_mode else \
-                a.func.merge(plan, cols)
-            agg_buffers.append(bufs)
         # group count stays on device: a per-batch int(num_groups) pull
         # would sync the host with the device once per batch (LazyCount
-        # doc); output capacity = input capacity (groups <=
-        # rows) so no host value is needed to shape the result
+        # doc); output capacity = input capacity (groups <= rows) so no
+        # host value is needed to shape the result
         ng = plan.num_groups
-        lazy_groups = LazyCount(ng)
         out_cap = batch.capacity
-
+        live = jnp.arange(out_cap) < ng
         # compact group keys: representative original-row indices
         rep = plan.rep_indices
-        take = jnp.where(jnp.arange(out_cap) < ng,
+        take = jnp.where(live,
                          rep[:out_cap] if out_cap <= rep.shape[0] else
                          jnp.pad(rep, (0, out_cap - rep.shape[0]))[:out_cap],
                          0)
-        live = jnp.arange(out_cap) < ng
         out_cols = [c.gather(take, live=live, unique=True)
-                    for c in key_cols]
-        out_cols = [c.mask_validity(live) for c in out_cols]
-
-        # compact agg outputs: buffer arrays are already segment-indexed
-        for a, bufs in zip(self.aggs, agg_buffers):
-            if self.mode == PARTIAL or emit_buffers:
-                outs = bufs
-            else:
-                outs = [a.func.finalize(bufs)]
-            for o in outs:
-                seg_take = jnp.where(live, jnp.arange(out_cap), 0)
+                    .mask_validity(live) for c in key_cols]
+        # agg outputs: buffer arrays are already segment-indexed
+        seg_take = jnp.where(live, jnp.arange(out_cap), 0)
+        for a, cols in zip(self.aggs, input_cols):
+            bufs = a.func.update(plan, cols) if update else \
+                a.func.merge(plan, cols)
+            for o in (bufs if emit else [a.func.finalize(bufs)]):
                 assert o.capacity >= out_cap, (o.capacity, out_cap)
-                c = o.gather(seg_take, live=live, unique=True)
-                out_cols.append(c.mask_validity(live))
-        out_schema = buffer_schema(self.group_exprs, self.aggs) \
-            if emit_buffers else self.output_schema
-        return ColumnarBatch(out_schema, out_cols, lazy_groups)
+                out_cols.append(o.gather(seg_take, live=live, unique=True)
+                                .mask_validity(live))
+        return ColumnarBatch(self._out_schema(emit), out_cols, LazyCount(ng))
 
     def _global_agg(self, batch: ColumnarBatch,
-                    input_cols: List[List[Column]],
-                    emit_buffers: bool = False) -> ColumnarBatch:
+                    input_cols: List[List[Column]], update_mode: bool,
+                    emit: bool) -> ColumnarBatch:
         """No group keys: aggregate everything into one row (one segment).
 
         The whole computation is one jitted program (one dispatch
         instead of one per eager op);
         falls back to the traced body run eagerly for exotic columns."""
-        from ..expr.aggregates import Count
-        update_mode = self.mode in (PARTIAL, COMPLETE)
-        emit = emit_buffers or self.mode == PARTIAL
-        out_schema = buffer_schema(self.group_exprs, self.aggs) \
-            if emit else self.output_schema
+        out_schema = self._out_schema(emit)
         aggs = self.aggs
         in_dts = tuple(tuple(None if c is None else c.dtype for c in cols)
                        for cols in input_cols)
@@ -1429,7 +1391,7 @@ class TpuHashAggregate(TpuExec):
                 for o in cols_out:
                     c = o.gather(jnp.zeros(out_cap, jnp.int32))
                     live = jnp.arange(out_cap) < 1
-                    if isinstance(a.func, Count):
+                    if isinstance(a.func, ea.Count):
                         # counts are valid even over empty input (0)
                         c = Column(T.INT64,
                                    jnp.where(live,
@@ -1440,40 +1402,18 @@ class TpuHashAggregate(TpuExec):
                     outs.append((c.data, c.validity))
             return outs
 
-        plain = all(c is None or type(c) is Column
-                    for cols in input_cols for c in cols)
         in_arrays = tuple((c.data, c.validity)
                           for cols in input_cols for c in cols
                           if c is not None)
         pairs = None
-        if plain:
-            import jax
-            import logging
-            from ..kernels.aggregate import _pair_sum_enabled
+        if all(c is None or type(c) is Column
+               for cols in input_cols for c in cols):
             cache_key = ("global", update_mode, emit, in_dts,
-                         batch.capacity, _pair_sum_enabled(),
-                         tuple((type(a.func).__name__, repr(a.func),
-                                getattr(a.func, "ignore_nulls", None))
-                               for a in aggs))
-            core = TpuHashAggregate._CORE_CACHE.get(cache_key)
-            if core is not False:
-                if core is None:
-                    core = _compile_watch.wrap_miss(
-                        "hash_aggregate",
-                        _compile_watch.jit(_core, "agg_global_core"),
-                        str(cache_key))
-                    TpuHashAggregate._CORE_CACHE[cache_key] = core
-                _aot.note_demand("hash_aggregate", batch.capacity,
-                                 _costplane.rows_if_resolved(batch))
-                try:
-                    pairs = core(in_arrays, batch.rows_dev)
-                except Exception:  # noqa: BLE001 - fall back, but loudly
-                    logging.getLogger(
-                        "spark_rapids_tpu.exec.aggregate").warning(
-                        "global aggregate core failed; falling back",
-                        exc_info=True)
-                    TpuHashAggregate._CORE_CACHE[cache_key] = False
-                    pairs = None
+                         batch.capacity, _agg_signature(aggs))
+            pairs = self._run_core(
+                cache_key,
+                lambda: _compile_watch.jit(_core, "agg_global_core"),
+                (in_arrays, batch.rows_dev), batch, "global")
         _obs_trace.count("agg.batches.eager" if pairs is None
                          else "agg.batches.fused")
         if pairs is None:
@@ -1498,7 +1438,6 @@ def _int_col(cap, fill=None):
 
 
 def _audit_agg(group=True):
-    from ..expr import aggregates as ea
     agg = object.__new__(TpuHashAggregate)
     agg.aggs = [AggExpr(ea.Sum(ec.BoundReference(1 if group else 0,
                                                  T.INT64)), "s")]
@@ -1522,12 +1461,6 @@ def _audit_specs():
     import jax
     import numpy as np
     from ..analysis.program_audit import AuditSpec
-    from ..kernels.aggregate import _pair_sum_enabled
-
-    def _agg_sig(agg):
-        return tuple((type(a.func).__name__, repr(a.func),
-                      getattr(a.func, "ignore_nulls", None))
-                     for a in agg.aggs)
 
     def _pair_sds(cap):
         return (jax.ShapeDtypeStruct((cap,), np.int64),
@@ -1544,7 +1477,7 @@ def _audit_specs():
                                   False)
         assert out is not None, "grouped agg core fell back"
         cache_key = (True, False, (T.INT64,), ((T.INT64,),), None,
-                     _pair_sum_enabled(), _agg_sig(agg))
+                     _agg_signature(agg.aggs))
         core = _cached_core(cache_key, "grouped")
         c = batch.capacity
         args = ((_pair_sds(c),), (_pair_sds(c),),
@@ -1566,7 +1499,7 @@ def _audit_specs():
         assert out is not None, "whole-stage agg core fell back"
         mkey = tuple(f.dtype.name for f in batch.schema)
         prep = agg._ws_memo[mkey]
-        cache_key = prep[0] + (True, None, _pair_sum_enabled(), ())
+        cache_key = prep[0] + (True, None, ())
         core = _cached_core(cache_key, "whole-stage")
         c = batch.capacity
         d = jax.ShapeDtypeStruct((c,), np.int64)
@@ -1580,9 +1513,9 @@ def _audit_specs():
         val_col = _int_col(cap, 1)
         schema = Schema([Field("v", T.INT64, True)])
         batch = ColumnarBatch(schema, [val_col], 8)
-        agg._global_agg(batch, [[val_col]], emit_buffers=False)
+        agg._global_agg(batch, [[val_col]], True, True)
         cache_key = ("global", True, True, ((T.INT64,),),
-                     batch.capacity, _pair_sum_enabled(), _agg_sig(agg))
+                     batch.capacity, _agg_signature(agg.aggs))
         core = _cached_core(cache_key, "global")
         c = batch.capacity
         args = ((_pair_sds(c),), jax.ShapeDtypeStruct((), np.int32))

@@ -182,26 +182,24 @@ class TpuHashJoinBase(TpuExec):
                 and build.capacity > 0 \
                 and max(b.capacity for b in stream_batches) \
                 < self._SIZED_MIN_CAPACITY:
-            from ..config import get_active, SUPERSTAGE_SPEC_JOIN
-            if get_active().get(SUPERSTAGE_SPEC_JOIN):
-                from ..obs import profile
-                spec_outs = []
-                for sb, skey_cols in zip(stream_batches,
-                                         skey_cols_per_batch):
-                    with timed(self.metrics[JOIN_TIME], self), \
-                            profile.dispatch(profile.SITE_SPEC_PROBE):
-                        out = self._spec_join_batch(
-                            sb, skey_cols, bt, build, direct,
-                            stream_keys, str_words)
-                    if out is None:
-                        spec_outs = None
-                        break
-                    spec_outs.append(out)
-                if spec_outs is not None:
-                    _trace.count("join.batches.spec", len(spec_outs))
-                    for out in spec_outs:
-                        yield self._note_output(out)
-                    return
+            from ..obs import profile
+            spec_outs = []
+            for sb, skey_cols in zip(stream_batches,
+                                     skey_cols_per_batch):
+                with timed(self.metrics[JOIN_TIME], self), \
+                        profile.dispatch(profile.SITE_SPEC_PROBE):
+                    out = self._spec_join_batch(
+                        sb, skey_cols, bt, build, direct,
+                        stream_keys, str_words)
+                if out is None:
+                    spec_outs = None
+                    break
+                spec_outs.append(out)
+            if spec_outs is not None:
+                _trace.count("join.batches.spec", len(spec_outs))
+                for out in spec_outs:
+                    yield self._note_output(out)
+                return
 
         # Phase A: probe counts for EVERY stream batch first; the output
         # sizes (total matches) stage into the pending pool so one fused

@@ -239,8 +239,6 @@ def stack_float_sums(plan: GroupPlan, cols) -> None:
     tree order, no scatter.  At 2^20 rows and five columns the chip
     takes 5.9 ms for the scan, 90 ms for one stacked ``segment_sum`` and
     386 ms for five (PERF.md section 5 has the table)."""
-    if jax.default_backend() != "cpu" and _pair_sum_enabled():
-        return              # the opt-in superaccumulator sums each alone
     todo = {}
     for c in cols:
         key = (id(c.data), id(c.validity))
@@ -292,203 +290,9 @@ def seg_sum(plan: GroupPlan, values, validity, out_dtype=None):
     if jnp.issubdtype(contrib.dtype, jnp.integer) or \
             contrib.dtype == jnp.bool_:
         return seg_prefix_sum(plan, contrib)
-    if contrib.dtype == jnp.float64 and jax.default_backend() != "cpu" \
-            and _pair_sum_enabled():
-        # Opt-in accuracy mode: on chip f64 IS an (hi, lo) f32 pair;
-        # accumulate with the integer superaccumulator over the two
-        # components — deterministic, order-independent, and faithful
-        # to everything the device representation holds.  Costs ~4x the
-        # scatter (the chip's emulated 64-bit integer ALU is slow), so
-        # the default is the f64-emulated scatter (error ~(n/G)*2^-48,
-        # far inside the engines' 1e-9 comparison tolerance).
-        return _seg_sum_f64_pair(plan, acc, ok)
     _trace.count_eager("eager.seg_sum_scatter", contrib)
     return jax.ops.segment_sum(contrib, plan.seg_id,
                                num_segments=plan.num_slots)
-
-
-def _pair_sum_enabled() -> bool:
-    from ..config import get_active, AGG_PAIR_SUM
-    try:
-        return bool(get_active().get(AGG_PAIR_SUM))
-    except Exception:  # noqa: BLE001 - before config init
-        return False
-
-
-# -- f32-pair superaccumulator for FLOAT64 sums ------------------------------
-# The chip has no f64 ALU: XLA emulates f64 as an (hi, lo) f32 pair, so a
-# FLOAT64 column's device value IS hi+lo with 24-bit-exact components.
-# Summing with emulated adds costs a long pair-arithmetic chain per element
-# AND loses precision with batch size.  Instead: split each value into its
-# two f32 components (exact), decompose each component into <=2 signed
-# 32-bit limb contributions on a 160-bit integer window anchored at the
-# batch max exponent, reduce per limb with integer prefix sums over the
-# sorted segment order (seg_prefix_sum: cumsum + boundary gathers), and
-# reconstruct one f32-pair result per GROUP.  Deterministic,
-# order-independent, error <= 2^-47 relative to the window (terms >W0
-# bits below the batch max fold into sticky; W0 ~ 111 bits).
-
-_PAIR_NL = 5                 # 160-bit window
-
-
-def _pair_w0(n: int) -> int:
-    # 2n terms (hi+lo per row); keep c1 within limb NL-1: j = W0>>5 <= 3
-    return min(127, _PAIR_NL * 32 - 24 - (2 * max(n, 2)).bit_length() - 2)
-
-
-def _f32_parts(sig, e, fin_ok, emax, W0):
-    """One f32 component -> (limb index j, c0, c1, lost) contributions.
-
-    value = sig * 2^(e-150); window bit 0 weighs 2^(emax-150-W0)."""
-    d = emax - e
-    p = jnp.int32(W0) - d
-    keep = fin_ok & (p > jnp.int32(-24)) & (sig != jnp.uint64(0))
-    rs = jnp.clip(-p, 0, 31).astype(jnp.uint64)
-    sig2 = sig >> rs
-    lost = fin_ok & ((sig2 << rs) != sig)
-    lost = lost | (fin_ok & (p <= jnp.int32(-24)) & (sig != jnp.uint64(0)))
-    pc = jnp.clip(p, 0, W0)
-    j = pc >> jnp.int32(5)
-    r = (pc & jnp.int32(31)).astype(jnp.uint64)
-    l64 = sig2 << r                                  # <= 55 bits
-    c0 = (l64 & jnp.uint64(0xFFFFFFFF)).astype(jnp.int64)
-    c1 = (l64 >> jnp.uint64(32)).astype(jnp.int64)
-    return j, c0, c1, keep, lost
-
-
-def _unpack_f32(f):
-    u = jax.lax.bitcast_convert_type(f, jnp.uint32)
-    neg = (u >> jnp.uint32(31)) != jnp.uint32(0)
-    e = ((u >> jnp.uint32(23)) & jnp.uint32(0xFF)).astype(jnp.int32)
-    m = (u & jnp.uint32(0x7FFFFF)).astype(jnp.uint64)
-    sig = jnp.where(e > 0, m | jnp.uint64(1 << 23), m)
-    ee = jnp.maximum(e, 1)
-    return neg, ee, sig, e
-
-
-def _pack_f32(sig24, e_biased):
-    """(up-to-24-bit significand, biased f32 exponent for bit 23)
-    -> f32, with left-normalization of leading zeros, subnormal squeeze
-    and overflow->inf.  No rounding: the caller passes truncated bits
-    (we keep 48 = 2x24 bits total, well past the pair's precision)."""
-    # normalize: shift the MSB up to bit 23 (the residual component can
-    # carry leading zeros when the sum's bits 39..16 start low)
-    lz = jnp.zeros(sig24.shape, jnp.int32)
-    x = sig24
-    for shift in (16, 8, 4, 2, 1):
-        m = x < (jnp.uint64(1) << jnp.uint64(24 - shift))
-        lz = jnp.where(m, lz + shift, lz)
-        x = jnp.where(m, x << jnp.uint64(shift), x)
-    lz = jnp.minimum(lz, jnp.int32(24))
-    sig24 = jnp.where(sig24 == jnp.uint64(0), sig24,
-                      sig24 << jnp.clip(lz, 0, 24).astype(jnp.uint64))
-    e_biased = e_biased - lz
-    squeeze = jnp.clip(jnp.int32(1) - e_biased, 0, 31).astype(jnp.uint64)
-    sig = sig24 >> squeeze
-    e = jnp.where(squeeze > 0, jnp.int32(1), e_biased)
-    subn = sig < jnp.uint64(1 << 23)
-    exp_field = jnp.where(subn | (sig == jnp.uint64(0)), jnp.int32(0), e)
-    u = ((exp_field.astype(jnp.uint32) & jnp.uint32(0xFF))
-         << jnp.uint32(23)) | \
-        (sig.astype(jnp.uint32) & jnp.uint32(0x7FFFFF))
-    u = jnp.where(e_biased > 254, jnp.uint32(0x7F800000), u)
-    return jax.lax.bitcast_convert_type(u, jnp.float32)
-
-
-def _seg_sum_f64_pair(plan: GroupPlan, v, ok):
-    n = v.shape[0]
-    W0 = _pair_w0(n)
-    fin = jnp.isfinite(v)
-    fin_ok = ok & fin
-    nan_f = ok & jnp.isnan(v)
-    pinf_f = ok & jnp.isposinf(v)
-    ninf_f = ok & jnp.isneginf(v)
-    vq = jnp.where(fin_ok, v, 0.0)
-    hi = vq.astype(jnp.float32)
-    lo = (vq - hi.astype(jnp.float64)).astype(jnp.float32)
-    hneg, he, hsig, _ = _unpack_f32(hi)
-    lneg, le, lsig, _ = _unpack_f32(lo)
-    # per-GROUP anchor: one large-magnitude group must not push other
-    # groups' rows below the window (i32 scatter-max is native)
-    slots = plan.num_slots
-    emax_g = jax.ops.segment_max(jnp.where(fin_ok, he, jnp.int32(0)),
-                                 plan.seg_id, num_segments=slots)
-    emax = jnp.take(emax_g, plan.seg_id, mode="clip")
-    hj, hc0, hc1, hkeep, hlost = _f32_parts(hsig, he, fin_ok, emax, W0)
-    lj, lc0, lc1, lkeep, llost = _f32_parts(lsig, le, fin_ok, emax, W0)
-    z = jnp.int64(0)
-    hs = jnp.where(hneg, jnp.int64(-1), jnp.int64(1))
-    ls = jnp.where(lneg, jnp.int64(-1), jnp.int64(1))
-    hc0 = jnp.where(hkeep, hc0 * hs, z)
-    hc1 = jnp.where(hkeep, hc1 * hs, z)
-    lc0 = jnp.where(lkeep, lc0 * ls, z)
-    lc1 = jnp.where(lkeep, lc1 * ls, z)
-    limbs = []
-    for L in range(_PAIR_NL):
-        lc = jnp.where(hj == L, hc0, z) + jnp.where(lj == L, lc0, z)
-        if L >= 1:
-            lc = lc + jnp.where(hj == L - 1, hc1, z) + \
-                jnp.where(lj == L - 1, lc1, z)
-        limbs.append(seg_prefix_sum(plan, lc))
-    nan_cnt = seg_prefix_sum(plan, nan_f.astype(jnp.int32))
-    pinf_cnt = seg_prefix_sum(plan, pinf_f.astype(jnp.int32))
-    ninf_cnt = seg_prefix_sum(plan, ninf_f.astype(jnp.int32))
-
-    # ---- per-group finalize ----
-    m32 = jnp.int64(0xFFFFFFFF)
-    carry = jnp.int64(0)
-    lo32s = []
-    for L in range(_PAIR_NL):
-        s = limbs[L] + carry
-        l32 = s & m32
-        carry = (s - l32) >> jnp.int64(32)
-        lo32s.append(l32)
-    total_neg = carry < 0
-    mags = []
-    c = jnp.where(total_neg, jnp.int64(1), jnp.int64(0))
-    for L in range(_PAIR_NL):
-        t = jnp.where(total_neg, (~lo32s[L]) & m32, lo32s[L]) + c
-        mags.append((t & m32).astype(jnp.uint64))
-        c = jnp.where(total_neg, t >> jnp.int64(32), jnp.int64(0))
-    words = [(mags[1] << jnp.uint64(32)) | mags[0],
-             (mags[3] << jnp.uint64(32)) | mags[2],
-             mags[4]]
-    nzs = [w != jnp.uint64(0) for w in words]
-    top = jnp.zeros(slots, jnp.int32)
-    any_nz = jnp.zeros(slots, bool)
-    for i in range(3):
-        top = jnp.where(nzs[i], jnp.int32(i), top)
-        any_nz = any_nz | nzs[i]
-
-    def pick(idx):
-        out = jnp.zeros(slots, jnp.uint64)
-        for i in range(3):
-            out = jnp.where(idx == i, words[i], out)
-        return out
-    hiw = pick(top)
-    loww = pick(top - 1)
-    from .binary64 import _clz64
-    lz = _clz64(hiw)
-    lzu = jnp.clip(lz, 0, 63).astype(jnp.uint64)
-    combined = (hiw << lzu) | ((loww >> (jnp.uint64(63) - lzu))
-                               >> jnp.uint64(1))
-    b_msb = jnp.int64(64) * top.astype(jnp.int64) + 63 - lz
-    # f32-biased exponent of the MSB: 2^(b_msb + emax-150-W0) = 2^(e-127)
-    e1 = (b_msb + emax_g.astype(jnp.int64) -
-          jnp.int64(W0 + 23)).astype(jnp.int32)
-    f1 = _pack_f32(combined >> jnp.uint64(40), e1)
-    # second component: next 24 bits, 24 binades down
-    sig2 = (combined >> jnp.uint64(16)) & jnp.uint64(0xFFFFFF)
-    f2 = _pack_f32(sig2, e1 - 24)
-    mag_val = f1.astype(jnp.float64) + f2.astype(jnp.float64)
-    out = jnp.where(total_neg, -mag_val, mag_val)
-    out = jnp.where(any_nz, out, 0.0)
-    out = jnp.where(pinf_cnt > 0, jnp.float64(jnp.inf), out)
-    out = jnp.where(ninf_cnt > 0, jnp.float64(-jnp.inf), out)
-    out = jnp.where((nan_cnt > 0) | ((pinf_cnt > 0) & (ninf_cnt > 0)),
-                    jnp.float64(jnp.nan), out)
-    gi = jnp.arange(slots, dtype=jnp.int32)
-    return jnp.where(gi < plan.num_groups, out, 0.0)
 
 
 def seg_count(plan: GroupPlan, validity):
@@ -649,8 +453,8 @@ def seg_last_index(plan: GroupPlan, validity, ignore_nulls: bool = True):
 # but most BI group-bys have small combined key cardinality RANGE —
 # so instead of hashing, each key word is rebased by its device-computed
 # minimum and the keys mixed-radix-packed into a bucket id < table_size.
-# Aggregation is then direct per-bucket reduction: sums/counts ride
-# one-hot matmuls on the MXU; min/max ride small-output scatters.
+# Aggregation is then direct per-bucket reduction: sums/counts ride one
+# stacked small-output scatter-add, min/max a scatter-max each.
 # No sort, no gathers, no 64-bit scatters (which cost ~20x f32 on TPU).
 #
 # A device-side `fit` flag records whether the batch really fit the
@@ -710,3 +514,22 @@ def table_compact(counts, table: int):
     order = jnp.argsort(jnp.where(present, 0, 1), stable=True) \
         .astype(jnp.int32)
     return present, order, num_groups
+
+
+def table_reduce(bucket, sum_rows, max_rows, table: int):
+    """Reduce f32 rows into ``table`` buckets (+1 dead slot dropped).
+
+    sum_rows: list of f32[n] contribution rows (dead rows must be 0).
+    max_rows: list of f32[n] rows (dead rows must be -inf); min via
+    caller-side negation.  Returns (sums: list of f32[table],
+    maxs: list of f32[table]).  One multi-column scatter-add for all
+    the sum rows (it costs what a single-column one does) and a
+    scatter-max a max row."""
+    sums = []
+    if sum_rows:
+        out = jax.ops.segment_sum(jnp.stack(sum_rows, 1), bucket,
+                                  num_segments=table + 1)
+        sums = [out[:table, i] for i in range(len(sum_rows))]
+    maxs = [jax.ops.segment_max(r, bucket, num_segments=table + 1)[:table]
+            for r in max_rows]
+    return sums, maxs

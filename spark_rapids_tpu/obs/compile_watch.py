@@ -1,8 +1,8 @@
 """Compile telemetry — where multi-second inline XLA compiles land.
 
 Seven engine JIT caches (fused project, staged compute, hash
-aggregate, the three mesh SPMD programs, the Pallas hash-partition
-kernel) already report hit/miss counts to Prometheus.  What they could
+aggregate, the three mesh SPMD programs, the hash-partition
+program) already report hit/miss counts to Prometheus.  What they could
 not answer is the question the AOT shape-bucketed compile cache
 (ROADMAP item 4) will be built and judged against: *how long does each
 miss actually cost, and did a query block on it?*

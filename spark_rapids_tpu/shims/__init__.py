@@ -28,11 +28,6 @@ class JaxShim:
         return jax.shard_map
 
     @staticmethod
-    def pallas():
-        from jax.experimental import pallas as pl
-        return pl
-
-    @staticmethod
     def key_array(seed: int):
         import jax.random as jr
         return jr.key(seed)

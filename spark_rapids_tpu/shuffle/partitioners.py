@@ -58,8 +58,8 @@ def partition_sort_counts(pids, num_rows, num_partitions: int):
 @functools.partial(jax.jit, static_argnums=(1,))
 def partition_hash_ids(word_lists, num_partitions: int):
     """murmur-mix + mod over the key words -> partition id per row, as
-    one program (XLA fuses the elementwise chain; 64-bit lanes rule out
-    a Mosaic kernel, see kernels/pallas_ops.py)."""
+    one program (XLA fuses the elementwise chain; Mosaic does not lower
+    the 64-bit lanes a hand-written kernel would need)."""
     return bk.hash_to_partition(bk.hash_words(list(word_lists)),
                                 num_partitions)
 

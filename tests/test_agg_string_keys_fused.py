@@ -185,7 +185,7 @@ def _q1_core():
                                       emit_buffers=True, out_cap=SLOTS)
     assert out is not None, "Q1's shape fell off the whole-stage core"
     core, = [c for k, c in TA.TpuHashAggregate._CORE_CACHE.items()
-             if k[0] == "ws" and k[-4:-2] == (True, SLOTS)]
+             if k[0] == "ws" and k[-3:-1] == (True, SLOTS)]
 
     def sds(dt):
         return jax.ShapeDtypeStruct((CAP,), dt)
@@ -322,23 +322,68 @@ def test_row_gather_moves_every_width_exactly(dtype, chip_floats,
             np.asarray(f)[np.asarray(perm)].tolist()
 
 
-@pytest.mark.parametrize("groups,dead", [(1, 0), (4, 17), (300, 5),
-                                         (1000, 0), (0, 64)])
-def test_segmented_totals_equal_a_sum_per_group(groups, dead):
-    """The stacked segmented scan against numpy's per-segment sums:
-    float64 adds in tree order, dead rows (zeros past the live ones)
-    and empty slots included."""
+def _uniform(groups, dead):
+    """Three columns of uniform values over ``groups`` sorted segments
+    (some ids unused), ``dead`` zero rows after them."""
+    rng = np.random.default_rng(groups)
+    seg = np.sort(rng.integers(0, max(groups, 1), 1000)) \
+        if groups else np.zeros(0, np.int64)
+    return rng.uniform(-1e5, 1e5, (3, len(seg))), seg, dead
+
+
+def _keyed(vals, keys):
+    """One column, rows brought into their keys' order."""
+    order = np.argsort(np.asarray(keys), kind="stable")
+    return np.asarray(vals, np.float64)[None, order], \
+        np.asarray(keys)[order], 3
+
+
+def _wide_exponents():
+    rng = np.random.default_rng(8)
+    return _keyed(np.ldexp(rng.standard_normal(600),
+                           rng.integers(-60, 60, 600)),
+                  rng.integers(0, 5, 600))
+
+
+# name -> (values [k, live rows], sorted segment ids, dead rows)
+TOTALS = {
+    "1_group": lambda: _uniform(1, 0),
+    "4_groups_17_dead": lambda: _uniform(4, 17),
+    "300_groups": lambda: _uniform(300, 5),
+    "1000_groups": lambda: _uniform(1000, 0),
+    "no_live_row": lambda: _uniform(0, 64),
+    # the inputs of the pair-sum accumulator's tests (gone with it in
+    # PR 30): what it promised, the tree-ordered scan has to keep
+    "wide_exponents": _wide_exponents,
+    "specials_and_signs": lambda: _keyed(
+        [1e30, 1.0, -1e30, np.inf, 3.0, np.nan, 2.0, -0.5, -0.25, -0.25,
+         0.0, -0.0, np.inf, -np.inf],
+        [0, 0, 0, 1, 1, 2, 3, 3, 4, 4, 5, 5, 6, 6]),
+    # +x / -x pairs leave a small residue a group
+    "cancellation": lambda: _keyed(
+        np.array([1e12, -1e12] * 500) + 1e-3, np.zeros(1000, np.int64)),
+    # a 1e38 group beside a 1e-9 group: neither reaches into the other
+    "group_isolation": lambda: _keyed([1e38, 1e-9, 1e-9, 3e37, 2e-9],
+                                      [0, 1, 1, 0, 1]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TOTALS))
+def test_segmented_totals_equal_a_sum_per_group(case):
+    """The stacked segmented scan against ``math.fsum`` a group:
+    float64 adds in tree order (an error of at most log2(rows) roundings
+    of the group's absolute sum), infinities and NaNs as IEEE adds them,
+    dead rows (zeros past the live ones) and empty slots included."""
+    import math
     from spark_rapids_tpu.kernels import aggregate as agg_k
     jnp = jax.numpy
-    rng = np.random.default_rng(groups)
-    n = 1000 + dead
-    seg = np.sort(rng.integers(0, max(groups, 1), n - dead)) \
-        if groups else np.zeros(0, np.int64)
-    if groups:
+    live_vals, seg, dead = TOTALS[case]()
+    if len(seg):
         seg = np.unique(seg, return_inverse=True)[1]     # dense ids
-    live_n = len(seg)
-    vals = np.zeros((3, n))
-    vals[:, :live_n] = rng.uniform(-1e5, 1e5, (3, live_n))
+    k, live_n = live_vals.shape
+    n = live_n + dead
+    vals = np.zeros((k, n))
+    vals[:, :live_n] = live_vals
     boundary = np.zeros(n, bool)
     if live_n:
         boundary[:live_n] = np.r_[True, seg[1:] != seg[:-1]]
@@ -351,11 +396,20 @@ def test_segmented_totals_equal_a_sum_per_group(groups, dead):
                            last_pos=jnp.asarray(last), num_slots=slots,
                            num_groups=jnp.int32(g))
     got = np.asarray(agg_k._segmented_totals(plan, jnp.asarray(vals)))
-    want = np.zeros((3, slots))
-    for i in range(3):
-        want[i, :g] = np.bincount(seg, vals[i, :live_n], minlength=g)[:g] \
-            if g else []
-    np.testing.assert_allclose(got, want, rtol=1e-13, atol=1e-7)
+    assert got.shape == (k, slots)
+    assert not got[:, g:].any()
+    for i in range(k):
+        for grp in range(g):
+            sel = live_vals[i, seg == grp]
+            if np.all(np.isfinite(sel)):
+                bound = np.sum(np.abs(sel)) * 2.0 ** -48
+                assert abs(got[i, grp] - math.fsum(sel)) <= bound, \
+                    (grp, got[i, grp], math.fsum(sel), bound)
+            else:
+                with np.errstate(invalid="ignore"):
+                    want = np.sum(sel)
+                assert (np.isnan(got[i, grp]) and np.isnan(want)) or \
+                    got[i, grp] == want, (grp, got[i, grp], want)
 
 
 # -- the benchmark metric that reads the counters ----------------------------

@@ -215,39 +215,7 @@ def test_host_arena_slab_outlives_its_views():
     assert alive() is None
 
 
-class TestPallas:
-    @pytest.mark.parametrize("backend,want", [
-        ("cpu", True), ("tpu", False), ("gpu", False),
-        ("some_plugin", False)])
-    def test_interpret_only_on_cpu(self, monkeypatch, backend, want):
-        from spark_rapids_tpu.kernels import pallas_ops
-        monkeypatch.setattr(pallas_ops.jax, "default_backend",
-                            lambda: backend)
-        assert pallas_ops.interpret_mode() is want
-
-    def test_pallas_impl_runs_the_kernel_and_matches_scatter(self):
-        """impl='pallas' used to be honoured only when the backend was
-        literally "tpu" and silently ran the scatter path elsewhere."""
-        import jax.numpy as jnp
-        from spark_rapids_tpu.kernels import pallas_ops
-        n, table = 5000, 64
-        rng = np.random.default_rng(3)
-        b = jnp.asarray(rng.integers(0, 10, n).astype(np.int32))
-        v = jnp.asarray(rng.random(n).astype(np.float32))
-        ones = jnp.ones(n, jnp.float32)
-        jaxpr = str(jax.make_jaxpr(
-            lambda: pallas_ops.table_reduce(b, [ones, v], [v], table,
-                                            impl="pallas"))())
-        assert "pallas_call" in jaxpr
-        ps, pm = pallas_ops.table_reduce(b, [ones, v], [v], table,
-                                         impl="pallas")
-        xs, xm = pallas_ops.table_reduce(b, [ones, v], [v], table,
-                                         impl="scatter")
-        np.testing.assert_array_equal(np.asarray(ps[0]), np.asarray(xs[0]))
-        np.testing.assert_allclose(np.asarray(ps[1]), np.asarray(xs[1]),
-                                   rtol=1e-5)
-        np.testing.assert_array_equal(np.asarray(pm[0]), np.asarray(xm[0]))
-
+class TestPartitionIds:
     def test_hash_partition_ids_match_the_jnp_chain(self):
         import jax.numpy as jnp
         from spark_rapids_tpu.kernels import basic as bk

@@ -198,7 +198,7 @@ class TestBasic:
 
 class TestTableGroupby:
     """Sort-free bucket-table group-by kernels (kernels/aggregate.py
-    table_bucket/table_compact + pallas_ops.table_reduce)."""
+    table_bucket / table_reduce / table_compact)."""
 
     def test_table_bucket_single_key(self):
         import jax.numpy as jnp
@@ -233,7 +233,7 @@ class TestTableGroupby:
         import jax.numpy as jnp
         import numpy as np
         from spark_rapids_tpu.kernels import aggregate as agg_k
-        from spark_rapids_tpu.kernels.pallas_ops import table_reduce
+        from spark_rapids_tpu.kernels.aggregate import table_reduce
         n, T = 4096, 64
         rng = np.random.default_rng(3)
         b = jnp.asarray(rng.integers(0, 10, n).astype(np.int32))
@@ -273,67 +273,6 @@ class TestTableGroupby:
         assert_tpu_and_cpu_are_equal_collect(
             q, conf={"spark.rapids.tpu.sql.variableFloatAgg.enabled":
                      False})
-
-
-class TestPairSuperaccumulator:
-    """_seg_sum_f64_pair: the on-chip FLOAT64 sum path (f32-pair integer
-    superaccumulator).  Called directly so the CPU test platform
-    exercises the device code path."""
-
-    def _run(self, vals, ks):
-        import math
-        import jax.numpy as jnp
-        from spark_rapids_tpu.columnar import dtypes as T
-        from spark_rapids_tpu.columnar.column import Column
-        from spark_rapids_tpu.kernels import canon, aggregate as agg_k
-        n = len(vals)
-        kcol = Column(T.INT64, jnp.asarray(np.asarray(ks, np.int64)),
-                      jnp.ones(n, bool))
-        words = canon.batch_key_words([kcol], jnp.int32(n))
-        plan = agg_k.groupby_plan(words)
-        v = jnp.asarray(np.asarray(vals, np.float64))
-        sv, sok = agg_k._sorted_vals(plan, v, jnp.ones(n, bool))
-        got = np.asarray(agg_k._seg_sum_f64_pair(plan, sv, sok))
-        for g, key in enumerate(np.unique(ks)):
-            sel = np.asarray(vals)[np.asarray(ks) == key]
-            if np.all(np.isfinite(sel)):
-                expect = math.fsum(sel)
-                # pair split keeps 48 bits/value; window keeps ~110 bits
-                err = abs(got[g] - expect)
-                bound = max(np.sum(np.abs(sel)) * 2.0 ** -46, 1e-300)
-                assert err <= bound, (key, got[g], expect, err, bound)
-            else:
-                expect = np.sum(sel)
-                assert (np.isnan(got[g]) and np.isnan(expect)) or \
-                    got[g] == expect, (key, got[g], expect)
-
-    def test_random_groups(self):
-        rng = np.random.default_rng(7)
-        self._run(rng.standard_normal(2000), rng.integers(0, 13, 2000))
-
-    def test_wide_exponents(self):
-        rng = np.random.default_rng(8)
-        self._run(np.ldexp(rng.standard_normal(600),
-                           rng.integers(-60, 60, 600)),
-                  rng.integers(0, 5, 600))
-
-    def test_specials_and_signs(self):
-        self._run(np.array([1e30, 1.0, -1e30, np.inf, 3.0, np.nan,
-                            2.0, -0.5, -0.25, -0.25, 0.0, -0.0]),
-                  np.array([0, 0, 0, 1, 1, 2, 3, 3, 4, 4, 5, 5]))
-
-    def test_cancellation_accuracy(self):
-        # +x/-x pairs leave a small residue: the superaccumulator keeps
-        # it exactly; pairwise f32-pair addition would lose it
-        base = np.array([1e12, -1e12] * 500)
-        resid = np.full(1000, 1e-3)
-        self._run(base + resid, np.zeros(1000, np.int64))
-
-    def test_group_isolation(self):
-        # the window anchor is per GROUP: a 1e38 group must not push a
-        # 1e-9 group's rows out of the accumulation window
-        self._run(np.array([1e38, 1e-9, 1e-9, 3e37, 2e-9]),
-                  np.array([0, 1, 1, 0, 1]))
 
 
 class TestExactTableLanes:

@@ -43,7 +43,6 @@ def _banded_data(rows_per_band=ROWS_PER_BAND, seed=3):
 
 CONF = {
     # keep the table path on and small enough that mixed bands misfit
-    "spark.rapids.tpu.sql.agg.tablePath.enabled": True,
     "spark.rapids.tpu.sql.agg.tableSize": TABLE_SIZE,
     "spark.rapids.tpu.sql.variableFloatAgg.enabled": True,
 }
