@@ -1,0 +1,8 @@
+"""Device ms per traced query in the string key packing programs
+(``jit_str_pack*`` rows of the traced pass's ``device_ops``).  That list
+is a top ten: a lower bound when the pack program falls off it."""
+import span_reduce
+
+
+def read(run):
+    return span_reduce.device_ms_per_query(run, "jit_str_pack")

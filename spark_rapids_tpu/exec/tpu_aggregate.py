@@ -120,7 +120,8 @@ def _pack_string_key(col, num_rows):
     bound = skern.key_byte_bound(col, num_rows)
     bound = 1 << max(0, bound - 1).bit_length()
     words = canon.value_words(col, num_rows,
-                              str_words=max(1, -(-bound // 8)))
+                              str_words=skern.bucket_words(bound),
+                              str_bound=bound)
     return (tuple(words), col.validity), bound
 
 

@@ -238,8 +238,8 @@ def _segment_equals(elements: Column, needle: Column, needle_valid,
         from ..kernels import strings as sk
         nw = max(sk.needed_key_words(elements, elements.capacity),
                  sk.needed_key_words(needle, num_rows))
-        ewords = sk.str_pack_words(elements.offsets, elements.data, nw)
-        nwords = sk.str_pack_words(needle.offsets, needle.data, nw)
+        ewords = sk.pack_words(elements, nw)
+        nwords = sk.pack_words(needle, nw)
         eq = jnp.all(ewords == jnp.take(nwords, seg_rows, axis=0), axis=1)
         elens = elements.offsets[1:] - elements.offsets[:-1]
         nlens = needle.offsets[1:] - needle.offsets[:-1]

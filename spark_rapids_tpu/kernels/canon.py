@@ -134,9 +134,12 @@ def column_key_words(col: Column, num_rows: int, *, descending: bool = False,
     return [null_rank] + words
 
 
-def value_words(col: Column, num_rows: int,
-                str_words: int = None) -> List[jnp.ndarray]:
-    """uint64 word list for the column values (no null rank)."""
+def value_words(col: Column, num_rows: int, str_words: int = None,
+                str_bound: int = None) -> List[jnp.ndarray]:
+    """uint64 word list for the column values (no null rank).
+    ``str_bound``: for a STRING column, ``strings.key_byte_bound`` of it
+    where the caller holds that already (it sizes the byte gather, not
+    the words: ``strings.pack_words``)."""
     dt = col.dtype
     if type(col) is PackedStringKey:
         return list(col.words)
@@ -145,18 +148,23 @@ def value_words(col: Column, num_rows: int,
         # lazy gather view: gather the SOURCE column's words by index —
         # pure integer device work, no byte materialization and no
         # sizing sync.  num_words from the source's full capacity so
-        # every view over one source agrees on word count.
+        # every view over one source agrees on word count.  (A bound on
+        # the view is one on the source's rows it reads:
+        # ``key_byte_bound`` takes it from the source.)
         from . import strings as skern
         src = col.src
         if str_words is None:
-            str_words = skern.needed_key_words(src, src.capacity)
+            str_bound = skern.key_byte_bound(src, src.capacity)
+            str_words = skern.bucket_words(str_bound)
         src_words = skern.string_key_words(src, src.capacity,
-                                           num_words=str_words)
+                                           num_words=str_words,
+                                           byte_bound=str_bound)
         return [jnp.take(w, col.idx, axis=0, mode="clip")
                 for w in src_words]
     if isinstance(col, StringColumn):
         from . import strings as skern
-        return skern.string_key_words(col, num_rows, num_words=str_words)
+        return skern.string_key_words(col, num_rows, num_words=str_words,
+                                      byte_bound=str_bound)
     from ..columnar.binary64 import Binary64Column
     if isinstance(col, Binary64Column):
         # exact total-order word straight from the bit pattern (the

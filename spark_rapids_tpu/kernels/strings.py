@@ -15,18 +15,28 @@ import jax
 import jax.numpy as jnp
 
 from ..columnar.column import StringColumn, bucket_capacity
+from ..obs import trace as _obs_trace
 
 
 def string_lengths(offsets) -> jnp.ndarray:
     return (offsets[1:] - offsets[:-1]).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("num_words",))
-def str_pack_words(offsets, data, num_words: int):
-    """[cap, num_words] big-endian uint64 words of each string, zero-padded."""
+@functools.partial(jax.jit, static_argnames=("num_words", "num_bytes"))
+def str_pack_words(offsets, data, num_words: int, num_bytes: int = None):
+    """[cap, num_words] big-endian uint64 words of each string, zero-padded.
+
+    ``num_bytes`` (static; None = all ``8 * num_words``) is how many
+    leading bytes a row the program gathers: a caller that holds a bound
+    on the strings' bytes (``key_byte_bound``) pays one gathered index a
+    byte of the bound, not eight a word.  Every row whose string is
+    within ``num_bytes`` gets the same bits either way; a longer string
+    is cut at ``num_bytes``."""
     cap = offsets.shape[0] - 1
     starts = offsets[:-1]
     lens = offsets[1:] - starts
+    if num_bytes is not None and num_bytes < num_words * 8:
+        return _pack_leading_bytes(starts, lens, data, num_words, num_bytes)
     # byte index matrix [cap, num_words*8]
     k = jnp.arange(num_words * 8, dtype=jnp.int32)
     idx = starts[:, None] + k[None, :]
@@ -39,10 +49,31 @@ def str_pack_words(offsets, data, num_words: int):
     return words
 
 
+def _pack_leading_bytes(starts, lens, data, num_words: int, num_bytes: int):
+    """``str_pack_words`` from the first ``num_bytes`` bytes a row: one
+    1-D gather a byte, the bytes assembled as 32-bit halves (the chip's
+    64-bit integer is a pair of them), the rest of the words zero."""
+    cap = starts.shape[0]
+    last = data.shape[0] - 1
+    halves = [jnp.zeros(cap, jnp.uint32) for _ in range(2 * num_words)]
+    for j in range(num_bytes):
+        byte = jnp.where(j < lens,
+                         jnp.take(data, jnp.clip(starts + j, 0, last)),
+                         jnp.uint8(0))
+        halves[j // 4] |= byte.astype(jnp.uint32) << (8 * (3 - j % 4))
+    halves = [h.astype(jnp.uint64) for h in halves]
+    return jnp.stack([(halves[2 * w] << jnp.uint64(32)) | halves[2 * w + 1]
+                      for w in range(num_words)], axis=1)
+
+
 def needed_key_words(col: StringColumn, num_rows: int) -> int:
     """Bucketed uint64 word count needed to encode this column's strings
     (``key_byte_bound`` in words, rounded up to a power of two)."""
-    num_words = max(1, -(-key_byte_bound(col, num_rows) // 8))
+    return bucket_words(key_byte_bound(col, num_rows))
+
+
+def bucket_words(byte_bound: int) -> int:
+    num_words = max(1, -(-byte_bound // 8))
     return 1 << (num_words - 1).bit_length()
 
 
@@ -90,18 +121,41 @@ def key_byte_bound(col: StringColumn, num_rows: int) -> int:
     return max_len
 
 
+def pack_words(col: StringColumn, num_words: int, byte_bound: int = None):
+    """``str_pack_words`` over a column: the one place the program is
+    launched from, and where it is counted.  ``byte_bound`` (or None: no
+    bound at hand) is a host-known bound on the bytes of the strings the
+    caller reads (``key_byte_bound``); rounded up to a power of two it
+    is what the program gathers a row, when that is under the words'
+    ``8 * num_words``."""
+    num_bytes = None
+    if byte_bound is not None:
+        num_bytes = 1 << max(0, byte_bound - 1).bit_length()
+        if num_bytes >= 8 * num_words:
+            num_bytes = None
+    _obs_trace.count_eager(
+        "str.pack.full" if num_bytes is None else "str.pack.narrow",
+        col.offsets)
+    return str_pack_words(col.offsets, col.data, num_words, num_bytes)
+
+
 def string_key_words(col: StringColumn, num_rows: int,
-                     num_words: int = None) -> List[jnp.ndarray]:
+                     num_words: int = None,
+                     byte_bound: int = None) -> List[jnp.ndarray]:
     """uint64 key words for sort/group/join: byte words + length tiebreak.
 
     ``num_words`` must be agreed across batches that will be compared
     against each other (joins unify via needed_key_words over both sides).
+    ``byte_bound``: ``key_byte_bound`` of the rows the caller reads,
+    where it holds one (``pack_words``); the words are the same bits.
     """
     if num_words is None:
         # max length is host-known from offsets (one small sync per batch;
         # the reference similarly reads cuDF column metadata host-side).
-        num_words = needed_key_words(col, num_rows)
-    words = str_pack_words(col.offsets, col.data, num_words)
+        if byte_bound is None:
+            byte_bound = key_byte_bound(col, num_rows)
+        num_words = bucket_words(byte_bound)
+    words = pack_words(col, num_words, byte_bound)
     out = [words[:, i] for i in range(num_words)]
     out.append(string_lengths(col.offsets).astype(jnp.uint64))
     return out

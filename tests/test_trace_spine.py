@@ -280,10 +280,11 @@ class TestCoarseSpans:
         # every counter belongs to a documented family
         # (docs/observability.md); jit_build.* appears whenever a
         # neighbour dropped the jit caches, join.* with every hash join,
-        # scan.* with every file scan, agg.* with every aggregate
+        # scan.* with every file scan, agg.* with every aggregate,
+        # str.* with every string key packed
         for tbl in counts.values():
             assert all(k.startswith(("eager.", "jit_build.", "join.",
-                                     "scan.", "agg."))
+                                     "scan.", "agg.", "str."))
                        and v > 0 for k, v in tbl.items())
         assert any(k.startswith("eager.")
                    for tbl in counts.values() for k in tbl)
