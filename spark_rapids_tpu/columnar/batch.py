@@ -366,56 +366,124 @@ def concat_batches(batches: Sequence[ColumnarBatch]) -> ColumnarBatch:
     if len(batches) == 1:
         return batches[0]
     schema = batches[0].schema
-    total = sum(b.num_rows for b in batches)
+    nrows = [b.num_rows for b in batches]
+    total = sum(nrows)
     cap = bucket_capacity(total)
-    if all(type(c) is Column for b in batches for c in b.columns) and \
-            len(schema):
-        return _concat_plain_jit(batches, schema, cap, total)
-    out_cols: List[Column] = []
+    # every fixed-width column's data and validity, and every string
+    # column's offsets and validity, are row lanes: one pass appends
+    # them all (``_concat_lanes``); string bytes go through the host and
+    # nested columns keep their own concat
+    lanes, caps, fills, slots = [], [], [], []
+    out_cols: List[Optional[Column]] = [None] * len(schema)
     for ci, field in enumerate(schema):
-        out_cols.append(_concat_cols(
-            field.dtype, [b.columns[ci] for b in batches],
-            [b.num_rows for b in batches], cap))
+        cols = [b.columns[ci] for b in batches]
+        dt = field.dtype
+        if dt == T.STRING:
+            parts, data, mb = _concat_string_bytes(cols, nrows)
+            slots.append((ci, dt, data, mb))
+            lanes += [[shifted for shifted, _ in parts],
+                      [c.validity for c in cols]]
+            caps += [cap + 1, cap]
+            fills += [parts[-1][1], False]      # offsets end at the bytes
+        elif isinstance(dt, (T.StructType, T.ArrayType, T.MapType)):
+            out_cols[ci] = _concat_cols(dt, cols, nrows, cap)
+        else:
+            slots.append((ci, dt, None, None))
+            lanes += [[c.data for c in cols], [c.validity for c in cols]]
+            caps += [cap, cap]
+            fills += [0, False]
+    if lanes:
+        outs = _concat_lanes(lanes, nrows, caps, fills)
+        for k, (ci, dt, data, mb) in enumerate(slots):
+            first, valid = outs[2 * k], outs[2 * k + 1]
+            if data is not None:
+                out_cols[ci] = StringColumn(first, data, valid, max_bytes=mb)
+            else:
+                out_cols[ci] = Column(dt, first, valid)
     return ColumnarBatch(schema, out_cols, total)
 
 
+#: the concat programs by the shapes they were first called with
 _CONCAT_JIT: dict = {}
 
 
-def _concat_plain_jit(batches, schema, cap: int, total: int):
-    """One jitted program for fixed-width concat (slice+concat+pad per
-    column) — the eager per-column path pays one dispatch per op."""
-    from ..obs import compile_watch as _cw
-    nrows = tuple(b.num_rows for b in batches)
-    key = (nrows, cap, len(schema))
-    fn = _CONCAT_JIT.get(key)
-    if fn is None:
-        ncols = len(schema)
+def _lane_start(parts, rooms):
+    from jax import lax
+    return tuple(lax.dynamic_update_slice_in_dim(
+        jnp.zeros((room,) + p.shape[1:], p.dtype), p, 0, 0)
+        for p, room in zip(parts, rooms))
 
-        def _concat(datas, valids):
-            outs = []
-            for ci in range(ncols):
-                ds = [d[:n] for d, n in zip(datas[ci], nrows)]
-                vs = [v[:n] for v, n in zip(valids[ci], nrows)]
-                d = jnp.concatenate(ds)
-                v = jnp.concatenate(vs)
-                pad = cap - int(d.shape[0])
-                if pad:
-                    d = jnp.pad(d, (0, pad))
-                    v = jnp.pad(v, (0, pad))
-                outs.append((d, v))
-            return outs
-        fn = _cw.wrap_miss("batch_concat", _cw.jit(_concat, "batch_concat"),
-                           key)
+
+def _lane_append(outs, parts, off):
+    from jax import lax
+    return tuple(lax.dynamic_update_slice_in_dim(o, p, off, 0)
+                 for o, p in zip(outs, parts))
+
+
+def _lane_finish(outs, total, fills, caps):
+    res = []
+    for o, f, cap in zip(outs, fills, caps):
+        live = (jnp.arange(cap) < total).reshape(
+            (cap,) + (1,) * (o.ndim - 1))
+        res.append(jnp.where(live, o[:cap], f.astype(o.dtype)))
+    return tuple(res)
+
+
+def _concat_programs():
+    """The three programs of ``_concat_lanes``, built once; jax keys
+    their compiled forms by argument shapes."""
+    progs = _CONCAT_JIT.get("programs")
+    if progs is None:
+        from ..obs import compile_watch as _cw
+        progs = _CONCAT_JIT["programs"] = (
+            _cw.jit(_lane_start, "batch_concat_start", static_argnums=(1,)),
+            _cw.jit(_lane_append, "batch_concat_append",
+                    donate_argnums=(0,)),
+            _cw.jit(_lane_finish, "batch_concat_finish",
+                    static_argnums=(3,)))
+    return progs
+
+
+def _concat_step(jitted, key):
+    """``jitted`` behind the first-call timing of its shapes ``key``."""
+    prog = _CONCAT_JIT.get(key)
+    if prog is None:
+        from ..obs import compile_watch as _cw
+        prog = _cw.wrap_miss("batch_concat", jitted, key)
         if len(_CONCAT_JIT) < 4096:
-            _CONCAT_JIT[key] = fn
-    datas = tuple(tuple(b.columns[ci].data for b in batches)
-                  for ci in range(len(schema)))
-    valids = tuple(tuple(b.columns[ci].validity for b in batches)
-                   for ci in range(len(schema)))
-    pairs = fn(datas, valids)
-    cols = [Column(f.dtype, d, v) for f, (d, v) in zip(schema, pairs)]
-    return ColumnarBatch(schema, cols, total)
+            _CONCAT_JIT[key] = prog
+    return prog
+
+
+def _concat_lanes(lanes, nrows: Sequence[int], caps: Sequence[int],
+                  fills) -> tuple:
+    """For every lane (one array a batch, rows leading, the batch's first
+    ``nrows[k]`` rows live): those live rows one after another, filled
+    with the lane's ``fill`` from the total up to the lane's ``cap``.
+
+    The row counts are traced, never static: batch k's whole array is
+    written at the running offset (what lies past its live rows is
+    overwritten by batch k + 1, or filled at the end), so a program is
+    keyed by the arrays' capacities and dtypes alone and a new seed's
+    row counts compile nothing.  One launch a batch, all lanes in it."""
+    def shapes(arrays):
+        return tuple((str(a.dtype), tuple(a.shape)) for a in arrays)
+    start, append, finish = _concat_programs()
+    rooms = tuple(cap + max(int(p.shape[0]) for p in lane)
+                  for lane, cap in zip(lanes, caps))
+    parts = tuple(lane[0] for lane in lanes)
+    outs = _concat_step(start, ("start", shapes(parts), rooms))(parts, rooms)
+    room_shapes = shapes(outs)          # an append keeps them
+    off = nrows[0]
+    for k in range(1, len(nrows)):
+        parts = tuple(lane[k] for lane in lanes)
+        outs = _concat_step(append, ("append", room_shapes, shapes(parts)))(
+            outs, parts, np.int32(off))
+        off += nrows[k]
+    caps = tuple(caps)
+    fills = tuple(np.asarray(f, o.dtype) for f, o in zip(fills, outs))
+    return _concat_step(finish, ("finish", room_shapes, caps))(
+        outs, np.int32(off), fills, caps)
 
 
 def _concat_cols(dtype: T.DType, cols: Sequence[Column],
@@ -426,14 +494,9 @@ def _concat_cols(dtype: T.DType, cols: Sequence[Column],
         return _concat_struct_cols(dtype, cols, nrows, cap)
     if isinstance(dtype, (T.ArrayType, T.MapType)):
         return _concat_list_cols(cols, nrows, cap)
-    datas = [c.data[:n] for c, n in zip(cols, nrows)]
-    valids = [c.validity[:n] for c, n in zip(cols, nrows)]
-    data = jnp.concatenate(datas) if datas else jnp.zeros(0)
-    valid = jnp.concatenate(valids)
-    pad = cap - int(data.shape[0])
-    if pad:
-        data = jnp.pad(data, (0, pad))
-        valid = jnp.pad(valid, (0, pad))
+    data, valid = _concat_lanes(
+        [[c.data for c in cols], [c.validity for c in cols]], nrows,
+        [cap, cap], [0, False])
     return Column(dtype, data, valid)
 
 
@@ -505,41 +568,37 @@ def _concat_list_cols(cols: Sequence[Column], nrows: Sequence[int],
                          valid)
 
 
-def _concat_string_cols(cols: Sequence[StringColumn], nrows: Sequence[int],
-                        cap: int) -> StringColumn:
+def _concat_string_bytes(cols: Sequence[StringColumn],
+                         nrows: Sequence[int]):
+    """-> (one (offsets rebased onto the joint buffer, total bytes)
+    pair a column, the joint byte buffer, max_bytes)."""
     from ..analysis import residency  # lazy: avoids import cycle
-    offsets_parts, valid_parts = [], []
+    parts, np_bytes = [], []
     base = 0
     with residency.declared_transfer(site="batch_concat"):
-        for c, n in zip(cols, nrows):
-            offs_np = np.asarray(c.offsets)
-            o0 = int(offs_np[0])
-            offsets_parts.append(c.offsets[:n] - jnp.int32(o0 - base))
-            base = base + int(offs_np[n]) - o0
-            valid_parts.append(c.validity[:n])
         # bytes: need exact live bytes from each column; slicing with
         # dynamic sizes is not static-shape friendly on device, so
         # gather via numpy on host (concat is a batch boundary; the
         # reference also round-trips host for shuffle concat of
         # serialized batches).
-        np_bytes = []
         for c, n in zip(cols, nrows):
             offs = np.asarray(c.offsets)
-            np_bytes.append(np.asarray(c.data)[int(offs[0]):int(offs[n])])
+            o0, o1 = int(offs[0]), int(offs[n])
+            np_bytes.append(np.asarray(c.data)[o0:o1])
+            shifted = c.offsets.astype(jnp.int32) - jnp.int32(o0 - base)
+            base += o1 - o0
+            parts.append((shifted, base))
     all_bytes = np.concatenate(np_bytes) if np_bytes else np.zeros(0, np.uint8)
     byte_cap = bucket_capacity(max(1, all_bytes.shape[0]))
     buf = np.zeros(byte_cap, np.uint8)
     buf[: all_bytes.shape[0]] = all_bytes
-    offsets = jnp.concatenate(offsets_parts + [jnp.array([all_bytes.shape[0]],
-                                                         jnp.int32)])
-    total = sum(nrows)
-    pad = cap + 1 - int(offsets.shape[0])
-    if pad > 0:
-        offsets = jnp.pad(offsets, (0, pad), mode="edge")
-    valid = jnp.concatenate(valid_parts)
-    vpad = cap - int(valid.shape[0])
-    if vpad > 0:
-        valid = jnp.pad(valid, (0, vpad))
-    mb = StringColumn.combined_max_bytes(cols)
-    return StringColumn(offsets.astype(jnp.int32), jnp.asarray(buf), valid,
-                        max_bytes=mb)
+    return parts, jnp.asarray(buf), StringColumn.combined_max_bytes(cols)
+
+
+def _concat_string_cols(cols: Sequence[StringColumn], nrows: Sequence[int],
+                        cap: int) -> StringColumn:
+    parts, data, mb = _concat_string_bytes(cols, nrows)
+    off, valid = _concat_lanes(
+        [[shifted for shifted, _ in parts], [c.validity for c in cols]],
+        nrows, [cap + 1, cap], [parts[-1][1], False])
+    return StringColumn(off, data, valid, max_bytes=mb)

@@ -25,6 +25,7 @@ from __future__ import annotations
 from typing import List, Optional, Tuple
 
 from ..columnar.batch import ColumnarBatch, concat_batches
+from ..obs import trace as _trace
 from ..shuffle.partitioners import HashPartitioner
 from .base import PhysicalPlan, NUM_OUTPUT_ROWS
 from .exchange import TpuShuffleExchange
@@ -180,8 +181,10 @@ class TpuAdaptiveShuffledJoin(TpuExec):
         join = TJ.TpuShuffledHashJoin(p, left, right,
                                       build_right=self.build_right)
 
+        # +1 per decision: which way the runtime threshold fell
+        self.strategy = "broadcast" if can_broadcast else "shuffled"
+        _trace.count("join.adaptive." + self.strategy)
         if can_broadcast:
-            self.strategy = "broadcast"
             # the build side is already materialized in the catalog; the
             # probe side streams its ORIGINAL partitions — no shuffle
             batches = []
@@ -201,7 +204,6 @@ class TpuAdaptiveShuffledJoin(TpuExec):
                                                    part)
             return [run_bcast(part) for part in probe.execute()]
 
-        self.strategy = "shuffled"
         pkeys = p.left_keys if self.build_right else p.right_keys
         probe_side = left if self.build_right else right
         probe_ex = self._exchange(probe_side, pkeys)

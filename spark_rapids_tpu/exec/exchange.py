@@ -142,6 +142,7 @@ class TpuShuffleExchange(TpuExec):
                 # fused pool, never an inline np.asarray
                 pending.flush()
                 per_reduce_by_map = {}
+                crossed = 0
                 for map_id, batch, (sorted_batch, counts), st in staged:
                     checked = resolve_speculative(batch)
                     if checked is not batch:
@@ -164,6 +165,7 @@ class TpuShuffleExchange(TpuExec):
                                                             counts)
                     if stats_on:
                         acc.absorb(split.offsets, st)
+                    crossed += int(split.offsets[-1])
                     if split.offsets[-1] == 0:
                         continue
                     per_reduce = per_reduce_by_map.setdefault(map_id, {})
@@ -171,6 +173,12 @@ class TpuShuffleExchange(TpuExec):
                         piece = split.partition_slice(pid)
                         if piece is not None:
                             per_reduce.setdefault(pid, []).append(piece)
+                # the offsets are on the host here: map batches split and
+                # the rows that crossed, into the draining query's table
+                if staged:
+                    _trace.count("exchange.batches", len(staged))
+                if crossed:
+                    _trace.count("exchange.rows", crossed)
                 staged.clear()
                 staged_bytes = 0
                 for map_id, per_reduce in per_reduce_by_map.items():
@@ -249,8 +257,9 @@ class TpuShuffleExchange(TpuExec):
                     # SUPPOSED to park until then, and device permits
                     # are dropped for the whole region (above) so the
                     # wait cannot deadlock the dispatch pool
-                    # lint: allow(LOCK003)
-                    self._materialize_map_side()
+                    with _trace.span("srt.exchange.map", coarse=True):
+                        # lint: allow(LOCK003)
+                        self._materialize_map_side()
                 self._materialized = True
 
     def partition_stats(self):

@@ -357,6 +357,10 @@ class TpuHashAggregate(TpuExec):
                     else partials[0]
                 out = self._merge_finalize(merged,
                                            multiple=len(partials) > 1)
+                # slots of group state carried into a merge and yielded
+                _obs_trace.count(
+                    "agg.groups_capacity", out.capacity +
+                    (merged.capacity if len(partials) > 1 else 0))
                 if defer and spec is not None:
                     out_spec = getattr(out, "_speculative", None)
 
@@ -697,6 +701,9 @@ class TpuHashAggregate(TpuExec):
                             out_cols, LazyCount(ng))
 
         def redo():
+            # the table did not fit: this batch is computed a second
+            # time on the sort path, and later ones skip the table
+            _obs_trace.count("agg.table.misfit")
             self._ws_memo["table_state"] = "off"
             return self._aggregate_batch(batch, PARTIAL, no_table=True)
         out._speculative = SpeculativeResult([LazyCount(fit)], redo)
@@ -1255,6 +1262,8 @@ class TpuHashAggregate(TpuExec):
             t = self._fused_table_core(batch)
             if t is not None:
                 _obs_trace.count("agg.batches.table")
+                # a table batch that fits reads 0 misfits, not nothing
+                _obs_trace.count("agg.table.misfit", 0)
                 return t
         # speculative device-side compaction: hand downstream a small-
         # capacity batch instead of the input-capacity one (group counts

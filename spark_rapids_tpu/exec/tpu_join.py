@@ -25,6 +25,7 @@ from ..columnar.column import Column, StringColumn, bucket_capacity
 from ..columnar.batch import ColumnarBatch, concat_batches
 from ..expr import core as ec
 from ..kernels import canon, join as join_k
+from ..kernels.basic import prefix_sum
 from ..kernels import strings as skern
 from ..obs import compile_watch as _compile_watch
 from ..obs import trace as _trace
@@ -111,6 +112,9 @@ class TpuHashJoinBase(TpuExec):
                                     "build": build,
                                     "bkey_cols": bkey_cols}
 
+        # what every probe of this partition sorts or searches against
+        if build.capacity:
+            _trace.count("join.build_rows", build.capacity)
         stream_batches = list(stream_iter)
         if not stream_batches:
             stream_batches = [ColumnarBatch.empty(stream_schema)]
@@ -258,6 +262,7 @@ class TpuHashJoinBase(TpuExec):
     _PROBE_JIT: dict = {}
     _EXPAND_JIT: dict = {}
     _SPEC_JIT: dict = {}
+    _DIRECT_JIT: dict = {}
 
     # max entries in the direct-address probe table (64 MB of i32 HBM)
     _DIRECT_MAX_RANGE = 1 << 24
@@ -290,21 +295,29 @@ class TpuHashJoinBase(TpuExec):
         c = bkey_cols[0]
         w = canon.value_words(c, build.num_rows)[0]
 
-        def _minmax(w, validity, num_rows):
-            valid = validity & (jnp.arange(validity.shape[0]) < num_rows)
-            any_v = jnp.any(valid)
-            wmin = jnp.where(any_v,
-                             jnp.min(jnp.where(valid, w,
-                                               jnp.uint64(2**64 - 1))),
-                             jnp.uint64(0))
-            wmax = jnp.where(any_v,
-                             jnp.max(jnp.where(valid, w, jnp.uint64(0))),
-                             jnp.uint64(0))
-            nvalid = jnp.sum(valid)
-            return wmin, wmax, nvalid
-        wmin, wmax, nvalid = _compile_watch.jit(
-            _minmax, "join_direct_minmax")(
-                w, c.validity, jnp.int32(build.num_rows))
+        # both programs are kept by what they close over (nothing, the
+        # table's size): a jit built per partition would be traced and
+        # looked up in the compile cache again on every warm query
+        minmax = TpuHashJoinBase._DIRECT_JIT.get("minmax")
+        if minmax is None:
+            def _minmax(w, validity, num_rows):
+                valid = validity & (jnp.arange(validity.shape[0]) <
+                                    num_rows)
+                any_v = jnp.any(valid)
+                wmin = jnp.where(any_v,
+                                 jnp.min(jnp.where(valid, w,
+                                                   jnp.uint64(2**64 - 1))),
+                                 jnp.uint64(0))
+                wmax = jnp.where(any_v,
+                                 jnp.max(jnp.where(valid, w,
+                                                   jnp.uint64(0))),
+                                 jnp.uint64(0))
+                nvalid = jnp.sum(valid)
+                return wmin, wmax, nvalid
+            minmax = TpuHashJoinBase._DIRECT_JIT["minmax"] = \
+                _compile_watch.jit(_minmax, "join_direct_minmax")
+        wmin, wmax, nvalid = minmax(w, c.validity,
+                                    jnp.int32(build.num_rows))
         # one host pull per build table (cached on the exec)
         import numpy as _np
         from ..analysis import residency  # lazy: avoids import cycle
@@ -316,17 +329,21 @@ class TpuHashJoinBase(TpuExec):
             return None
         tbl = bucket_capacity(rng)
 
-        def _tables(w, validity, num_rows, wmin, nnull):
-            valid = validity & (jnp.arange(validity.shape[0]) < num_rows)
-            idx = jnp.clip((w - wmin).astype(jnp.int32), 0, tbl - 1)
-            contrib = jnp.where(valid, idx, tbl)
-            hist = jnp.bincount(contrib, length=tbl + 1)[:tbl] \
-                .astype(jnp.int32)
-            excl = (jnp.cumsum(hist) - hist + nnull).astype(jnp.int32)
-            return hist, excl
-        hist, excl = _compile_watch.jit(_tables, "join_direct_tables")(
-            w, c.validity, jnp.int32(build.num_rows), wmin,
-            jnp.int32(nnull_h))
+        tables = TpuHashJoinBase._DIRECT_JIT.get(tbl)
+        if tables is None:
+            def _tables(w, validity, num_rows, wmin, nnull):
+                valid = validity & (jnp.arange(validity.shape[0]) <
+                                    num_rows)
+                idx = jnp.clip((w - wmin).astype(jnp.int32), 0, tbl - 1)
+                contrib = jnp.where(valid, idx, tbl)
+                hist = jnp.bincount(contrib, length=tbl + 1)[:tbl] \
+                    .astype(jnp.int32)
+                excl = (prefix_sum(hist) - hist + nnull).astype(jnp.int32)
+                return hist, excl
+            tables = TpuHashJoinBase._DIRECT_JIT[tbl] = \
+                _compile_watch.jit(_tables, "join_direct_tables")
+        hist, excl = tables(w, c.validity, jnp.int32(build.num_rows), wmin,
+                            jnp.int32(nnull_h))
         return (jnp.uint64(wmin_h), jnp.uint64(wmax_h), hist, excl, tbl)
 
     def _probe_phase(self, sb, skey_cols, bt, str_words, build_matched,

@@ -279,12 +279,12 @@ class SortArray(ec.Expression):
             else jnp.where(evalid, jnp.uint64(0), jnp.uint64(1))
         # LSD chained pair-sorts (kernels/sort.py rationale): significance
         # order is segment > null rank > value words, so least first
-        from ..kernels.sort import sort_stable_pair
-        perm = jnp.arange(ecap, dtype=jnp.int32)
+        from ..kernels.sort import lsd_pass
+        perm = None
         passes = list(reversed([seg.astype(jnp.uint64), nk] +
                                [(w if self.asc else ~w) for w in words]))
         for w in passes:
-            perm = sort_stable_pair(jnp.take(w, perm), perm)
+            perm = lsd_pass(w, perm)
         elems = col.elements.gather(perm)
         return ListColumn(col.dtype, col.offsets, elems, col.validity)
 

@@ -40,6 +40,7 @@ from jax import lax
 
 from ..obs import trace as _trace
 from . import canon
+from .basic import prefix_sum, rows_flagged_first
 from .sort import sorted_words
 
 
@@ -170,7 +171,7 @@ def groupby_plan(words: List[jnp.ndarray], num_slots: Optional[int] = None,
     Besides the segment ids, the plan carries each group's first/last
     SORTED position (``head_pos``/``last_pos``): groups are contiguous
     runs after the sort, so per-group reductions of sums/counts become
-    prefix-scan + two boundary gathers — a cumsum is near-free on the
+    prefix-scan + two boundary gathers — a prefix sum is near-free on the
     VPU while a 64-bit scatter-add costs ~5x an f32 one (measured; XLA
     emulates i64 as 32-bit pairs and scatters serialize badly).
     """
@@ -183,16 +184,12 @@ def groupby_plan(words: List[jnp.ndarray], num_slots: Optional[int] = None,
         # dead rows sort past every live one
         live = jnp.arange(n) < jnp.sum(live.astype(jnp.int32))
     boundary = canon.words_equal_adjacent(sorted_ws) & live
-    seg_id = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+    seg_id = prefix_sum(boundary.astype(jnp.int32)) - 1
     seg_id = jnp.maximum(seg_id, 0)
     num_groups = jnp.sum(boundary)
     slots = n if num_slots is None else min(num_slots, n)
-    # the groups' first sorted rows, in order: a stable sort of the
-    # boundary flags on 32-bit operands (basic.filter_compact_indices
-    # argsorts 64-bit ones, three times the lanes)
-    _, rep_order = lax.sort(
-        (jnp.where(boundary, jnp.uint32(0), jnp.uint32(1)),
-         jnp.arange(n, dtype=jnp.int32)), num_keys=1, is_stable=True)
+    # the groups' first sorted rows, in order
+    rep_order = rows_flagged_first(boundary).astype(jnp.int32)
     rep_indices = jnp.take(perm, rep_order[:slots])
     # group g spans sorted rows [head_pos[g], last_pos[g]]; dead rows sort
     # after all live rows, so the last live group ends at live_count-1
@@ -217,7 +214,9 @@ def seg_prefix_sum(plan: GroupPlan, contrib):
     exact whenever it fits the dtype — the same contract as a direct
     per-group sum."""
     cap = contrib.shape[0]
-    cum = jnp.cumsum(contrib)
+    if contrib.dtype == jnp.bool_:
+        contrib = contrib.astype(jnp.int64)     # as cumsum widens it
+    cum = prefix_sum(contrib)
     ex = cum - contrib                       # exclusive prefix per row
     hp = jnp.clip(plan.head_pos, 0, cap - 1)
     lp = jnp.clip(plan.last_pos, 0, cap - 1)
@@ -511,8 +510,7 @@ def table_compact(counts, table: int):
     num_groups) where order[g] = bucket of group g, ascending."""
     present = counts > 0
     num_groups = jnp.sum(present).astype(jnp.int32)
-    order = jnp.argsort(jnp.where(present, 0, 1), stable=True) \
-        .astype(jnp.int32)
+    order = rows_flagged_first(present).astype(jnp.int32)
     return present, order, num_groups
 
 

@@ -10,7 +10,41 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
+
+
+def prefix_sum(x):
+    """Inclusive running sum of a 1-D integer array by log2(n)
+    shift-and-add steps; exact, wraps as the dtype does.
+
+    In place of ``jnp.cumsum`` inside device programs: the chip's
+    compiler takes 32 s for an int32 ``cumsum`` at 2^20 elements and
+    twice that for an int64 one (3 s at 2^23: the time follows the
+    length by no rule), which made ``join_expand_matches`` the costliest
+    program of a cold start (39-103 s a capacity).  These steps are
+    elementwise, compile in under a second at any length and run no
+    slower (0.65 ms against 0.86 at 2^20; PERF.md section 6, PR 32)."""
+    d = 1
+    while d < x.shape[0]:
+        x = x + jnp.concatenate([jnp.zeros((d,), x.dtype), x[:-d]])
+        d *= 2
+    return x
+
+
+def rows_flagged_first(flag):
+    """Row numbers (uint32) of a 1-D boolean array, the flagged rows
+    first and each side in row order: one uint32 word a row (the flag's
+    complement above the row number) sorted alone.  The stable pair sort
+    of (flag, row number) this replaces compiled for 22 s on the chip at
+    2^20 rows (40 s with 64-bit flags) and ran for 2.4 ms; a lone uint32
+    operand compiles in 3 s and runs in 1.1 ms (PERF.md section 6, PR
+    32)."""
+    n = flag.shape[0]
+    assert n <= 1 << 31
+    word = jnp.where(flag, jnp.uint32(0), jnp.uint32(1 << 31)) \
+        | jnp.arange(n, dtype=jnp.uint32)
+    return lax.sort(word, is_stable=False) & jnp.uint32(0x7FFFFFFF)
 
 
 @jax.jit
@@ -24,8 +58,7 @@ def filter_compact_indices(keep_mask, num_rows):
     cap = keep_mask.shape[0]
     in_range = jnp.arange(cap) < num_rows
     keep = keep_mask & in_range
-    # stable: argsort of (not keep) keeps relative order of kept rows
-    order = jnp.argsort(jnp.where(keep, 0, 1), stable=True)
+    order = rows_flagged_first(keep).astype(jnp.int64)
     new_count = jnp.sum(keep)
     return order, new_count
 
@@ -33,7 +66,7 @@ def filter_compact_indices(keep_mask, num_rows):
 @jax.jit
 def filter_prefix_positions(keep_mask):
     """positions[i] = output slot of row i if kept (cumsum-1)."""
-    return jnp.cumsum(keep_mask.astype(jnp.int32)) - 1
+    return prefix_sum(keep_mask.astype(jnp.int32)) - 1
 
 
 # ---------------------------------------------------------------------------

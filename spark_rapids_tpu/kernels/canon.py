@@ -134,6 +134,20 @@ def column_key_words(col: Column, num_rows: int, *, descending: bool = False,
     return [null_rank] + words
 
 
+def _few_rows_of_a_large_source(view) -> bool:
+    """A lazy string gather view that reads at most a sixteenth of a
+    source of 2^16 rows or more, and whose own bytes
+    ``strings.gather_strings`` sizes from ``max_bytes`` without a sync
+    (under 2^22).  All of it is host-known, so the choice is part of no
+    program's shape."""
+    import jax
+    src, rows = view.src, int(view.idx.shape[0])
+    return (src.capacity >= (1 << 16) and rows * 16 <= src.capacity
+            and src.max_bytes is not None
+            and rows * src.max_bytes <= (1 << 22)
+            and not isinstance(view.idx, jax.core.Tracer))
+
+
 def value_words(col: Column, num_rows: int, str_words: int = None,
                 str_bound: int = None) -> List[jnp.ndarray]:
     """uint64 word list for the column values (no null rank).
@@ -156,6 +170,16 @@ def value_words(col: Column, num_rows: int, str_words: int = None,
         if str_words is None:
             str_bound = skern.key_byte_bound(src, src.capacity)
             str_words = skern.bucket_words(str_bound)
+        if _few_rows_of_a_large_source(col):
+            # packing every row of the source to gather a sixteenth of
+            # the words costs the SOURCE's size a view (a semi join's
+            # survivors over a 2^19-row build: 137 ms a launch on the
+            # chip); the view's own strings are few, and their bytes are
+            # sized without a sync
+            return skern.string_key_words(col._materialize(),
+                                          col.capacity,
+                                          num_words=str_words,
+                                          byte_bound=str_bound)
         src_words = skern.string_key_words(src, src.capacity,
                                            num_words=str_words,
                                            byte_bound=str_bound)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import canon
+from .basic import prefix_sum
 from .sort import sorted_words
 
 
@@ -105,7 +106,7 @@ def join_expand_matches(lo, counts, perm, out_cap: int):
     Output row t belongs to probe row p where exclusive-cumsum[p] <= t <
     inclusive-cumsum[p]; its build position is lo[p] + (t - excl[p]).
     """
-    incl = jnp.cumsum(counts.astype(jnp.int64))
+    incl = prefix_sum(counts.astype(jnp.int64))
     excl = incl - counts
     total = incl[-1]
     t = jnp.arange(out_cap, dtype=jnp.int64)

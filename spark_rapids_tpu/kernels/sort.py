@@ -24,34 +24,53 @@ from jax import lax
 
 
 
+def stable_sort_rows(key):
+    """``key`` in stable ascending order beside the row numbers (int32)
+    in that order.  The row number is the second sort key, so no two
+    rows compare equal and the sort itself need not be a stable one:
+    the same answer as ``is_stable=True`` with one key, which the
+    chip's compiler takes longer over (at 2^20 rows 39.9 s against
+    28.4 s on a uint64 key, 3.0 ms to run either way; PERF.md section
+    6, PR 32)."""
+    iota = jnp.arange(key.shape[0], dtype=jnp.int32)
+    return lax.sort((key, iota), num_keys=2, is_stable=False)
+
+
 @jax.jit
-def sort_stable_pair(key, perm):
-    """The one compiled sort primitive: stable ascending by ``key``,
-    carrying ``perm`` — shape-cached per (capacity bucket, key dtype).
+def sort_stable_pair(key):
+    """The one compiled sort primitive: the row numbers in the stable
+    ascending order of ``key`` — shape-cached per (capacity bucket, key
+    dtype).  A caller that sorts rows already permuted gathers its
+    permutation by the result (``lsd_pass``): carrying it through the
+    sort would be a second program a capacity.
 
     64-bit keys cost ~6x a u32 sort on real TPU (u64 ops lower to u32
     pairs), so callers with provably-narrow keys (partition ids, table
     buckets, range-rebased words) pass u32 keys directly."""
-    _, out = lax.sort((key, perm), num_keys=1, is_stable=True)
-    return out
+    return stable_sort_rows(key)[1]
+
+
+def lsd_pass(key, perm):
+    """One pass of an LSD chain: ``perm`` (None: the rows as they lie)
+    re-ordered stably by ``key``, a word in row order."""
+    if perm is None:
+        return sort_stable_pair(key)
+    return jnp.take(perm, sort_stable_pair(jnp.take(key, perm)))
 
 
 @jax.named_scope("sort_permutation")
 def sort_permutation(words: List[jnp.ndarray]) -> jnp.ndarray:
     """Stable ascending sort over word tuples; returns permutation indices."""
-    cap = words[0].shape[0]
-    perm = jnp.arange(cap, dtype=jnp.int32)
     if len(words) == 1:
         w = words[0]
         if w.dtype != jnp.dtype(jnp.uint32):
             w = w.astype(jnp.uint64)
-        return sort_stable_pair(w, perm)
+        return sort_stable_pair(w)
     # LSD: least-significant word first; stability makes later (more
     # significant) passes dominate
-    for i, w in enumerate(reversed(words)):
-        k = w.astype(jnp.uint64)
-        # the first pass sorts the rows as they lie
-        perm = sort_stable_pair(k if i == 0 else jnp.take(k, perm), perm)
+    perm = None
+    for w in reversed(words):
+        perm = lsd_pass(w.astype(jnp.uint64), perm)
     return perm
 
 
@@ -59,9 +78,7 @@ def sorted_words(words: List[jnp.ndarray]):
     """Sort and also return the sorted word arrays (for boundary detection)."""
     if len(words) == 1:
         # one word: the sort hands back the sorted key beside the rows
-        w = words[0]
-        iota = jnp.arange(w.shape[0], dtype=jnp.int32)
-        key, perm = lax.sort((w, iota), num_keys=1, is_stable=True)
+        key, perm = stable_sort_rows(words[0])
         return [key], perm
     perm = sort_permutation(words)
     return [jnp.take(w, perm) for w in words], perm

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from ..columnar.column import StringColumn, bucket_capacity
 from ..obs import trace as _obs_trace
+from .basic import prefix_sum
 
 
 def string_lengths(offsets) -> jnp.ndarray:
@@ -176,7 +177,7 @@ def str_gather_offsets(offsets, validity, indices, live=None):
         gvalid = gvalid & live
     glens = jnp.where(gvalid, glens, 0)
     new_offsets = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(glens).astype(jnp.int32)])
+        [jnp.zeros(1, jnp.int32), prefix_sum(glens).astype(jnp.int32)])
     total = new_offsets[-1]
     return new_offsets, gvalid, jnp.take(starts, src), total
 

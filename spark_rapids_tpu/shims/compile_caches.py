@@ -25,6 +25,7 @@ def clear_compile_caches() -> None:
     tpu_aggregate.TpuHashAggregate._CORE_CACHE.clear()
     tpu_join.TpuHashJoinBase._PROBE_JIT.clear()
     tpu_join.TpuHashJoinBase._EXPAND_JIT.clear()
+    tpu_join.TpuHashJoinBase._DIRECT_JIT.clear()
     tpu_mesh_aggregate.TpuMeshAggregate._PROGRAM_CACHE.clear()
     tpu_mesh_join.TpuMeshShuffledJoin._PROGRAM_CACHE.clear()
     tpu_mesh_sort.TpuMeshSort._PROGRAM_CACHE.clear()

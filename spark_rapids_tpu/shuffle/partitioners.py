@@ -18,14 +18,13 @@ from typing import List, Optional, Sequence
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ..columnar.batch import ColumnarBatch, LazyArray
 from ..columnar.column import Column, StringColumn, bucket_capacity
 from ..expr import core as ec
 from ..kernels import basic as bk
 from ..kernels import canon
-from ..kernels.sort import sort_permutation
+from ..kernels.sort import sort_permutation, stable_sort_rows
 
 
 @dataclasses.dataclass
@@ -48,8 +47,7 @@ def partition_sort_counts(pids, num_rows, num_partitions: int):
     cap = pids.shape[0]
     in_range = jnp.arange(cap) < num_rows
     sort_key = jnp.where(in_range, pids, jnp.uint32(num_partitions))
-    perm = jnp.arange(cap, dtype=jnp.int32)
-    sk, perm = lax.sort((sort_key, perm), num_keys=1, is_stable=True)
+    sk, perm = stable_sort_rows(sort_key)
     bounds = jnp.searchsorted(
         sk, jnp.arange(num_partitions + 1, dtype=jnp.uint32), side="left")
     return perm, jnp.diff(bounds)
@@ -173,9 +171,7 @@ class HashPartitioner(Partitioner):
                 in_range = jnp.arange(cap) < num_rows
                 sort_key = jnp.where(in_range, pids.astype(jnp.uint32),
                                      jnp.uint32(nparts))
-                perm = jnp.arange(cap, dtype=jnp.int32)
-                sk, perm = lax.sort((sort_key, perm), num_keys=1,
-                                    is_stable=True)
+                sk, perm = stable_sort_rows(sort_key)
                 bounds = jnp.searchsorted(
                     sk, jnp.arange(nparts + 1, dtype=jnp.uint32),
                     side="left")

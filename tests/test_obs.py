@@ -17,6 +17,9 @@ from data_gen import IntGen, KeyGen, gen_df
 
 @pytest.fixture(autouse=True)
 def _trace_off_after():
+    # before too: under xdist the test that ran before on this worker
+    # may be another file's and have left coarse spans in the ring
+    trace.reset()
     yield
     trace.disable()
     trace.reset()

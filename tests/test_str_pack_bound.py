@@ -167,6 +167,45 @@ def test_lazy_view_packs_its_source_by_the_bound(bound):
         [np.asarray(w).tolist() for w in words]
 
 
+@pytest.mark.parametrize("src_rows,view_rows,packs_the_view", [
+    (1 << 16, 1 << 12, True),       # a sixteenth of a large source
+    (1 << 16, 64, True),            # a semi join's survivors
+    (1 << 16, 1 << 13, False),      # an eighth: the source's words
+    (1 << 15, 64, False),           # a dimension table: as before
+])
+def test_few_rows_of_a_large_source_pack_their_own_bytes(
+        src_rows, view_rows, packs_the_view):
+    """A view of a few rows packs those rows' strings, not every row
+    of its source (Q18's partial aggregate behind the semi join: one
+    source-sized pack a surviving batch); the words are the same."""
+    rng = np.random.default_rng(src_rows + view_rows)
+    names = np.array([f"Customer#{i:09d}" for i in range(1000)] +
+                     ["", "x"], object)
+    strings = names[rng.integers(0, len(names), src_rows)].tolist()
+    strings[7] = None
+    src = StringColumn.from_pylist(strings, src_rows)
+    src = StringColumn(src.offsets, src.data, src.validity, max_bytes=18)
+    live = view_rows - 5
+    idx = np.pad(rng.integers(0, src_rows, live).astype(np.int32),
+                 (0, view_rows - live))
+    idx[3] = 7                                              # the NULL
+    valid = np.asarray(src.validity)[idx] & (np.arange(view_rows) < live)
+    view = GatheredStringColumn(src, jnp.asarray(idx), jnp.asarray(valid))
+    assert canon._few_rows_of_a_large_source(view) is packs_the_view
+    (words, validity), bound = TA._pack_string_key(view, live)
+    assert bound == 32 and len(words) == 5
+    assert (view._mat is not None) is packs_the_view
+    want = numpy_words(strings, 4, src_rows)[idx]
+    got = np.stack([np.asarray(w) for w in words[:-1]], 1)
+    assert got[valid].tobytes() == want[valid].tobytes()
+    lens = np.array([len((strings[i] or "").encode()) for i in idx])
+    assert np.asarray(words[-1])[valid].tolist() == lens[valid].tolist()
+    # the caller masks the dead lanes by the validity it is handed
+    assert np.asarray(validity).tolist() == valid.tolist()
+    if packs_the_view:
+        assert not got[~valid].any()
+
+
 @pytest.mark.parametrize("num_words,num_bytes,indices_a_row", [
     (1, 1, 1), (1, 2, 2), (1, 4, 4), (1, None, 8), (1, 8, 8),
     (2, None, 16), (2, 4, 4)])

@@ -155,6 +155,7 @@ def _drop_engine_jit_caches():
     from spark_rapids_tpu.cache import plan_cache
     for cache in (TpuHashAggregate._CORE_CACHE, TpuHashJoinBase._PROBE_JIT,
                   TpuHashJoinBase._SPEC_JIT, TpuHashJoinBase._EXPAND_JIT,
+                  TpuHashJoinBase._DIRECT_JIT,
                   fused._JIT_CACHE, staged.TpuStagedCompute._JIT_CACHE,
                   cbatch._CONCAT_JIT, cbatch.ColumnarBatch._SLICE_JIT,
                   HashPartitioner._SPLIT_JIT):
@@ -281,10 +282,12 @@ class TestCoarseSpans:
         # (docs/observability.md); jit_build.* appears whenever a
         # neighbour dropped the jit caches, join.* with every hash join,
         # scan.* with every file scan, agg.* with every aggregate,
-        # str.* with every string key packed
+        # str.* with every string key packed, exchange.* with every
+        # in-process shuffle
         for tbl in counts.values():
             assert all(k.startswith(("eager.", "jit_build.", "join.",
-                                     "scan.", "agg.", "str."))
+                                     "scan.", "agg.", "str.",
+                                     "exchange."))
                        and v > 0 for k, v in tbl.items())
         assert any(k.startswith("eager.")
                    for tbl in counts.values() for k in tbl)
