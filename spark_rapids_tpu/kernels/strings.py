@@ -2,8 +2,8 @@
 
 Reference analogue: cuDF string kernels used by stringFunctions.scala.
 TPU-first: strings have no native XLA type, so every op here is integer
-arithmetic over the offsets/bytes buffers — gathers, searchsorted-style
-binary searches, and byte-table lookups — all static-shape.
+arithmetic over the offsets/bytes buffers — gathers, scatters at row
+starts with running sums, and byte-table lookups — all static-shape.
 """
 from __future__ import annotations
 
@@ -182,16 +182,44 @@ def str_gather_offsets(offsets, validity, indices, live=None):
     return new_offsets, gvalid, jnp.take(starts, src), total
 
 
+def _count_materialize(program):
+    """Count each eager launch of the program and the lanes it pays
+    for; under a ``jax.jit`` trace nothing is launched and nothing is
+    counted (``obs/trace.count_eager``)."""
+    @functools.wraps(program)
+    def launch(data, new_offsets, src_starts, out_bytes: int):
+        _obs_trace.count_eager("str.materialize.launches", new_offsets)
+        _obs_trace.count_eager("str.materialize.lanes", new_offsets,
+                               out_bytes)
+        return program(data, new_offsets, src_starts, out_bytes)
+    return launch
+
+
+@_count_materialize
 @functools.partial(jax.jit, static_argnames=("out_bytes",))
 def str_materialize_bytes(data, new_offsets, src_starts, out_bytes: int):
+    """``uint8[out_bytes]``: row ``r``'s bytes ``data[src_starts[r]:]``
+    laid at ``new_offsets[r] .. new_offsets[r + 1]``, zero past
+    ``new_offsets[-1]``.  ``new_offsets`` starts at 0 and never falls.
+
+    Linear in rows + lanes: lane ``j`` of row ``r`` reads byte
+    ``j + shift[r]`` with ``shift = src_starts - new_offsets[:-1]``, so
+    each row scatters the step ``shift[r] - shift[r - 1]`` at its first
+    lane (empty rows share a lane and their steps add up) and a running
+    sum over the lanes hands every lane its row's shift.  One gathered
+    byte a lane is then all the per-lane work: the chip pays per gathered
+    index, 9 ns, so a search for the lane's row would cost twenty times
+    the copy (PERF.md section 5, PR 33)."""
+    starts = new_offsets[:-1].astype(jnp.int32)
+    shift = src_starts.astype(jnp.int32) - starts
+    step = shift - jnp.concatenate([jnp.zeros(1, jnp.int32), shift[:-1]])
+    lane_shift = prefix_sum(jnp.zeros(out_bytes, jnp.int32).at[starts].add(
+        step, indices_are_sorted=True, mode="drop"))
     j = jnp.arange(out_bytes, dtype=jnp.int32)
-    row = jnp.searchsorted(new_offsets[1:], j, side="right").astype(jnp.int32)
-    row = jnp.clip(row, 0, new_offsets.shape[0] - 2)
-    within = j - new_offsets[row]
-    src_idx = jnp.take(src_starts, row) + within
     live = j < new_offsets[-1]
     return jnp.where(live,
-                     jnp.take(data, jnp.clip(src_idx, 0, data.shape[0] - 1)),
+                     jnp.take(data,
+                              jnp.clip(j + lane_shift, 0, data.shape[0] - 1)),
                      jnp.uint8(0))
 
 

@@ -38,13 +38,14 @@ FULL = (5, 7, 8, 9, 16, 17)         # rounds up to the words' own width
 CAP = 64
 
 
-def pack_counts():
-    """The ``str.pack.*`` counters since the last ``trace.reset()``,
-    over every query's table (and the one of no query)."""
+def pack_counts(prefix="str.pack."):
+    """The ``str.pack.*`` counters (or another ``prefix``'s) since the
+    last ``trace.reset()``, over every query's table (and the one of no
+    query)."""
     out = {}
     for table in trace.coarse_counts().values():
         for name, n in table.items():
-            if name.startswith("str.pack."):
+            if name.startswith(prefix):
                 out[name] = out.get(name, 0) + n
     return out
 

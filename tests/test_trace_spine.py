@@ -282,7 +282,8 @@ class TestCoarseSpans:
         # (docs/observability.md); jit_build.* appears whenever a
         # neighbour dropped the jit caches, join.* with every hash join,
         # scan.* with every file scan, agg.* with every aggregate,
-        # str.* with every string key packed, exchange.* with every
+        # str.* with every string key packed or gathered string laid
+        # out, exchange.* with every
         # in-process shuffle
         for tbl in counts.values():
             assert all(k.startswith(("eager.", "jit_build.", "join.",
