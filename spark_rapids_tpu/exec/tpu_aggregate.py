@@ -67,9 +67,13 @@ def _assemble_group_output(plan, key_cols, aggs, agg_buffers,
     return ng, outs
 
 
+#: core cache key -> 64-bit key words the core sorts (one LSD pass each)
+_KEY_WORDS: dict = {}
+
+
 def _group_reduce(key_cols, live, num_rows, aggs, agg_cols,
                   update_mode: bool, out_cap: Optional[int],
-                  emit_buffers: bool):
+                  emit_buffers: bool, cache_key=None):
     """The traced body both grouped cores share: merged key words ->
     sort -> ONE row gather of every array the aggregates read in sorted
     order -> update / merge (the DOUBLE sums as one stacked pass) ->
@@ -77,12 +81,15 @@ def _group_reduce(key_cols, live, num_rows, aggs, agg_cols,
     ``live`` (or None) marks the rows a folded-in filter kept: a dead
     row gets rank 2 in the first key field and sorts past every group,
     so nothing is compacted.  Returns (num_groups, fit, output pairs in
-    schema order)."""
+    schema order).  ``cache_key``: the core's, under which the number
+    of merged key words it sorts is left for ``agg.key_words`` (known
+    when the core is traced, and the same at every capacity)."""
     cap = key_cols[0].capacity
     rows = jnp.arange(cap) < num_rows
     live = rows if live is None else live & rows
     with jax.named_scope("key_words"):
         words = canon.group_key_words(key_cols, num_rows, live)
+    _KEY_WORDS[cache_key] = len(words)
     carried = []                # what the aggregates read in sorted order
     for a, cols in zip(aggs, agg_cols):
         for c in cols:
@@ -109,18 +116,26 @@ def _group_reduce(key_cols, live, num_rows, aggs, agg_cols,
     return ng, fit, outs
 
 
-def _pack_string_key(col, num_rows):
+def _pack_string_key(col, num_rows, at_least: int = 0):
     """(value words, validity) of a STRING key column for a core's
     ``canon.PackedStringKey``, and the byte bound that sized the words:
     ``jit_str_pack_words`` on the source column (a lazy gather view
     gathers its source's words), outside the core because the bound is
     host-known, not traced.  The bound is rounded up to a power of two:
-    it is part of the core's cache key."""
+    it is part of the core's cache key.  ``at_least``: the widest bound
+    an earlier batch of the same key had; the words are sized by the
+    wider of the two, so the batches share one program.  A column whose
+    own bound is 0 holds only NULLs and empty strings (a key a grouping
+    set rolled up): its words are zero at any width and nothing is
+    launched for them."""
     from ..kernels import strings as skern
-    bound = skern.key_byte_bound(col, num_rows)
-    bound = 1 << max(0, bound - 1).bit_length()
-    words = canon.value_words(col, num_rows,
-                              str_words=skern.bucket_words(bound),
+    own = skern.key_byte_bound(col, num_rows)
+    bound = max(1 << max(0, own - 1).bit_length(), at_least)
+    num_words = skern.bucket_words(bound)
+    if own == 0:
+        zero = jnp.zeros(col.capacity, jnp.uint64)
+        return ((zero,) * (num_words + 1), col.validity), bound
+    words = canon.value_words(col, num_rows, str_words=num_words,
                               str_bound=bound)
     return (tuple(words), col.validity), bound
 
@@ -450,7 +465,10 @@ class TpuHashAggregate(TpuExec):
         _aot.note_demand("hash_aggregate", batch.capacity,
                          _costplane.rows_if_resolved(batch))
         try:
-            return core(*args)
+            out = core(*args)
+            if cache_key in _KEY_WORDS:         # a grouped core
+                _obs_trace.count("agg.key_words", _KEY_WORDS[cache_key])
+            return out
         except Exception:  # noqa: BLE001 - fall back, but loudly
             logging.getLogger("spark_rapids_tpu.exec.aggregate").warning(
                 "%s aggregate core failed; falling back", what,
@@ -500,8 +518,18 @@ class TpuHashAggregate(TpuExec):
         in_dts = tuple(tuple(None if c is None else c.dtype for c in cols)
                        for cols in input_cols)
         aggs = self.aggs
-        packed = {i: _pack_string_key(c, batch.rows_dev)
-                  for i, c in enumerate(key_cols) if c.dtype == T.STRING}
+        # one program for every batch of this exec: a key's words are
+        # sized by the widest bound a batch of it has had (under a
+        # ROLLUP each grouping set NULLs other keys, and a bound a
+        # projection would be nine update programs of 30-45 s each to
+        # compile where the finest set's serves all nine)
+        widest = self._ws_memo.setdefault(("key_bounds", update_mode), {})
+        packed = {}
+        for i, c in enumerate(key_cols):
+            if c.dtype == T.STRING:
+                packed[i] = _pack_string_key(c, batch.rows_dev,
+                                             widest.get(i, 0))
+                widest[i] = packed[i][1]
         # a STRING key's dtype stands with its byte bound in the key
         key_dts = tuple((c.dtype, packed[i][1]) if i in packed else c.dtype
                         for i, c in enumerate(key_cols))
@@ -516,7 +544,8 @@ class TpuHashAggregate(TpuExec):
             agg_cols = [[None if dt is None else Column(dt, *next(it))
                          for dt in dts] or [None] for dts in in_dts]
             return _group_reduce(kcols, None, num_rows, aggs, agg_cols,
-                                 update_mode, out_cap, emit_buffers)
+                                 update_mode, out_cap, emit_buffers,
+                                 cache_key)
 
         key_nps = tuple(None if isinstance(dt, tuple) else dt.np_dtype
                         for dt in key_dts)
@@ -1206,7 +1235,7 @@ class TpuHashAggregate(TpuExec):
                 agg_cols.append([evaluated[expr_signature(e)]
                                  for e in bs] or [None])
             return _group_reduce(kcols, live, num_rows, aggs, agg_cols,
-                                 True, out_cap, emit_buffers)
+                                 True, out_cap, emit_buffers, cache_key)
 
         ws_nps = tuple(f.dtype.np_dtype for f in src_schema)
 

@@ -9,11 +9,12 @@ from typing import List
 
 import jax.numpy as jnp
 
-from ..columnar.batch import ColumnarBatch
+from ..columnar.batch import ColumnarBatch, resolve_speculative
 from ..columnar.column import bucket_capacity
 from ..expr import core as ec
+from ..obs import trace as _trace
 from ..plan.logical import Expand
-from .base import PhysicalPlan, NUM_OUTPUT_ROWS
+from .base import PhysicalPlan, NUM_OUTPUT_ROWS, OP_TIME, timed
 from .tpu_basic import TpuExec
 
 
@@ -33,10 +34,16 @@ class TpuExpand(TpuExec):
 
         def run(part):
             for batch in part:
+                # every projection reads the batch: vouch for it once
+                batch = resolve_speculative(batch)
                 for proj in bound:
-                    cols = [ec.eval_as_column(e, batch) for e in proj]
-                    out = ColumnarBatch(self.output_schema, cols,
-                                        batch.num_rows)
+                    with timed(self.metrics[OP_TIME], self):
+                        cols = [ec.eval_as_column(e, batch) for e in proj]
+                        out = ColumnarBatch(self.output_schema, cols,
+                                            batch.rows_lazy)
+                    # the slots the aggregate above runs over
+                    _trace.count("expand.batches")
+                    _trace.count("expand.rows", out.capacity)
                     self.metrics[NUM_OUTPUT_ROWS] += out.rows_lazy
                     yield out
         return [run(p) for p in self.children[0].execute()]

@@ -3,38 +3,107 @@
 Reference: GpuWindowExec.scala:338 + GpuWindowExpression.scala (cuDF
 rolling/scan windows, running-window optimization for row_number etc.).
 
-TPU-first: one sort by (partition keys, order keys) per spec, then every
-window function is a segmented scan/reduce over the sorted order:
-  row_number        position - segment_start
-  rank / dense_rank run boundaries + segment-min of run ids
-  lead / lag        shifted gather with same-segment mask
-  agg (whole part.) segment reduce broadcast back through seg ids
-  agg (running/rows frame) prefix sums with segment clamping
-Results are scattered back to the original row order (inverse perm), so
-row identity is preserved for downstream operators.
+TPU-first: one sort by (partition keys, order keys) per spec, shared by
+every function over that spec, then each function is a scan over the
+sorted runs (``kernels/window.py``).  The device work runs as named
+programs, one a role, keyed by capacities, dtypes and the spec's static
+shape, never by a row count:
+
+  window_plan         key words -> sort -> partition and peer runs,
+                      their first and last positions, the inverse
+                      permutation (one scatter)
+  window_rank         row_number / rank / dense_rank / ntile /
+                      percent_rank / cume_dist
+  window_part_agg     sum / count / avg / min / max over the whole
+                      partition, broadcast back
+  window_frame        the same over a ROWS or RANGE frame (prefix sums
+                      with partition clamping, segmented running
+                      min/max, a sparse table for bounded min/max)
+  window_frame_bounds a RANGE frame's first and last positions
+  window_shift        lead / lag: the source row of each row
+  window_collect_plan collect_list: each row's element span
+
+Results come back in the original row order (a gather by the inverse
+permutation), so row identity is preserved for downstream operators.
+What stays outside a program: evaluating the spec's expressions (plain
+column references launch nothing), packing a STRING key's words
+(``jit_str_pack_words``: the byte bound is host-known), lead / lag's
+final gather of the source column (a string column gathers lazily) and
+collect_list's element expansion, which is sized by a host pull.
 """
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, Optional
 
-import jax
 import jax.numpy as jnp
 
 from ..columnar import dtypes as T
 from ..columnar.schema import Field, Schema
 from ..columnar.column import Column
-from ..columnar.batch import ColumnarBatch, concat_batches
+from ..columnar.batch import (ColumnarBatch, concat_batches,
+                              resolve_speculative)
 from ..expr import core as ec
 from ..expr import aggregates as eagg
 from ..expr import window_funcs as wfn
 from ..kernels import canon
-from ..kernels.sort import sorted_words
+from ..kernels import window as wk
+from ..kernels.basic import prefix_sum
+from ..obs import compile_watch as _compile_watch
+from ..obs import trace as _trace
+from ..obs.registry import compile_cache_event
 from ..plan.logical import Window, WindowFunc
 from .base import PhysicalPlan, OP_TIME, NUM_OUTPUT_ROWS, timed
 from .tpu_basic import TpuExec
 
+_RANK_KINDS = {wfn.RowNumber: "row_number", wfn.Rank: "rank",
+               wfn.DenseRank: "dense_rank", wfn.NTile: "ntile",
+               wfn.PercentRank: "percent_rank", wfn.CumeDist: "cume_dist"}
+_AGG_KINDS = {eagg.Sum: "sum", eagg.Count: "count", eagg.Average: "avg",
+              eagg.Min: "min", eagg.Max: "max"}
+
+
+class _Spec:
+    """One (partition, order) sort of one batch: the key columns as
+    ``window_plan`` takes them (a STRING key as its packed words) and
+    the sorted partitions every function over the spec shares.
+    ``nbytes``: the device bytes of the key columns, from capacities and
+    dtypes alone (``Column.nbytes``: data and validity; a string column
+    by its byte buffer and offsets; a lazy string gather by its index
+    map and the source column it reads)."""
+
+    def __init__(self, batch: ColumnarBatch, spec):
+        from .tpu_aggregate import _pack_string_key
+        schema = batch.schema
+        self.pcols = [ec.eval_as_column(e.bind(schema), batch)
+                      for e in spec.partition_by]
+        self.ocols = [ec.eval_as_column(o.expr.bind(schema), batch)
+                      for o in spec.order_by]
+        self.descending = tuple(not o.ascending for o in spec.order_by)
+        self.nulls_last = tuple(not o.effective_nulls_first
+                                for o in spec.order_by)
+        arrays, dts = [], []
+        for c in self.pcols + self.ocols:
+            if c.dtype == T.STRING:
+                packed, bound = _pack_string_key(c, batch.rows_dev)
+                arrays.append(packed)
+                dts.append((c.dtype, bound))
+            else:
+                if type(c) is not Column:
+                    raise NotImplementedError(
+                        f"window key of type {type(c).__name__}")
+                arrays.append((c.data, c.validity))
+                dts.append(c.dtype)
+        self.key_arrays = tuple(arrays)
+        self.key_dts = tuple(dts)
+        self.nbytes = sum(c.nbytes() for c in self.pcols + self.ocols)
+        self.parts: Optional[wk.SortedPartitions] = None
+
 
 class TpuWindow(TpuExec):
+    # class-level jit cache, keyed by everything a traced closure
+    # captures: plans are rebuilt per query, the programs outlive them
+    _PROGRAMS: dict = {}
+
     def __init__(self, logical: Window, child: PhysicalPlan):
         super().__init__(child)
         self.logical = logical
@@ -48,11 +117,13 @@ class TpuWindow(TpuExec):
 
     def execute(self):
         def run(part):
-            batches = [b for b in part]
+            batches = [resolve_speculative(b) for b in part]
             if not batches:
                 return
             batch = concat_batches(batches) if len(batches) > 1 else \
                 batches[0]
+            _trace.count("window.batches")
+            _trace.count("window.rows", batch.capacity)
             with timed(self.metrics[OP_TIME], self):
                 out = self._apply(batch)
             self.metrics[NUM_OUTPUT_ROWS] += out.rows_lazy
@@ -60,318 +131,196 @@ class TpuWindow(TpuExec):
         return [run(p) for p in self.children[0].execute()]
 
     # ------------------------------------------------------------------
+    @staticmethod
+    def _program(key, build):
+        """The jitted program for ``key`` (role first), built on a miss
+        behind ``wrap_miss``."""
+        cache = TpuWindow._PROGRAMS
+        fn = cache.get(key)
+        compile_cache_event("window", fn is not None)
+        if fn is None:
+            fn = cache[key] = _compile_watch.wrap_miss(
+                "window", build(), str(key))
+        return fn
+
     def _apply(self, batch: ColumnarBatch) -> ColumnarBatch:
-        schema = batch.schema
+        from .fused import expr_signature
         new_cols: List[Column] = list(batch.columns)
-        fields = list(schema.fields)
+        fields = list(batch.schema.fields)
+        specs = {}
         for wf in self.logical.window_funcs:
-            col = self._eval_window(batch, wf)
+            s = wf.spec
+            sig = (tuple(expr_signature(e) for e in s.partition_by),
+                   tuple((expr_signature(o.expr), o.ascending,
+                          o.effective_nulls_first) for o in s.order_by))
+            if any(x is None for x in sig[0]) or \
+                    any(x[0] is None for x in sig[1]):
+                sig = id(wf)            # opaque expressions: no sharing
+            spec = specs.get(sig)
+            if spec is None:
+                spec = specs[sig] = _Spec(batch, s)
+                self._sort(spec, batch)
+            _trace.count("window.funcs")
+            col = self._eval_window(batch, wf, spec)
+            _trace.count("window.bytes", col.nbytes())
             new_cols.append(col)
             fields.append(Field(wf.alias, col.dtype, True))
-        return ColumnarBatch(Schema(fields), new_cols, batch.num_rows)
+        return ColumnarBatch(Schema(fields), new_cols, batch.rows_lazy)
 
-    def _eval_window(self, batch: ColumnarBatch, wf: WindowFunc) -> Column:
-        spec = wf.spec
-        cap = batch.capacity
-        n = batch.num_rows
-        pcols = [ec.eval_as_column(e.bind(batch.schema), batch)
-                 for e in spec.partition_by]
-        ocols = [ec.eval_as_column(o.expr.bind(batch.schema), batch)
-                 for o in spec.order_by]
+    def _sort(self, spec: _Spec, batch: ColumnarBatch) -> None:
+        key_dts, npart = spec.key_dts, len(spec.pcols)
+        descending, nulls_last = spec.descending, spec.nulls_last
+        # a spec with no key at all has nothing to read its capacity from
+        cap = None if key_dts else batch.capacity
 
-        pwords = canon.batch_key_words(pcols, n) if pcols else \
-            [jnp.where(jnp.arange(cap) < n, jnp.uint64(1), jnp.uint64(2))]
-        owords = canon.batch_key_words(
-            ocols, n,
-            descending=[not o.ascending for o in spec.order_by],
-            nulls_last=[not o.effective_nulls_first
-                        for o in spec.order_by]) if ocols else []
+        def _plan(key_arrays, num_rows):
+            kcols = [canon.PackedStringKey(d, v, dt[1])
+                     if isinstance(dt, tuple) else Column(dt, d, v)
+                     for dt, (d, v) in zip(key_dts, key_arrays)]
+            return wk.sorted_partitions(
+                kcols[:npart], kcols[npart:], num_rows, list(descending),
+                list(nulls_last),
+                cap if cap is not None else kcols[0].capacity)
 
-        all_words = pwords + owords
-        sorted_ws, perm = sorted_words(all_words)
-        live = sorted_ws[0] != jnp.uint64(2)
-
-        npw = len(pwords)
-        seg_boundary = canon.words_equal_adjacent(sorted_ws[:npw]) & live
-        seg = jnp.maximum(jnp.cumsum(seg_boundary.astype(jnp.int32)) - 1, 0)
-        pos = jnp.arange(cap, dtype=jnp.int64)
-        # position of segment start, broadcast per row
-        seg_start = jax.ops.segment_min(
-            jnp.where(live, pos, jnp.int64(cap)), seg, num_segments=cap)
-        row_in_seg = pos - jnp.take(seg_start, seg)
-
-        func = wf.func
-        if isinstance(func, wfn.RowNumber):
-            vals = (row_in_seg + 1).astype(jnp.int64)
-            out_valid = live
-            out_dtype = T.INT64
-        elif isinstance(func, (wfn.Rank, wfn.DenseRank)):
-            run_boundary = canon.words_equal_adjacent(sorted_ws) & live
-            run_id = jnp.maximum(
-                jnp.cumsum(run_boundary.astype(jnp.int32)) - 1, 0)
-            if isinstance(func, wfn.Rank):
-                run_first = jax.ops.segment_min(
-                    jnp.where(live, pos, jnp.int64(cap)), run_id,
-                    num_segments=cap)
-                vals = (jnp.take(run_first, run_id) -
-                        jnp.take(seg_start, seg) + 1).astype(jnp.int64)
-            else:
-                seg_first_run = jax.ops.segment_min(
-                    jnp.where(live, run_id.astype(jnp.int64),
-                              jnp.int64(cap)), seg, num_segments=cap)
-                vals = (run_id - jnp.take(seg_first_run, seg) + 1
-                        ).astype(jnp.int64)
-            out_valid = live
-            out_dtype = T.INT64
-        elif isinstance(func, (wfn.NTile, wfn.PercentRank, wfn.CumeDist)):
-            seg_len = jax.ops.segment_sum(
-                jnp.where(live, jnp.int64(1), jnp.int64(0)), seg,
-                num_segments=cap)
-            L = jnp.take(seg_len, seg)
-            if isinstance(func, wfn.NTile):
-                # Spark NTile: first (L % n) buckets hold ceil(L/n) rows
-                nb = jnp.int64(func.n)
-                base = L // nb
-                rem = L % nb
-                cut = rem * (base + 1)
-                vals = jnp.where(
-                    row_in_seg < cut,
-                    row_in_seg // jnp.maximum(base + 1, 1),
-                    rem + (row_in_seg - cut) // jnp.maximum(base, 1)) + 1
-                out_valid = live
-                out_dtype = T.INT64
-            else:
-                run_boundary = canon.words_equal_adjacent(sorted_ws) & live
-                run_id = jnp.maximum(
-                    jnp.cumsum(run_boundary.astype(jnp.int32)) - 1, 0)
-                if isinstance(func, wfn.PercentRank):
-                    run_first = jax.ops.segment_min(
-                        jnp.where(live, pos, jnp.int64(cap)), run_id,
-                        num_segments=cap)
-                    rank = (jnp.take(run_first, run_id) -
-                            jnp.take(seg_start, seg) + 1)
-                    vals = jnp.where(
-                        L > 1,
-                        (rank - 1).astype(jnp.float64) /
-                        jnp.maximum(L - 1, 1).astype(jnp.float64), 0.0)
-                else:   # CumeDist: rows <= current / partition rows
-                    run_last = jax.ops.segment_max(
-                        jnp.where(live, pos, jnp.int64(-1)), run_id,
-                        num_segments=cap)
-                    vals = (jnp.take(run_last, run_id) -
-                            jnp.take(seg_start, seg) + 1).astype(
-                        jnp.float64) / jnp.maximum(L, 1).astype(
-                        jnp.float64)
-                out_valid = live
-                out_dtype = T.FLOAT64
-        elif isinstance(func, (wfn.Lead, wfn.Lag)):
-            src = ec.eval_as_column(func.children[0].bind(batch.schema),
-                                    batch)
-            off = func.offset if isinstance(func, wfn.Lead) else -func.offset
-            shifted_pos = pos + off
-            inb = (shifted_pos >= 0) & (shifted_pos < cap)
-            sp = jnp.clip(shifted_pos, 0, cap - 1).astype(jnp.int32)
-            same_seg = inb & (jnp.take(seg, sp) == seg) & \
-                jnp.take(live, sp) & live
-            src_sorted_idx = jnp.take(perm, sp)
-            sorted_vals = src.gather(src_sorted_idx)
-            valid = sorted_vals.validity & same_seg
-            # scatter back to original order
-            inv = jnp.argsort(perm)
-            out = sorted_vals.gather(inv)
-            return out.mask_validity(jnp.take(valid, inv) &
-                                     (jnp.arange(cap) < n))
-        elif isinstance(func, eagg.AggregateFunction):
-            return self._window_agg(batch, func, spec, perm, seg, live,
-                                    row_in_seg, seg_start, n)
-        else:
-            raise NotImplementedError(f"window function {func.name}")
-
-        inv = jnp.argsort(perm)
-        vals_orig = jnp.take(vals, inv)
-        valid_orig = jnp.take(out_valid, inv) & (jnp.arange(cap) < n)
-        return Column(out_dtype, vals_orig.astype(out_dtype.np_dtype),
-                      valid_orig)
+        fn = self._program(
+            ("plan", key_dts, npart, descending, nulls_last, cap),
+            lambda: _compile_watch.jit(_plan, "window_plan"))
+        _trace.count("window.specs")
+        _trace.count("window.bytes", spec.nbytes)
+        spec.parts = fn(spec.key_arrays, batch.rows_dev)
 
     # ------------------------------------------------------------------
-    def _window_agg(self, batch, func, spec, perm, seg, live, row_in_seg,
-                    seg_start, n) -> Column:
-        cap = batch.capacity
+    def _eval_window(self, batch: ColumnarBatch, wf: WindowFunc,
+                     spec: _Spec) -> Column:
+        func = wf.func
+        kind = _RANK_KINDS.get(type(func))
+        if kind is not None:
+            return self._rank(batch, func, kind, spec)
+        if isinstance(func, (wfn.Lead, wfn.Lag)):
+            return self._shift(batch, func, spec)
         if isinstance(func, eagg.CollectList):
-            return self._window_collect(batch, func, spec, perm, seg,
-                                        live, row_in_seg, seg_start, n)
+            return self._collect(batch, func, wf.spec, spec)
+        if isinstance(func, eagg.AggregateFunction):
+            return self._aggregate(batch, func, wf.spec, spec)
+        raise NotImplementedError(f"window function {func.name}")
+
+    def _rank(self, batch, func, kind: str, spec: _Spec) -> Column:
+        out_dtype = func.dtype()
+        buckets = func.n if kind == "ntile" else 0
+        np_dtype = out_dtype.np_dtype
+
+        def _rank(p, num_rows):
+            vals = wk.ranking(p, kind, buckets)
+            return (jnp.take(vals, p.inv).astype(np_dtype),
+                    jnp.arange(p.inv.shape[0]) < num_rows)
+
+        fn = self._program(("rank", kind, buckets, out_dtype.name),
+                           lambda: _compile_watch.jit(_rank, "window_rank"))
+        return Column(out_dtype, *fn(spec.parts, batch.rows_dev))
+
+    def _shift(self, batch, func, spec: _Spec) -> Column:
+        src = ec.eval_as_column(func.children[0].bind(batch.schema), batch)
+        _trace.count("window.bytes", src.nbytes())
+        off = func.offset if isinstance(func, wfn.Lead) else -func.offset
+
+        def _shift(p, num_rows):
+            cap = p.perm.shape[0]
+            pos = jnp.arange(cap, dtype=jnp.int32)
+            at = pos + off
+            sp = jnp.clip(at, 0, cap - 1)
+            same = (at >= 0) & (at < cap) & p.live & jnp.take(p.live, sp) \
+                & (jnp.take(p.seg_start, sp) == p.seg_start)
+            return (jnp.take(jnp.take(p.perm, sp), p.inv),
+                    jnp.take(same, p.inv) & (pos < num_rows))
+
+        fn = self._program(("shift", off),
+                           lambda: _compile_watch.jit(_shift, "window_shift"))
+        idx, ok = fn(spec.parts, batch.rows_dev)
+        out = src.gather(idx)
+        return out.mask_validity(ok)
+
+    # ------------------------------------------------------------------
+    def _aggregate(self, batch, func, wspec, spec: _Spec) -> Column:
+        kind = _AGG_KINDS.get(type(func))
+        if kind is None:
+            raise NotImplementedError(f"window aggregate {func.name}")
         child = func.children[0] if func.children else None
         if child is not None:
             src = ec.eval_as_column(child.bind(batch.schema), batch)
-            sv = jnp.take(src.data, perm) if not hasattr(src, "offsets") \
-                else None
-            if sv is None:
+            if type(src) is not Column:
                 raise NotImplementedError("string window aggregates")
-            sok = jnp.take(src.validity, perm) & live
+            _trace.count("window.bytes", src.nbytes())
+            value = (src.data, src.validity)
         else:
-            sv = jnp.ones(cap, jnp.int64)
-            sok = live
-
-        kind, frame_lo, frame_hi = spec.frame
-        unbounded = frame_lo is None and frame_hi is None
+            value = None                    # count(*): every live row
+        frame_kind, frame_lo, frame_hi = wspec.frame
         out_dtype = func.dtype()
+        np_dtype = out_dtype.np_dtype
+        fractional = bool(out_dtype.is_fractional)
 
-        if unbounded or not spec.order_by:
-            # whole-partition aggregate broadcast back
-            vals, ok = self._seg_reduce(func, sv, sok, seg, cap)
-            vals = jnp.take(vals, seg)
-            ok = jnp.take(ok, seg) & live
-        elif kind == "range":
-            lo_pos, hi_pos = self._range_positions(
-                batch, spec, perm, seg, seg_start, live, cap,
-                frame_lo, frame_hi)
-            vals, ok = self._frame_agg(func, sv, sok, seg, row_in_seg,
-                                       seg_start, cap, None, None,
-                                       lo_pos=lo_pos, hi_pos=hi_pos,
-                                       lo_unbounded=frame_lo is None,
-                                       hi_unbounded=frame_hi is None)
-            ok = ok & live
-        else:
-            lo = frame_lo  # None = unbounded preceding
-            hi = frame_hi if frame_hi is not None else None
-            vals, ok = self._frame_agg(func, sv, sok, seg, row_in_seg,
-                                       seg_start, cap, lo, hi)
-            ok = ok & live
-        inv = jnp.argsort(perm)
-        vals_orig = jnp.take(vals, inv)
-        ok_orig = jnp.take(ok, inv) & (jnp.arange(cap) < n)
-        return Column(out_dtype, vals_orig.astype(out_dtype.np_dtype),
-                      ok_orig)
+        def sorted_value(p, value):
+            if value is None:
+                return jnp.ones(p.perm.shape[0], jnp.int64), p.live
+            data, valid = value
+            return jnp.take(data, p.perm), jnp.take(valid, p.perm) & p.live
 
-    def _window_collect(self, batch, func, spec, perm, seg, live,
-                        row_in_seg, seg_start, n) -> Column:
-        """collect_list over a window frame -> ListColumn.
+        if (frame_lo is None and frame_hi is None) or not wspec.order_by:
+            def _part_agg(p, value, num_rows):
+                sv, sok = sorted_value(p, value)
+                vals, ok = wk.partition_aggregate(p, kind, sv, sok,
+                                                  fractional)
+                # each row reads its partition's last sorted row
+                at = jnp.take(p.seg_end, p.inv)
+                return (jnp.take(vals, at).astype(np_dtype),
+                        jnp.take(ok, at)
+                        & (jnp.arange(at.shape[0]) < num_rows))
 
-        Elements come from the globally valid-compacted sorted rows:
-        row i's list is vpos[c_lo_i .. c_hi_i) where cnt is the prefix
-        count of valid sorted rows — one cumsum + one expand, no
-        per-row loops (GpuWindowExpression collect_list role)."""
-        from ..columnar.column import ListColumn, bucket_capacity
-        from ..kernels import basic as bk
-        from ..kernels import join as join_k
-        cap = batch.capacity
-        src = ec.eval_as_column(func.children[0].bind(batch.schema),
-                                batch)
-        sorted_src = src.gather(perm)
-        valid = sorted_src.validity & live
-        kind, frame_lo, frame_hi = spec.frame
-        seg_start_pos, seg_end_pos = self._seg_extents(seg, seg_start,
-                                                       cap)
-        pos = jnp.arange(cap, dtype=jnp.int64)
-        if (frame_lo is None and frame_hi is None) or not spec.order_by:
-            lo_pos, hi_pos = seg_start_pos, seg_end_pos
-        elif kind == "range":
-            lo_pos, hi_pos = self._range_positions(
-                batch, spec, perm, seg, seg_start, live, cap,
-                frame_lo, frame_hi)
-        else:
-            lo_pos = seg_start_pos if frame_lo is None else \
-                jnp.maximum(pos + frame_lo, seg_start_pos)
-            hi_pos = seg_end_pos if frame_hi is None else \
-                jnp.minimum(pos + frame_hi, seg_end_pos)
-        cnt = jnp.cumsum(valid.astype(jnp.int64))
-        hi_c = jnp.clip(hi_pos, 0, cap - 1).astype(jnp.int32)
-        lo_c = jnp.clip(lo_pos - 1, -1, cap - 1)
-        c_hi = jnp.take(cnt, hi_c)
-        c_lo = jnp.where(lo_c < 0, 0, jnp.take(cnt, jnp.maximum(lo_c, 0)))
-        m_sorted = jnp.where(hi_pos < lo_pos, 0, c_hi - c_lo)
-        vpos, _ = bk.filter_compact_indices(valid, cap)
-        inv = jnp.argsort(perm)
-        m_orig = jnp.where(jnp.arange(cap) < n,
-                           jnp.take(m_sorted, inv), 0)
-        c_lo_orig = jnp.take(c_lo, inv)
-        from ..analysis import residency  # lazy: avoids import cycle
-        with residency.declared_transfer(site="size_probe"):
-            total = int(jnp.sum(m_orig))
-        out_cap = bucket_capacity(max(total, 1))
-        _, elem_pos, live_e, _ = join_k.join_expand_matches(
-            c_lo_orig.astype(jnp.int32), m_orig.astype(jnp.int32),
-            vpos.astype(jnp.int32), out_cap)
-        elements = sorted_src.gather(elem_pos)
-        elements = elements.mask_validity(live_e)
-        offsets = jnp.concatenate(
-            [jnp.zeros(1, jnp.int64),
-             jnp.cumsum(m_orig)]).astype(jnp.int32)
-        out_valid = jnp.arange(cap) < n
-        return ListColumn(T.ArrayType(src.dtype), offsets, elements,
-                          out_valid)
+            fn = self._program(
+                ("part_agg", kind, fractional, out_dtype.name),
+                lambda: _compile_watch.jit(_part_agg, "window_part_agg"))
+            return Column(out_dtype, *fn(spec.parts, value, batch.rows_dev))
 
-    @staticmethod
-    def _seg_extents(seg, seg_start, cap):
-        """(per-row segment start position, per-row segment end
-        position) — shared by every frame kind."""
-        seg_start_pos = jnp.take(seg_start, seg)
-        seg_len = jax.ops.segment_sum(
-            jnp.ones(cap, jnp.int64), seg, num_segments=cap)
-        seg_end_pos = seg_start_pos + jnp.take(seg_len, seg) - 1
-        return seg_start_pos, seg_end_pos
+        bounds, levels = None, 0
+        if frame_kind == "range":
+            frame_lo, frame_hi, bounds = self._range_bounds(
+                batch, wspec, spec, frame_lo, frame_hi)
+            if kind in ("min", "max") and frame_lo is not None \
+                    and frame_hi is not None:
+                # a bounded RANGE frame's widest window sizes the sparse
+                # table: one host pull
+                from ..analysis import residency  # lazy: import cycle
+                with residency.declared_transfer(site="size_probe"):
+                    widest = int(jnp.max(bounds[1] - bounds[0] + 1))
+                levels = max(widest, 1)
+        elif kind in ("min", "max") and frame_lo is not None \
+                and frame_hi is not None:
+            levels = max(frame_hi - frame_lo + 1, 1)
+        # the sparse table's depth, not the width, shapes the program
+        levels = max(levels - 1, 0).bit_length()
+        lo_unb, hi_unb = frame_lo is None, frame_hi is None
+        explicit = bounds is not None
+        lo = None if explicit else frame_lo
+        hi = None if explicit else frame_hi
 
-    @staticmethod
-    def _minmax_ident(is_min: bool, dtype):
-        if jnp.issubdtype(dtype, jnp.floating):
-            return jnp.asarray(jnp.inf if is_min else -jnp.inf, dtype)
-        info = jnp.iinfo(dtype)
-        return jnp.asarray(info.max if is_min else info.min, dtype)
+        def _frame(p, value, bounds, num_rows):
+            sv, sok = sorted_value(p, value)
+            vals, ok = _frame_aggregate(p, kind, sv, sok, lo, hi, bounds,
+                                        lo_unb, hi_unb, levels)
+            return (jnp.take(vals, p.inv).astype(np_dtype),
+                    jnp.take(ok & p.live, p.inv)
+                    & (jnp.arange(p.inv.shape[0]) < num_rows))
 
-    def _seg_reduce(self, func, sv, sok, seg, cap):
-        contrib_ok = sok
-        if isinstance(func, eagg.Sum):
-            vals = jax.ops.segment_sum(
-                jnp.where(contrib_ok, sv.astype(jnp.float64)
-                          if func.dtype().is_fractional else
-                          sv.astype(jnp.int64), 0), seg, num_segments=cap)
-            cnt = jax.ops.segment_sum(contrib_ok.astype(jnp.int64), seg,
-                                      num_segments=cap)
-            return vals, cnt > 0
-        if isinstance(func, eagg.Count):
-            vals = jax.ops.segment_sum(contrib_ok.astype(jnp.int64), seg,
-                                       num_segments=cap)
-            return vals, jnp.ones_like(vals, bool)
-        if isinstance(func, eagg.Average):
-            s = jax.ops.segment_sum(
-                jnp.where(contrib_ok, sv.astype(jnp.float64), 0.0), seg,
-                num_segments=cap)
-            c = jax.ops.segment_sum(contrib_ok.astype(jnp.int64), seg,
-                                    num_segments=cap)
-            return s / jnp.maximum(c, 1), c > 0
-        if isinstance(func, eagg.Min):
-            big = jnp.asarray(jnp.inf if jnp.issubdtype(sv.dtype,
-                                                        jnp.floating)
-                              else jnp.iinfo(sv.dtype).max, sv.dtype)
-            vals = jax.ops.segment_min(jnp.where(contrib_ok, sv, big), seg,
-                                       num_segments=cap)
-            cnt = jax.ops.segment_sum(contrib_ok.astype(jnp.int64), seg,
-                                      num_segments=cap)
-            return vals, cnt > 0
-        if isinstance(func, eagg.Max):
-            small = jnp.asarray(-jnp.inf if jnp.issubdtype(sv.dtype,
-                                                           jnp.floating)
-                                else jnp.iinfo(sv.dtype).min, sv.dtype)
-            vals = jax.ops.segment_max(jnp.where(contrib_ok, sv, small), seg,
-                                       num_segments=cap)
-            cnt = jax.ops.segment_sum(contrib_ok.astype(jnp.int64), seg,
-                                      num_segments=cap)
-            return vals, cnt > 0
-        raise NotImplementedError(f"window aggregate {func.name}")
+        fn = self._program(
+            ("frame", kind, lo, hi, explicit, lo_unb, hi_unb, levels,
+             out_dtype.name),
+            lambda: _compile_watch.jit(_frame, "window_frame"))
+        return Column(out_dtype,
+                      *fn(spec.parts, value, bounds, batch.rows_dev))
 
-    def _range_positions(self, batch, spec, perm, seg, seg_start, live,
-                         cap, frame_lo, frame_hi):
-        """RANGE frame bounds as sorted-row positions via rank search.
-
-        Reference: cuDF range-window support behind GpuWindowExec.  For
-        each row with order value v the frame covers rows of its
-        partition with value in [v+lo, v+hi] (direction-corrected for
-        DESC).  Computed without per-row loops: encode values as
-        order-preserving uint64 words, rank every row's word in the
-        batch-wide sorted word array, and binary-search composite
-        (segment, rank) keys — all vectorized searchsorted.
-        """
-        order = spec.order_by[0]
+    def _range_bounds(self, batch, wspec, spec: _Spec, frame_lo, frame_hi):
+        """A RANGE frame's offsets (scaled for a decimal order key) and
+        its (first, last) sorted positions per sorted row."""
+        order = wspec.order_by[0]
         odt = order.expr.dtype()
         if isinstance(odt, T.DecimalType):
             # decimal order key: data is unscaled int64, so literal
@@ -382,210 +331,229 @@ class TpuWindow(TpuExec):
                 int(round(frame_lo * sf))
             frame_hi = None if frame_hi is None else \
                 int(round(frame_hi * sf))
-        ocol = ec.eval_as_column(order.expr.bind(batch.schema), batch)
-        vals_sorted = jnp.take(ocol.data, perm).astype(jnp.int64)
-        ovalid = jnp.take(ocol.validity, perm) & live
+        ocol = spec.ocols[0]
+        ascending, nulls_first = order.ascending, \
+            order.effective_nulls_first
 
-        def enc(x):
-            w = canon._ints_to_words(x, 64)
-            return ~w if not order.ascending else w
+        def _bounds(p, odata, ovalid):
+            return _range_positions(p, odata, ovalid, ascending,
+                                    nulls_first, frame_lo, frame_hi)
 
-        words = jnp.where(ovalid, enc(vals_sorted),
-                          jnp.uint64(0xFFFFFFFFFFFFFFFF))
-        v_sorted = jnp.sort(words)
-        lo_off = jnp.int64(0 if frame_lo is None else frame_lo)
-        hi_off = jnp.int64(0 if frame_hi is None else frame_hi)
-        if order.ascending:
-            t1, t2 = vals_sorted + lo_off, vals_sorted + hi_off
-        else:
-            # DESC: "preceding" rows hold LARGER values, so the value
-            # interval flips to [v - hi, v - lo] (Spark range semantics)
-            t1, t2 = vals_sorted - hi_off, vals_sorted - lo_off
-        e1 = enc(t1)
-        e2 = enc(t2)
-        wlo = jnp.minimum(e1, e2)
-        whi = jnp.maximum(e1, e2)
-        r_lo = jnp.searchsorted(v_sorted, wlo, side="left")
-        r_hi = jnp.searchsorted(v_sorted, whi, side="right")
-        # composite (seg, rank) keys: valid rows at 1+rank, null-order
-        # rows pinned to the null end of their segment
-        BIG = jnp.int64(1) << jnp.int64(33)
-        nulls_first = order.effective_nulls_first
-        null_slot = jnp.int64(0) if nulls_first else BIG - 1
-        rank_row = jnp.where(
-            ovalid,
-            1 + jnp.searchsorted(v_sorted, words, side="left"), null_slot)
-        C = seg.astype(jnp.int64) * BIG + rank_row.astype(jnp.int64)
-        # padding rows past num_rows sort AFTER every live row: pin their
-        # composite to +inf or the searchsorted precondition breaks
-        C = jnp.where(live, C, jnp.int64(2 ** 62))
-        seg64 = seg.astype(jnp.int64)
-        t_lo = jnp.where(ovalid, seg64 * BIG + 1 + r_lo,
-                         seg64 * BIG + null_slot)
-        t_hi = jnp.where(ovalid, seg64 * BIG + 1 + r_hi,
-                         seg64 * BIG + null_slot + 1)
-        lo_pos = jnp.searchsorted(C, t_lo, side="left")
-        hi_pos = jnp.searchsorted(C, t_hi, side="left") - 1
-        # unbounded ends widen to the partition
-        seg_start_pos = jnp.take(seg_start, seg)
-        seg_len = jax.ops.segment_sum(
-            jnp.ones(cap, jnp.int64), seg, num_segments=cap)
-        seg_end_pos = seg_start_pos + jnp.take(seg_len, seg) - 1
-        if frame_lo is None:
-            lo_pos = seg_start_pos
-        if frame_hi is None:
-            hi_pos = seg_end_pos
-        lo_pos = jnp.maximum(lo_pos, seg_start_pos)
-        hi_pos = jnp.minimum(hi_pos, seg_end_pos)
-        return lo_pos, hi_pos
+        fn = self._program(
+            ("frame_bounds", ascending, nulls_first, frame_lo, frame_hi),
+            lambda: _compile_watch.jit(_bounds, "window_frame_bounds"))
+        return frame_lo, frame_hi, fn(spec.parts, ocol.data, ocol.validity)
 
-    def _frame_agg(self, func, sv, sok, seg, row_in_seg, seg_start, cap,
-                   lo: Optional[int], hi: Optional[int],
-                   lo_pos=None, hi_pos=None,
-                   lo_unbounded: bool = False,
-                   hi_unbounded: bool = False):
-        """Frame [lo, hi] row offsets, or explicit positions
-        (lo_pos/hi_pos from a RANGE frame)."""
-        pos = jnp.arange(cap, dtype=jnp.int64)
-        explicit = lo_pos is not None
-        if isinstance(func, (eagg.Sum, eagg.Count, eagg.Average)):
-            acc_dtype = jnp.float64 if not isinstance(func, eagg.Count) \
-                else jnp.int64
-            contrib = jnp.where(sok, sv.astype(acc_dtype)
-                                if not isinstance(func, eagg.Count)
-                                else jnp.ones(cap, jnp.int64),
-                                jnp.zeros(cap, acc_dtype))
-            ps = jnp.cumsum(contrib)          # inclusive prefix sum
-            cnt = jnp.cumsum(sok.astype(jnp.int64))
-            seg_start_pos, seg_end_pos = self._seg_extents(
-                seg, seg_start, cap)
-            if not explicit:
-                lo_pos = seg_start_pos if lo is None else \
-                    jnp.maximum(pos + lo, seg_start_pos)
-                hi_pos = seg_end_pos if hi is None else \
-                    jnp.minimum(pos + hi, seg_end_pos)
-            hi_c = jnp.clip(hi_pos, 0, cap - 1).astype(jnp.int32)
-            lo_c = jnp.clip(lo_pos - 1, -1, cap - 1)
-            ps_hi = jnp.take(ps, hi_c)
-            ps_lo = jnp.where(lo_c < 0, 0,
-                              jnp.take(ps, jnp.maximum(lo_c, 0)))
-            cnt_hi = jnp.take(cnt, hi_c)
-            cnt_lo = jnp.where(lo_c < 0, 0,
-                               jnp.take(cnt, jnp.maximum(lo_c, 0)))
-            s = ps_hi - ps_lo
-            c = cnt_hi - cnt_lo
-            empty = hi_pos < lo_pos
-            if isinstance(func, eagg.Count):
-                return jnp.where(empty, 0, c), jnp.ones(cap, bool)
-            if isinstance(func, eagg.Average):
-                return s / jnp.maximum(c, 1), (c > 0) & ~empty
-            return s, (c > 0) & ~empty
-        if isinstance(func, (eagg.Min, eagg.Max)) and lo is None and \
-                hi == 0:
-            # running min/max: segmented inclusive scan
-            is_min = isinstance(func, eagg.Min)
-            ident = self._minmax_ident(is_min, sv.dtype)
-            x = jnp.where(sok, sv, ident)
-            reset = row_in_seg == 0
+    # ------------------------------------------------------------------
+    def _collect(self, batch, func, wspec, spec: _Spec) -> Column:
+        """collect_list over a window frame -> ListColumn.
 
-            def combine(a, b):
-                av, ar = a
-                bv, br = b
-                merged = jnp.where(br, bv,
-                                   jnp.minimum(av, bv) if is_min
-                                   else jnp.maximum(av, bv))
-                return merged, ar | br
-            scanned, _ = jax.lax.associative_scan(combine, (x, reset))
-            cnt = jnp.cumsum(sok.astype(jnp.int64))
-            seg_start_pos = jnp.take(seg_start, seg)
-            cnt_before = jnp.where(
-                seg_start_pos > 0,
-                jnp.take(cnt, jnp.clip(seg_start_pos - 1, 0, cap - 1)), 0)
-            has = (cnt - cnt_before) > 0
-            return scanned, has
-        if isinstance(func, (eagg.Min, eagg.Max)):
-            is_min = isinstance(func, eagg.Min)
-            ident = self._minmax_ident(is_min, sv.dtype)
-            seg_start_pos, seg_end_pos = self._seg_extents(
-                seg, seg_start, cap)
-            x = jnp.where(sok, sv, ident)
-            comb = jnp.minimum if is_min else jnp.maximum
+        Elements come from the globally valid-compacted sorted rows:
+        row i's list is vpos[c_lo_i .. c_hi_i) where cnt is the prefix
+        count of valid sorted rows: one running sum and one expand, no
+        per-row loops (GpuWindowExpression collect_list role).  The
+        spans are one program; the expansion is sized by a host pull."""
+        from ..columnar.column import ListColumn, bucket_capacity
+        from ..kernels import basic as bk
+        from ..kernels import join as join_k
+        cap = batch.capacity
+        src = ec.eval_as_column(func.children[0].bind(batch.schema),
+                                batch)
+        _trace.count("window.bytes", src.nbytes())
+        frame_kind, frame_lo, frame_hi = wspec.frame
+        bounds = None
+        if (frame_lo is None and frame_hi is None) or not wspec.order_by:
+            frame_lo = frame_hi = None
+        elif frame_kind == "range":
+            frame_lo, frame_hi, bounds = self._range_bounds(
+                batch, wspec, spec, frame_lo, frame_hi)
+        explicit = bounds is not None
 
-            def seg_scan(values, reverse=False):
-                reset = (row_in_seg == 0) if not reverse else \
-                    (pos == seg_end_pos)
-                v = values[::-1] if reverse else values
-                r = reset[::-1] if reverse else reset
-
-                def combine(a, b):
-                    av, ar = a
-                    bv, br = b
-                    return jnp.where(br, bv, comb(av, bv)), ar | br
-                scanned, _ = jax.lax.associative_scan(combine, (v, r))
-                return scanned[::-1] if reverse else scanned
-            if not explicit:
-                lo_pos = seg_start_pos if lo is None else \
-                    jnp.maximum(pos + lo, seg_start_pos)
-                hi_pos = seg_end_pos if hi is None else \
-                    jnp.minimum(pos + hi, seg_end_pos)
-            if (not explicit and (lo is None or hi is None)) or \
-                    (explicit and (lo_unbounded or hi_unbounded)):
-                # half-unbounded frame (ROWS offsets or RANGE with one
-                # unbounded side): one segmented scan + a gather,
-                # O(cap) memory, no host sync (no sparse table needed)
-                if lo is None if not explicit else lo_unbounded:
-                    scanned = seg_scan(x)            # prefix from start
-                    vals = jnp.take(scanned,
-                                    jnp.clip(hi_pos, 0, cap - 1))
-                else:
-                    scanned = seg_scan(x, reverse=True)  # suffix to end
-                    vals = jnp.take(scanned,
-                                    jnp.clip(lo_pos, 0, cap - 1))
+        def _collect_plan(p, valid, bounds, num_rows):
+            cap = p.perm.shape[0]
+            pos = jnp.arange(cap, dtype=jnp.int64)
+            start, end = p.seg_start.astype(jnp.int64), \
+                p.seg_end.astype(jnp.int64)
+            if explicit:
+                lo_pos, hi_pos = bounds
             else:
-                # general bounded frame: log-doubling range-min/max
-                # table; range [l, r] = combine of the two overlapping
-                # 2^k blocks at its ends (sparse-table RMQ).  Levels
-                # stop at the widest frame actually present.
-                if not explicit:
-                    max_window = max(hi - lo + 1, 1)
-                else:
-                    # RANGE frame: one host sync learns the widest window
-                    from ..analysis import residency  # lazy import
-                    with residency.declared_transfer(site="size_probe"):
-                        max_window = max(
-                            int(jnp.max(hi_pos - lo_pos + 1)), 1)
-                tables = [x]
-                step = 1
-                while step < max_window:
-                    prev = tables[-1]
-                    shifted = jnp.concatenate(
-                        [prev[step:], jnp.full(step, ident, prev.dtype)])
-                    tables.append(comb(prev, shifted))
-                    step *= 2
-                rmq = jnp.stack(tables)            # [levels, cap]
-                length = jnp.maximum(hi_pos - lo_pos + 1, 0)
-                # k = floor(log2(length)) via static comparisons (no
-                # float log on the emulated-f64 chip); 2^k <= length
-                k = jnp.zeros(cap, jnp.int32)
-                for j in range(1, len(tables)):
-                    k = jnp.where(length >= (1 << j), j, k)
-                k = jnp.minimum(k, len(tables) - 1)
-                two_k = jnp.left_shift(jnp.int64(1),
-                                       k.astype(jnp.int64))
-                a_idx = jnp.clip(lo_pos, 0, cap - 1)
-                b_idx = jnp.clip(hi_pos - two_k + 1, 0, cap - 1)
-                flat = rmq.reshape(-1)
-                a = jnp.take(flat, k.astype(jnp.int64) * cap + a_idx)
-                b = jnp.take(flat, k.astype(jnp.int64) * cap + b_idx)
-                vals = comb(a, b)
-            cnt = jnp.cumsum(sok.astype(jnp.int64))
+                lo_pos = start if frame_lo is None else \
+                    jnp.maximum(pos + frame_lo, start)
+                hi_pos = end if frame_hi is None else \
+                    jnp.minimum(pos + frame_hi, end)
+            ok = jnp.take(valid, p.perm) & p.live
+            cnt = prefix_sum(ok.astype(jnp.int64))
             hi_c = jnp.clip(hi_pos, 0, cap - 1).astype(jnp.int32)
             lo_c = jnp.clip(lo_pos - 1, -1, cap - 1)
-            cnt_hi = jnp.take(cnt, hi_c)
-            cnt_lo = jnp.where(lo_c < 0, 0,
-                               jnp.take(cnt, jnp.maximum(lo_c, 0)))
-            has = (cnt_hi - cnt_lo) > 0
-            empty = hi_pos < lo_pos
-            return vals, has & ~empty
-        raise NotImplementedError(
-            f"window frame ({lo},{hi}) for {func.name}")
+            c_hi = jnp.take(cnt, hi_c)
+            c_lo = jnp.where(lo_c < 0, 0,
+                             jnp.take(cnt, jnp.maximum(lo_c, 0)))
+            m_sorted = jnp.where(hi_pos < lo_pos, 0, c_hi - c_lo)
+            m_orig = jnp.where(jnp.arange(cap) < num_rows,
+                               jnp.take(m_sorted, p.inv), 0)
+            offsets = jnp.concatenate(
+                [jnp.zeros(1, jnp.int64),
+                 prefix_sum(m_orig)]).astype(jnp.int32)
+            return (ok, jnp.take(c_lo, p.inv).astype(jnp.int32),
+                    m_orig.astype(jnp.int32), offsets, jnp.sum(m_orig))
+
+        fn = self._program(
+            ("collect_plan", frame_lo, frame_hi, explicit),
+            lambda: _compile_watch.jit(_collect_plan,
+                                       "window_collect_plan"))
+        ok, c_lo, m_orig, offsets, total = fn(
+            spec.parts, src.validity, bounds, batch.rows_dev)
+        sorted_src = src.gather(spec.parts.perm)
+        vpos, _ = bk.filter_compact_indices(ok, cap)
+        from ..analysis import residency  # lazy: avoids import cycle
+        with residency.declared_transfer(site="size_probe"):
+            total = int(total)
+        out_cap = bucket_capacity(max(total, 1))
+        _, elem_pos, live_e, _ = join_k.join_expand_matches(
+            c_lo, m_orig, vpos.astype(jnp.int32), out_cap)
+        elements = sorted_src.gather(elem_pos)
+        elements = elements.mask_validity(live_e)
+        return ListColumn(T.ArrayType(src.dtype), offsets, elements,
+                          jnp.arange(cap) < batch.rows_dev)
+
+
+# ---------------------------------------------------------------------------
+# traced bodies of the frame programs
+# ---------------------------------------------------------------------------
+
+def _range_positions(p: wk.SortedPartitions, odata, ovalid, ascending: bool,
+                     nulls_first: bool, frame_lo, frame_hi):
+    """RANGE frame bounds as sorted-row positions via rank search.
+
+    Reference: cuDF range-window support behind GpuWindowExec.  For
+    each row with order value v the frame covers rows of its
+    partition with value in [v+lo, v+hi] (direction-corrected for
+    DESC).  Computed without per-row loops: encode values as
+    order-preserving uint64 words, rank every row's word in the
+    batch-wide sorted word array, and binary-search composite
+    (segment, rank) keys — all vectorized searchsorted.
+    """
+    live = p.live
+    vals_sorted = jnp.take(odata, p.perm).astype(jnp.int64)
+    ovalid = jnp.take(ovalid, p.perm) & live
+
+    def enc(x):
+        w = canon._ints_to_words(x, 64)
+        return ~w if not ascending else w
+
+    words = jnp.where(ovalid, enc(vals_sorted),
+                      jnp.uint64(0xFFFFFFFFFFFFFFFF))
+    v_sorted = jnp.sort(words)
+    lo_off = jnp.int64(0 if frame_lo is None else frame_lo)
+    hi_off = jnp.int64(0 if frame_hi is None else frame_hi)
+    if ascending:
+        t1, t2 = vals_sorted + lo_off, vals_sorted + hi_off
+    else:
+        # DESC: "preceding" rows hold LARGER values, so the value
+        # interval flips to [v - hi, v - lo] (Spark range semantics)
+        t1, t2 = vals_sorted - hi_off, vals_sorted - lo_off
+    e1 = enc(t1)
+    e2 = enc(t2)
+    wlo = jnp.minimum(e1, e2)
+    whi = jnp.maximum(e1, e2)
+    r_lo = jnp.searchsorted(v_sorted, wlo, side="left")
+    r_hi = jnp.searchsorted(v_sorted, whi, side="right")
+    # composite (seg, rank) keys: valid rows at 1+rank, null-order
+    # rows pinned to the null end of their segment
+    BIG = jnp.int64(1) << jnp.int64(33)
+    null_slot = jnp.int64(0) if nulls_first else BIG - 1
+    rank_row = jnp.where(
+        ovalid,
+        1 + jnp.searchsorted(v_sorted, words, side="left"), null_slot)
+    # a partition's number: any value that grows with the partition
+    seg64 = p.seg_start.astype(jnp.int64)
+    C = seg64 * BIG + rank_row.astype(jnp.int64)
+    # padding rows past num_rows sort AFTER every live row: pin their
+    # composite to +inf or the searchsorted precondition breaks
+    C = jnp.where(live, C, jnp.int64(2 ** 62))
+    t_lo = jnp.where(ovalid, seg64 * BIG + 1 + r_lo,
+                     seg64 * BIG + null_slot)
+    t_hi = jnp.where(ovalid, seg64 * BIG + 1 + r_hi,
+                     seg64 * BIG + null_slot + 1)
+    lo_pos = jnp.searchsorted(C, t_lo, side="left")
+    hi_pos = jnp.searchsorted(C, t_hi, side="left") - 1
+    # unbounded ends widen to the partition
+    start, end = p.seg_start.astype(jnp.int64), p.seg_end.astype(jnp.int64)
+    if frame_lo is None:
+        lo_pos = start
+    if frame_hi is None:
+        hi_pos = end
+    return jnp.maximum(lo_pos, start), jnp.minimum(hi_pos, end)
+
+
+def _frame_aggregate(p: wk.SortedPartitions, kind: str, sv, sok,
+                     lo: Optional[int], hi: Optional[int], bounds,
+                     lo_unbounded: bool, hi_unbounded: bool, levels: int):
+    """An aggregate over the frame [lo, hi] row offsets, or over the
+    explicit positions ``bounds`` of a RANGE frame, per sorted row."""
+    cap = sv.shape[0]
+    pos = jnp.arange(cap, dtype=jnp.int64)
+    start, end = p.seg_start.astype(jnp.int64), p.seg_end.astype(jnp.int64)
+    if bounds is not None:
+        lo_pos, hi_pos = bounds
+    else:
+        lo_pos = start if lo is None else jnp.maximum(pos + lo, start)
+        hi_pos = end if hi is None else jnp.minimum(pos + hi, end)
+    hi_c = jnp.clip(hi_pos, 0, cap - 1).astype(jnp.int32)
+    lo_c = jnp.clip(lo_pos - 1, -1, cap - 1)
+    empty = hi_pos < lo_pos
+
+    def between(running):
+        """running[hi] - running[lo - 1] of an inclusive running sum."""
+        return jnp.take(running, hi_c) - jnp.where(
+            lo_c < 0, 0, jnp.take(running, jnp.maximum(lo_c, 0)))
+
+    c = between(prefix_sum(sok.astype(jnp.int64)))
+    if kind == "count":
+        return jnp.where(empty, 0, c), jnp.ones(cap, bool)
+    if kind in ("sum", "avg"):
+        s = between(prefix_sum(jnp.where(sok, sv.astype(jnp.float64), 0.0)))
+        if kind == "avg":
+            s = s / jnp.maximum(c, 1)
+        return s, (c > 0) & ~empty
+    want_max = kind == "max"
+    ident = wk.extreme_of(sv.dtype, want_max)
+    comb = jnp.maximum if want_max else jnp.minimum
+    x = jnp.where(sok, sv, ident)
+    if lo_unbounded or hi_unbounded:
+        # half-unbounded frame (a running min/max among them): one
+        # segmented scan from the partition's open end and a gather
+        if lo_unbounded:
+            scanned = wk.seg_scan(x, p.seg_first, comb)
+            vals = jnp.take(scanned, hi_c)
+        else:
+            is_last = pos == end
+            scanned = wk.seg_scan(x, is_last, comb, reverse=True)
+            vals = jnp.take(scanned, jnp.clip(lo_pos, 0, cap - 1))
+    else:
+        # general bounded frame: log-doubling range-min/max table;
+        # range [l, r] = combine of the two overlapping 2^k blocks at
+        # its ends (sparse-table RMQ), ``levels`` deep
+        tables = [x]
+        step = 1
+        for _ in range(levels):
+            prev = tables[-1]
+            shifted = jnp.concatenate(
+                [prev[step:], jnp.full(step, ident, prev.dtype)])
+            tables.append(comb(prev, shifted))
+            step *= 2
+        rmq = jnp.stack(tables)            # [levels + 1, cap]
+        length = jnp.maximum(hi_pos - lo_pos + 1, 0)
+        # k = floor(log2(length)) via static comparisons (no float
+        # log on the emulated-f64 chip); 2^k <= length
+        k = jnp.zeros(cap, jnp.int32)
+        for j in range(1, len(tables)):
+            k = jnp.where(length >= (1 << j), j, k)
+        two_k = jnp.left_shift(jnp.int64(1), k.astype(jnp.int64))
+        a_idx = jnp.clip(lo_pos, 0, cap - 1)
+        b_idx = jnp.clip(hi_pos - two_k + 1, 0, cap - 1)
+        flat = rmq.reshape(-1)
+        a = jnp.take(flat, k.astype(jnp.int64) * cap + a_idx)
+        b = jnp.take(flat, k.astype(jnp.int64) * cap + b_idx)
+        vals = comb(a, b)
+    return vals, (c > 0) & ~empty

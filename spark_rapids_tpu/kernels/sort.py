@@ -58,8 +58,38 @@ def lsd_pass(key, perm):
     return jnp.take(perm, sort_stable_pair(jnp.take(key, perm)))
 
 
+#: inside a traced program a chain of this many passes or more runs as
+#: a loop (``_lsd_loop``); shorter chains stay unrolled, as every
+#: program compiled before PR 34 has them
+ROLL_FROM = 8
+
+
+def _lsd_loop(words: List[jnp.ndarray]) -> jnp.ndarray:
+    """The LSD chain as ONE loop over the stacked words.  Inside a
+    traced program the nested ``sort_stable_pair`` is inlined once a
+    pass, and the chip's compiler emits each sort's code again: a core
+    that sorts 16 key words at 2^18 slots came to 66.8 MB of code
+    against 4.7 MB for this loop (the same 31-33 s to compile), fourteen
+    such cores overran the machine's 192 MiB persistent compile cache,
+    and every run of ``tpcds_sf1_olap.power`` compiled for 1,000 s
+    (PERF.md section 6, PR 34).  The first pass gathers by the identity:
+    one take more than the unrolled chain.  Every word gets its pass,
+    one that every row shares too (the zero words of a key a grouping
+    set rolled up): a ``lax.cond`` round the pass to skip those read
+    33% slower on the chip for every pass it did not skip
+    (``jit_window_plan`` 0.905 -> 1.207 s; PERF.md section 6, PR 34)."""
+    n = words[0].shape[0]
+    stacked = jnp.stack([w.astype(jnp.uint64) for w in reversed(words)])
+
+    def one_pass(perm, key):
+        return lsd_pass(key, perm), None
+    perm, _ = lax.scan(one_pass, jnp.arange(n, dtype=jnp.int32), stacked)
+    return perm
+
+
 @jax.named_scope("sort_permutation")
-def sort_permutation(words: List[jnp.ndarray]) -> jnp.ndarray:
+def sort_permutation(words: List[jnp.ndarray],
+                     roll_from: int = ROLL_FROM) -> jnp.ndarray:
     """Stable ascending sort over word tuples; returns permutation indices."""
     if len(words) == 1:
         w = words[0]
@@ -67,18 +97,21 @@ def sort_permutation(words: List[jnp.ndarray]) -> jnp.ndarray:
             w = w.astype(jnp.uint64)
         return sort_stable_pair(w)
     # LSD: least-significant word first; stability makes later (more
-    # significant) passes dominate
+    # significant) passes dominate.  Run eagerly, every pass launches
+    # the one compiled pair sort of its capacity: nothing to roll.
+    if len(words) >= roll_from and isinstance(words[0], jax.core.Tracer):
+        return _lsd_loop(words)
     perm = None
     for w in reversed(words):
         perm = lsd_pass(w.astype(jnp.uint64), perm)
     return perm
 
 
-def sorted_words(words: List[jnp.ndarray]):
+def sorted_words(words: List[jnp.ndarray], roll_from: int = ROLL_FROM):
     """Sort and also return the sorted word arrays (for boundary detection)."""
     if len(words) == 1:
         # one word: the sort hands back the sorted key beside the rows
         key, perm = stable_sort_rows(words[0])
         return [key], perm
-    perm = sort_permutation(words)
+    perm = sort_permutation(words, roll_from)
     return [jnp.take(w, perm) for w in words], perm

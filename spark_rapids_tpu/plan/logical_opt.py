@@ -68,6 +68,18 @@ def _or_all(disjuncts: List[ec.Expression]) -> ec.Expression:
     return out
 
 
+def _same_as(e: ec.Expression):
+    """A key two expressions share only when they are the same
+    expression.  ``repr`` is not one: it leaves out what a node holds
+    besides its children (``In``'s value list, a LIKE pattern, a cast's
+    type), so ``c in ('a') and P or c in ('b') and Q`` read as two arms
+    with ``c in (...)`` in common and lost both lists (TPC-DS q89's two
+    category / class triples; found by PR 34's plain reference)."""
+    state = tuple(sorted((k, repr(v)) for k, v in vars(e).items()
+                         if k != "children"))
+    return (type(e).__name__, state, tuple(_same_as(c) for c in e.children))
+
+
 def _factor_or(e: ec.Expression) -> List[ec.Expression]:
     """Factor conjuncts common to every OR arm out of the disjunction:
     ``(A and B) or (A and C)  ->  A and (B or C)``.
@@ -80,19 +92,19 @@ def _factor_or(e: ec.Expression) -> List[ec.Expression]:
     if len(disjuncts) < 2:
         return [e]
     conj_lists = [_flatten_and(d) for d in disjuncts]
-    first_keys = {repr(c): c for c in conj_lists[0]}
+    first_keys = {_same_as(c): c for c in conj_lists[0]}
     common_keys = [k for k in first_keys
-                   if all(any(repr(x) == k for x in cl)
+                   if all(any(_same_as(x) == k for x in cl)
                           for cl in conj_lists[1:])]
     if not common_keys:
         return [e]
     common_set = set(common_keys)
     remainders = []
     for cl in conj_lists:
-        removed: Set[str] = set()
+        removed: Set[tuple] = set()
         rem = []
         for x in cl:
-            rx = repr(x)
+            rx = _same_as(x)
             if rx in common_set and rx not in removed:
                 removed.add(rx)
                 continue

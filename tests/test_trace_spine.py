@@ -18,7 +18,7 @@ from spark_rapids_tpu.obs import compile_watch, flight, trace
 
 ROOT = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "spark_rapids_tpu")
-PREFIXES = ("agg_", "join_", "sort_", "filter_", "hash_", "scan_",
+PREFIXES = ("agg_", "join_", "sort_", "filter_", "hash_", "scan_", "window_",
             "staged_", "fused_", "batch_", "partition_", "pending_",
             "str_", "list_", "mesh_", "stats_")
 BANNED = {"_core", "_eval", "_prog", "_slice", "_concat"}
@@ -284,11 +284,12 @@ class TestCoarseSpans:
         # scan.* with every file scan, agg.* with every aggregate,
         # str.* with every string key packed or gathered string laid
         # out, exchange.* with every
-        # in-process shuffle
+        # in-process shuffle, window.* with every window operator and
+        # expand.* with every grouping set
         for tbl in counts.values():
             assert all(k.startswith(("eager.", "jit_build.", "join.",
                                      "scan.", "agg.", "str.",
-                                     "exchange."))
+                                     "exchange.", "window.", "expand."))
                        and v > 0 for k, v in tbl.items())
         assert any(k.startswith("eager.")
                    for tbl in counts.values() for k in tbl)
