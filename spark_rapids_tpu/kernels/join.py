@@ -6,7 +6,12 @@ TPU-first: instead of cuDF's GPU hash table build+probe, the build side is
 sorted by canonical key words and every probe row runs a vectorized binary
 search (lower/upper bound) — O(log n) integer compares per row, fully
 static-shape, no data-dependent control flow.  Match expansion ("gather
-maps") is a cumsum + searchsorted expansion with host-sized output capacity,
+maps") is one pass over the output rows: each probe row scatters a +1 at
+its first output row and a running sum (``kernels/basic.prefix_sum``) hands
+every output row its probe row; its build position comes the same way (the
+step of the row's shift scattered, a second running sum) or by one gather
+through the probe row, whichever costs fewer indices at the launch's
+shapes; one gather reads ``perm``.  The output capacity is host-sized,
 playing the JoinGatherer role of bounding output batch size.
 """
 from __future__ import annotations
@@ -19,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs import trace as _obs_trace
 from . import canon
 from .basic import prefix_sum
 from .sort import sorted_words
@@ -99,23 +105,72 @@ def probe_counts(bt: BuildTable, probe_words: List[jnp.ndarray],
     return JoinCounts(lo, counts, counts > 0)
 
 
+def _count_expand(program):
+    """Count each eager launch of the program, the probe rows it
+    scatters and the output lanes it gathers over; under a ``jax.jit``
+    trace nothing is launched and nothing is counted
+    (``obs/trace.count_eager``)."""
+    @functools.wraps(program)
+    def launch(lo, counts, perm, out_cap: int):
+        _obs_trace.count_eager("join.expand.launches", counts)
+        _obs_trace.count_eager("join.expand.probe_rows", counts,
+                               counts.shape[0])
+        _obs_trace.count_eager("join.expand.out_lanes", counts, out_cap)
+        return program(lo, counts, perm, out_cap)
+    return launch
+
+
+@_count_expand
 @functools.partial(jax.jit, static_argnames=("out_cap",))
 def join_expand_matches(lo, counts, perm, out_cap: int):
     """Expand (lo, counts) into flat (probe_idx, build_idx) gather maps.
 
-    Output row t belongs to probe row p where exclusive-cumsum[p] <= t <
-    inclusive-cumsum[p]; its build position is lo[p] + (t - excl[p]).
-    """
+    Output row t belongs to probe row p where exclusive-sum[p] <= t <
+    inclusive-sum[p]; its build position is lo[p] + (t - excl[p]).
+
+    Linear in probe rows + output rows: the output rows of a probe row
+    are a run, so both maps are piecewise.  Each probe row scatters a +1
+    at its first output row ``excl[p]`` (rows with no match share a lane
+    with the next matched row) and a running sum over the output rows
+    gives ``p + 1``.  The build position is ``t + shift[p]`` with
+    ``shift = lo - excl``, by whichever costs fewer indices: with fewer
+    probe rows than output rows the step ``shift[p] - shift[p - 1]`` is
+    scattered the same way (``lo`` need not be sorted, the step is
+    signed; the steps of rows that share a lane add up) and a second
+    running sum hands each output row its shift; otherwise each output
+    row gathers its shift by ``p``.  The chip pays per scattered or
+    gathered index, 7-9 ns, and next to nothing for a running sum, so
+    the binary search an output row this replaces cost eight to thirty
+    times either.  Neither wins at every shape (PERF.md section 5, PR
+    35): the gather is 34% ahead at 2^20 probe rows into 2^18 output
+    rows and 4-10% at equal counts, the scatter 13% ahead at 2^17 into
+    2^18 and 40% at 2^16 into 2^20, so the shapes the program is traced
+    with decide.  ``total`` is the exact int64 sum even past
+    ``out_cap``; a row whose first output row is at or past ``out_cap``
+    is dropped by the scatter.  On dead lanes (``t >= total``) both maps
+    stay in range and mean nothing."""
+    n = counts.shape[0]
     incl = prefix_sum(counts.astype(jnp.int64))
     excl = incl - counts
     total = incl[-1]
-    t = jnp.arange(out_cap, dtype=jnp.int64)
-    p = jnp.searchsorted(incl, t, side="right").astype(jnp.int32)
-    pc = jnp.clip(p, 0, counts.shape[0] - 1)
-    build_pos = jnp.take(lo, pc) + (t - jnp.take(excl, pc)).astype(jnp.int32)
-    build_pos = jnp.clip(build_pos, 0, perm.shape[0] - 1)
+    first = jnp.minimum(excl, out_cap).astype(jnp.int32)
+    lanes = jnp.zeros(out_cap, jnp.int32)
+    rows = prefix_sum(lanes.at[first].add(
+        1, indices_are_sorted=True, mode="drop"))
+    pc = jnp.clip(rows - 1, 0, n - 1)
+    # int32 wrap-around cancels: a live lane's shift is its own row's,
+    # which fits, and the steps telescope to it
+    shift = lo.astype(jnp.int32) - excl.astype(jnp.int32)
+    if n >= out_cap:
+        lane_shift = jnp.take(shift, pc)
+    else:
+        step = shift - jnp.concatenate([jnp.zeros(1, jnp.int32), shift[:-1]])
+        lane_shift = prefix_sum(lanes.at[first].add(
+            step, indices_are_sorted=True, mode="drop"))
+    t = jnp.arange(out_cap, dtype=jnp.int32)
+    build_pos = jnp.clip(t + lane_shift, 0, perm.shape[0] - 1)
     build_idx = jnp.take(perm, build_pos)
-    live = t < total
+    live = t < jnp.minimum(total, out_cap).astype(jnp.int32)
     return pc, build_idx, live, total
 
 
