@@ -351,7 +351,7 @@ def declared_transfer(site: str):
     The guard lift is dynamic (thread-local), so pulls in callees are
     covered too.  The region is the coarse span ``srt.pull`` (args:
     ``site``; obs/trace.py): the host blocked on the device and the
-    copy back.
+    copy back; the query's counter ``pull.<site>`` (+1) counts it.
     """
     spec = SITES.get(site)
     if spec is None:
@@ -366,6 +366,7 @@ def declared_transfer(site: str):
     import jax
     from ..obs import trace as _trace
     _TLS.allow = getattr(_TLS, "allow", 0) + 1
+    _trace.count("pull." + site)
     try:
         with _trace.span("srt.pull", "pool", True, site=site), \
                 jax.transfer_guard_device_to_host("allow"):

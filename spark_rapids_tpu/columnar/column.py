@@ -214,12 +214,12 @@ class Column:
         ``live``/``unique`` are sizing hints for variable-width columns
         (kernels/strings.py gather_strings); fixed-width gathers ignore
         them."""
-        _trace.count_eager("eager.column_gather", indices, 2)
-        valid = jnp.take(self.validity, indices, axis=0, mode="clip")
+        with _trace.launch("column_gather", 2, 2 * indices.shape[0]):
+            valid = jnp.take(self.validity, indices, axis=0, mode="clip")
+            data = jnp.take(self.data, indices, axis=0, mode="clip")
         if live is not None:
             valid = valid & live
-        return Column(self.dtype, jnp.take(self.data, indices, axis=0,
-                                           mode="clip"), valid)
+        return Column(self.dtype, data, valid)
 
     def mask_validity(self, keep_mask) -> "Column":
         return Column(self.dtype, self.data, self.validity & keep_mask)
@@ -321,8 +321,8 @@ class StringColumn(Column):
         # aggregate's 1000x row reduction never materializes the
         # intermediate gigabytes (and never pays its sizing sync) —
         # the cuDF-style dictionary/gather-map trick.
-        _trace.count_eager("eager.string_gather", indices)
-        valid = jnp.take(self.validity, indices, axis=0, mode="clip")
+        with _trace.launch("string_gather", 1, indices.shape[0]):
+            valid = jnp.take(self.validity, indices, axis=0, mode="clip")
         if live is not None:
             valid = valid & live
         src_idx = jnp.clip(indices, 0, self.capacity - 1) \
@@ -368,8 +368,8 @@ class GatheredStringColumn(StringColumn):
             if src._mat is not None:
                 src = src._mat
                 continue
-            _trace.count_eager("eager.string_gather_compose", idx)
-            idx = jnp.take(src.idx, idx, axis=0, mode="clip")
+            with _trace.launch("string_gather_compose", 1, idx.shape[0]):
+                idx = jnp.take(src.idx, idx, axis=0, mode="clip")
             # a composed map repeats source rows unless EVERY stage was
             # repeat-free
             unique = unique and src._unique
@@ -629,12 +629,12 @@ class StructColumn(Column):
 
     def gather(self, indices, live=None,
                unique=False) -> "StructColumn":
-        _trace.count_eager("eager.struct_gather", indices)
+        with _trace.launch("struct_gather", 1, indices.shape[0]):
+            valid = jnp.take(self.validity, indices, axis=0, mode="clip")
         return StructColumn(
             self.dtype,
             [c.gather(indices, live=live, unique=unique)
-             for c in self.children],
-            jnp.take(self.validity, indices, axis=0, mode="clip"))
+             for c in self.children], valid)
 
     def mask_validity(self, keep_mask) -> "StructColumn":
         return StructColumn(self.dtype, self.children,

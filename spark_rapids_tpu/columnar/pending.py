@@ -101,6 +101,7 @@ def stage(dev) -> Staged:
 # jit dispatch covers the whole encode, so per-item encode work must
 # never run eagerly.
 
+@_trace.launched()
 @jax.jit
 def pending_enc_bytes(x):
     """u8-ish[n] -> u32[ceil(n/4)] little-endian (host unpacks via .view)."""
@@ -117,6 +118,7 @@ def pending_enc_bytes(x):
     return (w[:, 0] | (w[:, 1] << 8) | (w[:, 2] << 16) | (w[:, 3] << 24))
 
 
+@_trace.launched()
 @jax.jit
 def pending_enc_wide16(x):
     x = jnp.ravel(x)
@@ -124,11 +126,13 @@ def pending_enc_wide16(x):
             if np.dtype(x.dtype).kind == "i" else x.astype(jnp.uint32))
 
 
+@_trace.launched()
 @jax.jit
 def pending_enc_u32(x):
     return lax.bitcast_convert_type(jnp.ravel(x), jnp.uint32)
 
 
+@_trace.launched()
 @jax.jit
 def pending_enc_split64(x):
     # 64-bit ints: exact shift/mask split (the chip rejects 64-bit
@@ -140,6 +144,7 @@ def pending_enc_split64(x):
     return lo, hi
 
 
+@_trace.launched()
 @jax.jit
 def pending_enc_f64(x):
     return jnp.ravel(x)

@@ -141,9 +141,10 @@ class timed:
     operator; obs/trace.py), nesting under ``srt.query`` / the service
     attempt span and over flush, jit-build, shuffle and memory spans.
     The span's two clock reads are the metric's: one measurement feeds
-    both."""
+    both.  The node is the thread's operator while the region is open:
+    the launches inside it are counted under its name."""
 
-    __slots__ = ("metric", "name", "_span")
+    __slots__ = ("metric", "name", "_span", "_outer")
 
     def __init__(self, metric: Metric, node: "PhysicalPlan" = None):
         self.metric = metric
@@ -158,9 +159,11 @@ class timed:
         self._span = _trace.Span("srt.exec." + self.name, "exec",
                                  {"metric": self.metric.name}, True)
         self._span.__enter__()
+        self._outer = _trace.operator(self.name)
         return self
 
     def __exit__(self, *a):
+        _trace.operator(self._outer)
         self._span.__exit__(*a)
         self.metric.add(self._span.dur_ns)
         _flight.record(_flight.EV_END, self.name)

@@ -152,8 +152,8 @@ def _output_columns(schema, pairs, string_keys) -> list:
         if src is None:
             cols.append(Column(f.dtype, d, v))
         else:
-            _obs_trace.count_eager("eager.string_gather", d)
-            cols.append(GatheredStringColumn(src, d, v, unique=True))
+            with _obs_trace.launch("string_gather", 1, d.shape[0]):
+                cols.append(GatheredStringColumn(src, d, v, unique=True))
     return cols
 
 
@@ -964,10 +964,11 @@ class TpuHashAggregate(TpuExec):
             # costs the same as single-column; lane sums < 2^31, exact)
             chunk_out = None
             if chunk_rows:
-                _obs_trace.count_eager("eager.table_chunk_scatter", bucket)
-                chunk_out = jax.ops.segment_sum(
-                    jnp.stack(chunk_rows, 1), bucket,
-                    num_segments=table + 1)[:table]
+                with _obs_trace.launch("table_chunk_scatter", 1,
+                                       bucket.shape[0]):
+                    chunk_out = jax.ops.segment_sum(
+                        jnp.stack(chunk_rows, 1), bucket,
+                        num_segments=table + 1)[:table]
             # two-stage u32 min/max: hi words, then lo among hi-winners
             mm1 = mm2 = None
             if mm_hi_rows:

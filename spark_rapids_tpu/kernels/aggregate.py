@@ -289,9 +289,9 @@ def seg_sum(plan: GroupPlan, values, validity, out_dtype=None):
     if jnp.issubdtype(contrib.dtype, jnp.integer) or \
             contrib.dtype == jnp.bool_:
         return seg_prefix_sum(plan, contrib)
-    _trace.count_eager("eager.seg_sum_scatter", contrib)
-    return jax.ops.segment_sum(contrib, plan.seg_id,
-                               num_segments=plan.num_slots)
+    with _trace.launch("seg_sum_scatter", 1, contrib.shape[0]):
+        return jax.ops.segment_sum(contrib, plan.seg_id,
+                                   num_segments=plan.num_slots)
 
 
 def seg_count(plan: GroupPlan, validity):

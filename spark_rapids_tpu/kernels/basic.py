@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs import trace as _obs_trace
 
 
 def prefix_sum(x):
@@ -47,6 +48,7 @@ def rows_flagged_first(flag):
     return lax.sort(word, is_stable=False) & jnp.uint32(0x7FFFFFFF)
 
 
+@_obs_trace.launched()
 @jax.jit
 def filter_compact_indices(keep_mask, num_rows):
     """Turn a boolean keep-mask into a stable gather plan.
@@ -63,6 +65,7 @@ def filter_compact_indices(keep_mask, num_rows):
     return order, new_count
 
 
+@_obs_trace.launched()
 @jax.jit
 def filter_prefix_positions(keep_mask):
     """positions[i] = output slot of row i if kept (cumsum-1)."""
@@ -79,6 +82,7 @@ M1 = 0xff51afd7ed558ccd
 M2 = 0xc4ceb9fe1a85ec53
 
 
+@_obs_trace.launched()
 @jax.jit
 def hash_mix64(x):
     x = x.astype(jnp.uint64)
@@ -99,6 +103,7 @@ def hash_words(word_lists, seed: int = 42):
     return h
 
 
+@_obs_trace.launched()
 @functools.partial(jax.jit, static_argnames=("num_parts",))
 def hash_to_partition(hashes, num_parts: int):
     return (hashes % jnp.uint64(num_parts)).astype(jnp.int32)

@@ -105,22 +105,15 @@ def probe_counts(bt: BuildTable, probe_words: List[jnp.ndarray],
     return JoinCounts(lo, counts, counts > 0)
 
 
-def _count_expand(program):
-    """Count each eager launch of the program, the probe rows it
-    scatters and the output lanes it gathers over; under a ``jax.jit``
-    trace nothing is launched and nothing is counted
-    (``obs/trace.count_eager``)."""
-    @functools.wraps(program)
-    def launch(lo, counts, perm, out_cap: int):
-        _obs_trace.count_eager("join.expand.launches", counts)
-        _obs_trace.count_eager("join.expand.probe_rows", counts,
-                               counts.shape[0])
-        _obs_trace.count_eager("join.expand.out_lanes", counts, out_cap)
-        return program(lo, counts, perm, out_cap)
-    return launch
+def _expand_lanes(lo, counts, perm, out_cap: int) -> int:
+    """A launch's output lanes: the rows its running sums run over and
+    the indices it gathers; the probe rows it scatters are the plain
+    count ``join.expand.probe_rows``."""
+    _obs_trace.count("join.expand.probe_rows", counts.shape[0])
+    return out_cap
 
 
-@_count_expand
+@_obs_trace.launched(lanes=_expand_lanes)
 @functools.partial(jax.jit, static_argnames=("out_cap",))
 def join_expand_matches(lo, counts, perm, out_cap: int):
     """Expand (lo, counts) into flat (probe_idx, build_idx) gather maps.

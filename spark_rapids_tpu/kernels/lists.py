@@ -20,13 +20,16 @@ import jax
 import jax.numpy as jnp
 
 from ..columnar.column import bucket_capacity
+from ..obs import trace as _obs_trace
 
 
+@_obs_trace.launched()
 @jax.jit
 def list_lengths(offsets) -> jnp.ndarray:
     return (offsets[1:] - offsets[:-1]).astype(jnp.int32)
 
 
+@_obs_trace.launched()
 @jax.jit
 def list_gather_offsets(offsets, validity, indices):
     """Phase 1 of a list-column row gather: new offsets + element total.
@@ -45,6 +48,7 @@ def list_gather_offsets(offsets, validity, indices):
     return new_offsets, gvalid, jnp.take(starts, src), new_offsets[-1]
 
 
+@_obs_trace.launched()
 @functools.partial(jax.jit, static_argnames=("elem_cap",))
 def list_element_gather_indices(new_offsets, src_starts, elem_cap: int):
     """Phase 2: for each output element slot, the source element index.
@@ -62,6 +66,7 @@ def list_element_gather_indices(new_offsets, src_starts, elem_cap: int):
     return jnp.where(live, src_idx, 0), live
 
 
+@_obs_trace.launched()
 @functools.partial(jax.jit, static_argnames=("num_rows", "outer"))
 def list_explode_offsets(offsets, validity, num_rows: int, outer: bool):
     """Per-row output counts for explode (GpuGenerateExec.scala role).
@@ -81,6 +86,7 @@ def list_explode_offsets(offsets, validity, num_rows: int, outer: bool):
     return out_offsets, out_offsets[-1]
 
 
+@_obs_trace.launched()
 @functools.partial(jax.jit, static_argnames=("out_cap",))
 def list_explode_indices(offsets, validity, out_offsets, out_cap: int):
     """Row/element/position indices for each exploded output row.
@@ -109,6 +115,7 @@ def segment_ids_for(offsets, elem_cap: int):
     return list_segment_ids(offsets, elem_cap)
 
 
+@_obs_trace.launched()
 @functools.partial(jax.jit, static_argnames=("elem_cap",))
 def list_segment_ids(offsets, elem_cap: int):
     j = jnp.arange(elem_cap, dtype=jnp.int32)
@@ -119,6 +126,7 @@ def list_segment_ids(offsets, elem_cap: int):
     return jnp.where(live, jnp.clip(row, 0, n_lists - 1), n_lists)
 
 
+@_obs_trace.launched()
 @functools.partial(jax.jit, static_argnames=("num_segments",))
 def list_segmented_any(flags, seg_ids, num_segments: int):
     """OR-reduce boolean flags per segment."""

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs import trace as _obs_trace
+
 
 
 def stable_sort_rows(key):
@@ -36,6 +38,7 @@ def stable_sort_rows(key):
     return lax.sort((key, iota), num_keys=2, is_stable=False)
 
 
+@_obs_trace.launched()
 @jax.jit
 def sort_stable_pair(key):
     """The one compiled sort primitive: the row numbers in the stable

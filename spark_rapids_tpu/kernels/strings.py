@@ -23,6 +23,12 @@ def string_lengths(offsets) -> jnp.ndarray:
     return (offsets[1:] - offsets[:-1]).astype(jnp.int32)
 
 
+def _pack_lanes(offsets, data, num_words: int, num_bytes: int = None):
+    """A launch's gathered bytes: rows x bytes a row."""
+    return (offsets.shape[0] - 1) * (num_bytes or 8 * num_words)
+
+
+@_obs_trace.launched(lanes=_pack_lanes)
 @functools.partial(jax.jit, static_argnames=("num_words", "num_bytes"))
 def str_pack_words(offsets, data, num_words: int, num_bytes: int = None):
     """[cap, num_words] big-endian uint64 words of each string, zero-padded.
@@ -124,19 +130,16 @@ def key_byte_bound(col: StringColumn, num_rows: int) -> int:
 
 def pack_words(col: StringColumn, num_words: int, byte_bound: int = None):
     """``str_pack_words`` over a column: the one place the program is
-    launched from, and where it is counted.  ``byte_bound`` (or None: no
-    bound at hand) is a host-known bound on the bytes of the strings the
-    caller reads (``key_byte_bound``); rounded up to a power of two it
-    is what the program gathers a row, when that is under the words'
+    launched from.  ``byte_bound`` (or None: no bound at hand) is a
+    host-known bound on the bytes of the strings the caller reads
+    (``key_byte_bound``); rounded up to a power of two it is what the
+    program gathers a row, when that is under the words'
     ``8 * num_words``."""
     num_bytes = None
     if byte_bound is not None:
         num_bytes = 1 << max(0, byte_bound - 1).bit_length()
         if num_bytes >= 8 * num_words:
             num_bytes = None
-    _obs_trace.count_eager(
-        "str.pack.full" if num_bytes is None else "str.pack.narrow",
-        col.offsets)
     return str_pack_words(col.offsets, col.data, num_words, num_bytes)
 
 
@@ -162,6 +165,7 @@ def string_key_words(col: StringColumn, num_rows: int,
     return out
 
 
+@_obs_trace.launched()
 @jax.jit
 def str_gather_offsets(offsets, validity, indices, live=None):
     starts = offsets[:-1]
@@ -182,20 +186,12 @@ def str_gather_offsets(offsets, validity, indices, live=None):
     return new_offsets, gvalid, jnp.take(starts, src), total
 
 
-def _count_materialize(program):
-    """Count each eager launch of the program and the lanes it pays
-    for; under a ``jax.jit`` trace nothing is launched and nothing is
-    counted (``obs/trace.count_eager``)."""
-    @functools.wraps(program)
-    def launch(data, new_offsets, src_starts, out_bytes: int):
-        _obs_trace.count_eager("str.materialize.launches", new_offsets)
-        _obs_trace.count_eager("str.materialize.lanes", new_offsets,
-                               out_bytes)
-        return program(data, new_offsets, src_starts, out_bytes)
-    return launch
+def _materialize_lanes(data, new_offsets, src_starts, out_bytes: int):
+    """A launch's lanes: the bytes it lays out, live and dead alike."""
+    return out_bytes
 
 
-@_count_materialize
+@_obs_trace.launched(lanes=_materialize_lanes)
 @functools.partial(jax.jit, static_argnames=("out_bytes",))
 def str_materialize_bytes(data, new_offsets, src_starts, out_bytes: int):
     """``uint8[out_bytes]``: row ``r``'s bytes ``data[src_starts[r]:]``
@@ -272,11 +268,13 @@ _LOWER_TBL = np.arange(256, dtype=np.uint8)
 _LOWER_TBL[ord("A"): ord("Z") + 1] += 32
 
 
+@_obs_trace.launched()
 @jax.jit
 def str_upper_bytes(data):
     return jnp.take(jnp.asarray(_UPPER_TBL), data.astype(jnp.int32))
 
 
+@_obs_trace.launched()
 @jax.jit
 def str_lower_bytes(data):
     return jnp.take(jnp.asarray(_LOWER_TBL), data.astype(jnp.int32))
@@ -292,6 +290,7 @@ def lower(col: StringColumn) -> StringColumn:
                         max_bytes=col.max_bytes)
 
 
+@_obs_trace.launched()
 @jax.jit
 def str_substring_offsets(offsets, start, length):
     """Spark substring semantics: 1-based start, negative counts from end."""

@@ -47,6 +47,17 @@ cached site or not.  A warmed engine builds none per query; every one
 it does build is a re-trace and a persistent-cache load (or a compile)
 on some query's path.  The first call of a wrapped closure is the coarse
 span ``srt.jit_build`` (obs/trace.py), and its record carries ``t0_ns``.
+The program ``jit`` returns is a ``trace.Launcher``: every eager call is
+a launch, timed and counted by operator.
+
+Every compile inside a query is named: one ``jax.monitoring`` duration
+listener takes jax's ``/jax/core/compile/backend_compile_duration``
+event, which wraps ``compile_or_get_cached`` and carries the program's
+name, and writes the retroactive coarse span ``srt.compile`` (args
+``program``, ``how`` = ``compile`` or ``cache_load``: jax's nameless
+``/jax/compilation_cache/cache_hits`` event fired on the same thread
+inside it) under the span open there, and the counter
+``compile.<program>`` (+1) in the query's table.
 
 Hot-path discipline (this file is on the SYNC001/OBS002 lint scope):
 the warm path is one list-index check; recording happens once per
@@ -82,12 +93,49 @@ _RECORDS: List[Dict] = []
 _JIT_BUILDS = 0         #: jax.jit objects built through ``jit()``
 _JIT_BUILD_SITES: Dict[str, int] = {}   #: the same, by program name
 
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_HIT = threading.local()    #: a cache hit seen inside the open compile
+_LISTENING = False
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT:
+        _HIT.seen = True
+
+
+def _on_duration(event: str, secs: float, fun_name: str = "?",
+                 **_kw) -> None:
+    if event != _BACKEND_COMPILE:
+        return
+    how = "cache_load" if _HIT.__dict__.pop("seen", False) else "compile"
+    dur_ns = int(secs * 1e9)
+    # jax names the module "jit(<fn>)"; XLA and the device trace "jit_<fn>"
+    program = fun_name.replace("(", "_").rstrip(")")
+    _trace.compiled(program, how, time.perf_counter_ns() - dur_ns, dur_ns)
+    _trace.count("compile." + program)
+
+
+def listen() -> None:
+    """Register the compile listener, once a process: ``trace`` does
+    when it first binds jax, before any span or launch of its own."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    import jax.monitoring as mon
+    with _LOCK:
+        if not _LISTENING:
+            mon.register_event_duration_secs_listener(_on_duration)
+            mon.register_event_listener(_on_event)
+            _LISTENING = True
+
 
 def jit(fn: Callable, name: str, **jit_kwargs) -> Callable:
     """``jax.jit(fn, **jit_kwargs)`` with the program named ``name``
     (``<operator>_<role>``, unique across the engine, the same from
     query to query) and the construction counted.  Naming happens on
-    the python function before ``jax.jit`` sees it: no run-time cost."""
+    the python function before ``jax.jit`` sees it: no run-time cost.
+    The program comes back as a ``trace.Launcher``."""
     global _JIT_BUILDS
     import jax
     try:
@@ -105,7 +153,7 @@ def jit(fn: Callable, name: str, **jit_kwargs) -> Callable:
         _JIT_BUILD_SITES[name] = _JIT_BUILD_SITES.get(name, 0) + 1
     # and in the building query's counter table (trace.coarse_counts)
     _trace.count("jit_build." + name)
-    return jax.jit(fn, **jit_kwargs)
+    return _trace.Launcher(jax.jit(fn, **jit_kwargs), name)
 
 
 def _store(rec: Dict) -> None:

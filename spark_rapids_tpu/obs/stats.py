@@ -54,6 +54,7 @@ import jax.numpy as jnp
 
 from . import flight
 from . import overhead as _overhead
+from . import trace as _obs_trace
 
 _LOG = logging.getLogger("spark_rapids_tpu.obs.stats")
 
@@ -107,6 +108,7 @@ def sample_every(conf=None) -> int:
 # on-device sketch program (enqueued with the split; never pulled here)
 # ---------------------------------------------------------------------------
 
+@_obs_trace.launched()
 @functools.partial(jax.jit, static_argnums=(5, 6))
 def stats_map_sketch(h, pids, valid, word0, num_rows, nparts: int, m: int):
     """One fused stats program per map batch: HLL registers + null

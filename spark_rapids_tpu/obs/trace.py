@@ -12,8 +12,10 @@ One ``Span`` class, two levels:
   xplane's host plane, the device trace's clock; one ``is_enabled()``
   read otherwise) and reads ``time.perf_counter_ns``; on exit it writes
   one slot of the tracer's bounded ring: id, parent id, name, start,
-  duration, thread, query number.  ``coarse_spans()`` reads the ring
-  back; ``chipbench/span_reduce.py`` turns it into host time by layer.
+  duration, thread, query number, and the thread's CPU time over the
+  span (``time.thread_time_ns``: wall less CPU is the time the thread
+  was blocked or descheduled).  ``coarse_spans()`` reads the ring back;
+  ``chipbench/span_reduce.py`` turns it into host time by layer.
 - **fine** spans (``span(...)``, today's behaviour) exist only while
   ``spark.rapids.tpu.obs.trace.enabled`` is set and buffer as Chrome
   trace events ("X" complete events) loadable in Perfetto /
@@ -26,7 +28,19 @@ query shares a query number: the active
 service, else a sequence number taken at ``session.sql()`` /
 ``execute_to_arrow`` (``begin_query``) that pool workers adopt
 (``adopt_query``).  ``count()`` keeps per-query counters of the same
-key (the eager one-op launches, ``eager.<site>``).
+key.
+
+Every eager device launch the engine makes goes through one helper:
+``Launcher`` (the programs ``compile_watch.jit`` builds and the
+module-level jitted kernels) or ``launch()`` (the sites that launch
+jax's one-op programs).  A launch reads ``perf_counter_ns`` around the
+call, nothing else, and adds to its query's table under
+``<name>@<Operator>``, the operator being the innermost
+``srt.exec.<Node>`` open on the thread (``operator()``, kept by
+``exec/base.timed``; ``-`` outside one): ``launch.`` / ``eager.`` (+n),
+``launch_ns.`` (host ns of the call, less any compile inside it) and
+``lanes.`` / ``eager_lanes.`` (the indices it gathers or scatters).
+Under a ``jax.jit`` trace nothing is launched and nothing is counted.
 
 The ring is the flight recorder's discipline: preallocated slots
 mutated in place, overwrite-oldest, no lock (the slot index comes from
@@ -52,26 +66,31 @@ from ..service.cancellation import current_token
 #: enable()/disable().  Coarse spans do not consult it to record.
 _ENABLED = False
 
-#: slots in the coarse ring: a benchmark window is 15-20 queries of
-#: under 200 coarse spans each
-RING_SLOTS = 16_384
-#: queries whose ``count()`` tables are kept
-COUNT_QUERIES = 64
+#: slots in the coarse ring: at least four times the coarse spans of
+#: the largest benchmark window (PERF.md section 3 counts them a cell:
+#: 100 store queries of 52 spans, 4 q3q18 queries of 550)
+RING_SLOTS = 65_536
+#: queries whose ``count()`` tables are kept: a benchmark window holds
+#: at most about a hundred
+COUNT_QUERIES = 1024
 
 _PID = os.getpid()
 _TLS = threading.local()
 _IDS = itertools.count(1)
 _QUERY_SEQ = itertools.count(1)
-#: jax.profiler.TraceAnnotation and jax.core.Tracer, bound at first use
+#: jax.profiler.TraceAnnotation and jax's "no trace is open on this
+#: thread" check, bound at first use
 _ANNOTATION = None
-_JAX_TRACER = None
+_EAGER = None
 
 
 def _bind_jax():
-    global _ANNOTATION, _JAX_TRACER
-    from jax.core import Tracer
+    global _ANNOTATION, _EAGER
+    from jax._src.core import trace_state_clean
     from jax.profiler import TraceAnnotation
-    _ANNOTATION, _JAX_TRACER = TraceAnnotation, Tracer
+    from . import compile_watch
+    compile_watch.listen()
+    _ANNOTATION, _EAGER = TraceAnnotation, trace_state_clean
 
 
 class _NoopSpan:
@@ -110,8 +129,8 @@ class Span:
     holds the measured duration afterwards, so a site that needs the
     number (``exec/base.timed``, the flush observer) reads the clock
     once with the span."""
-    __slots__ = ("name", "cat", "args", "coarse", "t0", "dur_ns", "id",
-                 "parent", "qno", "_ann")
+    __slots__ = ("name", "cat", "args", "coarse", "t0", "c0", "dur_ns",
+                 "id", "parent", "qno", "_ann")
 
     def __init__(self, name: str, cat: str, args: Dict,
                  coarse: bool = False):
@@ -135,6 +154,9 @@ class Span:
                 self._ann = _ANNOTATION(self.name, **self.args)
                 self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
+        if self.coarse:
+            # read inside the wall clock's interval: CPU <= wall
+            self.c0 = time.thread_time_ns()
         return self
 
     def set(self, **attrs) -> "Span":
@@ -144,6 +166,7 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        cpu = time.thread_time_ns() - self.c0 if self.coarse else None
         dur = self.dur_ns = time.perf_counter_ns() - self.t0
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
@@ -156,7 +179,7 @@ class Span:
         tr = _TRACER or get_tracer()
         if self.coarse:
             tr.ring_write(self.id, self.parent, self.name, self.t0, dur,
-                          self.qno, self.args)
+                          self.qno, self.args, cpu)
         if _ENABLED:
             tr.record(self.name, self.cat, self.t0, dur, depth, self.args,
                       self.id, self.parent)
@@ -187,14 +210,16 @@ class SpanTracer:
 
     # -- coarse ring ---------------------------------------------------------
     def reset_ring(self):
-        # slot: [seq, id, parent, name, t0_ns, dur_ns, thread, query, args]
-        self._ring = [[-1, 0, 0, "", 0, 0, 0, None, None]
+        # slot: [seq, id, parent, name, t0_ns, dur_ns, thread, query,
+        #        args, cpu_ns]
+        self._ring = [[-1, 0, 0, "", 0, 0, 0, None, None, None]
                       for _ in range(self.ring_slots)]
         self._ring_seq = itertools.count()
         self._counts: Dict = {}
 
     def ring_write(self, sid: int, parent: int, name: str, t0_ns: int,
-                   dur_ns: int, qno, args: Optional[Dict]):
+                   dur_ns: int, qno, args: Optional[Dict],
+                   cpu_ns: Optional[int] = None):
         k = next(self._ring_seq)
         s = self._ring[k % self.ring_slots]
         s[1] = sid
@@ -205,15 +230,17 @@ class SpanTracer:
         s[6] = threading.get_ident()
         s[7] = qno
         s[8] = args
+        s[9] = cpu_ns
         s[0] = k
 
     def coarse_spans(self, since_ns: Optional[int] = None
                      ) -> Optional[List[Dict]]:
         """The ring's spans in completion order, those that ended
-        before ``since_ns`` left out.  None (and a line on stderr) when
-        the ring has wrapped past ``since_ns``: spans of the asked-for
-        interval may be overwritten, and a partial sum is worse than
-        none."""
+        before ``since_ns`` left out; ``cpu_ns`` is the thread's CPU
+        time over the span, None for a retroactive one (``emit``).
+        None (and a line on stderr) when the ring has wrapped past
+        ``since_ns``: spans of the asked-for interval may be
+        overwritten, and a partial sum is worse than none."""
         rows = sorted((list(s) for s in self._ring if s[0] >= 0),
                       key=lambda s: s[0])
         if rows and rows[0][0] > 0:
@@ -226,7 +253,7 @@ class SpanTracer:
                 return None
         return [{"id": s[1], "parent": s[2], "name": s[3], "t0_ns": s[4],
                  "dur_ns": s[5], "thread": s[6], "query": s[7],
-                 "args": dict(s[8]) if s[8] else {}}
+                 "args": dict(s[8]) if s[8] else {}, "cpu_ns": s[9]}
                 for s in rows
                 if since_ns is None or s[4] + s[5] >= since_ns]
 
@@ -235,15 +262,30 @@ class SpanTracer:
         ``ring_slots`` the oldest are gone)."""
         return max((s[0] for s in self._ring), default=-1) + 1
 
+    def _table(self, qno) -> Dict:
+        """``qno``'s counter table; the caller holds the lock."""
+        tbl = self._counts.get(qno)
+        if tbl is None:
+            tbl = self._counts[qno] = {}
+            while len(self._counts) > COUNT_QUERIES:
+                # dicts keep insertion order: the first is the oldest
+                del self._counts[next(iter(self._counts))]
+        return tbl
+
     def count(self, qno, name: str, n: int = 1):
         with self._lock:
-            tbl = self._counts.get(qno)
-            if tbl is None:
-                tbl = self._counts[qno] = {}
-                while len(self._counts) > COUNT_QUERIES:
-                    # dicts keep insertion order: the first is the oldest
-                    del self._counts[next(iter(self._counts))]
+            tbl = self._table(qno)
             tbl[name] = tbl.get(name, 0) + n
+
+    def count_launch(self, qno, keys, n: int, ns: int, lanes: int):
+        """One launch's three counters (``_launch_keys``), one lock."""
+        count_key, ns_key, lanes_key = keys
+        with self._lock:
+            tbl = self._table(qno)
+            tbl[count_key] = tbl.get(count_key, 0) + n
+            tbl[ns_key] = tbl.get(ns_key, 0) + ns
+            if lanes:
+                tbl[lanes_key] = tbl.get(lanes_key, 0) + lanes
 
     def coarse_counts(self) -> Dict:
         with self._lock:
@@ -367,7 +409,8 @@ def emit(name: str, cat: str, start_ns: int, dur_ns: int,
     semaphore wait measured by its own clock), as a child of the
     calling thread's open span.  ``start_ns`` is a
     time.perf_counter_ns() instant.  The profiler has no retroactive
-    annotation: a coarse emit lands in the ring only."""
+    annotation: a coarse emit lands in the ring only, with no CPU time
+    (``cpu_ns`` None)."""
     if not (coarse or _ENABLED):
         return
     d = _TLS.__dict__
@@ -418,16 +461,130 @@ def count(name: str, n: int = 1) -> None:
     (_TRACER or get_tracer()).count(_query_number(_TLS.__dict__), name, n)
 
 
-def count_eager(name: str, operand, n: int = 1) -> None:
-    """``count(name, n)`` for a site that launches ``n`` one-op jax
-    programs when it runs eagerly (``jit__take``, ``jit_scatter-add``:
-    jax's names, not the engine's to change).  Under a ``jax.jit`` trace
-    ``operand`` is a tracer and the ops join the program being built:
-    nothing is launched and nothing is counted."""
-    if _JAX_TRACER is None:
+# ---------------------------------------------------------------------------
+# launches: every eager device launch, timed and counted by operator
+# ---------------------------------------------------------------------------
+
+#: ``(prefix, name, operator)`` -> the launch's three counter names
+_LAUNCH_KEYS: Dict = {}
+
+
+def operator(name: str) -> str:
+    """Make ``name`` the calling thread's operator (the innermost open
+    ``srt.exec.<Node>``) and return the one it replaces: ``exec/base.timed``
+    sets it on enter and puts the old one back on exit."""
+    d = _TLS.__dict__
+    prev = d.get("op", "-")
+    d["op"] = name
+    return prev
+
+
+def _launch_keys(prefix: str, name: str, op: str):
+    keys = _LAUNCH_KEYS.get((prefix, name, op))
+    if keys is None:
+        tail = f"{name}@{op}"
+        lanes = "eager_lanes." if prefix == "eager." else "lanes."
+        keys = _LAUNCH_KEYS[(prefix, name, op)] = (
+            prefix + tail, "launch_ns." + tail, lanes + tail)
+    return keys
+
+
+def _launched(d: Dict, prefix: str, name: str, n: int, ns: int,
+              lanes: int) -> None:
+    (_TRACER or get_tracer()).count_launch(
+        _query_number(d), _launch_keys(prefix, name, d.get("op", "-")),
+        n, ns, lanes)
+
+
+def _eager() -> bool:
+    """True when no ``jax.jit`` trace is open on this thread: a call
+    launches.  Under a trace the ops join the program being built."""
+    if _EAGER is None:
         _bind_jax()
-    if not isinstance(operand, _JAX_TRACER):
-        count(name, n)
+    return _EAGER()
+
+
+class _Launch:
+    """One eager site's launch region (``launch()``)."""
+    __slots__ = ("name", "n", "lanes", "t0", "c0")
+
+    def __init__(self, name: str, n: int, lanes: int):
+        self.name = name
+        self.n = n
+        self.lanes = lanes
+
+    def __enter__(self):
+        self.c0 = _TLS.__dict__.get("compile_ns", 0)
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        ns = time.perf_counter_ns() - self.t0
+        d = _TLS.__dict__
+        _launched(d, "eager.", self.name, self.n,
+                  ns - (d.get("compile_ns", 0) - self.c0), self.lanes)
+        return False
+
+
+def launch(site: str, n: int = 1, lanes: int = 0):
+    """The region of a site that launches ``n`` of jax's one-op programs
+    when it runs eagerly (``jit__take``, ``jit_scatter-add``: jax's
+    names, not the engine's to change), gathering or scattering
+    ``lanes`` indices in all.  Adds ``eager.<site>@<Op>`` (+n),
+    ``launch_ns.<site>@<Op>`` and ``eager_lanes.<site>@<Op>``; under a
+    ``jax.jit`` trace it is the shared no-op."""
+    if not _eager():
+        return _NOOP
+    return _Launch(site, n, lanes)
+
+
+class Launcher:
+    """A jitted program whose every eager call is a launch: adds
+    ``launch.<name>@<Op>`` (+1), ``launch_ns.<name>@<Op>`` (the call's
+    host ns: jax's dispatch and enqueue, which waits when the runtime's
+    allocator does, less any compile inside it, which is ``srt.compile``)
+    and, given ``lanes(*args, **kwargs)``, ``lanes.<name>@<Op>``.
+    Called under a ``jax.jit`` trace it is the program and nothing more.
+    Everything else (``lower``, ``__wrapped__``, ...) is the program's."""
+    __slots__ = ("__wrapped__", "name", "lanes")
+
+    def __init__(self, program, name: str, lanes=None):
+        self.__wrapped__ = program
+        self.name = name
+        self.lanes = lanes
+
+    def __call__(self, *args, **kwargs):
+        if not _eager():
+            return self.__wrapped__(*args, **kwargs)
+        d = _TLS.__dict__
+        c0 = d.get("compile_ns", 0)
+        t0 = time.perf_counter_ns()
+        out = self.__wrapped__(*args, **kwargs)
+        ns = time.perf_counter_ns() - t0 - (d.get("compile_ns", 0) - c0)
+        _launched(d, "launch.", self.name, 1, ns,
+                  self.lanes(*args, **kwargs) if self.lanes else 0)
+        return out
+
+    def __getattr__(self, attr):
+        return getattr(self.__wrapped__, attr)
+
+
+def launched(lanes=None):
+    """Decorator form of ``Launcher`` for a module-level jitted kernel,
+    named after its function."""
+    return lambda program: Launcher(program, program.__name__, lanes)
+
+
+def compiled(program: str, how: str, start_ns: int, dur_ns: int) -> None:
+    """A backend compile (``how`` = ``compile``) or persistent-cache
+    load (``cache_load``) of ``program`` that just ended on this thread:
+    the retroactive coarse span ``srt.compile`` under the span open
+    here, and its ns kept out of the enclosing launch's ``launch_ns``
+    (``obs/compile_watch.py``'s jax.monitoring listener calls this)."""
+    d = _TLS.__dict__
+    d["compile_ns"] = d.get("compile_ns", 0) + dur_ns
+    emit("srt.compile", "compile", start_ns, dur_ns, True, program=program,
+         how=how)
 
 
 def coarse_spans(since_ns: Optional[int] = None) -> Optional[List[Dict]]:

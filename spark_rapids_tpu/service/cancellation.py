@@ -106,7 +106,10 @@ _TLS = threading.local()
 
 
 def current_token() -> Optional[CancelToken]:
-    return getattr(_TLS, "token", None)
+    # a dict read: ``getattr`` with a default raises and catches
+    # AttributeError on a thread that never set one (every span, count
+    # and launch asks)
+    return _TLS.__dict__.get("token")
 
 
 class query_context:

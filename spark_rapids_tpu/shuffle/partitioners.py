@@ -25,6 +25,7 @@ from ..expr import core as ec
 from ..kernels import basic as bk
 from ..kernels import canon
 from ..kernels.sort import sort_permutation, stable_sort_rows
+from ..obs import trace as _obs_trace
 
 
 @dataclasses.dataclass
@@ -40,6 +41,7 @@ class SplitBatch:
         return self.batch.slice(lo, hi - lo)
 
 
+@_obs_trace.launched()
 @functools.partial(jax.jit, static_argnums=(2,))
 def partition_sort_counts(pids, num_rows, num_partitions: int):
     """One program: stable u32 sort by partition id (rows past num_rows
@@ -53,6 +55,7 @@ def partition_sort_counts(pids, num_rows, num_partitions: int):
     return perm, jnp.diff(bounds)
 
 
+@_obs_trace.launched()
 @functools.partial(jax.jit, static_argnums=(1,))
 def partition_hash_ids(word_lists, num_partitions: int):
     """murmur-mix + mod over the key words -> partition id per row, as

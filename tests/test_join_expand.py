@@ -233,20 +233,27 @@ def test_the_indices_an_output_row_and_no_search(n, out_cap, scatters,
 
 
 def expand_counts():
-    return pack_counts("join.expand.")
+    """The program's launch counters (its host ns left out, outside any
+    operator: ``@-``) and the probe rows it scatters."""
+    got = pack_counts("launch.join_expand_matches@")
+    got.update(pack_counts("lanes.join_expand_matches@"))
+    got.update(pack_counts("join.expand."))
+    return got
 
 
 def test_counters_read_launches_probe_rows_and_out_lanes():
     lo, counts, perm = (jnp.asarray(a) for a in operands("skew", 64, 256))
     assert expand_counts() == {}
     jkern.join_expand_matches(lo, counts, perm, 256)
-    assert expand_counts() == {"join.expand.launches": 1,
+    assert expand_counts() == {"launch.join_expand_matches@-": 1,
                                "join.expand.probe_rows": 64,
-                               "join.expand.out_lanes": 256}
+                               "lanes.join_expand_matches@-": 256}
     jkern.join_expand_matches(lo, counts, perm, out_cap=1024)
-    assert expand_counts() == {"join.expand.launches": 2,
+    assert expand_counts() == {"launch.join_expand_matches@-": 2,
                                "join.expand.probe_rows": 128,
-                               "join.expand.out_lanes": 1280}
+                               "lanes.join_expand_matches@-": 1280}
+    assert pack_counts("launch_ns.join_expand_matches@-")[
+        "launch_ns.join_expand_matches@-"] > 0
 
 
 def test_counters_count_nothing_under_a_jit_trace():

@@ -38,16 +38,27 @@ FULL = (5, 7, 8, 9, 16, 17)         # rounds up to the words' own width
 CAP = 64
 
 
-def pack_counts(prefix="str.pack."):
-    """The ``str.pack.*`` counters (or another ``prefix``'s) since the
-    last ``trace.reset()``, over every query's table (and the one of no
-    query)."""
+def pack_counts(prefix):
+    """The counters named ``prefix*`` since the last ``trace.reset()``,
+    over every query's table (and the one of no query)."""
     out = {}
     for table in trace.coarse_counts().values():
         for name, n in table.items():
             if name.startswith(prefix):
                 out[name] = out.get(name, 0) + n
     return out
+
+
+def packs():
+    """``(launches, lanes)`` of ``str_pack_words`` since the last reset,
+    over every operator: the lanes are rows x the bytes a row gathers."""
+    return (sum(pack_counts("launch.str_pack_words@").values()),
+            sum(pack_counts("lanes.str_pack_words@").values()))
+
+
+def width(bound, num_words):
+    """The bytes a row the pack gathers under a byte bound."""
+    return min(1 << max(0, bound - 1).bit_length(), 8 * num_words)
 
 
 def numpy_words(strings, num_words, cap):
@@ -87,13 +98,13 @@ def test_byte_bound_pack_is_the_full_pack_bit_for_bit(bound):
     num_words = skern.needed_key_words(col, len(strings))
     trace.reset()
     got = np.asarray(skern.pack_words(col, num_words, bound))
+    assert packs() == (1, CAP * width(bound, num_words))
+    assert (width(bound, num_words) < 8 * num_words) == (bound in NARROW)
     full = np.asarray(skern.str_pack_words(col.offsets, col.data, num_words))
     want = numpy_words(strings, num_words, CAP)
     assert got.dtype == np.uint64 and got.shape == (CAP, num_words)
     assert got.tobytes() == want.tobytes()
     assert got.tobytes() == full.tobytes()
-    assert pack_counts() == {"str.pack.narrow" if bound in NARROW
-                             else "str.pack.full": 1}
     # the key words a sort or a join reads, bound found by the callee
     words = skern.string_key_words(col, len(strings))
     assert len(words) == num_words + 1
@@ -107,14 +118,14 @@ def test_no_bound_is_the_full_program():
     strings, col = column(1)
     trace.reset()
     got = skern.pack_words(col, 2)
-    assert pack_counts() == {"str.pack.full": 1}
+    assert packs() == (1, CAP * 16)
     assert np.asarray(got).tobytes() == \
         numpy_words(strings, 2, CAP).tobytes()
     # a bound that fills the agreed words takes the same program
     again = skern.pack_words(col, 1, 8)
     assert np.asarray(again).tobytes() == \
         numpy_words(strings, 1, CAP).tobytes()
-    assert pack_counts() == {"str.pack.full": 2}
+    assert packs() == (2, CAP * 16 + CAP * 8)
 
 
 def test_stale_rows_past_num_rows_may_be_longer():
@@ -129,7 +140,7 @@ def test_stale_rows_past_num_rows_may_be_longer():
     assert skern.key_byte_bound(col, n) == 1
     trace.reset()
     words = skern.string_key_words(col, n)
-    assert pack_counts() == {"str.pack.narrow": 1}
+    assert packs() == (1, 32 * 1)
     want = numpy_words(strings, 1, 32)
     got = np.asarray(words[0])
     assert got[:n].tolist() == want[:n, 0].tolist()
@@ -160,8 +171,7 @@ def test_lazy_view_packs_its_source_by_the_bound(bound):
     assert len(words) == num_words + 1
     want = numpy_words(strings, num_words, CAP)[np.pad(idx, (0, 24))]
     assert np.stack(words[:-1], 1).tobytes() == want.tobytes()
-    assert pack_counts() == {"str.pack.narrow" if bound < 8
-                             else "str.pack.full": 1}
+    assert packs() == (1, src.capacity * width(got_bound, num_words))
     # and with nothing handed in: the source's own bound
     plain = canon.value_words(view, 40)
     assert [np.asarray(w).tolist() for w in plain] == \
@@ -275,22 +285,29 @@ def _run(enabled, sql, conf):
     # a 12-byte key is two full words
     (BY_MODE, False),
 ], ids=["q1_flags", "key_of_12_bytes"])
-def test_group_by_counts_its_packs(sql, narrow):
+def test_group_by_counts_its_packs(sql, narrow, monkeypatch):
     conf = {"spark.rapids.tpu.sql.batchSizeRows": 256}
     _, want = _run(False, sql, conf)
+    widths = []
+    program = skern.str_pack_words
+
+    def spy(offsets, data, num_words, num_bytes=None):
+        widths.append(num_bytes is not None)
+        return program(offsets, data, num_words, num_bytes)
+    monkeypatch.setattr(skern, "str_pack_words", spy)
     trace.reset()
     s, got = _run(True, sql, conf)
     _compare_rows(want, got)
     assert "Cpu" not in s.last_physical_plan.tree_string()
-    counts = pack_counts()
     assert not any(t.get("agg.batches.eager")
                    for t in trace.coarse_counts().values())
+    # every launch is counted, under the operator that packed
+    assert packs()[0] == len(widths)
+    assert all("@Tpu" in k for k in pack_counts("launch.str_pack_words@"))
     if narrow:
-        assert counts.get("str.pack.narrow", 0) >= 6, counts
-        assert counts.get("str.pack.full", 0) == 0, counts
+        assert len(widths) >= 6 and all(widths), widths
     else:
-        assert counts.get("str.pack.full", 0) >= 3, counts
-        assert counts.get("str.pack.narrow", 0) == 0, counts
+        assert len(widths) >= 3 and not any(widths), widths
 
 
 # -- the benchmark metric that reads the program's device time ----------------
