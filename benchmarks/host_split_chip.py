@@ -174,6 +174,7 @@ def cell_tables(run: dict) -> dict:
         "top_lanes": by(("lanes.", "eager_lanes."))[:8],
         "top_launches": by(("launch.", "eager."))[:8],
         "compiles": by(("compile.",)),
+        "agg_counters": by(("agg.",)),
         "pull_by_site_count_ms_selfms": sorted(
             pulls.items(), key=lambda r: -r[1][1]),
         "pull_spans_per_query": span_reduce.spans_per_query(run,

@@ -116,6 +116,71 @@ def _group_reduce(key_cols, live, num_rows, aggs, agg_cols,
     return ng, fit, outs
 
 
+def _global_reduce(aggs, agg_cols, live, update_mode: bool,
+                   emit_buffers: bool):
+    """The traced body of the global core (no group keys): every
+    ``live`` row is one group (``agg_k.single_group_plan``), so nothing
+    is sorted or moved; the DOUBLE sums are one stacked tree-ordered
+    reduce and every other kernel reduces the masked rows.  Returns the
+    output pairs in schema order, one row at ``bucket_capacity(1)``:
+    over no live row a count reads 0 and every other result NULL."""
+    plan = agg_k.single_group_plan(live)
+    out_cap = bucket_capacity(1)
+    one = jnp.arange(out_cap) < 1
+    has_rows = jnp.any(live)
+    with jax.named_scope("segment_reduce"):
+        agg_k.stack_float_sums(
+            plan, [c for a, cols in zip(aggs, agg_cols)
+                   for c in a.func.float_sum_cols(cols) if type(c) is Column])
+        agg_buffers = [a.func.update(plan, cols) if update_mode
+                       else a.func.merge(plan, cols)
+                       for a, cols in zip(aggs, agg_cols)]
+    outs = []
+    for a, bufs in zip(aggs, agg_buffers):
+        for o in (bufs if emit_buffers else [a.func.finalize(bufs)]):
+            c = o.gather(jnp.zeros(out_cap, jnp.int32))
+            if isinstance(a.func, ea.Count):
+                # counts are valid even over empty input (0)
+                c = Column(T.INT64,
+                           jnp.where(one, c.data.astype(jnp.int64), 0), one)
+            else:
+                c = c.mask_validity(one & has_rows)
+            outs.append((c.data, c.validity))
+    return outs
+
+
+def _masked_chain(src_schema, pre_ops, bounds, bound_keys, bound_inputs,
+                  datas, valids, num_rows):
+    """The traced front of the whole-stage and folded global cores: the
+    source columns (a STRING key source as its packed words, of
+    ``bounds``' byte bound; any other STRING column, which nothing
+    reads, as None), the filter / project chain folded into the row
+    mask (``staged.apply_ops_masked``), then the keys and the
+    aggregates' inputs, keyed by bound expression: sum(x) and avg(x)
+    read ONE evaluated column.  Returns (live, keys, inputs)."""
+    from .fused import _TracedBatch, expr_signature
+    from .staged import apply_ops_masked
+    cap = next(v for v in valids if v is not None).shape[0]
+    byte_bound = dict(bounds)
+    cols = [None if v is None else
+            canon.PackedStringKey(d, v, byte_bound[i])
+            if f.dtype == T.STRING else Column(f.dtype, d, v)
+            for i, (f, d, v) in enumerate(zip(src_schema, datas, valids))]
+    b = _TracedBatch(src_schema, cols, num_rows, cap)
+    with jax.named_scope("pre_ops"):
+        b, live = apply_ops_masked(pre_ops, b, jnp.arange(cap) < num_rows)
+    kcols = [ec.eval_as_column(e, b) for e in bound_keys]
+    evaluated = {}
+    agg_cols = []
+    for bs in bound_inputs:
+        for e in bs:
+            sig = expr_signature(e)
+            if sig not in evaluated:
+                evaluated[sig] = ec.eval_as_column(e, b)
+        agg_cols.append([evaluated[expr_signature(e)] for e in bs] or [None])
+    return live, kcols, agg_cols
+
+
 def _pack_string_key(col, num_rows, at_least: int = 0):
     """(value words, validity) of a STRING key column for a core's
     ``canon.PackedStringKey``, and the byte bound that sized the words:
@@ -1182,9 +1247,7 @@ class TpuHashAggregate(TpuExec):
         tries ``_fused_agg_core``).  ``out_cap`` requests speculative
         device-side compaction to that capacity; ``fit`` is the device
         flag that the group count fit (always-1 when uncompacted)."""
-        from .fused import _TracedBatch, expr_signature
         from ..columnar.column import StringColumn
-        from .staged import apply_ops_masked
         if batch.capacity > _CORE_MAX_CAPACITY or not batch.columns:
             return None
         if not all(type(c) is Column or isinstance(c, StringColumn)
@@ -1210,31 +1273,9 @@ class TpuHashAggregate(TpuExec):
         aggs = self.aggs
 
         def _core(datas, valids, num_rows):
-            cap = next(v for v in valids if v is not None).shape[0]
-            # a column the core was not given (None) is a STRING
-            # column nothing reads; a key source holds its words
-            byte_bound = dict(bounds)
-            cols = [None if v is None else
-                    canon.PackedStringKey(d, v, byte_bound[i])
-                    if f.dtype == T.STRING else Column(f.dtype, d, v)
-                    for i, (f, d, v) in enumerate(
-                        zip(src_schema, datas, valids))]
-            b = _TracedBatch(src_schema, cols, num_rows, cap)
-            with jax.named_scope("pre_ops"):
-                b, live = apply_ops_masked(
-                    pre_ops, b, jnp.arange(cap) < num_rows)
-            kcols = [ec.eval_as_column(e, b) for e in bound_keys]
-            # inputs are keyed by bound expression: sum(x) and
-            # avg(x) read ONE evaluated column, moved once
-            evaluated = {}
-            agg_cols = []
-            for bs in bound_inputs:
-                for e in bs:
-                    sig = expr_signature(e)
-                    if sig not in evaluated:
-                        evaluated[sig] = ec.eval_as_column(e, b)
-                agg_cols.append([evaluated[expr_signature(e)]
-                                 for e in bs] or [None])
+            live, kcols, agg_cols = _masked_chain(
+                src_schema, pre_ops, bounds, bound_keys, bound_inputs,
+                datas, valids, num_rows)
             return _group_reduce(kcols, live, num_rows, aggs, agg_cols,
                                  True, out_cap, emit_buffers, cache_key)
 
@@ -1277,10 +1318,12 @@ class TpuHashAggregate(TpuExec):
         rows (how a COMPLETE node runs its batches too) and emits
         buffers; FINAL merges a buffer-shaped batch and finalizes unless
         ``emit_buffers``.  Top to bottom: a keyed update the bucket
-        table admits -> the table core; no keys -> the global core; an
-        update whose pre_ops trace -> the whole-stage core; else pre_ops
-        run eagerly and the grouped core takes the update, as it takes
-        every merge; else the eager grouped fallback.
+        table admits -> the table core; an update whose pre_ops trace ->
+        the whole-stage core, or with no keys the global core with the
+        chain folded in (``agg.global.folded``); else pre_ops run
+        eagerly, then no keys -> the global core; the grouped core
+        takes the update, as it takes every merge; else the eager
+        grouped fallback.
         ``agg.batches.{table,fused,eager}`` count where a batch settled.
 
         What still lands on the eager fallback: STRING or nested
@@ -1328,11 +1371,17 @@ class TpuHashAggregate(TpuExec):
             return out
 
         if update and self.pre_ops:
-            ws = self._fused_whole_stage_core(batch, emit,
-                                              out_cap=compact_cap) \
-                if self.group_exprs else None
-            if ws is not None:
-                return fused_out(ws)
+            if not self.group_exprs:
+                out = self._fused_global_core(batch)
+                if out is not None:
+                    _obs_trace.count("agg.global.folded")
+                    _obs_trace.count("agg.batches.fused")
+                    return out
+            else:
+                ws = self._fused_whole_stage_core(batch, emit,
+                                                  out_cap=compact_cap)
+                if ws is not None:
+                    return fused_out(ws)
             from .staged import apply_ops_eager, build_fused_per_op
             fkey = ("fpo", tuple(f.dtype.name for f in batch.schema))
             fpo = self._ws_memo.get(fkey)
@@ -1400,47 +1449,20 @@ class TpuHashAggregate(TpuExec):
     def _global_agg(self, batch: ColumnarBatch,
                     input_cols: List[List[Column]], update_mode: bool,
                     emit: bool) -> ColumnarBatch:
-        """No group keys: aggregate everything into one row (one segment).
-
-        The whole computation is one jitted program (one dispatch
-        instead of one per eager op);
-        falls back to the traced body run eagerly for exotic columns."""
-        out_schema = self._out_schema(emit)
+        """No group keys: aggregate everything into one row
+        (``_global_reduce``) as one jitted program, ``agg_global_core``;
+        exotic columns (Binary64, strings) run the same body eagerly."""
         aggs = self.aggs
         in_dts = tuple(tuple(None if c is None else c.dtype for c in cols)
                        for cols in input_cols)
-        cap0 = batch.capacity  # captured as int: the closure must not pin
-        # the batch (jit cores are cached class-level and would leak it)
+        cap = batch.capacity  # an int: the cached core must not pin the batch
 
         def _core(in_arrays, num_rows):
-            const = Column(T.INT64, jnp.zeros(cap0, jnp.int64),
-                           jnp.arange(cap0) < num_rows)
-            words = canon.batch_key_words([const], num_rows)
-            plan = agg_k.groupby_plan(words)
-            out_cap = bucket_capacity(1)
-            has_rows = num_rows > 0
-            outs = []
             it = iter(in_arrays)
-            for a, dts in zip(aggs, in_dts):
-                cols = [None if dt is None else Column(dt, *next(it))
-                        for dt in dts] or [None]
-                with jax.named_scope("segment_reduce"):
-                    bufs = a.func.update(plan, cols) if update_mode \
-                        else a.func.merge(plan, cols)
-                cols_out = bufs if emit else [a.func.finalize(bufs)]
-                for o in cols_out:
-                    c = o.gather(jnp.zeros(out_cap, jnp.int32))
-                    live = jnp.arange(out_cap) < 1
-                    if isinstance(a.func, ea.Count):
-                        # counts are valid even over empty input (0)
-                        c = Column(T.INT64,
-                                   jnp.where(live,
-                                             c.data.astype(jnp.int64), 0),
-                                   live)
-                    else:
-                        c = c.mask_validity(live & has_rows)
-                    outs.append((c.data, c.validity))
-            return outs
+            agg_cols = [[None if dt is None else Column(dt, *next(it))
+                         for dt in dts] or [None] for dts in in_dts]
+            return _global_reduce(aggs, agg_cols, jnp.arange(cap) < num_rows,
+                                  update_mode, emit)
 
         in_arrays = tuple((c.data, c.validity)
                           for cols in input_cols for c in cols
@@ -1448,27 +1470,77 @@ class TpuHashAggregate(TpuExec):
         pairs = None
         if all(c is None or type(c) is Column
                for cols in input_cols for c in cols):
-            cache_key = ("global", update_mode, emit, in_dts,
-                         batch.capacity, _agg_signature(aggs))
-            pairs = self._run_core(
-                cache_key,
-                lambda: _compile_watch.jit(_core, "agg_global_core"),
-                (in_arrays, batch.rows_dev), batch, "global")
+            cache_key = ("global", update_mode, emit, in_dts, cap,
+                         _agg_signature(aggs))
+            pairs = self._run_global(cache_key, _core,
+                                     (in_arrays, batch.rows_dev), batch)
         _obs_trace.count("agg.batches.eager" if pairs is None
                          else "agg.batches.fused")
         if pairs is None:
-            pairs = _core(in_arrays, batch.rows_dev)
-        out_cols = [Column(f.dtype, d, v)
-                    for f, (d, v) in zip(out_schema, pairs)]
-        return ColumnarBatch(out_schema, out_cols, 1)
+            pairs = _global_reduce(aggs, input_cols,
+                                   jnp.arange(cap) < batch.rows_dev,
+                                   update_mode, emit)
+        return self._global_batch(pairs, emit)
+
+    def _run_global(self, cache_key, body, args, batch: ColumnarBatch):
+        """``body`` as the one global program, ``agg_global_core``."""
+        return self._run_core(
+            cache_key, lambda: _compile_watch.jit(body, "agg_global_core"),
+            args, batch, "global")
+
+    def _global_batch(self, pairs, emit: bool) -> ColumnarBatch:
+        out_schema = self._out_schema(emit)
+        return ColumnarBatch(out_schema, [Column(f.dtype, d, v) for f, (d, v)
+                                          in zip(out_schema, pairs)], 1)
+
+    def _fused_global_core(self, batch: ColumnarBatch):
+        """A global update's filter / project chain, its inputs and the
+        reduce as ONE program (the same ``agg_global_core``): filters
+        fold into row liveness (``staged.apply_ops_masked``) and
+        projections evaluate at the batch's capacity, so nothing is
+        compacted.  Returns the buffer batch, or None when the chain or
+        an input cannot trace (the caller runs the chain eagerly)."""
+        from ..columnar.binary64 import exact_double_enabled
+        from ..columnar.column import StringColumn
+        if exact_double_enabled() or batch.capacity > _CORE_MAX_CAPACITY:
+            return None
+        if not any(type(c) is Column for c in batch.columns) or not all(
+                type(c) is Column or isinstance(c, StringColumn)
+                for c in batch.columns):
+            return None
+        mkey = tuple(f.dtype.name for f in batch.schema)
+        prep = self._ws_memo.get(mkey)
+        if prep is None:
+            prep = self._ws_memo[mkey] = self._ws_prepare(batch.schema)
+        if prep is False:
+            return None
+        src_schema = batch.schema
+        pre_ops = self.pre_ops
+        aggs = self.aggs
+        bound_inputs = prep[2]
+
+        def _core(datas, valids, num_rows):
+            live, _, agg_cols = _masked_chain(
+                src_schema, pre_ops, (), (), bound_inputs, datas, valids,
+                num_rows)
+            return _global_reduce(aggs, agg_cols, live, True, True)
+
+        datas = tuple(c.data if type(c) is Column else None
+                      for c in batch.columns)
+        valids = tuple(c.validity if type(c) is Column else None
+                       for c in batch.columns)
+        pairs = self._run_global(prep[0] + ("global",), _core,
+                                 (datas, valids, batch.rows_dev), batch)
+        return None if pairs is None else self._global_batch(pairs, True)
 
 
 # ---------------------------------------------------------------------------
-# program audit registration (analysis/program_audit.py): the three
+# program audit registration (analysis/program_audit.py): the four
 # hash_aggregate core sites (_fused_agg_core, _fused_whole_stage_core,
-# _global_agg) build their programs per-batch inside the exec, so each
-# provider DRIVES a tiny CPU batch through the real site and then pulls
-# the freshly cached core out of _CORE_CACHE for abstract tracing.
+# _global_agg, _fused_global_core) build their programs per-batch inside
+# the exec, so each provider DRIVES a tiny CPU batch through the real
+# site and then pulls the freshly cached core out of _CORE_CACHE for
+# abstract tracing.
 # ---------------------------------------------------------------------------
 
 def _int_col(cap, fill=None):
@@ -1561,6 +1633,25 @@ def _audit_specs():
         args = ((_pair_sds(c),), jax.ShapeDtypeStruct((), np.int32))
         return core, args, {}
 
+    def _global_folded():
+        from ..expr.predicates import GreaterThan
+        agg = _audit_agg(group=False)
+        schema = Schema([Field("v", T.INT64, True)])
+        agg.pre_ops = [("filter",
+                        GreaterThan(ec.BoundReference(0, T.INT64),
+                                    ec.lit(0)), schema)]
+        batch = ColumnarBatch(schema, [_int_col(16, 1)], 8)
+        assert agg._fused_global_core(batch) is not None, \
+            "folded global core fell back"
+        mkey = tuple(f.dtype.name for f in schema)
+        core = _cached_core(agg._ws_memo[mkey][0] + ("global",),
+                            "folded global")
+        c = batch.capacity
+        args = ((jax.ShapeDtypeStruct((c,), np.int64),),
+                (jax.ShapeDtypeStruct((c,), np.bool_),),
+                jax.ShapeDtypeStruct((), np.int32))
+        return core, args, {}
+
     return [
         AuditSpec("hash_aggregate_grouped", "hash_aggregate", _grouped,
                   notes="sum(v) group by k, update mode",
@@ -1573,6 +1664,11 @@ def _audit_specs():
                            "sort": 3}),
         AuditSpec("hash_aggregate_global", "hash_aggregate", _global,
                   notes="global (no group keys) sum, partial mode",
-                  budgets={"gather": 14, "scatter": 2, "transpose": 4,
-                           "sort": 4}),
+                  budgets={"gather": 2, "scatter": 0, "transpose": 0,
+                           "sort": 0}),
+        AuditSpec("hash_aggregate_global_folded", "hash_aggregate",
+                  _global_folded,
+                  notes="filter(v>0) chain folded into a global sum(v)",
+                  budgets={"gather": 2, "scatter": 0, "transpose": 0,
+                           "sort": 0}),
     ]

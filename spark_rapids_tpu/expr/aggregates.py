@@ -82,7 +82,7 @@ def _b64_seg_sum(plan, c):
     from ..kernels import binary64 as b64
     from ..columnar.binary64 import Binary64Column
     v, ok = agg_k._sorted_vals(plan, c.data, c.validity)
-    s = b64.segmented_sum(v, ok, plan.seg_id, c.capacity,
+    s = b64.segmented_sum(v, ok, plan.seg_id, plan.num_slots,
                           head_pos=plan.head_pos, last_pos=plan.last_pos,
                           num_groups=plan.num_groups)
     cnt = agg_k.seg_count(plan, c.validity)
@@ -323,17 +323,14 @@ class CentralMoment(AggregateFunction):
 
     def update(self, plan, cols):
         c = cols[0]
-        cap = plan.num_slots
         x, ok = agg_k._sorted_vals(plan, c.data, c.validity)
         x = x.astype(jnp.float64)
-        cnt = jax.ops.segment_sum(ok.astype(jnp.int64), plan.seg_id,
-                                  num_segments=cap)
-        s = jax.ops.segment_sum(jnp.where(ok, x, 0.0), plan.seg_id,
-                                num_segments=cap)
+        cnt = agg_k.seg_reduce(plan, ok.astype(jnp.int64), "sum")
+        s = agg_k.seg_reduce(plan, jnp.where(ok, x, 0.0), "sum")
         mean = s / jnp.maximum(cnt, 1).astype(jnp.float64)
-        delta = x - jnp.take(mean, plan.seg_id, mode="clip")
-        m2 = jax.ops.segment_sum(jnp.where(ok, delta * delta, 0.0),
-                                 plan.seg_id, num_segments=cap)
+        delta = x - agg_k.seg_spread(plan, mean)
+        m2 = agg_k.seg_reduce(plan, jnp.where(ok, delta * delta, 0.0),
+                              "sum")
         always = jnp.ones_like(cnt, dtype=bool)
         return [Column(T.INT64, cnt, always),
                 Column(T.FLOAT64, mean, always),
@@ -349,14 +346,12 @@ class CentralMoment(AggregateFunction):
         m2_i, _ = agg_k._sorted_vals(plan, buffers[2].data,
                                      buffers[2].validity)
         n_i = jnp.where(ok, n_i, 0.0)
-        n = jax.ops.segment_sum(n_i, plan.seg_id, num_segments=cap)
-        wsum = jax.ops.segment_sum(n_i * mean_i, plan.seg_id,
-                                   num_segments=cap)
+        n = agg_k.seg_reduce(plan, n_i, "sum")
+        wsum = agg_k.seg_reduce(plan, n_i * mean_i, "sum")
         mean = wsum / jnp.maximum(n, 1.0)
-        delta = mean_i - jnp.take(mean, plan.seg_id, mode="clip")
-        m2 = jax.ops.segment_sum(
-            jnp.where(ok, m2_i + n_i * delta * delta, 0.0),
-            plan.seg_id, num_segments=cap)
+        delta = mean_i - agg_k.seg_spread(plan, mean)
+        m2 = agg_k.seg_reduce(
+            plan, jnp.where(ok, m2_i + n_i * delta * delta, 0.0), "sum")
         always = jnp.ones(cap, dtype=bool)
         return [Column(T.INT64, n.astype(jnp.int64), always),
                 Column(T.FLOAT64, mean, always),

@@ -23,11 +23,14 @@ a pair sort 3 ms, a float64 scatter-add 74 ms):
   sum(x) and avg(x) share a lane); boundary takes and per-group outputs
   run at the plan's ``num_slots`` (the core's output capacity), not at
   the batch's capacity;
-- everywhere else (the eager grouped fallback, the global core, the mesh
-  step) the plan is the chained pair sort of ``kernels/sort.py`` over
-  unmerged words, an input is gathered into sorted order on first use,
-  once per array (``GroupPlan.in_order``), each DOUBLE sum is its own
-  ``segment_sum``, and ``num_slots`` is the batch's capacity.
+- everywhere else (the eager grouped fallback, the mesh step) the plan
+  is the chained pair sort of ``kernels/sort.py`` over unmerged words,
+  an input is gathered into sorted order on first use, once per array
+  (``GroupPlan.in_order``), each DOUBLE sum is its own ``segment_sum``,
+  and ``num_slots`` is the batch's capacity;
+- a global aggregate (no group keys) takes ``single_group_plan``: no
+  sort, the rows stay where they are, and every segment kernel reduces
+  the masked rows instead of scattering them into one slot.
 """
 from __future__ import annotations
 
@@ -60,6 +63,9 @@ class GroupPlan:
     moved: dict = dataclasses.field(default_factory=dict)
     # (id(data), id(validity)) -> per-group float64 sum (stack_float_sums)
     sums: dict = dataclasses.field(default_factory=dict)
+    # static: the one-group plan (``single_group_plan``), whose rows are
+    # in place and whose kernels reduce instead of scattering
+    single: bool = False
 
     @property
     def num_slots(self) -> int:
@@ -70,11 +76,17 @@ class GroupPlan:
     def in_order(self, values):
         """``values`` in the plan's sorted order: moved there with the
         other inputs (``_gather_rows_once``), or gathered on first use."""
+        if self.single:
+            return values
         hit = self.moved.get(id(values))
         if hit is None:
             hit = self.moved[id(values)] = (values,
                                             jnp.take(values, self.perm))
         return hit[1]
+
+    def source_rows(self, pos):
+        """The input rows at sorted positions ``pos``."""
+        return pos if self.single else jnp.take(self.perm, pos)
 
 
 def _as_lanes(a):
@@ -202,8 +214,61 @@ def groupby_plan(words: List[jnp.ndarray], num_slots: Optional[int] = None,
                      head_pos, last_pos, boundary, moved)
 
 
+def single_group_plan(live) -> GroupPlan:
+    """The plan of a global aggregate: every ``live`` row (in input
+    order) is one group.  Nothing is sorted or moved; the group spans
+    every slot (a dead row contributes nothing: each kernel masks by
+    ``live_sorted``), and every per-group output has one slot.  The
+    segment kernels see ``single`` and reduce the masked rows where a
+    grouped plan scatters them (``seg_reduce``)."""
+    cap = live.shape[0]
+    first = jnp.zeros(1, jnp.int32)
+    return GroupPlan(perm=jnp.arange(cap, dtype=jnp.int32),
+                     seg_id=jnp.zeros(cap, jnp.int32), live_sorted=live,
+                     rep_indices=first, num_groups=jnp.int32(1),
+                     head_pos=first,
+                     last_pos=jnp.full(1, cap - 1, jnp.int32),
+                     boundary=jnp.arange(cap) == 0, single=True)
+
+
 def _sorted_vals(plan: GroupPlan, values, validity):
     return plan.in_order(values), plan.in_order(validity) & plan.live_sorted
+
+
+def _one_group_totals(stack):
+    """float64 totals of ``stack`` ([k, rows], dead rows zero) along its
+    rows as [k, 1]: halves added pairwise, full float64 adds in tree
+    order (an error of at most log2(rows) roundings of the absolute
+    sum, as ``_segmented_totals`` gives a group)."""
+    k, n = stack.shape
+    width = 1 << max(0, n - 1).bit_length()
+    run = jnp.pad(stack, ((0, 0), (0, width - n)))
+    while width > 1:
+        width //= 2
+        run = run[:, :width] + run[:, width:]
+    return run
+
+
+def seg_reduce(plan: GroupPlan, contrib, how: str):
+    """Per-group ``how`` ("sum", "min", "max") of an already-masked
+    per-sorted-row array: a scatter into ``num_slots`` segments, or on
+    the one-group plan a reduce (float64 sums in tree order)."""
+    if plan.single:
+        if how != "sum":
+            return getattr(jnp, how)(contrib, keepdims=True)
+        if jnp.issubdtype(contrib.dtype, jnp.floating):
+            return _one_group_totals(contrib[None])[0]
+        return jnp.sum(contrib, dtype=contrib.dtype, keepdims=True)
+    scatter = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
+               "max": jax.ops.segment_max}[how]
+    return scatter(contrib, plan.seg_id, num_segments=plan.num_slots)
+
+
+def seg_spread(plan: GroupPlan, per_group):
+    """A per-group array read back at every sorted row."""
+    if plan.single:
+        return jnp.broadcast_to(per_group[0], plan.seg_id.shape)
+    return jnp.take(per_group, plan.seg_id, mode="clip")
 
 
 def seg_prefix_sum(plan: GroupPlan, contrib):
@@ -216,6 +281,8 @@ def seg_prefix_sum(plan: GroupPlan, contrib):
     cap = contrib.shape[0]
     if contrib.dtype == jnp.bool_:
         contrib = contrib.astype(jnp.int64)     # as cumsum widens it
+    if plan.single:
+        return seg_reduce(plan, contrib, "sum")
     cum = prefix_sum(contrib)
     ex = cum - contrib                       # exclusive prefix per row
     hp = jnp.clip(plan.head_pos, 0, cap - 1)
@@ -250,7 +317,8 @@ def stack_float_sums(plan: GroupPlan, cols) -> None:
         v, ok = _sorted_vals(plan, c.data, c.validity)
         lanes.append(jnp.where(ok, v.astype(jnp.float64), 0.0))
     stack = jnp.stack(lanes)
-    totals = _segmented_totals(plan, stack)
+    totals = _one_group_totals(stack) if plan.single \
+        else _segmented_totals(plan, stack)
     for i, (key, c) in enumerate(todo.items()):
         # the Column rides along so the ids in the key stay its own
         plan.sums[key] = (c, totals[i])
@@ -289,6 +357,8 @@ def seg_sum(plan: GroupPlan, values, validity, out_dtype=None):
     if jnp.issubdtype(contrib.dtype, jnp.integer) or \
             contrib.dtype == jnp.bool_:
         return seg_prefix_sum(plan, contrib)
+    if plan.single:
+        return seg_reduce(plan, contrib, "sum")
     with _trace.launch("seg_sum_scatter", 1, contrib.shape[0]):
         return jax.ops.segment_sum(contrib, plan.seg_id,
                                    num_segments=plan.num_slots)
@@ -313,21 +383,18 @@ def _type_extreme(dtype, want_max: bool):
 
 def seg_minmax_u64(plan: GroupPlan, words, ok, want_max: bool):
     """Per-group min/max of uint64 order-words WITHOUT a 64-bit scatter:
-    two u32 scatter passes (hi word, then lo word among hi-winners).
+    two u32 passes (hi word, then lo word among hi-winners).
     64-bit scatters are ~5x slower than 32-bit ones on the chip (XLA
     lowers i64 as 32-bit pairs); this keeps the reduction native."""
-    slots = plan.num_slots
     w = words.astype(jnp.uint64)
     if not want_max:
         w = ~w                               # min == max of complement
     hi = (w >> jnp.uint64(32)).astype(jnp.uint32)
     lo = (w & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
     z = jnp.uint32(0)
-    mhi = jax.ops.segment_max(jnp.where(ok, hi, z), plan.seg_id,
-                              num_segments=slots)
-    on_hi = ok & (hi == jnp.take(mhi, plan.seg_id, mode="clip"))
-    mlo = jax.ops.segment_max(jnp.where(on_hi, lo, z), plan.seg_id,
-                              num_segments=slots)
+    mhi = seg_reduce(plan, jnp.where(ok, hi, z), "max")
+    on_hi = ok & (hi == seg_spread(plan, mhi))
+    mlo = seg_reduce(plan, jnp.where(on_hi, lo, z), "max")
     out = (mhi.astype(jnp.uint64) << jnp.uint64(32)) | \
         mlo.astype(jnp.uint64)
     if not want_max:
@@ -349,7 +416,6 @@ def _seg_minmax_i64(plan, v, ok, want_max: bool):
 
 
 def seg_min(plan: GroupPlan, values, validity):
-    cap = plan.num_slots
     v, ok = _sorted_vals(plan, values, validity)
     if jnp.issubdtype(v.dtype, jnp.floating):
         # Spark total order: NaN greatest, -0.0 == 0.0.  No bit encoding
@@ -358,7 +424,7 @@ def seg_min(plan: GroupPlan, values, validity):
         v = jnp.where(v == 0.0, jnp.array(0.0, v.dtype), v)
         nan = jnp.isnan(v)
         contrib = jnp.where(ok & ~nan, v, jnp.array(jnp.inf, v.dtype))
-        m = jax.ops.segment_min(contrib, plan.seg_id, num_segments=cap)
+        m = seg_reduce(plan, contrib, "min")
         has_num = seg_prefix_sum(plan, (ok & ~nan).astype(jnp.int32)) > 0
         return jnp.where(has_num, m, jnp.array(jnp.nan, v.dtype))
     if v.dtype in (jnp.int64, jnp.uint64):
@@ -367,18 +433,17 @@ def seg_min(plan: GroupPlan, values, validity):
         return _seg_minmax_i64(plan, v, ok, want_max=False)
     ident = _type_extreme(v.dtype, want_max=False)
     contrib = jnp.where(ok, v, ident)
-    return jax.ops.segment_min(contrib, plan.seg_id, num_segments=cap)
+    return seg_reduce(plan, contrib, "min")
 
 
 def seg_max(plan: GroupPlan, values, validity):
-    cap = plan.num_slots
     v, ok = _sorted_vals(plan, values, validity)
     if jnp.issubdtype(v.dtype, jnp.floating):
         # NaN is the greatest value: any NaN in the group wins
         v = jnp.where(v == 0.0, jnp.array(0.0, v.dtype), v)
         nan = jnp.isnan(v)
         contrib = jnp.where(ok & ~nan, v, jnp.array(-jnp.inf, v.dtype))
-        m = jax.ops.segment_max(contrib, plan.seg_id, num_segments=cap)
+        m = seg_reduce(plan, contrib, "max")
         has_nan = seg_prefix_sum(plan, (ok & nan).astype(jnp.int32)) > 0
         return jnp.where(has_nan, jnp.array(jnp.nan, v.dtype), m)
     if v.dtype in (jnp.int64, jnp.uint64):
@@ -387,7 +452,7 @@ def seg_max(plan: GroupPlan, values, validity):
         return _seg_minmax_i64(plan, v, ok, want_max=True)
     ident = _type_extreme(v.dtype, want_max=True)
     contrib = jnp.where(ok, v, ident)
-    return jax.ops.segment_max(contrib, plan.seg_id, num_segments=cap)
+    return seg_reduce(plan, contrib, "max")
 
 
 def seg_first_index(plan: GroupPlan, validity, ignore_nulls: bool = True):
@@ -397,10 +462,9 @@ def seg_first_index(plan: GroupPlan, validity, ignore_nulls: bool = True):
         else plan.live_sorted
     pos = jnp.arange(cap, dtype=jnp.int32)
     contrib = jnp.where(ok, pos, jnp.int32(cap))
-    first_pos = jax.ops.segment_min(contrib, plan.seg_id,
-                                    num_segments=plan.num_slots)
+    first_pos = seg_reduce(plan, contrib, "min")
     safe = jnp.clip(first_pos, 0, cap - 1).astype(jnp.int32)
-    return jnp.take(plan.perm, safe), first_pos < cap
+    return plan.source_rows(safe), first_pos < cap
 
 
 def seg_first_index_by_order(plan: GroupPlan, col, want_min: bool = True,
@@ -420,16 +484,15 @@ def seg_first_index_by_order(plan: GroupPlan, col, want_min: bool = True,
     ok = plan.in_order(col.validity) & plan.live_sorted
     cand = ok
     for w in words:
-        ws = jnp.take(w, plan.perm).astype(jnp.uint64)
+        ws = plan.in_order(w).astype(jnp.uint64)
         m = seg_minmax_u64(plan, ws, cand, want_max=False)
-        cand = cand & (ws == jnp.take(m, plan.seg_id, mode="clip"))
+        cand = cand & (ws == seg_spread(plan, m))
     pos = jnp.arange(cap, dtype=jnp.int32)
     contrib = jnp.where(cand, pos, jnp.int32(cap))
-    first_pos = jax.ops.segment_min(contrib, plan.seg_id,
-                                    num_segments=plan.num_slots)
+    first_pos = seg_reduce(plan, contrib, "min")
     has = first_pos < cap
     safe = jnp.clip(first_pos, 0, cap - 1).astype(jnp.int32)
-    return jnp.take(plan.perm, safe), has
+    return plan.source_rows(safe), has
 
 
 def seg_last_index(plan: GroupPlan, validity, ignore_nulls: bool = True):
@@ -438,10 +501,9 @@ def seg_last_index(plan: GroupPlan, validity, ignore_nulls: bool = True):
         else plan.live_sorted
     pos = jnp.arange(cap, dtype=jnp.int32)
     contrib = jnp.where(ok, pos, jnp.int32(-1))
-    last_pos = jax.ops.segment_max(contrib, plan.seg_id,
-                                   num_segments=plan.num_slots)
+    last_pos = seg_reduce(plan, contrib, "max")
     safe = jnp.clip(last_pos, 0, cap - 1).astype(jnp.int32)
-    return jnp.take(plan.perm, safe), last_pos >= 0
+    return plan.source_rows(safe), last_pos >= 0
 
 
 # ---------------------------------------------------------------------------
