@@ -6,8 +6,13 @@ N small pulls serialize the host against the device N times.  The
 engine therefore NEVER pulls values one at a time: every host-visible
 value (row counts, shuffle bin counts, output column buffers, speculative
 fit flags) is *staged* here, and the first forced value flushes the whole
-pool as at most TWO fused transfers (a uint32 stream and, when doubles
-are present, a float64 stream).  (The design dates from a backend whose
+pool in one wait: every staged part's copy to the host starts at once
+(``jax.device_get`` of them all) and the parts are joined on the host
+into at most two streams (uint32 and, when doubles are present,
+float64): joined on the device by ``jnp.concatenate`` they cost one
+eager program for every tuple of part sizes a flush happened to hold,
+compiled again and again inside a benchmark's window (ROADMAP S9; 43-59
+compiles a window in ``tpch_q13q21.power``).  (The design dates from a backend whose
 round trip was ~65-100 ms; the per-pull cost on the local chip is not
 measured on the current machine — the flush count stays the unit the
 planner predicts and the tests pin.)
@@ -296,8 +301,7 @@ def _flush_items(items: List[Staged]):
         flats, offs = {}, {}
         for name, parts in streams.items():
             if parts:
-                flats[name] = np.asarray(jnp.concatenate(parts)
-                                         if len(parts) > 1 else parts[0])
+                flats[name] = np.concatenate(jax.device_get(parts))
                 o, lst = 0, []
                 for p in parts:
                     lst.append(o)

@@ -50,6 +50,20 @@ def _null_column(dtype: T.DType, capacity: int) -> Column:
     return Column.all_null(dtype, capacity)
 
 
+def _bound_ordinals(e: ec.Expression) -> set:
+    if isinstance(e, ec.BoundReference):
+        return {e.ordinal}
+    return set().union(*[_bound_ordinals(c) for c in e.children])
+
+
+def _rebind(e: ec.Expression, ordinal: dict) -> ec.Expression:
+    """``e`` with every column reference moved to ``ordinal[old]``."""
+    if isinstance(e, ec.BoundReference):
+        return ec.BoundReference(ordinal[e.ordinal], e.dtype(), True,
+                                 e.col_name)
+    return e.map_children(lambda c: _rebind(c, ordinal))
+
+
 class TpuHashJoinBase(TpuExec):
     """Shared build/probe logic.  children = [left, right]; the build side
 
@@ -162,8 +176,7 @@ class TpuHashJoinBase(TpuExec):
         # join's first small partition must not freeze the strategy for
         # later large ones); once built, the table is memoized.
         stream_cap = sum(b.capacity for b in stream_batches)
-        if (not memo["direct_done"] and lg.condition is None
-                and lg.join_type != "full"
+        if (not memo["direct_done"] and lg.join_type != "full"
                 and stream_cap >= (1 << 19)):
             memo["direct"] = self._prepare_direct(bt, bkey_cols, build)
             memo["direct_done"] = True
@@ -205,6 +218,7 @@ class TpuHashJoinBase(TpuExec):
                     yield self._note_output(out)
                 return
 
+        kept = []   # a residual join's surviving pairs, a batch each
         # Phase A: probe counts for EVERY stream batch first; the output
         # sizes (total matches) stage into the pending pool so one fused
         # flush covers all of them (columnar/pending.py).  Phase B then
@@ -235,10 +249,15 @@ class TpuHashJoinBase(TpuExec):
                     pa = self._probe_phase(sb, skey_cols, bt, str_words,
                                            build_matched, direct)
                 pending.flush()
-            if pa is None:   # legacy eager path (full/residual/etc)
+            if pa is None:   # legacy eager path (full outer, etc.)
                 with timed(self.metrics[JOIN_TIME], self):
                     outs = [self._join_batch(sb, skey_cols, build, bt,
-                                             str_words, build_matched)]
+                                             str_words, build_matched, kept)]
+            elif lg.condition is not None:
+                _, _, lo, counts, _, total = pa
+                with timed(self.metrics[JOIN_TIME], self):
+                    outs = [self._residual_batch(sb, build, bt, lo, counts,
+                                                 int(total), None, kept)]
             else:
                 # generator: each chunk's expansion times itself
                 outs = self._expand_phases(sb, build, bt, *pa)
@@ -251,6 +270,10 @@ class TpuHashJoinBase(TpuExec):
                                              stream_schema)
             if out is not None and out.num_rows > 0:
                 yield self._note_output(out)
+        if kept:
+            # read once the partition is done: by then a later flush of
+            # the pool has most likely brought the counts over already
+            _trace.count("join.residual.kept", sum(int(k) for k in kept))
 
     def _note_output(self, out: ColumnarBatch) -> ColumnarBatch:
         self.metrics[NUM_OUTPUT_ROWS] += out.rows_lazy
@@ -357,14 +380,16 @@ class TpuHashJoinBase(TpuExec):
         from ..columnar.batch import LazyCount
         lg = self.logical
         jt = lg.join_type
-        if jt == "full" or lg.condition is not None or build_matched \
-                is not None:
+        if jt == "full" or build_matched is not None:
             return None
         if not all(type(c) is Column for c in skey_cols):
             return None
+        # a residual condition decides per candidate pair: the total to
+        # size is the pairs, whatever the join type
+        residual = lg.condition is not None
         key = ("probe", jt, tuple(c.dtype.name for c in skey_cols),
                sb.capacity, bt.capacity, len(bt.sorted_words),
-               self.build_right, direct is not None and direct[4])
+               self.build_right, direct is not None and direct[4], residual)
         fn = TpuHashJoinBase._PROBE_JIT.get(key)
         if fn is False:
             return None
@@ -394,7 +419,9 @@ class TpuHashJoinBase(TpuExec):
                     bt2 = join_k.BuildTable(list(bws), None, None)
                     jc = join_k.probe_counts(bt2, swords, num_rows)
                     counts, lo = jc.counts, jc.lo
-                if jt in ("semi", "anti"):
+                if residual:
+                    eff = counts
+                elif jt in ("semi", "anti"):
                     keep = (counts > 0) if jt == "semi" else \
                         ((counts == 0) & in_range)
                     eff = keep.astype(jnp.int32)
@@ -740,7 +767,8 @@ class TpuHashJoinBase(TpuExec):
 
     # ------------------------------------------------------------------
     def _join_batch(self, sb: ColumnarBatch, skey_cols, build, bt,
-                    str_words, build_matched) -> Optional[ColumnarBatch]:
+                    str_words, build_matched,
+                    kept=None) -> Optional[ColumnarBatch]:
         lg = self.logical
         jt = lg.join_type
         swords = _key_words(skey_cols, sb.num_rows, str_words)
@@ -750,8 +778,10 @@ class TpuHashJoinBase(TpuExec):
             # residual restricts which PAIRS match; outer/semi/anti row
             # semantics are decided on the surviving pairs (a plain
             # post-filter would wrongly drop null-extended outer rows)
-            return self._join_batch_residual(sb, jc, build, bt,
-                                             build_matched)
+            return self._residual_batch(
+                sb, build, bt, jc.lo, jc.counts,
+                join_k.total_matches(jc.counts), build_matched,
+                [] if kept is None else kept)
 
         if jt in ("semi", "anti"):
             from ..kernels import basic as bk
@@ -813,79 +843,156 @@ class TpuHashJoinBase(TpuExec):
         bcols = [c.mask_validity(live_mask) for c in build_out.columns]
         return self._assemble(scols, bcols, total)
 
-    def _join_batch_residual(self, sb, jc, build, bt,
-                             build_matched) -> Optional[ColumnarBatch]:
-        """Join with a residual (non-equi) condition: expand the INNER
-        pairs, evaluate the condition per pair, then derive the join
-        type's row set from the surviving pairs."""
-        from ..kernels import basic as bk
-        lg = self.logical
-        jt = lg.join_type
+    _RESIDUAL_JIT: dict = {}
+
+    def _residual_plan(self):
+        """-> (the condition bound to the columns it reads, in order,
+        its signature, their ordinals on the stream side, on the build
+        side, their schema), once an exec.  The signature is taken before
+        any evaluation: evaluating memoizes on the nodes (``EqualTo``'s
+        promoted sides), which a later signature would read as another
+        program."""
+        plan = getattr(self, "_residual_memo", None)
+        if plan is not None:
+            return plan
         lschema = self.children[0].output_schema
         rschema = self.children[1].output_schema
-        pair_schema = Schema(
-            [Field(f.name, f.dtype, True) for f in lschema] +
-            [Field(f.name, f.dtype, True) for f in rschema])
+        fields = [Field(f.name, f.dtype, True)
+                  for f in list(lschema) + list(rschema)]
+        bound = self.logical.condition.bind(Schema(fields))
+        read = sorted(_bound_ordinals(bound))
+        nleft = len(lschema)
+        left = [i for i in read if i < nleft]
+        right = [i - nleft for i in read if i >= nleft]
+        s_ords, b_ords = (left, right) if self.build_right else (right, left)
+        # the gathered columns: the stream side's first, then the build's
+        order = ([i for i in read if (i < nleft) == self.build_right] +
+                 [i for i in read if (i < nleft) != self.build_right])
+        cond = _rebind(bound, {o: k for k, o in enumerate(order)})
+        from .fused import expr_signature
+        plan = (cond, expr_signature(cond), s_ords, b_ords,
+                Schema([fields[o] for o in order]))
+        self._residual_memo = plan
+        return plan
 
-        total = int(join_k.total_matches(jc.counts))
-        out_cap = bucket_capacity(max(total, 1))
-        p_idx, b_idx, _live, _ = join_k.join_expand_matches(
-            jc.lo, jc.counts, bt.perm, out_cap)
-        stream_out = sb.gather(p_idx, total)
-        build_out = build.gather(b_idx, total)
-        live_mask = jnp.arange(out_cap) < total
-        scols = [c.mask_validity(live_mask) for c in stream_out.columns]
-        bcols = [c.mask_validity(live_mask) for c in build_out.columns]
-        if self.build_right:
-            pair_cols = scols + bcols
+    def _residual_keep(self, sb, build, bt, lo, counts, out_cap: int,
+                       pairs: bool):
+        """ONE program over the candidate pairs: expand (lo, counts),
+        gather the columns the condition reads and nothing else, evaluate
+        it, and give each stream row whether one of its pairs survived
+        (its pairs are a run of the output rows: a running sum of the
+        survivors read at the run's two ends).  -> (surv, kept pairs, and
+        with ``pairs`` the survivors' flags and both gather maps)."""
+        import jax
+        cond, sig, s_ords, b_ords, schema = self._residual_plan()
+        scols = [sb.columns[i] for i in s_ords]
+        bcols = [build.columns[i] for i in b_ords]
+        n_read = len(scols) + len(bcols)
+
+        def _core(lo, counts, perm, sarrs, barrs, scols=None, bcols=None):
+            p_idx, b_idx, live, _ = join_k.join_expand_matches(
+                lo, counts, perm, out_cap)
+            if scols is None:
+                with jax.named_scope("gather_condition"):
+                    cols = [Column(f.dtype, jnp.take(d, i, mode="clip"),
+                                   jnp.take(v, i, mode="clip") & live)
+                            for f, (d, v), i in zip(
+                                schema, sarrs + barrs,
+                                [p_idx] * len(sarrs) + [b_idx] * len(barrs))]
+            else:
+                cols = [c.gather(p_idx, live=live) for c in scols] + \
+                    [c.gather(b_idx, live=live) for c in bcols]
+            pred = ec.eval_as_column(cond, ColumnarBatch(schema, cols,
+                                                         out_cap))
+            keep = pred.data.astype(bool) & pred.validity & live
+            kc = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                  prefix_sum(keep.astype(jnp.int32))])
+            incl = prefix_sum(counts.astype(jnp.int32))
+            surv = jnp.take(kc, incl) > jnp.take(kc, incl - counts)
+            if pairs:
+                return surv, kc[-1], keep, p_idx, b_idx
+            return surv, kc[-1]
+
+        if sig is None or not all(type(c) is Column
+                                  for c in scols + bcols):
+            # strings or nested columns size their buffers on the host
+            # (and an opaque condition shares no program): the same
+            # steps, eagerly, on the condition's columns alone
+            return _core(lo, counts, bt.perm, (), (), scols, bcols)
+        key = ("residual", sig, pairs, out_cap, sb.capacity,
+               build.capacity, tuple(f.dtype.name for f in schema),
+               len(scols))
+        fn = TpuHashJoinBase._RESIDUAL_JIT.get(key)
+        if fn is None:
+            prog = _compile_watch.jit(_core, "join_residual_core")
+            # a launch's lanes: the indices it gathers, out_cap a column
+            prog.lanes = lambda *a, _n=out_cap * n_read, **k: _n
+            fn = _compile_watch.wrap_miss("join_residual", prog, str(key))
+            if len(TpuHashJoinBase._RESIDUAL_JIT) < 4096:
+                TpuHashJoinBase._RESIDUAL_JIT[key] = fn
+        return fn(lo, counts, bt.perm,
+                  tuple((c.data, c.validity) for c in scols),
+                  tuple((c.data, c.validity) for c in bcols))
+
+    def _residual_batch(self, sb, build, bt, lo, counts, total,
+                        build_matched, kept) -> Optional[ColumnarBatch]:
+        """Join with a residual (non-equi) condition: decide every
+        candidate pair (``lo``, ``counts``: ``total`` of them), then
+        derive the join type's rows from the survivors.  Semi and anti
+        joins compact the stream batch by the survival flags and never
+        gather a pair's other columns, nor pull a count to the host.
+        ``kept`` gets the survivors' count, still on the device."""
+        from ..columnar.batch import LazyCount
+        from ..kernels import basic as bk
+        jt = self.logical.join_type
+        _trace.count("join.residual.pairs", int(total))
+        pairs = jt not in ("semi", "anti")
+        in_range = jnp.arange(sb.capacity) < sb.rows_dev
+        if total:
+            out_cap = bucket_capacity(int(total))
+            got = self._residual_keep(sb, build, bt, lo, counts, out_cap,
+                                      pairs)
+            surv = got[0]
+            kept.append(LazyCount(got[1]))
         else:
-            pair_cols = bcols + scols
-        pairs = ColumnarBatch(pair_schema, pair_cols, total)
-        pred = ec.eval_as_column(lg.condition.bind(pair_schema), pairs)
-        keep = pred.data.astype(bool) & pred.validity & live_mask
+            surv = jnp.zeros(sb.capacity, dtype=bool)
 
-        # per-stream-row "has a surviving pair"
-        surv = jnp.zeros(sb.capacity, dtype=bool).at[
-            jnp.where(keep, p_idx, 0)].max(keep)
-        in_range = jnp.arange(sb.capacity) < sb.num_rows
-
-        if jt in ("semi", "anti"):
+        if not pairs:
             sel = surv if jt == "semi" else (~surv & in_range)
-            idx, cnt = bk.filter_compact_indices(sel, sb.num_rows)
-            n = _host_int(cnt)
-            out = sb.gather(idx, n)
-            mask = jnp.arange(out.capacity) < n
-            return ColumnarBatch(
-                self.output_schema,
-                [c.mask_validity(mask) for c in out.columns], n)
+            idx, cnt = bk.filter_compact_indices(sel, sb.rows_dev)
+            n = LazyCount(cnt)
+            mask = jnp.arange(sb.capacity) < cnt
+            out = sb.gather(idx, n, live=mask, unique=True)
+            return ColumnarBatch(self.output_schema,
+                                 [c.mask_validity(mask) for c in out.columns],
+                                 n)
 
-        if build_matched is not None and total:
-            from ..analysis import residency  # lazy: avoids import cycle
-            with residency.declared_transfer(site="join_verify"):
-                midx = np.asarray(jnp.where(keep, b_idx, 0))
-                keep_np = np.asarray(keep)
-            flags = np.zeros(build.capacity, dtype=bool)
-            flags[midx[keep_np]] = True
-            build_matched |= flags
-
-        # surviving pairs
-        pidx2, pcnt = bk.filter_compact_indices(keep, total)
-        n_pairs = _host_int(pcnt)
-        sp = stream_out.gather(pidx2, n_pairs)
-        bp = build_out.gather(pidx2, n_pairs)
-        pmask = jnp.arange(sp.capacity) < n_pairs
-        sp_cols = [c.mask_validity(pmask) for c in sp.columns]
-        bp_cols = [c.mask_validity(pmask) for c in bp.columns]
         parts = []
-        if n_pairs:
-            parts.append(self._assemble(sp_cols, bp_cols, n_pairs))
+        if total:
+            _, _, keep, p_idx, b_idx = got
+            if build_matched is not None:
+                from ..analysis import residency  # lazy: avoids import cycle
+                with residency.declared_transfer(site="join_verify"):
+                    midx = np.asarray(jnp.where(keep, b_idx, 0))
+                    keep_np = np.asarray(keep)
+                build_matched[midx[keep_np]] = True
+            # the surviving pairs: each output column gathered once
+            pidx2, pcnt = bk.filter_compact_indices(keep, int(total))
+            n_pairs = _host_int(pcnt)
+            if n_pairs:
+                sp = sb.gather(jnp.take(p_idx, pidx2), n_pairs)
+                bp = build.gather(jnp.take(b_idx, pidx2), n_pairs)
+                pmask = jnp.arange(sp.capacity) < n_pairs
+                parts.append(self._assemble(
+                    [c.mask_validity(pmask) for c in sp.columns],
+                    [c.mask_validity(pmask) for c in bp.columns], n_pairs))
 
         outer_stream = ((jt == "left" and self.build_right) or
                         (jt == "right" and not self.build_right) or
                         jt == "full")
         if outer_stream:
-            un = ~surv & in_range
-            uidx, ucnt = bk.filter_compact_indices(un, sb.num_rows)
+            uidx, ucnt = bk.filter_compact_indices(~surv & in_range,
+                                                   sb.rows_dev)
             n_un = _host_int(ucnt)
             if n_un:
                 su = sb.gather(uidx, n_un)
@@ -1162,7 +1269,7 @@ def _audit_specs():
         out = j._probe_phase(sb, [scol], bt, [None], None, None)
         assert out is not None, "probe phase fell back"
         key = ("probe", "inner", (T.INT64.name,), sb.capacity,
-               bt.capacity, len(bt.sorted_words), True, False)
+               bt.capacity, len(bt.sorted_words), True, False, False)
         fn = TpuHashJoinBase._PROBE_JIT[key]
         sws, ka, nr = _sds_args(sb, bt)
         return fn, (sws, None, ka, nr), {}
@@ -1185,7 +1292,33 @@ def _audit_specs():
         args = (sws, None, ka, nr, perm, (d,), (v,), (d,), (v,))
         return fn, args, {}
 
+    def _residual_build():
+        """A semi join on ``sk = bk`` with the residual ``sk <> bk``."""
+        from ..expr import predicates as ep
+        j, sb, scol, bt, build = _fixture()
+        j.logical = SimpleNamespace(
+            join_type="semi", schema=sb.schema, condition=ep.Not(ep.EqualTo(
+                ec.AttributeReference("sk", T.INT64, True),
+                ec.AttributeReference("bk", T.INT64, True))))
+        j.children = [SimpleNamespace(output_schema=sb.schema),
+                      SimpleNamespace(output_schema=build.schema)]
+        lo = jnp.arange(sb.capacity, dtype=jnp.int32)
+        counts = jnp.ones(sb.capacity, jnp.int32)
+        j._residual_keep(sb, build, bt, lo, counts, sb.capacity, False)
+        fn = next(f for k, f in TpuHashJoinBase._RESIDUAL_JIT.items()
+                  if k[2:5] == (False, sb.capacity, sb.capacity))
+        i32 = jax.ShapeDtypeStruct((sb.capacity,), jnp.int32)
+        col = (jax.ShapeDtypeStruct((sb.capacity,), jnp.int64),
+               jax.ShapeDtypeStruct((sb.capacity,), jnp.bool_))
+        perm = jax.ShapeDtypeStruct(bt.perm.shape, bt.perm.dtype)
+        return fn, (i32, i32, perm, (col,), (col,)), {}
+
     return [
+        AuditSpec("join_residual", "join_residual", _residual_build,
+                  notes="residual semi join: expansion, the condition's "
+                        "two columns gathered, survival by a running sum",
+                  budgets={"gather": 8, "scatter": 1, "transpose": 0,
+                           "sort": 0}),
         AuditSpec("join_probe", "join_probe", _probe_build,
                   notes="phase-A probe counts, inner join, int64 key",
                   budgets={"gather": 16, "scatter": 2, "transpose": 2,

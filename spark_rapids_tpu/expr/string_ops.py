@@ -134,9 +134,10 @@ class Contains(_LiteralPatternPredicate):
 class Like(Expression):
     """SQL LIKE with literal pattern.
 
-    Device fast paths for pure prefix/suffix/contains patterns (the reference
-    treats 'regexp like a regular string' the same way,
-    GpuOverrides.scala:470); general patterns fall back to host regex.
+    Every pattern of literal bytes and ``%`` runs on the device as one
+    program (``kernels/strings.str_like_match``; the reference treats
+    'regexp like a regular string' the same way, GpuOverrides.scala:470);
+    ``_`` or an escape falls back to the host regex.
     """
 
     def __init__(self, child, pattern: Expression, escape: str = "\\"):
@@ -154,51 +155,19 @@ class Like(Expression):
         assert isinstance(pat, Literal), "LIKE pattern must be literal"
         col = _eval_string(self.children[0], batch)
         p = str(pat.value)
-        plain = p.replace("%", "").replace("_", "")
         # escaped wildcards (literal %% / _) need the unescaping regex
         # path: the byte fast paths would treat the escape as content
         has_special = "_" in p or self.escape in p
         if not has_special:
-            if p.startswith("%") and p.endswith("%") and \
-                    "%" not in p[1:-1] and len(p) >= 2:
-                mask = skern.contains(col, plain.encode())
-                return Column(T.BOOL, mask, col.validity)
-            if p.endswith("%") and "%" not in p[:-1]:
-                mask = skern.starts_with(col, plain.encode())
-                return Column(T.BOOL, mask, col.validity)
-            if p.startswith("%") and "%" not in p[1:]:
-                mask = skern.ends_with(col, plain.encode())
-                return Column(T.BOOL, mask, col.validity)
             if "%" not in p:
                 from .predicates import EqualTo
                 return EqualTo(self.children[0],
                                Literal(p, T.STRING)).columnar_eval(batch)
-            # general %-only pattern ('a%b%c'): ordered device segment
-            # search via find_in_row — no host round trip (the
-            # JoinGatherer-era weak spot: string filters silently
-            # serializing through the host per batch)
-            if self.escape not in p and len(p) <= 256:
-                segs = [sg.encode() for sg in p.split("%")]
-                cap = col.capacity
-                ok = col.validity.astype(bool) & jnp.ones(cap, bool)
-                pos = jnp.zeros(cap, jnp.int32)
-                anchored_start = segs[0] != b""
-                anchored_end = segs[-1] != b""
-                if anchored_start:
-                    ok = ok & skern.starts_with(col, segs[0])
-                    pos = jnp.full(cap, len(segs[0]), jnp.int32)
-                middle = [sg for sg in segs[1:-1] if sg]
-                for sg in middle:
-                    f = skern.find_in_row(col, sg, pos)
-                    ok = ok & (f >= 0)
-                    pos = jnp.where(f >= 0, f + len(sg), pos)
-                if anchored_end:
-                    last = segs[-1]
-                    blen = skern.byte_length(col)
-                    end_rel = blen - len(last)
-                    ok = ok & skern.ends_with(col, last) & \
-                        (end_rel >= pos)
-                return Column(T.BOOL, ok, col.validity)
+            # every %-only pattern ('abc%', '%x%', 'a%b%c'): one device
+            # program over the bytes, no host round trip (the weak spot
+            # of string filters serializing through the host per batch)
+            mask = skern.like(col, [s.encode() for s in p.split("%")])
+            return Column(T.BOOL, mask, col.validity)
         # host regex fallback
         _note_host_regex(f"LIKE {p!r}")
         rx = re.compile(_like_to_regex(p, self.escape), re.DOTALL)

@@ -33,6 +33,19 @@ def prefix_sum(x):
     return x
 
 
+def prefix_max(x, fill):
+    """Inclusive running maximum of a 1-D array by ``prefix_sum``'s
+    log2(n) shift steps; ``fill`` (at most every value) enters from the
+    left.  ``lax.cummax`` lowers to the windowed reduce whose compile is
+    the ``cumsum`` trouble ``prefix_sum`` describes."""
+    d = 1
+    while d < x.shape[0]:
+        x = jnp.maximum(x, jnp.concatenate([jnp.full((d,), fill, x.dtype),
+                                            x[:-d]]))
+        d *= 2
+    return x
+
+
 def rows_flagged_first(flag):
     """Row numbers (uint32) of a 1-D boolean array, the flagged rows
     first and each side in row order: one uint32 word a row (the flag's

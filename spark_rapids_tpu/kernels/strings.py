@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 from ..columnar.column import StringColumn, bucket_capacity
 from ..obs import trace as _obs_trace
-from .basic import prefix_sum
+from .basic import prefix_max, prefix_sum
 
 
 def string_lengths(offsets) -> jnp.ndarray:
@@ -339,82 +339,96 @@ def byte_length(col: StringColumn) -> jnp.ndarray:
     return (col.offsets[1:] - col.offsets[:-1]).astype(jnp.int32)
 
 
+def _like_lanes(offsets, data, segs):
+    """A launch's lanes: the string bytes it scans, which are also the
+    counter ``str.like.bytes``; ``str.like.rows`` adds its rows."""
+    _obs_trace.count("str.like.bytes", data.shape[0])
+    _obs_trace.count("str.like.rows", offsets.shape[0] - 1)
+    return data.shape[0]
+
+
+@_obs_trace.launched(lanes=_like_lanes)
+@functools.partial(jax.jit, static_argnames=("segs",))
+def str_like_match(offsets, data, segs):
+    """``bool[cap]``: does row ``r``'s string match the LIKE pattern
+    whose pieces between ``%`` are ``segs`` (two or more byte strings:
+    ``(b"ab", b"")`` is ``ab%``, ``(b"", b"x", b"y", b"")`` is
+    ``%x%y%``; no ``_`` and no escape)?
+
+    One pass over the bytes for all the pieces, in the greedy order
+    SQL's ``%`` allows: the head at the row's start, the tail at its
+    end, the pieces between them left to right without overlap.  A
+    piece's occurrences at every byte come from comparing shifted
+    slices of the byte buffer with its bytes; a running maximum of the
+    positions where the piece may stand (``prefix_max``) tells each
+    later position, and each row's end, where the nearest one is.  An
+    occurrence counts for a row only from that row's own bytes: the
+    last piece must end by the row's tail, and with two pieces or more
+    each must follow the one before inside its row, whose start every
+    byte learns from a scatter at the row starts and the same running
+    maximum.  No gather a byte, no search of a byte's row, no
+    ``cumsum``: the chip pays per gathered index (PERF.md section 5)."""
+    n = data.shape[0]
+    head, tail = segs[0], segs[-1]
+    mids = [s for s in segs[1:-1] if s]
+    starts = offsets[:-1].astype(jnp.int32)
+    lo = starts + len(head)                         # first byte past the head
+    hi = offsets[1:].astype(jnp.int32) - len(tail)  # first byte of the tail
+    ok = lo <= hi
+    if n == 0:
+        return ok & (not head) & (not tail) & (not mids)
+    width = max(len(s) for s in segs)
+    padded = jnp.concatenate([data, jnp.zeros(width, data.dtype)])
+    pos = jnp.arange(n, dtype=jnp.int32)
+
+    def found(seg):
+        eq = jnp.ones(n, bool)
+        for k, c in enumerate(seg):
+            eq = eq & (padded[k:k + n] == jnp.uint8(c))
+        return eq
+
+    def at(x, idx):
+        return jnp.take(x, jnp.clip(idx, 0, n - 1))
+
+    if head:
+        ok = ok & at(found(head), starts)
+    if tail:
+        ok = ok & at(found(tail), hi)
+    if not mids:
+        return ok
+    row_start = None
+    if len(mids) > 1:
+        row_start = prefix_max(jnp.zeros(n, jnp.int32).at[starts].set(
+            starts, mode="drop"), 0)
+    last, prev_len = None, 0
+    for i, seg in enumerate(mids):
+        may = found(seg)
+        if row_start is not None:
+            if i == 0:
+                may = may & (pos >= row_start + len(head))
+            else:
+                before = jnp.concatenate([jnp.full(prev_len, -1, jnp.int32),
+                                          last[:n - prev_len]])
+                may = may & (before >= row_start)
+        last = prefix_max(jnp.where(may, pos, -1), -1)
+        prev_len = len(seg)
+    end = hi - prev_len
+    return ok & (end >= 0) & (at(last, end) >= lo)
+
+
+def like(col: StringColumn, segs) -> jnp.ndarray:
+    """``str_like_match`` over a column: the one place the program is
+    launched from."""
+    return str_like_match(col.offsets, col.data, tuple(segs))
+
+
 def starts_with(col: StringColumn, prefix: bytes) -> jnp.ndarray:
-    pat = np.frombuffer(prefix, np.uint8)
-    cap = col.capacity
-    if pat.size == 0:
-        return jnp.ones(cap, bool)
-    starts = col.offsets[:-1]
-    lens = col.offsets[1:] - starts
-    k = jnp.arange(pat.size, dtype=jnp.int32)
-    idx = jnp.clip(starts[:, None] + k[None, :], 0, col.data.shape[0] - 1)
-    byts = jnp.take(col.data, idx)
-    eq = jnp.all(byts == jnp.asarray(pat)[None, :], axis=1)
-    return eq & (lens >= pat.size)
+    return like(col, (prefix, b""))
 
 
 def ends_with(col: StringColumn, suffix: bytes) -> jnp.ndarray:
-    pat = np.frombuffer(suffix, np.uint8)
-    cap = col.capacity
-    if pat.size == 0:
-        return jnp.ones(cap, bool)
-    lens = col.offsets[1:] - col.offsets[:-1]
-    starts = col.offsets[1:] - pat.size
-    k = jnp.arange(pat.size, dtype=jnp.int32)
-    idx = jnp.clip(starts[:, None] + k[None, :], 0, col.data.shape[0] - 1)
-    byts = jnp.take(col.data, idx)
-    eq = jnp.all(byts == jnp.asarray(pat)[None, :], axis=1)
-    return eq & (lens >= pat.size)
+    return like(col, (b"", suffix))
 
 
 def contains(col: StringColumn, needle: bytes) -> jnp.ndarray:
-    """Substring containment via sliding window compare on the byte buffer."""
-    pat = np.frombuffer(needle, np.uint8)
-    if pat.size == 0:
-        return jnp.ones(col.capacity, bool)
-    data = col.data
-    B = data.shape[0]
-    k = jnp.arange(pat.size, dtype=jnp.int32)
-    idx = jnp.clip(jnp.arange(B, dtype=jnp.int32)[:, None] + k[None, :], 0,
-                   B - 1)
-    win_eq = jnp.all(jnp.take(data, idx) == jnp.asarray(pat)[None, :], axis=1)
-    # match position p counts for row i if starts[i] <= p <= ends[i]-len(pat)
-    hit_cum = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(win_eq.astype(jnp.int32))])
-    starts = col.offsets[:-1]
-    ends = jnp.maximum(col.offsets[1:] - pat.size + 1, starts)
-    a = jnp.take(hit_cum, jnp.clip(starts, 0, B))
-    b = jnp.take(hit_cum, jnp.clip(ends, 0, B))
-    return (b - a) > 0
-
-
-def find_in_row(col: StringColumn, needle: bytes,
-                from_rel) -> jnp.ndarray:
-    """Per row: smallest byte offset >= ``from_rel[row]`` where
-    ``needle`` occurs, else -1.  Powers the device multi-%%-segment
-    LIKE path (GpuOverrides treats 'regexp like a regular string' the
-    same way) — ordered segment search without the host regex engine."""
-    import jax
-    pat = np.frombuffer(needle, np.uint8)
-    cap = col.capacity
-    if pat.size == 0:
-        return jnp.maximum(from_rel, 0).astype(jnp.int32)
-    data = col.data
-    B = data.shape[0]
-    k = jnp.arange(pat.size, dtype=jnp.int32)
-    idx = jnp.clip(jnp.arange(B, dtype=jnp.int32)[:, None] + k[None, :],
-                   0, B - 1)
-    win_eq = jnp.all(jnp.take(data, idx) == jnp.asarray(pat)[None, :],
-                     axis=1)
-    g = jnp.arange(B, dtype=jnp.int32)
-    row = jnp.clip(jnp.searchsorted(col.offsets[1:], g, side="right"),
-                   0, cap - 1).astype(jnp.int32)
-    starts = jnp.take(col.offsets[:-1], row)
-    ends = jnp.take(col.offsets[1:], row)
-    rel = g - starts
-    ok = win_eq & (g + pat.size <= ends) & \
-        (rel >= jnp.take(from_rel.astype(jnp.int32), row))
-    inf = jnp.int32(2 ** 31 - 1)
-    cand = jnp.where(ok, rel, inf)
-    best = jax.ops.segment_min(cand, row, num_segments=cap)
-    return jnp.where(best == inf, jnp.int32(-1), best.astype(jnp.int32))
+    return like(col, (b"", needle, b""))

@@ -7,7 +7,8 @@ standalone framework owns the front end, so the classical rewrites live
 here: conjuncts of a Filter over an inner/cross Join are split into
 per-side filters, cross-side equalities become hash-join keys (turning a
 cross join into an equi join the TPU hash-join exec can run), and the
-remainder stays as a residual filter.
+remainder stays as a residual filter.  An outer join's ON conjuncts that
+read its null-supplying side alone filter that side.
 """
 from __future__ import annotations
 
@@ -16,6 +17,7 @@ from typing import List, Optional, Set
 
 from ..expr import core as ec
 from ..expr import predicates as ep
+from ..obs import trace as _trace
 from . import logical as L
 
 
@@ -158,6 +160,38 @@ def _rewrite_filter_join(f: L.Filter) -> L.LogicalPlan:
     jt = "inner" if lkeys else j.join_type
     nj = L.Join(new_left, new_right, jt, lkeys, rkeys, j.condition)
     return _filter_over(rest, nj)
+
+
+#: outer join type -> the child whose rows the join may drop (the
+#: null-supplying side), the one an ON conjunct may filter
+_NULL_SUPPLYING = {"left": 1, "right": 0}
+
+
+def _push_on_conjuncts(j: L.Join) -> L.LogicalPlan:
+    """A LEFT (RIGHT) OUTER join's ON conjuncts that read only its right
+    (left) child become a Filter on that child (Spark's
+    PushPredicateThroughJoin): a row there that fails one matches no
+    row, which is all the join does with it.  Never the preserved side:
+    its rows are in the output whatever the ON clause says, with NULLs
+    where nothing matched (TPC-H Q13's ``o_comment not like ...``)."""
+    side = _NULL_SUPPLYING.get(j.join_type)
+    if side is None or j.condition is None:
+        return j
+    names = set(j.children[side].schema.names)
+    if names & set(j.children[1 - side].schema.names):
+        return j  # ambiguous column names: leave untouched
+    push: List[ec.Expression] = []
+    rest: List[ec.Expression] = []
+    for c in _flatten_and(j.condition):
+        refs = _refs(c)
+        (push if refs and refs <= names else rest).append(c)
+    if not push:
+        return j
+    _trace.count("plan.join.on_pushdown", len(push))
+    kids = list(j.children)
+    kids[side] = optimize(_filter_over(push, kids[side]))
+    return L.Join(kids[0], kids[1], j.join_type, j.left_keys, j.right_keys,
+                  _and_all(rest) if rest else None)
 
 
 def _rewrite_filter_semi(f: L.Filter) -> L.LogicalPlan:
@@ -463,11 +497,14 @@ def prune_scan_columns(plan: L.LogicalPlan,
 
 def optimize(plan: L.LogicalPlan) -> L.LogicalPlan:
     """Bottom-up: push Filter conjuncts through inner/cross joins and
-    promote cross-side equalities to join keys."""
+    promote cross-side equalities to join keys; push an outer join's
+    one-sided ON conjuncts into its null-supplying side."""
     new_children = [optimize(c) for c in plan.children]
     if any(n is not o for n, o in zip(new_children, plan.children)):
         plan = copy.copy(plan)
         plan.children = new_children
+    if isinstance(plan, L.Join):
+        return _push_on_conjuncts(plan)
     if isinstance(plan, L.Filter):
         # collapse Filter(Filter(..)) so conjuncts see the join below
         child = plan.children[0]

@@ -141,6 +141,8 @@ expr_rule(es.Rpad, TS.ExprSig(
     [_P("str", TS.STRING_SIG), _P("len", TS.INTEGRAL),
      _P("pad", TS.STRING_SIG)], TS.STRING_SIG,
     note="pad runs on the host string path"))
+for _cls in [es.Like, es.StartsWith, es.EndsWith, es.Contains]:
+    expr_rule(_cls, TS.PATTERN_PREDICATE)
 expr_rule(es.StringRepeat, TS.ExprSig(
     [_P("str", TS.STRING_SIG), _P("n", TS.INTEGRAL)], TS.STRING_SIG))
 expr_rule(es.RegexpExtract, TS.ExprSig(

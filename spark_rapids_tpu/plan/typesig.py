@@ -160,6 +160,15 @@ class ExprSig:
         return out
 
 
+#: a string predicate with a literal pattern (LIKE, StartsWith, EndsWith,
+#: Contains): a STRING and its pattern in, a BOOLEAN out.  The uniform
+#: string rule checked the BOOLEAN output against the string signature,
+#: which refused every such predicate on the device, in a Filter and in
+#: a join's condition alike (TPC-H Q13's ON clause)
+PATTERN_PREDICATE = ExprSig([ParamSig("str", STRING_SIG),
+                             ParamSig("pattern", STRING_SIG)], BOOLEAN)
+
+
 # ---------------------------------------------------------------------------
 # cast-pair support matrix (CastChecks role, TypeChecks.scala:367)
 # ---------------------------------------------------------------------------

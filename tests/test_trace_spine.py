@@ -286,13 +286,15 @@ class TestCoarseSpans:
         # window operator, expand.* with every grouping set, launch.* /
         # launch_ns.* / lanes.* / eager_lanes.* with every launch,
         # pull.* with every declared transfer, compile.* with every
-        # compile
+        # compile, str.* with every LIKE launch, plan.* with every ON
+        # conjunct pushed below an outer join
         for tbl in counts.values():
             assert all(k.startswith(("eager.", "jit_build.", "join.",
                                      "scan.", "agg.", "exchange.",
                                      "window.", "expand.", "launch.",
                                      "launch_ns.", "lanes.",
-                                     "eager_lanes.", "pull.", "compile."))
+                                     "eager_lanes.", "pull.", "compile.",
+                                     "str.", "plan."))
                        and v > 0 for k, v in tbl.items())
         assert any(k.startswith("eager.")
                    for tbl in counts.values() for k in tbl)
