@@ -294,52 +294,23 @@ class ColumnarBatch:
 
     def gather(self, indices, num_rows, live=None,
                unique=False) -> "ColumnarBatch":
-        cols = [c.gather(indices, live=live, unique=unique)
-                for c in self.columns]
-        return ColumnarBatch(self.schema, cols, num_rows)
-
-    # jitted slice programs keyed by (out_cap,); shapes key the rest.
-    # Eager per-column gathers pay one dispatch (and one small compile
-    # per new shape) EACH; one jit dispatch covers them all.
-    _SLICE_JIT: dict = {}
+        """Every column at rows ``indices``, ``live`` ANDed into every
+        validity: eagerly ONE ``batch_gather`` launch
+        (``columnar/gather.py``), not two takes a column."""
+        from .gather import gather_columns
+        return ColumnarBatch(
+            self.schema, gather_columns(self.columns, indices, live, unique),
+            num_rows)
 
     def slice(self, start: int, length: int) -> "ColumnarBatch":
+        """Rows ``[start, start + length)``: the columns in one
+        ``batch_slice`` launch (``columnar/gather.py``)."""
+        from .gather import slice_columns
         valid_rows = min(length, max(self.num_rows - start, 0))
-        out_cap = bucket_capacity(length)
-        # the fixed-width columns go through ONE jitted program; strings
-        # and nested columns beside them gather lazily, each on its own
-        plain = [i for i, c in enumerate(self.columns) if type(c) is Column]
-        cols = list(self.columns)
-        if plain:
-            fn = ColumnarBatch._SLICE_JIT.get(out_cap)
-            if fn is None:
-                from ..obs import compile_watch as _cw
-
-                def _slice(datas, valids, start_, nvalid):
-                    idx = jnp.arange(out_cap) + start_
-                    live = jnp.arange(out_cap) < nvalid
-                    outs = []
-                    for d, v in zip(datas, valids):
-                        outs.append((
-                            jnp.take(d, idx, axis=0, mode="clip"),
-                            jnp.take(v, idx, axis=0, mode="clip") & live))
-                    return outs
-                fn = _cw.wrap_miss("batch_slice",
-                                   _cw.jit(_slice, "batch_slice"), out_cap)
-                ColumnarBatch._SLICE_JIT[out_cap] = fn
-            pairs = fn(tuple(self.columns[i].data for i in plain),
-                       tuple(self.columns[i].validity for i in plain),
-                       start, valid_rows)
-            for i, (d, v) in zip(plain, pairs):
-                cols[i] = Column(cols[i].dtype, d, v)
-        if len(plain) < len(cols):
-            idx = jnp.arange(out_cap) + start
-            # rows past num_rows must be invalid
-            mask = jnp.arange(out_cap) < valid_rows
-            for i, c in enumerate(cols):
-                if type(c) is not Column:
-                    cols[i] = c.gather(idx).mask_validity(mask)
-        return ColumnarBatch(self.schema, cols, valid_rows)
+        return ColumnarBatch(
+            self.schema,
+            slice_columns(self.columns, start, bucket_capacity(length),
+                          valid_rows), valid_rows)
 
     def nbytes(self) -> int:
         return sum(c.nbytes() for c in self.columns)

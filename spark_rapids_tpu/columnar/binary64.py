@@ -84,6 +84,10 @@ class Binary64Column(Column):
         return Binary64Column(data, valid)
 
     def gather(self, indices, live=None, unique=False):
+        from ..obs import trace as _trace
+        if _trace.eager():
+            from .gather import gather_columns
+            return gather_columns([self], indices, live, unique)[0]
         valid = jnp.take(self.validity, indices, axis=0, mode="clip")
         if live is not None:
             valid = valid & live

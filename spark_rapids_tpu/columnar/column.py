@@ -211,12 +211,16 @@ class Column:
     def gather(self, indices, live=None, unique=False) -> "Column":
         """Take rows by index (device gather). indices: int array [new_cap].
 
-        ``live``/``unique`` are sizing hints for variable-width columns
-        (kernels/strings.py gather_strings); fixed-width gathers ignore
-        them."""
-        with _trace.launch("column_gather", 2, 2 * indices.shape[0]):
-            valid = jnp.take(self.validity, indices, axis=0, mode="clip")
-            data = jnp.take(self.data, indices, axis=0, mode="clip")
+        ``live`` (a mask over the new rows) is ANDed into the validity;
+        ``unique`` is a sizing hint for variable-width columns
+        (kernels/strings.py gather_strings).  Eagerly one
+        ``batch_gather`` launch (columnar/gather.py); under a trace the
+        two takes of the program being built."""
+        if _trace.eager():
+            from .gather import gather_columns
+            return gather_columns([self], indices, live, unique)[0]
+        valid = jnp.take(self.validity, indices, axis=0, mode="clip")
+        data = jnp.take(self.data, indices, axis=0, mode="clip")
         if live is not None:
             valid = valid & live
         return Column(self.dtype, data, valid)
@@ -320,9 +324,12 @@ class StringColumn(Column):
         # map, so a join expansion to fact capacity followed by an
         # aggregate's 1000x row reduction never materializes the
         # intermediate gigabytes (and never pays its sizing sync) —
-        # the cuDF-style dictionary/gather-map trick.
-        with _trace.launch("string_gather", 1, indices.shape[0]):
-            valid = jnp.take(self.validity, indices, axis=0, mode="clip")
+        # the cuDF-style dictionary/gather-map trick.  Eagerly the
+        # validity (and a view's map) move in one ``batch_gather``.
+        if _trace.eager():
+            from .gather import gather_columns
+            return gather_columns([self], indices, live, unique)[0]
+        valid = jnp.take(self.validity, indices, axis=0, mode="clip")
         if live is not None:
             valid = valid & live
         src_idx = jnp.clip(indices, 0, self.capacity - 1) \

@@ -154,10 +154,9 @@ def apply_ops_eager(ops: Sequence[Op], batch: ColumnarBatch,
             idx, cnt = bk.filter_compact_indices(keep, batch.rows_dev)
             n = LazyCount(cnt)
             mask = jnp.arange(batch.capacity) < cnt
-            out = batch.gather(idx, n, live=mask, unique=True)
             batch = ColumnarBatch(
-                out_schema, [c.mask_validity(mask) for c in out.columns],
-                n)
+                out_schema,
+                batch.gather(idx, n, live=mask, unique=True).columns, n)
         else:
             cols = fused(batch) if fused is not None else None
             if cols is None:

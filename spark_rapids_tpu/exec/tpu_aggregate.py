@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from ..columnar import dtypes as T
 from ..columnar.schema import Field, Schema
 from ..columnar.column import Column, bucket_capacity
+from ..columnar.gather import gather_columns
 from ..columnar.batch import (ColumnarBatch, LazyCount, SpeculativeResult,
                               concat_batches, resolve_speculative)
 from ..expr import aggregates as ea
@@ -1433,17 +1434,17 @@ class TpuHashAggregate(TpuExec):
                          rep[:out_cap] if out_cap <= rep.shape[0] else
                          jnp.pad(rep, (0, out_cap - rep.shape[0]))[:out_cap],
                          0)
-        out_cols = [c.gather(take, live=live, unique=True)
-                    .mask_validity(live) for c in key_cols]
+        out_cols = gather_columns(key_cols, take, live, unique=True)
         # agg outputs: buffer arrays are already segment-indexed
         seg_take = jnp.where(live, jnp.arange(out_cap), 0)
+        outs = []
         for a, cols in zip(self.aggs, input_cols):
             bufs = a.func.update(plan, cols) if update else \
                 a.func.merge(plan, cols)
             for o in (bufs if emit else [a.func.finalize(bufs)]):
                 assert o.capacity >= out_cap, (o.capacity, out_cap)
-                out_cols.append(o.gather(seg_take, live=live, unique=True)
-                                .mask_validity(live))
+                outs.append(o)
+        out_cols += gather_columns(outs, seg_take, live, unique=True)
         return ColumnarBatch(self._out_schema(emit), out_cols, LazyCount(ng))
 
     def _global_agg(self, batch: ColumnarBatch,

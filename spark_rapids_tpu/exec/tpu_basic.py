@@ -244,10 +244,7 @@ class TpuFilter(TpuExec):
             # costs a full dispatch-queue sync (LazyCount doc)
             n = LazyCount(cnt)
             mask = jnp.arange(batch.capacity) < cnt
-            out = batch.gather(idx, n, live=mask, unique=True)
-            return ColumnarBatch(
-                out.schema,
-                [c.mask_validity(mask) for c in out.columns], n)
+            return batch.gather(idx, n, live=mask, unique=True)
 
         def run(part):
             from ..columnar.batch import chain_speculative
@@ -320,10 +317,7 @@ def _limit_head_lazy(batch: ColumnarBatch, n: int):
     cap = min(bucket_capacity(max(n, 1)), batch.capacity)
     out_n = jnp.minimum(batch.rows_dev, jnp.int32(n))
     take = jnp.arange(cap)
-    live = take < out_n
-    cols = [c.gather(take, live=live).mask_validity(live)
-            for c in batch.columns]
-    out = ColumnarBatch(batch.schema, cols, LazyCount(out_n))
+    out = batch.gather(take, LazyCount(out_n), live=take < out_n)
 
     def redo(fixed):
         return fixed if fixed.num_rows <= n else fixed.slice(0, n)

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from ..columnar import dtypes as T
 from ..columnar.schema import Field, Schema
 from ..columnar.column import Column, bucket_capacity
+from ..columnar.gather import gather_columns
 from ..columnar.batch import ColumnarBatch
 from ..expr import core as ec
 from ..kernels import lists as lk
@@ -63,8 +64,7 @@ class TpuGenerate(TpuExec):
         out_cap = bucket_capacity(max(1, n))
         row_idx, elem_idx, posv, elem_valid, live = lk.list_explode_indices(
             lcol.offsets, lcol.validity, out_offsets, out_cap)
-        cols = [c.gather(row_idx).mask_validity(live)
-                for c in batch.columns]
+        cols = gather_columns(batch.columns, row_idx, live)
         if pos:
             # outer's synthetic null row has a null position (Spark
             # PosExplode outer semantics)
@@ -98,8 +98,7 @@ class TpuGenerate(TpuExec):
         row_idx = j // k
         posv = j % k
         live = j < n
-        cols = [c.gather(row_idx).mask_validity(live)
-                for c in batch.columns]
+        cols = gather_columns(batch.columns, row_idx, live)
         if pos:
             cols.append(Column(T.INT32, posv, live))
         et = bound.dtype().element_type

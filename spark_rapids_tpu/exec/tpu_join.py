@@ -23,6 +23,7 @@ from ..columnar import dtypes as T
 from ..columnar.schema import Field, Schema
 from ..columnar.column import Column, StringColumn, bucket_capacity
 from ..columnar.batch import ColumnarBatch, concat_batches
+from ..columnar.gather import gather_columns
 from ..expr import core as ec
 from ..kernels import canon, join as join_k
 from ..kernels.basic import prefix_sum
@@ -48,6 +49,18 @@ def _key_words(cols: List[Column], num_rows: int,
 
 def _null_column(dtype: T.DType, capacity: int) -> Column:
     return Column.all_null(dtype, capacity)
+
+
+def _with_gathered(cols, plain_outs, idx, live) -> List[Column]:
+    """``cols`` at rows ``idx``: each plain column from the program's
+    (data, validity) pairs, in order, and the others (strings) in one
+    eager gather."""
+    rest = [i for i, c in enumerate(cols) if type(c) is not Column]
+    moved = dict(zip(rest, gather_columns([cols[i] for i in rest], idx,
+                                          live)))
+    pairs = iter(plain_outs)
+    return [moved[i] if i in moved else Column(c.dtype, *next(pairs))
+            for i, c in enumerate(cols)]
 
 
 def _bound_ordinals(e: ec.Expression) -> set:
@@ -559,23 +572,10 @@ class TpuHashJoinBase(TpuExec):
                 "speculative join failed; falling back", exc_info=True)
             TpuHashJoinBase._SPEC_JIT[key] = False
             return None
-        s_it = iter(souts)
-        scols = []
-        for c in sb.columns:
-            if type(c) is Column:
-                d, v = next(s_it)
-                scols.append(Column(c.dtype, d, v))
-            else:
-                scols.append(c.gather(p_idx, live=live))
-        b_it = iter(bouts)
-        bcols = []
-        for c in build.columns:
-            if type(c) is Column:
-                d, v = next(b_it)
-                bcols.append(Column(c.dtype, d, v))
-            else:
-                bcols.append(c.gather(b_idx, live=live))
-        out = self._assemble(scols, bcols, LazyCount(cnt))
+        out = self._assemble(_with_gathered(sb.columns, souts, p_idx, live),
+                             _with_gathered(build.columns, bouts, b_idx,
+                                            live),
+                             LazyCount(cnt))
         # the probe ran on possibly-speculative input: compose its fits
         # with ours so one failed assumption anywhere redoes the chain
         in_spec = getattr(sb, "_speculative", None)
@@ -688,14 +688,12 @@ class TpuHashJoinBase(TpuExec):
             if out is None:
                 from ..kernels import basic as bk
                 idx, _ = bk.filter_compact_indices(eff > 0, sb.rows_dev)
-                out = sb.gather(idx[:out_cap] if out_cap <= sb.capacity
-                                else jnp.pad(idx, (0, out_cap -
-                                                   sb.capacity))[:out_cap],
-                                total)
-                mask = jnp.arange(out.capacity) < total
+                idx = idx[:out_cap] if out_cap <= sb.capacity \
+                    else jnp.pad(idx, (0, out_cap - sb.capacity))[:out_cap]
                 out = ColumnarBatch(
                     self.output_schema,
-                    [c.mask_validity(mask) for c in out.columns], total)
+                    gather_columns(sb.columns, idx,
+                                   jnp.arange(out_cap) < total), total)
             return out
         if not all(type(c) is Column for c in sb.columns) or \
                 not all(type(c) is Column for c in build.columns):
@@ -746,24 +744,18 @@ class TpuHashJoinBase(TpuExec):
 
     def _expand_eager(self, sb, build, bt, outer_stream, lo, counts, eff,
                       total):
-        """Non-plain columns (strings/nested): the original eager
-        expansion."""
+        """Non-plain columns (strings/nested): the expansion, then each
+        side's columns in one eager gather (``gather_columns``)."""
         out_cap = bucket_capacity(total)
         p_idx, b_idx, live, _ = join_k.join_expand_matches(lo, eff, bt.perm,
                                                       out_cap)
-        stream_out = sb.gather(p_idx, total)
-        build_out = build.gather(b_idx, total)
+        bmask = live
         if outer_stream:
-            row_matched = jnp.take(counts > 0,
-                                   jnp.clip(p_idx, 0, sb.capacity - 1))
-            build_out = ColumnarBatch(
-                build_out.schema,
-                [c.mask_validity(row_matched)
-                 for c in build_out.columns], total)
-        live_mask = jnp.arange(out_cap) < total
-        scols = [c.mask_validity(live_mask) for c in stream_out.columns]
-        bcols = [c.mask_validity(live_mask) for c in build_out.columns]
-        return self._assemble(scols, bcols, total)
+            bmask = live & jnp.take(counts > 0,
+                                    jnp.clip(p_idx, 0, sb.capacity - 1))
+        return self._assemble(gather_columns(sb.columns, p_idx, live),
+                              gather_columns(build.columns, b_idx, bmask),
+                              total)
 
     # ------------------------------------------------------------------
     def _join_batch(self, sb: ColumnarBatch, skey_cols, build, bt,
@@ -790,11 +782,10 @@ class TpuHashJoinBase(TpuExec):
                 ((jc.counts == 0) & in_range)
             idx, cnt = bk.filter_compact_indices(keep, sb.num_rows)
             n = _host_int(cnt)
-            out = sb.gather(idx, n)
-            mask = jnp.arange(out.capacity) < n
             return ColumnarBatch(
                 self.output_schema,
-                [c.mask_validity(mask) for c in out.columns], n)
+                gather_columns(sb.columns, idx,
+                               jnp.arange(idx.shape[0]) < n), n)
 
         outer_stream = ((jt == "left" and self.build_right) or
                         (jt == "right" and not self.build_right) or
@@ -812,16 +803,11 @@ class TpuHashJoinBase(TpuExec):
         p_idx, b_idx, live, _ = join_k.join_expand_matches(
             jc.lo, counts, bt.perm, out_cap)
 
-        stream_out = sb.gather(p_idx, total)
-        build_out = build.gather(b_idx, total)
+        bmask = live
         if outer_stream:
             # rows that came from the unmatched path carry null build side
-            row_matched = jnp.take(jc.counts > 0, jnp.clip(p_idx, 0,
-                                                           sb.capacity - 1))
-            build_out = ColumnarBatch(
-                build_out.schema,
-                [c.mask_validity(row_matched) for c in build_out.columns],
-                total)
+            bmask = live & jnp.take(jc.counts > 0,
+                                    jnp.clip(p_idx, 0, sb.capacity - 1))
         if build_matched is not None:
             from ..analysis import residency  # lazy: avoids import cycle
             with residency.declared_transfer(site="join_verify"):
@@ -837,11 +823,9 @@ class TpuHashJoinBase(TpuExec):
                                                   sb.capacity - 1)))
             flags[mi[lv & ok]] = True
             build_matched |= flags
-
-        live_mask = jnp.arange(out_cap) < total
-        scols = [c.mask_validity(live_mask) for c in stream_out.columns]
-        bcols = [c.mask_validity(live_mask) for c in build_out.columns]
-        return self._assemble(scols, bcols, total)
+        return self._assemble(gather_columns(sb.columns, p_idx, live),
+                              gather_columns(build.columns, b_idx, bmask),
+                              total)
 
     _RESIDUAL_JIT: dict = {}
 
@@ -962,10 +946,9 @@ class TpuHashJoinBase(TpuExec):
             idx, cnt = bk.filter_compact_indices(sel, sb.rows_dev)
             n = LazyCount(cnt)
             mask = jnp.arange(sb.capacity) < cnt
-            out = sb.gather(idx, n, live=mask, unique=True)
-            return ColumnarBatch(self.output_schema,
-                                 [c.mask_validity(mask) for c in out.columns],
-                                 n)
+            return ColumnarBatch(
+                self.output_schema,
+                gather_columns(sb.columns, idx, mask, unique=True), n)
 
         parts = []
         if total:
@@ -980,12 +963,12 @@ class TpuHashJoinBase(TpuExec):
             pidx2, pcnt = bk.filter_compact_indices(keep, int(total))
             n_pairs = _host_int(pcnt)
             if n_pairs:
-                sp = sb.gather(jnp.take(p_idx, pidx2), n_pairs)
-                bp = build.gather(jnp.take(b_idx, pidx2), n_pairs)
-                pmask = jnp.arange(sp.capacity) < n_pairs
+                pmask = jnp.arange(pidx2.shape[0]) < n_pairs
                 parts.append(self._assemble(
-                    [c.mask_validity(pmask) for c in sp.columns],
-                    [c.mask_validity(pmask) for c in bp.columns], n_pairs))
+                    gather_columns(sb.columns, jnp.take(p_idx, pidx2),
+                                   pmask),
+                    gather_columns(build.columns, jnp.take(b_idx, pidx2),
+                                   pmask), n_pairs))
 
         outer_stream = ((jt == "left" and self.build_right) or
                         (jt == "right" and not self.build_right) or
@@ -995,10 +978,9 @@ class TpuHashJoinBase(TpuExec):
                                                    sb.rows_dev)
             n_un = _host_int(ucnt)
             if n_un:
-                su = sb.gather(uidx, n_un)
-                umask = jnp.arange(su.capacity) < n_un
-                su_cols = [c.mask_validity(umask) for c in su.columns]
-                nulls = [_null_column(f.dtype, su.capacity)
+                su_cols = gather_columns(
+                    sb.columns, uidx, jnp.arange(uidx.shape[0]) < n_un)
+                nulls = [_null_column(f.dtype, uidx.shape[0])
                          for f in build.schema]
                 parts.append(self._assemble(su_cols, nulls, n_un))
         if not parts:
@@ -1023,10 +1005,9 @@ class TpuHashJoinBase(TpuExec):
         n = _host_int(cnt)
         if n == 0:
             return None
-        b_out = build.gather(idx, n)
-        mask = jnp.arange(b_out.capacity) < n
-        bcols = [c.mask_validity(mask) for c in b_out.columns]
-        scols = [_null_column(f.dtype, b_out.capacity)
+        bcols = gather_columns(build.columns, idx,
+                               jnp.arange(idx.shape[0]) < n)
+        scols = [_null_column(f.dtype, idx.shape[0])
                  for f in stream_schema]
         return self._assemble(scols, bcols, n)
 
@@ -1121,11 +1102,10 @@ class TpuNestedLoopJoin(TpuExec):
         def select_left(lb, sel, n_hint):
             idx, cnt = bk.filter_compact_indices(sel, n_hint)
             n = _host_int(cnt)
-            out = lb.gather(idx, n)
-            m = jnp.arange(out.capacity) < n
-            return ColumnarBatch(self.output_schema,
-                                 [c.mask_validity(m) for c in out.columns],
-                                 n)
+            return ColumnarBatch(
+                self.output_schema,
+                gather_columns(lb.columns, idx, jnp.arange(idx.shape[0]) < n),
+                n)
 
         from ..service.cancellation import cancel_checkpoint
         for lb in left_iter:
@@ -1150,12 +1130,10 @@ class TpuNestedLoopJoin(TpuExec):
             t = jnp.arange(out_cap)
             li = (t // max(n_r, 1)).astype(jnp.int32)
             ri = (t % max(n_r, 1)).astype(jnp.int32)
-            lout = lb.gather(li, total)
-            rout = rb.gather(ri, total)
             live = t < total
-            pair_cols = ([c.mask_validity(live) for c in lout.columns] +
-                         [c.mask_validity(live) for c in rout.columns])
-            pairs = ColumnarBatch(pair_schema, pair_cols, total)
+            pairs = ColumnarBatch(
+                pair_schema, gather_columns(lb.columns, li, live) +
+                gather_columns(rb.columns, ri, live), total)
             if self.logical.condition is not None:
                 cond = self.logical.condition.bind(pair_schema)
                 pred = ec.eval_as_column(cond, pairs)
@@ -1184,11 +1162,11 @@ class TpuNestedLoopJoin(TpuExec):
             n_pairs = _host_int(cnt)
             parts = []
             if n_pairs:
-                g = pairs.gather(idx, n_pairs)
-                m = jnp.arange(g.capacity) < n_pairs
                 parts.append(ColumnarBatch(
                     self.output_schema,
-                    [c.mask_validity(m) for c in g.columns], n_pairs))
+                    gather_columns(pairs.columns, idx,
+                                   jnp.arange(idx.shape[0]) < n_pairs),
+                    n_pairs))
             if jt in ("left", "full"):
                 surv = jnp.zeros(lb.capacity, dtype=bool).at[
                     jnp.where(keep, li, 0)].max(keep)
@@ -1196,14 +1174,13 @@ class TpuNestedLoopJoin(TpuExec):
                 uidx, ucnt = bk.filter_compact_indices(un, n_l)
                 n_un = _host_int(ucnt)
                 if n_un:
-                    lu = lb.gather(uidx, n_un)
-                    um = jnp.arange(lu.capacity) < n_un
-                    nulls = [_null_column(f.dtype, lu.capacity)
+                    nulls = [_null_column(f.dtype, uidx.shape[0])
                              for f in rschema]
                     parts.append(ColumnarBatch(
                         self.output_schema,
-                        [c.mask_validity(um) for c in lu.columns] + nulls,
-                        n_un))
+                        gather_columns(lb.columns, uidx,
+                                       jnp.arange(uidx.shape[0]) < n_un)
+                        + nulls, n_un))
             for out in parts:
                 self.metrics[NUM_OUTPUT_ROWS] += out.rows_lazy
                 yield out
@@ -1214,13 +1191,12 @@ class TpuNestedLoopJoin(TpuExec):
             uidx, ucnt = bk.filter_compact_indices(un, n_r)
             n_un = _host_int(ucnt)
             if n_un:
-                ru = rb.gather(uidx, n_un)
-                um = jnp.arange(ru.capacity) < n_un
-                nulls = [_null_column(f.dtype, ru.capacity)
+                nulls = [_null_column(f.dtype, uidx.shape[0])
                          for f in lschema]
                 out = ColumnarBatch(
                     self.output_schema,
-                    nulls + [c.mask_validity(um) for c in ru.columns],
+                    nulls + gather_columns(
+                        rb.columns, uidx, jnp.arange(uidx.shape[0]) < n_un),
                     n_un)
                 self.metrics[NUM_OUTPUT_ROWS] += out.rows_lazy
                 yield out

@@ -57,11 +57,9 @@ class TpuSort(TpuExec):
             return batch
         words = self._key_words(self._key_cols(batch), batch.num_rows)
         perm = sort_permutation(words)
-        out = batch.gather(perm, batch.num_rows, unique=True)
-        mask = jnp.arange(out.capacity) < batch.num_rows
-        return ColumnarBatch(out.schema,
-                             [c.mask_validity(mask) for c in out.columns],
-                             batch.num_rows)
+        return batch.gather(perm, batch.num_rows,
+                            live=jnp.arange(perm.shape[0]) < batch.num_rows,
+                            unique=True)
 
     def _sort_lazy_spec(self, batch: ColumnarBatch) -> ColumnarBatch:
         """Sort on device counts — no host pull.  Dead rows carry the
@@ -73,11 +71,8 @@ class TpuSort(TpuExec):
         nr = batch.rows_dev
         words = self._key_words(self._key_cols(batch), nr)
         perm = sort_permutation(words)
-        out = batch.gather(perm, batch.rows_lazy, unique=True)
-        mask = jnp.arange(out.capacity) < nr
-        out = ColumnarBatch(out.schema,
-                            [c.mask_validity(mask) for c in out.columns],
-                            batch.rows_lazy)
+        out = batch.gather(perm, batch.rows_lazy,
+                           live=jnp.arange(perm.shape[0]) < nr, unique=True)
         return chain_speculative(out, batch, self._sort_batch)
 
     def execute(self):
@@ -322,11 +317,7 @@ class TpuSort(TpuExec):
         from ..analysis import residency  # lazy: avoids import cycle
         with residency.declared_transfer(site="sort_ooc"):
             n = int(cnt)
-        out = chunk.gather(idx, n)
-        mask = jnp.arange(out.capacity) < n
-        return ColumnarBatch(out.schema,
-                             [c.mask_validity(mask) for c in out.columns],
-                             n)
+        return chunk.gather(idx, n, live=jnp.arange(idx.shape[0]) < n)
 
 
 class TpuTopN(TpuExec):
@@ -357,14 +348,12 @@ class TpuTopN(TpuExec):
         words = self._sorter._key_words(
             self._sorter._key_cols(batch), nr)
         perm = sort_permutation(words)
-        srt = batch.gather(perm, batch.rows_lazy, unique=True)
-        cap = min(bucket_capacity(max(self.n, 1)), srt.capacity)
-        take = jnp.arange(cap)
+        # the head of the sorted order: one gather by the permutation's
+        # first ``cap`` entries, not a sort's gather and then a head's
+        cap = min(bucket_capacity(max(self.n, 1)), batch.capacity)
         out_n = jnp.minimum(nr, jnp.int32(self.n))
-        live = take < out_n
-        cols = [c.gather(take, live=live).mask_validity(live)
-                for c in srt.columns]
-        return ColumnarBatch(batch.schema, cols, LazyCount(out_n))
+        return batch.gather(perm[:cap], LazyCount(out_n),
+                            live=jnp.arange(cap) < out_n, unique=True)
 
     def execute(self):
         from ..columnar.batch import (SpeculativeResult,

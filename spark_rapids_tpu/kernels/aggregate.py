@@ -17,7 +17,7 @@ a pair sort 3 ms, a float64 scatter-add 74 ms):
   merged (``canon.group_key_words``: two flag-like string keys are one
   22-bit word, one sort pass), and ``groupby_plan`` is given every array
   the aggregates read in sorted order: all of them move in ONE row
-  gather of a 32-bit-lane matrix (``_gather_rows_once``: each distinct
+  gather of a 32-bit-lane matrix (``gather_rows_once``: each distinct
   data array once, the validities as packed bits); the DOUBLE sums of
   every aggregate are one stacked segmented scan (``stack_float_sums``:
   sum(x) and avg(x) share a lane); boundary takes and per-group outputs
@@ -39,11 +39,11 @@ from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ..obs import trace as _trace
 from . import canon
 from .basic import prefix_sum, rows_flagged_first
+from .gather import gather_rows_once
 from .sort import sorted_words
 
 
@@ -75,7 +75,7 @@ class GroupPlan:
 
     def in_order(self, values):
         """``values`` in the plan's sorted order: moved there with the
-        other inputs (``_gather_rows_once``), or gathered on first use."""
+        other inputs (``gather_rows_once``), or gathered on first use."""
         if self.single:
             return values
         hit = self.moved.get(id(values))
@@ -89,79 +89,6 @@ class GroupPlan:
         return pos if self.single else jnp.take(self.perm, pos)
 
 
-def _as_lanes(a):
-    """A fixed-width array as uint32 lanes (a list of [rows] arrays) and
-    the function that puts the lanes back together.  Integers and
-    float32 are bit patterns; a float64 is its bit pattern where the
-    backend can bitcast one (CPU), and on the chip, where a float64 IS a
-    pair of float32s, that pair: exact either way."""
-    dt = a.dtype
-    u32 = jnp.uint32
-
-    def bits32(x):
-        return lax.bitcast_convert_type(x, u32)
-    if dt == jnp.float64 and canon._f64_bitcast_supported():
-        pair = lax.bitcast_convert_type(a, u32)          # [rows, 2]
-        return [pair[:, 0], pair[:, 1]], lambda p: \
-            lax.bitcast_convert_type(jnp.stack(p, 1), jnp.float64)
-    if dt == jnp.float64:
-        hi = a.astype(jnp.float32)
-        lo = jnp.where(jnp.isfinite(hi), a - hi.astype(jnp.float64),
-                       0.0).astype(jnp.float32)
-
-        def join(p):
-            h = lax.bitcast_convert_type(p[0], jnp.float32)
-            lw = lax.bitcast_convert_type(p[1], jnp.float32)
-            # h alone when there is no low part: keeps -0.0 and inf
-            return jnp.where(lw == 0, h.astype(jnp.float64),
-                             h.astype(jnp.float64) + lw.astype(jnp.float64))
-        return [bits32(hi), bits32(lo)], join
-    if dt.itemsize == 8:
-        w = a.view(jnp.uint64)
-        return [(w & jnp.uint64(0xFFFFFFFF)).astype(u32),
-                (w >> jnp.uint64(32)).astype(u32)], lambda p: \
-            ((p[1].astype(jnp.uint64) << jnp.uint64(32)) |
-             p[0].astype(jnp.uint64)).view(dt)
-    if dt.itemsize == 4:
-        return [bits32(a)], lambda p: lax.bitcast_convert_type(p[0], dt)
-    # 8- and 16-bit integers ride widened
-    return [bits32(a.astype(jnp.int32))], lambda p: \
-        lax.bitcast_convert_type(p[0], jnp.int32).astype(dt)
-
-
-def _gather_rows_once(perm, arrays):
-    """Every distinct array of ``arrays`` brought into ``perm``'s order
-    by ONE row gather of a ``[rows, lanes]`` uint32 matrix: 64-bit
-    values as two lanes, boolean arrays (validities) 32 to a lane.  On
-    the chip the row gather of ten lanes takes 8.3 ms at 2^20 rows where
-    five 1-D float64 takes in one program take 90 ms (PERF.md section
-    5).  Returns the ``moved`` table of ``GroupPlan``."""
-    distinct = list({id(a): a for a in arrays}.values())
-    flags = [a for a in distinct if a.dtype == jnp.bool_]
-    lanes, joins = [], []
-    for a in distinct:
-        if a.dtype != jnp.bool_:
-            mine, join = _as_lanes(a)
-            joins.append((a, len(lanes), len(mine), join))
-            lanes.extend(mine)
-    flag_lane0 = len(lanes)
-    for at in range(0, len(flags), 32):
-        word = jnp.zeros(perm.shape[0], jnp.uint32)
-        for bit, v in enumerate(flags[at:at + 32]):
-            word = word | (v.astype(jnp.uint32) << jnp.uint32(bit))
-        lanes.append(word)
-    if not lanes:
-        return {}
-    got = jnp.take(jnp.stack(lanes, 1), perm, axis=0)
-    moved = {id(a): (a, join([got[:, at + i] for i in range(n)]))
-             for a, at, n, join in joins}
-    for i, v in enumerate(flags):
-        bit = (got[:, flag_lane0 + i // 32] >> jnp.uint32(i % 32)) \
-            & jnp.uint32(1)
-        moved[id(v)] = (v, bit != jnp.uint32(0))
-    return moved
-
-
 @jax.named_scope("groupby_plan")
 def groupby_plan(words: List[jnp.ndarray], num_slots: Optional[int] = None,
                  inputs: Optional[list] = None, live=None) -> GroupPlan:
@@ -173,7 +100,7 @@ def groupby_plan(words: List[jnp.ndarray], num_slots: Optional[int] = None,
 
     ``inputs`` (the fused cores): the arrays the aggregates read in
     sorted order (input data and validities), all brought there by one
-    row gather (``_gather_rows_once``); without it each is gathered on
+    row gather (``gather_rows_once``); without it each is gathered on
     first use.  ``num_slots`` bounds the groups the per-group outputs
     have room for (default: one per row); a caller that passes less
     checks ``num_groups <= num_slots``.  ``live`` (with words from
@@ -188,7 +115,7 @@ def groupby_plan(words: List[jnp.ndarray], num_slots: Optional[int] = None,
     emulates i64 as 32-bit pairs and scatters serialize badly).
     """
     sorted_ws, perm = sorted_words(words)
-    moved = {} if inputs is None else _gather_rows_once(perm, inputs)
+    moved = {} if inputs is None else gather_rows_once(perm, inputs)
     n = sorted_ws[0].shape[0]
     if live is None:
         live = sorted_ws[0] != jnp.uint64(2)

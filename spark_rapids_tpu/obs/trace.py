@@ -496,7 +496,7 @@ def _launched(d: Dict, prefix: str, name: str, n: int, ns: int,
         n, ns, lanes)
 
 
-def _eager() -> bool:
+def eager() -> bool:
     """True when no ``jax.jit`` trace is open on this thread: a call
     launches.  Under a trace the ops join the program being built."""
     if _EAGER is None:
@@ -533,7 +533,7 @@ def launch(site: str, n: int = 1, lanes: int = 0):
     ``lanes`` indices in all.  Adds ``eager.<site>@<Op>`` (+n),
     ``launch_ns.<site>@<Op>`` and ``eager_lanes.<site>@<Op>``; under a
     ``jax.jit`` trace it is the shared no-op."""
-    if not _eager():
+    if not eager():
         return _NOOP
     return _Launch(site, n, lanes)
 
@@ -554,7 +554,7 @@ class Launcher:
         self.lanes = lanes
 
     def __call__(self, *args, **kwargs):
-        if not _eager():
+        if not eager():
             return self.__wrapped__(*args, **kwargs)
         d = _TLS.__dict__
         c0 = d.get("compile_ns", 0)

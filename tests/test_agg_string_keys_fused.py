@@ -292,11 +292,11 @@ def test_merged_key_words_keep_groups_and_order(keys):
     ("float64", True)])
 def test_row_gather_moves_every_width_exactly(dtype, chip_floats,
                                               monkeypatch):
-    """``_gather_rows_once``: any fixed-width array and any number of
+    """``gather_rows_once``: any fixed-width array and any number of
     validities, through 32-bit lanes and back, bit for bit.  The chip's
     branch for float64 (a pair of float32s) is exact for what the chip
     can hold: sums of two float32s."""
-    from spark_rapids_tpu.kernels import aggregate as agg_k, canon
+    from spark_rapids_tpu.kernels import canon, gather as gather_k
     jnp = jax.numpy
     rng = np.random.default_rng(5)
     n = 300
@@ -314,7 +314,7 @@ def test_row_gather_moves_every_width_exactly(dtype, chip_floats,
     flags = [jnp.asarray(rng.integers(0, 2, n) > 0) for _ in range(35)]
     data = jnp.asarray(vals)
     perm = jnp.asarray(rng.permutation(n).astype(np.int32))
-    moved = agg_k._gather_rows_once(perm, [data] + flags + [data])
+    moved = gather_k.gather_rows_once(perm, [data] + flags + [data])
     assert len(moved) == 36
     got = np.asarray(moved[id(data)][1])
     want = vals[np.asarray(perm)]

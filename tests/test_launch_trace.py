@@ -66,29 +66,35 @@ def _struct():
                         jnp.ones(CAP, bool))
 
 
-#: site -> (column, launches a call, lanes a gathered row)
+def _column():
+    return Column(T.INT64, jnp.arange(CAP), jnp.ones(CAP, bool))
+
+
+#: gather -> (column, what counts it: ``launch`` (the engine program
+#: ``batch_gather``, one index a row) or ``eager`` (a one-op site), its
+#: name, launches a call)
 SITES = {
-    "column_gather": (lambda: Column(T.INT64, jnp.arange(CAP),
-                                     jnp.ones(CAP, bool)), 2, 2),
-    "string_gather": (_strings, 1, 1),
-    "struct_gather": (_struct, 1, 1),
+    "column_gather": (_column, "launch", "batch_gather", 1),
+    "string_gather": (_strings, "launch", "batch_gather", 1),
+    "struct_gather": (_struct, "eager", "struct_gather", 1),
 }
 
 
 @pytest.mark.parametrize("site", sorted(SITES))
 def test_a_gather_under_an_operator_counts_launches_and_lanes(site):
-    make, n, per_row = SITES[site]
+    make, kind, name, n = SITES[site]
+    lanes = "lanes." if kind == "launch" else "eager_lanes."
     col = make()
     idx = jnp.arange(8)[::-1]
     with under("TpuHashJoin"):
         col.gather(idx)
-    got = site_counts(site)
-    assert got[f"eager.{site}@TpuHashJoin"] == n
-    assert got[f"eager_lanes.{site}@TpuHashJoin"] == per_row * 8
-    assert table()[f"launch_ns.{site}@TpuHashJoin"] > 0
+    got = site_counts(name)
+    assert got[f"{kind}.{name}@TpuHashJoin"] == n
+    assert got[f"{lanes}{name}@TpuHashJoin"] == 8
+    assert table()[f"launch_ns.{name}@TpuHashJoin"] > 0
     # outside any operator: ``-``
     col.gather(idx)
-    assert site_counts(site)[f"eager.{site}@-"] == n
+    assert site_counts(name)[f"{kind}.{name}@-"] == n
 
 
 @pytest.mark.parametrize("site", sorted(SITES))
@@ -101,14 +107,19 @@ def test_under_a_jit_trace_nothing_is_counted_or_timed(site):
     with under("TpuHashJoin"):
         got = inside(jnp.arange(8))
     assert np.asarray(got).shape == (8,)
-    assert not any(site in k for tbl in trace.coarse_counts().values()
-                   for k in tbl)
+    assert not any(SITES[site][2] in k
+                   for tbl in trace.coarse_counts().values() for k in tbl)
 
 
 def test_a_composed_string_gather_is_its_own_site():
+    """A view made over a view (a core's string key output) composes its
+    map by one take; an eager gather of a view moves the map as a lane
+    of ``batch_gather`` and composes nothing."""
+    from spark_rapids_tpu.columnar.column import GatheredStringColumn
     view = _strings().gather(jnp.arange(CAP))
     trace.reset()
     with under("TpuSuperstage"):
+        GatheredStringColumn(view, jnp.arange(4), jnp.ones(4, bool))
         view.gather(jnp.arange(4))
     got = site_counts("string_gather_compose")
     assert got == {"eager.string_gather_compose@TpuSuperstage": 1,
@@ -116,14 +127,14 @@ def test_a_composed_string_gather_is_its_own_site():
 
 
 def test_the_innermost_operator_takes_the_launch_and_hands_it_back():
-    col = SITES["column_gather"][0]()
+    col = _column()
     with under("TpuHashAggregate"):
         with under("TpuFilter"):
             col.gather(jnp.arange(4))
         col.gather(jnp.arange(4))
-    got = site_counts("column_gather")
-    assert got["eager.column_gather@TpuFilter"] == 2
-    assert got["eager.column_gather@TpuHashAggregate"] == 2
+    got = site_counts("batch_gather")
+    assert got["launch.batch_gather@TpuFilter"] == 1
+    assert got["launch.batch_gather@TpuHashAggregate"] == 1
     assert trace.operator("-") == "-"
 
 
@@ -167,12 +178,16 @@ def q3(bench, deployment, tmp_path_factory):
 
 
 def test_eager_launches_read_the_parents_count(q3):
-    """65: the sum of ``eager.*`` the parent of PR 36 counts for this
-    query (a CPU run of its tree at this seed and scale)."""
+    """The 65 one-op launches this query made when every column gathered
+    by two takes (a CPU run at this seed and scale: 32 fixed-width
+    columns and one string validity) are 33 columns moved by
+    ``batch_gather`` launches, and no one-op gather is left."""
     counts, _ = q3
-    assert sum(v for k, v in counts.items() if k.startswith("eager.")) == 65
-    assert not any(k.endswith("@-") for k in counts
-                   if k.startswith("eager."))
+    assert sum(v for k, v in counts.items() if k.startswith("eager.")) == 0
+    assert counts["gather.batch.columns"] == 33
+    launches = {k: v for k, v in counts.items()
+                if k.startswith("launch.batch_gather@")}
+    assert launches and not any(k.endswith("@-") for k in launches)
 
 
 def test_pulls_are_the_pull_spans_by_site(q3):
@@ -249,7 +264,7 @@ def test_a_compile_inside_a_query_is_named_under_the_open_span(bench):
 # ---------------------------------------------------------------------------
 
 def test_a_window_of_200_queries_keeps_every_table_and_span():
-    col = SITES["column_gather"][0]()
+    col = _column()
     col.gather(jnp.arange(4))
     trace.reset()
     numbers = []
@@ -261,7 +276,7 @@ def test_a_window_of_200_queries_keeps_every_table_and_span():
                     col.gather(jnp.arange(4))
     tables = trace.coarse_counts()
     assert sorted(tables) == numbers
-    assert all(t["eager.column_gather@TpuFilter"] == 40
+    assert all(t["launch.batch_gather@TpuFilter"] == 20
                for t in tables.values())
     assert trace.get_tracer().ring_written() == 200 * 21
     assert len(trace.coarse_spans()) == 200 * 21

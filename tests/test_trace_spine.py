@@ -147,7 +147,7 @@ def _join_group_by(s, rows=600):
 def _drop_engine_jit_caches():
     """Every engine jit cache empty: the next query builds (and so
     names, logs and counts) its programs again."""
-    from spark_rapids_tpu.columnar import batch as cbatch
+    from spark_rapids_tpu.columnar import batch as cbatch, gather as cgather
     from spark_rapids_tpu.exec import fused, staged
     from spark_rapids_tpu.exec.tpu_aggregate import TpuHashAggregate
     from spark_rapids_tpu.exec.tpu_join import TpuHashJoinBase
@@ -157,7 +157,7 @@ def _drop_engine_jit_caches():
                   TpuHashJoinBase._SPEC_JIT, TpuHashJoinBase._EXPAND_JIT,
                   TpuHashJoinBase._DIRECT_JIT,
                   fused._JIT_CACHE, staged.TpuStagedCompute._JIT_CACHE,
-                  cbatch._CONCAT_JIT, cbatch.ColumnarBatch._SLICE_JIT,
+                  cbatch._CONCAT_JIT, cgather._PROGRAMS,
                   HashPartitioner._SPLIT_JIT):
         cache.clear()
     plan_cache.reset()
@@ -287,16 +287,17 @@ class TestCoarseSpans:
         # launch_ns.* / lanes.* / eager_lanes.* with every launch,
         # pull.* with every declared transfer, compile.* with every
         # compile, str.* with every LIKE launch, plan.* with every ON
-        # conjunct pushed below an outer join
+        # conjunct pushed below an outer join, gather.* with every eager
+        # gather of a batch's columns
         for tbl in counts.values():
             assert all(k.startswith(("eager.", "jit_build.", "join.",
                                      "scan.", "agg.", "exchange.",
                                      "window.", "expand.", "launch.",
                                      "launch_ns.", "lanes.",
                                      "eager_lanes.", "pull.", "compile.",
-                                     "str.", "plan."))
+                                     "str.", "plan.", "gather."))
                        and v > 0 for k, v in tbl.items())
-        assert any(k.startswith("eager.")
+        assert any(k.startswith("launch.")
                    for tbl in counts.values() for k in tbl)
         # under a jit trace the same sites launch nothing
         from spark_rapids_tpu.columnar import dtypes as T
@@ -308,15 +309,15 @@ class TestCoarseSpans:
         col.gather(jnp.arange(4))
         counts = trace.coarse_counts()
         assert list(counts) == [trace.current_query()]
-        # (and the compiles of the small shapes' one-op programs)
+        # (and the compiles of the small shapes' programs)
         table = {k: v for k, v in counts[trace.current_query()].items()
-                 if "column_gather" in k}
-        assert table.pop("launch_ns.column_gather@-") > 0
-        assert table == {"eager.column_gather@-": 2,
-                         "eager_lanes.column_gather@-": 8}
+                 if "batch_gather@" in k}
+        assert table.pop("launch_ns.batch_gather@-") > 0
+        assert table == {"launch.batch_gather@-": 1,
+                         "lanes.batch_gather@-": 4}
         jax.jit(lambda i: col.gather(i).data)(jnp.arange(4))
         assert trace.coarse_counts()[trace.current_query()][
-            "eager.column_gather@-"] == 2
+            "launch.batch_gather@-"] == 1
 
     def test_count_tables_are_bounded(self):
         for _ in range(trace.COUNT_QUERIES + 5):
