@@ -1984,8 +1984,11 @@ class _Lowerer:
             return ea.UnaryMinus(lower(ast.operand))
         if isinstance(ast, Between):
             e = lower(ast.operand)
-            cond = ep.And(ep.GreaterThanOrEqual(e, lower(ast.lo)),
-                          ep.LessThanOrEqual(e, lower(ast.hi)))
+            cond = ep.And(
+                ep.GreaterThanOrEqual(*ep.coerce_date_string(
+                    e, lower(ast.lo))),
+                ep.LessThanOrEqual(*ep.coerce_date_string(
+                    e, lower(ast.hi))))
             return ep.Not(cond) if ast.negated else cond
         if isinstance(ast, InList):
             e = lower(ast.operand)
@@ -2089,6 +2092,8 @@ class _Lowerer:
             return ep.Or(l, r)
         if op == "and":
             return ep.And(l, r)
+        if op in ("=", "<>", "<", "<=", ">", ">="):
+            l, r = ep.coerce_date_string(l, r)
         if op == "=":
             return ep.EqualTo(l, r)
         if op == "<>":
@@ -2101,6 +2106,10 @@ class _Lowerer:
             return ep.GreaterThan(l, r)
         if op == ">=":
             return ep.GreaterThanOrEqual(l, r)
+        if op in ("+", "-"):
+            shifted = edt.date_plus_days(l, r, op)
+            if shifted is not None:
+                return shifted
         if op == "+":
             return ea.Add(l, r)
         if op == "-":

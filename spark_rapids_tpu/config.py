@@ -169,8 +169,9 @@ JOIN_GATHER_CHUNK_ROWS = conf_int(
     "Join output rows gathered per expansion chunk; a (stream batch, "
     "build) pair whose match total exceeds this expands incrementally "
     "— splitting even one probe row's matches across chunks — so no "
-    "single output allocation exceeds the budget "
-    "(reference: JoinGatherer.scala bounded gather)")
+    "single output allocation exceeds the budget; a join with a "
+    "residual condition decides at most this many candidate pairs a "
+    "launch (reference: JoinGatherer.scala bounded gather)")
 SORT_OOC_SAMPLES = conf_int(
     "spark.rapids.tpu.sql.sort.outOfCore.samplesPerRun", 256,
     "Sorted-run key samples kept per run for choosing merge range "

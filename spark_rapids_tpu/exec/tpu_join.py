@@ -267,10 +267,10 @@ class TpuHashJoinBase(TpuExec):
                     outs = [self._join_batch(sb, skey_cols, build, bt,
                                              str_words, build_matched, kept)]
             elif lg.condition is not None:
+                # generator: each chunk of pairs times itself
                 _, _, lo, counts, _, total = pa
-                with timed(self.metrics[JOIN_TIME], self):
-                    outs = [self._residual_batch(sb, build, bt, lo, counts,
-                                                 int(total), None, kept)]
+                outs = self._residual_batches(sb, build, bt, lo, counts,
+                                              int(total), None, kept)
             else:
                 # generator: each chunk's expansion times itself
                 outs = self._expand_phases(sb, build, bt, *pa)
@@ -770,10 +770,13 @@ class TpuHashJoinBase(TpuExec):
             # residual restricts which PAIRS match; outer/semi/anti row
             # semantics are decided on the surviving pairs (a plain
             # post-filter would wrongly drop null-extended outer rows)
-            return self._residual_batch(
+            parts = list(self._residual_batches(
                 sb, build, bt, jc.lo, jc.counts,
-                join_k.total_matches(jc.counts), build_matched,
-                [] if kept is None else kept)
+                int(join_k.total_matches(jc.counts)), build_matched,
+                [] if kept is None else kept))
+            if not parts:
+                return ColumnarBatch.empty(self.output_schema)
+            return parts[0] if len(parts) == 1 else concat_batches(parts)
 
         if jt in ("semi", "anti"):
             from ..kernels import basic as bk
@@ -918,76 +921,324 @@ class TpuHashJoinBase(TpuExec):
                   tuple((c.data, c.validity) for c in scols),
                   tuple((c.data, c.validity) for c in bcols))
 
-    def _residual_batch(self, sb, build, bt, lo, counts, total,
-                        build_matched, kept) -> Optional[ColumnarBatch]:
+    @staticmethod
+    def _chunk_windows(counts_np, cap: int):
+        """The chunks of ``cap`` candidate pairs of a stream batch past
+        the budget, on the host from its match counts: for each, the
+        pair it starts at (``base``), the window of stream rows that
+        holds its pairs (first row, rows) and the first pair of the
+        window's first row less ``base``.  A window is a bucket of rows,
+        at least ``cap / 64`` (fewer programs; the rows cost a chunk a
+        64th of its pairs' work at most) and at most the batch's
+        capacity, placed to end inside the batch."""
+        c = counts_np.astype(np.int64)
+        incl = np.cumsum(c)
+        start = incl - c
+        n = c.shape[0]
+        total = int(incl[-1]) if n else 0
+        for base in range(0, total, cap):
+            r0 = int(np.searchsorted(incl, base, side="right"))
+            r1 = int(np.searchsorted(start, base + cap, side="left"))
+            rows = min(bucket_capacity(max(r1 - r0, cap >> 6, 1)), n)
+            first = min(r0, n - rows)
+            yield base, first, rows, int(start[first]) - base
+
+    def _residual_sorted(self, build, bt):
+        """The build side's condition columns (data and validity) as one
+        ``pack_rows`` matrix in ``bt``'s sorted order: one program a
+        build, kept beside ``bt`` in the build's memo for its stream
+        batches.  A chunk's pairs then read their build values by their
+        sorted positions, with no gather through ``perm``."""
+        from ..kernels.gather import pack_rows
+        memo = getattr(self, "_build_memo", None) or {}
+        if memo.get("bt") is not bt:
+            memo = {}
+        if "residual_sorted" in memo:
+            return memo["residual_sorted"]
+        _, _, _, b_ords, _ = self._residual_plan()
+        arrs = tuple(a for i in b_ords for a in (build.columns[i].data,
+                                                 build.columns[i].validity))
+        if not arrs:
+            return None
+        key = ("residual_sorted", tuple(a.dtype.name for a in arrs),
+               build.capacity)
+        fn = TpuHashJoinBase._RESIDUAL_JIT.get(key)
+        if fn is None:
+            def _sorted(perm, arrs):
+                matrix, _ = pack_rows(list(arrs))
+                return jnp.take(matrix, perm, axis=0)
+            fn = _compile_watch.wrap_miss(
+                "join_residual_sorted",
+                _compile_watch.jit(_sorted, "join_residual_sorted"),
+                str(key))
+            if len(TpuHashJoinBase._RESIDUAL_JIT) < 4096:
+                TpuHashJoinBase._RESIDUAL_JIT[key] = fn
+        memo["residual_sorted"] = fn(bt.perm, arrs)
+        return memo["residual_sorted"]
+
+    def _residual_chunk(self, sb, build, bt, lo, counts, cap: int,
+                        window, surv_in, pairs: bool):
+        """ONE program for one chunk of ``cap`` candidate pairs of a
+        stream batch past the budget (``window``: a ``_chunk_windows``
+        entry).  The row work runs over the window's rows alone.  Each
+        pair slot takes its row, its sorted build position and its stream
+        row's condition values from running sums of steps scattered at
+        the rows' first slots (``join_expand_matches``'s method; every
+        value a 32-bit bit pattern, so the sums are exact by
+        wrap-around), and its build values by one row gather of
+        ``_residual_sorted``'s matrix: one index a pair where a gather of
+        each column (data and validity) by both maps and of ``perm`` took
+        five.  ORs the window's survival flags into ``surv_in``.  ->
+        (surv, kept pairs, and with ``pairs`` the slots survivors first,
+        each slot's stream row and sorted build position)."""
+        import jax
+        from jax import lax
+        from ..kernels.basic import rows_flagged_first
+        from ..kernels.gather import pack_rows
+        cond, sig, s_ords, b_ords, schema = self._residual_plan()
+        scols = [sb.columns[i] for i in s_ords]
+        bcols = [build.columns[i] for i in b_ords]
+        plain = sig is not None and all(type(c) is Column
+                                        for c in scols + bcols)
+        _, first, rows, off0 = window
+
+        def _core(lo, counts, perm, sarrs, barrs, bsorted, first, off0,
+                  surv_in):
+            def rows_of(a):
+                return lax.dynamic_slice(a, (first,), (rows,))
+            c = rows_of(counts).astype(jnp.int64)
+            incl = off0 + prefix_sum(c)          # pair numbers less base
+            start = incl - c
+            n_here = jnp.maximum(jnp.minimum(incl, cap) -
+                                 jnp.maximum(start, 0), 0).astype(jnp.int32)
+            # a row's first slot; rows past the chunk fall off the scatter
+            at = jnp.clip(start, 0, cap).astype(jnp.int32)
+            lo_w = rows_of(lo).astype(jnp.int32) + \
+                jnp.maximum(-start, 0).astype(jnp.int32)
+
+            def spread(v):
+                """Each slot its row's ``v`` (uint32): the steps between
+                rows telescope; rows sharing a slot add theirs."""
+                step = v - jnp.concatenate([jnp.zeros(1, v.dtype), v[:-1]])
+                return prefix_sum(jnp.zeros(cap, v.dtype).at[at].add(
+                    step, indices_are_sorted=True, mode="drop"))
+            t = jnp.arange(cap, dtype=jnp.int32)
+            live = t < jnp.sum(n_here)
+            row = jnp.clip(prefix_sum(jnp.zeros(cap, jnp.int32).at[at].add(
+                1, indices_are_sorted=True, mode="drop")) - 1, 0, rows - 1)
+            shift = lax.bitcast_convert_type(lo_w - at, jnp.uint32)
+            pos = jnp.clip(t + lax.bitcast_convert_type(spread(shift),
+                                                        jnp.int32),
+                           0, perm.shape[0] - 1)
+            if plain:
+                win = [rows_of(a) for d_v in sarrs for a in d_v]
+                smat, s_unpack = pack_rows(win)
+                got_s = {} if smat is None else s_unpack(jnp.stack(
+                    [spread(smat[:, k]) for k in range(smat.shape[1])], 1))
+                flat_b = [a for d_v in barrs for a in d_v]
+                _, b_unpack = pack_rows(flat_b)
+                with jax.named_scope("gather_condition"):
+                    got_b = {} if bsorted is None else \
+                        b_unpack(jnp.take(bsorted, pos, axis=0))
+                vals = [got_s[id(a)][1] for a in win] + \
+                    [got_b[id(a)][1] for a in flat_b]
+                cols = [Column(f.dtype, vals[2 * k], vals[2 * k + 1] & live)
+                        for k, f in enumerate(schema)]
+            else:
+                b_idx = jnp.take(perm, pos)
+                cols = [c.gather(first + row, live=live) for c in scols] + \
+                    [c.gather(b_idx, live=live) for c in bcols]
+            pred = ec.eval_as_column(cond, ColumnarBatch(schema, cols, cap))
+            keep = pred.data.astype(bool) & pred.validity & live
+            kc = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                  prefix_sum(keep.astype(jnp.int32))])
+            surv_w = jnp.take(kc, at + n_here) > jnp.take(kc, at)
+            surv = lax.dynamic_update_slice(
+                surv_in, rows_of(surv_in) | surv_w, (first,))
+            if pairs:
+                return surv, kc[-1], rows_flagged_first(keep), \
+                    first + row, pos
+            return surv, kc[-1]
+
+        if not plain:
+            return _core(lo, counts, bt.perm, (), (), None, first,
+                         np.int64(off0), surv_in)
+        key = ("residual_chunk", sig, pairs, cap, rows, sb.capacity,
+               build.capacity, tuple(f.dtype.name for f in schema),
+               len(scols))
+        fn = TpuHashJoinBase._RESIDUAL_JIT.get(key)
+        if fn is None:
+            prog = _compile_watch.jit(_core, "join_residual_chunk")
+            # a launch's lanes: a pair's row gather, a window row's steps
+            prog.lanes = lambda *a, _n=cap + rows * (2 + len(scols)), \
+                **k: _n
+            fn = _compile_watch.wrap_miss("join_residual", prog, str(key))
+            if len(TpuHashJoinBase._RESIDUAL_JIT) < 4096:
+                TpuHashJoinBase._RESIDUAL_JIT[key] = fn
+        return fn(lo, counts, bt.perm,
+                  tuple((c.data, c.validity) for c in scols),
+                  tuple((c.data, c.validity) for c in bcols),
+                  self._residual_sorted(build, bt), np.int32(first),
+                  np.int64(off0), surv_in)
+
+    def _residual_pair_bytes(self, sb, build) -> int:
+        """Bytes a residual program must touch once for one candidate
+        pair, from dtypes alone: each condition column's data and
+        validity on both sides, the pair's two int32 gather maps and its
+        survivor flag."""
+        _, _, s_ords, b_ords, _ = self._residual_plan()
+        cols = [sb.columns[i] for i in s_ords] + \
+            [build.columns[i] for i in b_ords]
+        per_row = 0
+        for c in cols:
+            # a string column by its byte buffer and offsets, a row's share
+            arrs = [c.data, c.validity, getattr(c, "offsets", None)]
+            per_row += sum(a.size * a.dtype.itemsize for a in arrs
+                           if a is not None) // max(c.capacity, 1)
+        return per_row + 2 * 4 + 1
+
+    def _residual_batches(self, sb, build, bt, lo, counts, total,
+                          build_matched, kept):
         """Join with a residual (non-equi) condition: decide every
         candidate pair (``lo``, ``counts``: ``total`` of them), then
-        derive the join type's rows from the survivors.  Semi and anti
-        joins compact the stream batch by the survival flags and never
-        gather a pair's other columns, nor pull a count to the host.
-        ``kept`` gets the survivors' count, still on the device."""
+        derive the join type's rows from the survivors.  Past
+        ``join.gather.chunkRows`` pairs the decisions run in chunks of
+        that many (``_residual_chunk``), each its own launch, and a
+        stream row's survival is the OR over the chunks: no allocation
+        grows with the batch's pairs.  Yields each launch's surviving
+        pairs as a batch of their own (a chunk's once the next chunk is
+        launched, so the device is not idle while the host reads its
+        count), then (outer, semi and anti joins) the stream rows the
+        survival flags pick.  Semi and anti joins compact the stream
+        batch by the flags and never gather a pair's other columns, nor
+        pull a count to the host.  ``kept`` gets the survivors' counts,
+        still on the device."""
         from ..columnar.batch import LazyCount
+        from ..config import get_active, JOIN_GATHER_CHUNK_ROWS
         from ..kernels import basic as bk
         jt = self.logical.join_type
-        _trace.count("join.residual.pairs", int(total))
+        total = int(total)
+        _trace.count("join.residual.pairs", total)
         pairs = jt not in ("semi", "anti")
         in_range = jnp.arange(sb.capacity) < sb.rows_dev
+        surv = jnp.zeros(sb.capacity, dtype=bool)
+        limit = max(int(get_active().get(JOIN_GATHER_CHUNK_ROWS)), 1)
         if total:
-            out_cap = bucket_capacity(int(total))
-            got = self._residual_keep(sb, build, bt, lo, counts, out_cap,
-                                      pairs)
-            surv = got[0]
-            kept.append(LazyCount(got[1]))
-        else:
-            surv = jnp.zeros(sb.capacity, dtype=bool)
+            _trace.count("join.residual.bytes",
+                         total * self._residual_pair_bytes(sb, build))
+        if 0 < total <= limit:
+            # under the budget: one launch at today's capacity
+            with timed(self.metrics[JOIN_TIME], self):
+                _trace.count("join.residual.chunks")
+                got = self._residual_keep(sb, build, bt, lo, counts,
+                                          bucket_capacity(total), pairs)
+                surv = got[0]
+                kept.append(LazyCount(got[1]))
+                out = self._surviving_pairs(sb, build, got, build_matched,
+                                            total) if pairs else None
+            if out is not None:
+                yield out
+        elif total:
+            from ..analysis import residency  # lazy: avoids import cycle
+            with residency.declared_transfer(site="join_verify"):
+                counts_np = np.asarray(counts)
+            ahead = None
+            for window in self._chunk_windows(counts_np, limit):
+                with timed(self.metrics[JOIN_TIME], self):
+                    _trace.count("join.residual.chunks")
+                    got = self._residual_chunk(sb, build, bt, lo, counts,
+                                               limit, window, surv, pairs)
+                    surv = got[0]
+                    kept.append(LazyCount(got[1]))
+                    out = self._chunk_pairs(sb, build, bt, ahead,
+                                            build_matched) \
+                        if pairs and ahead is not None else None
+                ahead = got
+                if out is not None:
+                    yield out
+            if pairs:
+                with timed(self.metrics[JOIN_TIME], self):
+                    out = self._chunk_pairs(sb, build, bt, ahead,
+                                            build_matched)
+                if out is not None:
+                    yield out
 
-        if not pairs:
-            sel = surv if jt == "semi" else (~surv & in_range)
-            idx, cnt = bk.filter_compact_indices(sel, sb.rows_dev)
-            n = LazyCount(cnt)
-            mask = jnp.arange(sb.capacity) < cnt
-            return ColumnarBatch(
-                self.output_schema,
-                gather_columns(sb.columns, idx, mask, unique=True), n)
+        with timed(self.metrics[JOIN_TIME], self):
+            out = None
+            if not pairs:
+                sel = surv if jt == "semi" else (~surv & in_range)
+                idx, cnt = bk.filter_compact_indices(sel, sb.rows_dev)
+                mask = jnp.arange(sb.capacity) < cnt
+                out = ColumnarBatch(
+                    self.output_schema,
+                    gather_columns(sb.columns, idx, mask, unique=True),
+                    LazyCount(cnt))
+            elif ((jt == "left" and self.build_right) or
+                  (jt == "right" and not self.build_right) or
+                  jt == "full"):
+                uidx, ucnt = bk.filter_compact_indices(~surv & in_range,
+                                                       sb.rows_dev)
+                n_un = _host_int(ucnt)
+                if n_un:
+                    su_cols = gather_columns(
+                        sb.columns, uidx, jnp.arange(uidx.shape[0]) < n_un)
+                    nulls = [_null_column(f.dtype, uidx.shape[0])
+                             for f in build.schema]
+                    out = self._assemble(su_cols, nulls, n_un)
+        if out is not None:
+            yield out
 
-        parts = []
-        if total:
-            _, _, keep, p_idx, b_idx = got
-            if build_matched is not None:
-                from ..analysis import residency  # lazy: avoids import cycle
-                with residency.declared_transfer(site="join_verify"):
-                    midx = np.asarray(jnp.where(keep, b_idx, 0))
-                    keep_np = np.asarray(keep)
-                build_matched[midx[keep_np]] = True
-            # the surviving pairs: each output column gathered once
-            pidx2, pcnt = bk.filter_compact_indices(keep, int(total))
-            n_pairs = _host_int(pcnt)
-            if n_pairs:
-                pmask = jnp.arange(pidx2.shape[0]) < n_pairs
-                parts.append(self._assemble(
-                    gather_columns(sb.columns, jnp.take(p_idx, pidx2),
-                                   pmask),
-                    gather_columns(build.columns, jnp.take(b_idx, pidx2),
-                                   pmask), n_pairs))
+    def _surviving_pairs(self, sb, build, got, build_matched,
+                         total: int) -> Optional[ColumnarBatch]:
+        """The pairs of one launch under the budget whose condition
+        held, each output column gathered once."""
+        from ..kernels import basic as bk
+        _, _, keep, p_idx, b_idx = got
+        if build_matched is not None:
+            from ..analysis import residency  # lazy: avoids import cycle
+            with residency.declared_transfer(site="join_verify"):
+                midx = np.asarray(jnp.where(keep, b_idx, 0))
+                keep_np = np.asarray(keep)
+            build_matched[midx[keep_np]] = True
+        pidx2, pcnt = bk.filter_compact_indices(keep, total)
+        n_pairs = _host_int(pcnt)
+        if not n_pairs:
+            return None
+        pmask = jnp.arange(pidx2.shape[0]) < n_pairs
+        return self._assemble(
+            gather_columns(sb.columns, jnp.take(p_idx, pidx2), pmask),
+            gather_columns(build.columns, jnp.take(b_idx, pidx2), pmask),
+            n_pairs)
 
-        outer_stream = ((jt == "left" and self.build_right) or
-                        (jt == "right" and not self.build_right) or
-                        jt == "full")
-        if outer_stream:
-            uidx, ucnt = bk.filter_compact_indices(~surv & in_range,
-                                                   sb.rows_dev)
-            n_un = _host_int(ucnt)
-            if n_un:
-                su_cols = gather_columns(
-                    sb.columns, uidx, jnp.arange(uidx.shape[0]) < n_un)
-                nulls = [_null_column(f.dtype, uidx.shape[0])
-                         for f in build.schema]
-                parts.append(self._assemble(su_cols, nulls, n_un))
-        if not parts:
-            return ColumnarBatch.empty(self.output_schema)
-        if len(parts) == 1:
-            return parts[0]
-        return concat_batches(parts)
+    _CHUNK_PAIRS_JIT: dict = {}
+
+    def _chunk_pairs(self, sb, build, bt, got,
+                     build_matched) -> Optional[ColumnarBatch]:
+        """A chunk's surviving pairs (``_residual_chunk``'s slots,
+        survivors first), cut to their bucket: one small program maps
+        them to stream rows and build rows, then each side's columns
+        move in one gather."""
+        _, kept, order, row, pos = got
+        n_pairs = _host_int(kept)
+        if not n_pairs:
+            return None
+        n_slots = min(bucket_capacity(n_pairs), order.shape[0])
+        fn = TpuHashJoinBase._CHUNK_PAIRS_JIT.get(n_slots)
+        if fn is None:
+            def _maps(order, row, pos, perm):
+                sel = order[:n_slots]
+                return jnp.take(row, sel), jnp.take(perm, jnp.take(pos, sel))
+            fn = TpuHashJoinBase._CHUNK_PAIRS_JIT[n_slots] = \
+                _compile_watch.jit(_maps, "join_residual_pairs")
+        p_idx, b_idx = fn(order, row, pos, bt.perm)
+        if build_matched is not None:
+            from ..analysis import residency  # lazy: avoids import cycle
+            with residency.declared_transfer(site="join_verify"):
+                build_matched[np.asarray(b_idx)[:n_pairs]] = True
+        pmask = jnp.arange(n_slots) < n_pairs
+        return self._assemble(gather_columns(sb.columns, p_idx, pmask),
+                              gather_columns(build.columns, b_idx, pmask),
+                              n_pairs)
 
     def _assemble(self, stream_cols, build_cols, total) -> ColumnarBatch:
         if self.build_right:

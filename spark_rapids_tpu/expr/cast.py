@@ -7,6 +7,9 @@ ANSI mode (conf spark.rapids.tpu.sql.ansi.enabled) raises on overflow.
 """
 from __future__ import annotations
 
+import datetime as _dt
+import re
+
 import numpy as np
 import jax.numpy as jnp
 
@@ -208,7 +211,7 @@ def _cast_from_string(col: StringColumn, to: T.DType, num_rows: int) -> Column:
                 else:
                     continue
             elif to == T.DATE:
-                out[i] = np.datetime64(s, "D").astype(np.int32)
+                out[i] = np.datetime64(parse_date(s), "D").astype(np.int32)
             elif to == T.TIMESTAMP:
                 out[i] = np.datetime64(s, "us").astype(np.int64)
             elif isinstance(to, T.DecimalType):
@@ -219,6 +222,22 @@ def _cast_from_string(col: StringColumn, to: T.DType, num_rows: int) -> Column:
         except (ValueError, OverflowError):
             continue
     return Column(to, jnp.asarray(out.astype(to.np_dtype)), jnp.asarray(ok))
+
+
+_DATE_TEXT = re.compile(r"([0-9]{4})(?:-([0-9]{1,2})(?:-([0-9]{1,2}))?)?"
+                        r"(?:[ T].*)?")
+
+
+def parse_date(text: str) -> _dt.date:
+    """A string as Spark's cast to DATE reads it: ``yyyy``,
+    ``yyyy-[m]m`` or ``yyyy-[m]m-[d]d``, optionally followed by a space
+    or ``T`` and anything (TPC-DS q95's ``'1999-2-01'``).  ValueError
+    where Spark's cast gives NULL."""
+    m = _DATE_TEXT.fullmatch(text.strip())
+    if m is None:
+        raise ValueError(f"not a date: {text!r}")
+    return _dt.date(int(m.group(1)), int(m.group(2) or 1),
+                    int(m.group(3) or 1))
 
 
 def _format_float(x: float) -> str:

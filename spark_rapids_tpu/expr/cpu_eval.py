@@ -326,8 +326,8 @@ def _cast(e: castmod.Cast, t):
                                else False if sl in ("false", "f", "no", "n",
                                                     "0") else None)
                 elif to == T.DATE:
-                    import datetime
-                    out.append(datetime.date.fromisoformat(s))
+                    from .cast import parse_date
+                    out.append(parse_date(s))
                 elif to == T.TIMESTAMP:
                     out.append(np.datetime64(s, "us").item())
                 else:

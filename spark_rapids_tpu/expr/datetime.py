@@ -204,6 +204,22 @@ class DateSub(Expression):
                           jnp.int32), av & bv)
 
 
+def date_plus_days(left: Expression, right: Expression, op: str):
+    """Spark's coercion of ``date + int``, ``int + date`` and ``date -
+    int`` (the analyzer's DateTimeOperations rule): ``date_add`` /
+    ``date_sub`` by that many days.  None for any other operands, which
+    stay arithmetic."""
+    try:
+        lt, rt = left.dtype(), right.dtype()
+    except Exception:  # noqa: BLE001 - an unresolved side: not a date
+        return None
+    if lt == T.DATE and rt.is_integral:
+        return (DateAdd if op == "+" else DateSub)(left, right)
+    if op == "+" and rt == T.DATE and lt.is_integral:
+        return DateAdd(right, left)
+    return None
+
+
 class DateDiff(Expression):
     def __init__(self, end, start):
         self.children = [end, start]

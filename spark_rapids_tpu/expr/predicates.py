@@ -24,6 +24,22 @@ def _comparable_words(expr: Expression, batch):
     return words, col.validity, isinstance(col, StringColumn)
 
 
+def coerce_date_string(left: Expression, right: Expression):
+    """Spark's comparison of a DATE with a STRING: the string is cast to
+    a date (TPC-DS q95's ``d_date between '1999-2-01' and ...``).  Any
+    other pair is returned as it is."""
+    try:
+        lt_, rt_ = left.dtype(), right.dtype()
+    except (ValueError, NotImplementedError):
+        return left, right
+    from .cast import Cast
+    if lt_ == T.DATE and rt_ == T.STRING:
+        return left, Cast(right, T.DATE)
+    if rt_ == T.DATE and lt_ == T.STRING:
+        return Cast(left, T.DATE), right
+    return left, right
+
+
 def promote_comparison_sides(left: Expression, right: Expression):
     """Insert casts so both sides share one dtype before key-word
     encoding (the Spark analyzer's binary-comparison coercion).

@@ -6,9 +6,11 @@ optimized; GpuOverrides.scala:3100 only re-maps physical ops).  This
 standalone framework owns the front end, so the classical rewrites live
 here: conjuncts of a Filter over an inner/cross Join are split into
 per-side filters, cross-side equalities become hash-join keys (turning a
-cross join into an equi join the TPU hash-join exec can run), and the
-remainder stays as a residual filter.  An outer join's ON conjuncts that
-read its null-supplying side alone filter that side.
+cross join into an equi join the TPU hash-join exec can run), the other
+two-sided conjuncts become an equi join's residual condition, and what
+is left stays as a Filter.  A WHERE conjunct that reads an outer join's
+preserved side alone filters that side, and an outer join's ON
+conjuncts that read its null-supplying side alone filter that side.
 """
 from __future__ import annotations
 
@@ -130,6 +132,7 @@ def _rewrite_filter_join(f: L.Filter) -> L.LogicalPlan:
     lkeys = list(j.left_keys)
     rkeys = list(j.right_keys)
     rest: List[ec.Expression] = []
+    both: List[ec.Expression] = []    # two-sided, not an equi-key
     conjuncts = [x for c in _flatten_and(f.condition)
                  for x in _factor_or(c)]
     for c in conjuncts:
@@ -140,25 +143,86 @@ def _rewrite_filter_join(f: L.Filter) -> L.LogicalPlan:
             lpush.append(c)
         elif refs <= rnames:
             rpush.append(c)
-        elif isinstance(c, ep.EqualTo):
-            a, b = c.children
-            ra, rb = _refs(a), _refs(b)
-            if ra and rb and ra <= lnames and rb <= rnames:
-                lkeys.append(a)
-                rkeys.append(b)
-            elif ra and rb and ra <= rnames and rb <= lnames:
-                lkeys.append(b)
-                rkeys.append(a)
-            else:
-                rest.append(c)
+        elif isinstance(c, ep.EqualTo) and \
+                (key := _equi_key(c, lnames, rnames)) is not None:
+            lkeys.append(key[0])
+            rkeys.append(key[1])
+        elif refs <= lnames | rnames:
+            both.append(c)
         else:
             rest.append(c)
-    if not lpush and not rpush and len(lkeys) == len(j.left_keys):
+    cond, above = _residual_condition(j.condition, lkeys, both)
+    rest += above
+    if not lpush and not rpush and len(lkeys) == len(j.left_keys) \
+            and cond is j.condition:
         return f
     new_left = optimize(_filter_over(lpush, left))
     new_right = optimize(_filter_over(rpush, right))
     jt = "inner" if lkeys else j.join_type
-    nj = L.Join(new_left, new_right, jt, lkeys, rkeys, j.condition)
+    nj = L.Join(new_left, new_right, jt, lkeys, rkeys, cond)
+    return _filter_over(rest, nj)
+
+
+def _residual_condition(condition, keys, both):
+    """-> (the inner join's condition, the conjuncts left above it).  An
+    equi join decides a two-sided conjunct over its candidate pairs: for
+    an inner join the rows a Filter above it would keep, without
+    gathering every column of every pair first (TPC-DS q72's
+    ``inv_quantity_on_hand < cs_quantity``).  A join with no key stays a
+    cross join under the Filter."""
+    if not keys or not both:
+        return condition, both
+    conds = ([condition] if condition is not None else []) + both
+    return _and_all(conds), []
+
+
+def _equi_key(c: ep.EqualTo, lnames: Set[str], rnames: Set[str]):
+    """-> (left key, right key) when ``c`` equates an expression of the
+    left side alone with one of the right side alone, else None."""
+    a, b = c.children
+    ra, rb = _refs(a), _refs(b)
+    if ra and rb and ra <= lnames and rb <= rnames:
+        return a, b
+    if ra and rb and ra <= rnames and rb <= lnames:
+        return b, a
+    return None
+
+
+#: outer join type -> the child whose rows it keeps whatever matches
+#: (the preserved side), the one a WHERE conjunct may filter first
+_PRESERVED = {"left": 0, "right": 1}
+
+
+def _rewrite_filter_outer(f: L.Filter) -> L.LogicalPlan:
+    """Filter over a LEFT (RIGHT) OUTER join: a conjunct that reads only
+    the left (right) child filters that child first (Spark's
+    PushPredicateThroughJoin).  A preserved row the conjunct drops would
+    be dropped above the join with every row it makes, and the rows it
+    keeps join as before.  A conjunct that reads the null-supplying side
+    (``p_promo_sk IS NULL`` among them) stays above: the join's NULLs
+    are what it tests.  A FULL join preserves both sides and takes
+    none.  The pushed Filter is optimized in turn, so a WHERE passes a
+    chain of outer joins down to the inner joins below them (TPC-DS
+    q72)."""
+    j = f.children[0]
+    if not isinstance(j, L.Join) or j.join_type not in _PRESERVED:
+        return f
+    side = _PRESERVED[j.join_type]
+    names = set(j.children[side].schema.names)
+    if names & set(j.children[1 - side].schema.names):
+        return f  # ambiguous column names: leave untouched
+    push: List[ec.Expression] = []
+    rest: List[ec.Expression] = []
+    for c in _flatten_and(f.condition):
+        refs = _refs(c)
+        (push if refs and refs <= names else rest).append(c)
+    if not push:
+        return f
+    _trace.count("plan.pushdown.outer", len(push))
+    kids = list(j.children)
+    kids[side] = optimize(_filter_over(push, kids[side]))
+    nj = L.Join(kids[0], kids[1], j.join_type, j.left_keys, j.right_keys,
+                j.condition)
     return _filter_over(rest, nj)
 
 
@@ -496,8 +560,10 @@ def prune_scan_columns(plan: L.LogicalPlan,
 
 
 def optimize(plan: L.LogicalPlan) -> L.LogicalPlan:
-    """Bottom-up: push Filter conjuncts through inner/cross joins and
-    promote cross-side equalities to join keys; push an outer join's
+    """Bottom-up: push Filter conjuncts through inner/cross joins,
+    promote cross-side equalities to join keys and make an equi join's
+    other two-sided conjuncts its condition; push a WHERE conjunct on an
+    outer join's preserved side into that side, and an outer join's
     one-sided ON conjuncts into its null-supplying side."""
     new_children = [optimize(c) for c in plan.children]
     if any(n is not o for n, o in zip(new_children, plan.children)):
@@ -517,6 +583,9 @@ def optimize(plan: L.LogicalPlan) -> L.LogicalPlan:
         if out is not plan:
             return out
         out = _rewrite_filter_semi(plan)
+        if out is not plan:
+            return out
+        out = _rewrite_filter_outer(plan)
         if out is not plan:
             return out
         out = _rewrite_filter_project(plan)

@@ -82,6 +82,23 @@ def supported_ops_doc() -> str:
         "| Expand | TpuExpand | grouping sets |",
         "| WriteFile | TpuFileWrite | parquet/orc/csv |",
         "",
+        "The planner (`plan/logical_opt.py`) rewrites a WHERE over joins "
+        "before a physical operator is chosen: each conjunct that reads "
+        "one side of an inner or cross join filters that side, a "
+        "cross-side equality becomes a hash-join key, and another "
+        "two-sided conjunct becomes the equi join's residual condition "
+        "(decided over the candidate pairs, at most "
+        "`spark.rapids.tpu.sql.join.gather.chunkRows` pairs a launch; a "
+        "join with no key keeps it as a Filter above).  A conjunct that "
+        "reads only a LEFT (RIGHT) OUTER join's preserved side filters "
+        "that side first, through a chain of outer joins (Spark's "
+        "PushPredicateThroughJoin); one that reads the null-supplying "
+        "side stays above, and a FULL join takes none.  An outer join's "
+        "ON conjunct on its null-supplying side alone filters that side.  "
+        "`date + int`, `int + date` and `date - int` are `date_add` / "
+        "`date_sub`, and a STRING compared with a DATE is cast to one "
+        "(`yyyy`, `yyyy-[m]m`, `yyyy-[m]m-[d]d`), as in Spark.",
+        "",
         "Unsupported constructs fall back to the CPU (pyarrow) engine "
         "per-operator with automatic RowToColumnar/ColumnarToRow "
         "transitions; `spark.rapids.tpu.sql.explain=NOT_ON_TPU` prints "
