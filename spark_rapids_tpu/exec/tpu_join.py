@@ -611,72 +611,48 @@ class TpuHashJoinBase(TpuExec):
         return out
 
     def _expand_phases(self, sb, build, bt, jt, outer_stream, lo, counts,
-                       eff, total_lazy):
+                       eff, total_lazy, by_rows: bool = False):
         """Bounded incremental gather (JoinGatherer.scala:1 role).
 
         A skewed key can explode one (stream batch, build) pair far past
         device memory; when the total exceeds the chunk budget, expand in
         probe-row ranges — splitting even a single probe row's matches
         across chunks by advancing its ``lo`` offset — so no single
-        output allocation exceeds the budget.  Yields chunks lazily so
+        output allocation exceeds the budget.  Chunk k holds the pairs
+        ``[k * budget, (k + 1) * budget)`` in row order: its rows' share
+        is computed on the device (``join_k.join_pairs_window``), and its
+        size is known from the total alone.  Yields chunks lazily so
         downstream can consume (or spill) chunk k before chunk k+1's
-        gather allocates."""
+        gather allocates.  ``by_rows``: see ``_expand_phase``."""
         from ..config import get_active, JOIN_GATHER_CHUNK_ROWS
         total = int(total_lazy)
         limit = int(get_active().get(JOIN_GATHER_CHUNK_ROWS))
         if total <= limit or jt in ("semi", "anti"):
             with timed(self.metrics[JOIN_TIME], self):
                 out = self._expand_phase(sb, build, bt, jt, outer_stream,
-                                         lo, counts, eff, total)
+                                         lo, counts, eff, total, by_rows)
             if out is not None:
                 yield out
             return
-        from ..analysis import residency  # lazy: avoids import cycle
-        with timed(self.metrics[JOIN_TIME], self):
-            with residency.declared_transfer(site="join_verify"):
-                eff_np = np.asarray(eff).astype(np.int64)
-                lo_np = np.asarray(lo).astype(np.int32)
-        nrows = eff_np.shape[0]
-        p0 = 0
-        off0 = 0          # matches of row p0 already emitted
-        while p0 < nrows:
-            budget = limit
-            chunk_eff = np.zeros(nrows, np.int64)
-            chunk_lo = lo_np.copy()
-            p, off = p0, off0
-            chunk_total = 0
-            while p < nrows and budget > 0:
-                avail = int(eff_np[p]) - off
-                if avail <= 0:
-                    p += 1
-                    off = 0
-                    continue
-                take = min(avail, budget)
-                chunk_eff[p] = take
-                if off:
-                    chunk_lo[p] = lo_np[p] + off
-                chunk_total += take
-                budget -= take
-                if take == avail:
-                    p += 1
-                    off = 0
-                else:
-                    off += take
-            if chunk_total == 0:
-                break
+        for base in range(0, total, limit):
             with timed(self.metrics[JOIN_TIME], self):
+                chunk_lo, chunk_eff = join_k.join_pairs_window(
+                    lo, eff, np.int64(base), limit)
                 out = self._expand_phase(
-                    sb, build, bt, jt, outer_stream,
-                    jnp.asarray(chunk_lo), counts,
-                    jnp.asarray(chunk_eff.astype(np.int32)), chunk_total)
+                    sb, build, bt, jt, outer_stream, chunk_lo, counts,
+                    chunk_eff, min(limit, total - base), by_rows)
             if out is not None:
                 yield out
-            p0, off0 = p, off
 
     def _expand_phase(self, sb, build, bt, jt, outer_stream, lo, counts,
-                      eff, total_lazy) -> Optional[ColumnarBatch]:
+                      eff, total_lazy,
+                      by_rows: bool = False) -> Optional[ColumnarBatch]:
         """Phase B: expansion + all output gathers as ONE jitted program
-        with a host-known output capacity."""
+        with a host-known output capacity.  ``by_rows`` (a residual
+        join's survivors, tens of millions of rows): each side's rows
+        move by one row gather instead (``_expand_eager``), where the
+        program's two takes a column cost q72's 47.6M survivors of
+        twelve columns 20.1 s a pass on the chip (PERF.md section 6)."""
         import jax
         total = int(total_lazy)
         if total == 0:
@@ -695,7 +671,7 @@ class TpuHashJoinBase(TpuExec):
                     gather_columns(sb.columns, idx,
                                    jnp.arange(out_cap) < total), total)
             return out
-        if not all(type(c) is Column for c in sb.columns) or \
+        if by_rows or not all(type(c) is Column for c in sb.columns) or \
                 not all(type(c) is Column for c in build.columns):
             return self._expand_eager(sb, build, bt, outer_stream, lo,
                                       counts, eff, total)
@@ -744,8 +720,9 @@ class TpuHashJoinBase(TpuExec):
 
     def _expand_eager(self, sb, build, bt, outer_stream, lo, counts, eff,
                       total):
-        """Non-plain columns (strings/nested): the expansion, then each
-        side's columns in one eager gather (``gather_columns``)."""
+        """Non-plain columns (strings/nested), or ``by_rows``: the
+        expansion, then each side's columns in one eager gather
+        (``gather_columns``)."""
         out_cap = bucket_capacity(total)
         p_idx, b_idx, live, _ = join_k.join_expand_matches(lo, eff, bt.perm,
                                                       out_cap)
@@ -861,6 +838,137 @@ class TpuHashJoinBase(TpuExec):
                 Schema([fields[o] for o in order]))
         self._residual_memo = plan
         return plan
+
+    def _range_plan(self):
+        """How an inner join whose condition is ONE comparison of a
+        build-side integer, date or timestamp expression with a
+        stream-side one decides its pairs by bounds (``_range_batches``),
+        once an exec, from the bound condition alone: -> (the build
+        side's expression and schema, the stream side's, whether the
+        search is for the first build value ``> s`` rather than ``>=
+        s``, whether the survivors are the values before it), or None
+        (another join type or condition: pair by pair)."""
+        if not hasattr(self, "_range_memo"):
+            self._range_memo = self._plan_range()
+        return self._range_memo
+
+    def _plan_range(self):
+        from ..expr import predicates as pr
+        cond, sig, s_ords, _, schema = self._residual_plan()
+        # the operator, and the one it reads as with its sides swapped
+        ops = {pr.LessThan: ("<", ">"), pr.LessThanOrEqual: ("<=", ">="),
+               pr.GreaterThan: (">", "<"),
+               pr.GreaterThanOrEqual: (">=", "<=")}.get(type(cond))
+        if self.logical.join_type != "inner" or ops is None or sig is None:
+            return None
+        ns = len(s_ords)
+
+        def ordered(dt):
+            return dt.is_integral or dt in (T.DATE, T.TIMESTAMP)
+        if not all(ordered(f.dtype) for f in schema):
+            return None
+        left, right = pr.promote_comparison_sides(*cond.children)
+        try:
+            if left.dtype() != right.dtype() or not ordered(left.dtype()):
+                return None
+        except (ValueError, NotImplementedError):
+            return None
+        sides = [{o < ns for o in _bound_ordinals(e)} for e in (left, right)]
+        if sides == [{False}, {True}]:
+            b, s, op = left, right, ops[0]
+        elif sides == [{True}, {False}]:
+            b, s, op = right, left, ops[1]
+        else:
+            return None
+        fields = list(schema)
+        b = _rebind(b, {ns + k: k for k in range(len(fields) - ns)})
+        return (b, Schema(fields[ns:]), s, Schema(fields[:ns]),
+                op in ("<=", ">"), op in ("<", "<="))
+
+    def _range_sorted(self, build, bt):
+        """The build side's comparison value in ``bt``'s order, each
+        equal-key run sorted by it (``join_k.sort_within_runs``): one
+        program a build, kept in the build's memo beside ``bt``, which
+        every other user of the build reads unchanged."""
+        memo = getattr(self, "_build_memo", None) or {}
+        if memo.get("bt") is not bt:
+            memo = {}
+        if "residual_range" in memo:
+            return memo["residual_range"]
+        b_expr, b_schema, *_ = self._range_plan()
+        _, sig, _, b_ords, _ = self._residual_plan()
+        bcols = [build.columns[i] for i in b_ords]
+        key = ("residual_range_sorted", sig, build.capacity,
+               len(bt.sorted_words), tuple(c.dtype.name for c in bcols))
+        fn = TpuHashJoinBase._RESIDUAL_JIT.get(key)
+        if fn is None:
+            cap = build.capacity
+
+            def _sorted(words, perm, barrs):
+                cols = [Column(f.dtype, d, v)
+                        for f, (d, v) in zip(b_schema, barrs)]
+                val = ec.eval_as_column(b_expr,
+                                        ColumnarBatch(b_schema, cols, cap))
+                return join_k.sort_within_runs(list(words), perm, val.data,
+                                               val.validity)
+            fn = _compile_watch.wrap_miss(
+                "join_residual",
+                _compile_watch.jit(_sorted, "join_residual_run_sort"),
+                str(key))
+            if len(TpuHashJoinBase._RESIDUAL_JIT) < 4096:
+                TpuHashJoinBase._RESIDUAL_JIT[key] = fn
+        memo["residual_range"] = fn(
+            tuple(bt.sorted_words), bt.perm,
+            tuple((c.data, c.validity) for c in bcols))
+        return memo["residual_range"]
+
+    def _range_batches(self, sb, build, bt, lo, counts, kept):
+        """An inner join's pairs decided by bounds: the build's runs
+        sorted by the comparison's build value (``_range_sorted``), each
+        stream row's survivors are one sub-range of its run, found by a
+        binary search in ONE program a stream batch
+        (``join_residual_range``), whose total is the batch's one read.
+        The survivors alone are then expanded, through the reordered
+        ``perm``, by the equi join's own bounded chunks
+        (``_expand_phases``), and each side's rows gathered once: no
+        failing pair is ever formed."""
+        from ..columnar.batch import LazyCount
+        _, _, s_expr, s_schema, strict, prefix = self._range_plan()
+        _, sig, s_ords, _, _ = self._residual_plan()
+        perm_r, vals_r, nvalid, rounds = self._range_sorted(build, bt)
+        scols = [sb.columns[i] for i in s_ords]
+        key = ("residual_range", sig, sb.capacity, perm_r.shape[0],
+               tuple(c.dtype.name for c in scols))
+        fn = TpuHashJoinBase._RESIDUAL_JIT.get(key)
+        if fn is None:
+            cap = sb.capacity
+
+            def _range(lo, counts, sarrs, vals_r, nvalid, rounds):
+                cols = [Column(f.dtype, d, v)
+                        for f, (d, v) in zip(s_schema, sarrs)]
+                sv = ec.eval_as_column(s_expr,
+                                       ColumnarBatch(s_schema, cols, cap))
+                lo2, n2 = join_k.run_bounds(
+                    lo, counts, vals_r, nvalid, rounds,
+                    sv.data.astype(vals_r.dtype), sv.validity, strict,
+                    prefix)
+                return lo2, n2, jnp.sum(n2.astype(jnp.int64))
+            fn = _compile_watch.wrap_miss(
+                "join_residual",
+                _compile_watch.jit(_range, "join_residual_range"), str(key))
+            if len(TpuHashJoinBase._RESIDUAL_JIT) < 4096:
+                TpuHashJoinBase._RESIDUAL_JIT[key] = fn
+        with timed(self.metrics[JOIN_TIME], self):
+            _trace.count("join.residual.range")
+            _trace.count("join.residual.chunks")
+            lo2, n2, total = fn(lo, counts,
+                                tuple((c.data, c.validity) for c in scols),
+                                vals_r, nvalid, rounds)
+        kept.append(LazyCount(total))
+        yield from self._expand_phases(
+            sb, build, join_k.BuildTable(bt.sorted_words, perm_r,
+                                         bt.capacity),
+            "inner", False, lo2, counts, n2, kept[-1], by_rows=True)
 
     def _residual_keep(self, sb, build, bt, lo, counts, out_cap: int,
                        pairs: bool):
@@ -1101,7 +1209,10 @@ class TpuHashJoinBase(TpuExec):
                           build_matched, kept):
         """Join with a residual (non-equi) condition: decide every
         candidate pair (``lo``, ``counts``: ``total`` of them), then
-        derive the join type's rows from the survivors.  Past
+        derive the join type's rows from the survivors.  An inner join
+        whose condition is one comparison of a build value with a stream
+        value (``_range_plan``) decides them by bounds instead
+        (``_range_batches``): its survivors alone are formed.  Past
         ``join.gather.chunkRows`` pairs the decisions run in chunks of
         that many (``_residual_chunk``), each its own launch, and a
         stream row's survival is the OR over the chunks: no allocation
@@ -1119,13 +1230,19 @@ class TpuHashJoinBase(TpuExec):
         jt = self.logical.join_type
         total = int(total)
         _trace.count("join.residual.pairs", total)
+        if total:
+            _trace.count("join.residual.bytes",
+                         total * self._residual_pair_bytes(sb, build))
+            if self._range_plan() is not None:
+                # one comparison of a build value with a stream value:
+                # by bounds, an inner join's survivors alone
+                yield from self._range_batches(sb, build, bt, lo, counts,
+                                               kept)
+                return
         pairs = jt not in ("semi", "anti")
         in_range = jnp.arange(sb.capacity) < sb.rows_dev
         surv = jnp.zeros(sb.capacity, dtype=bool)
         limit = max(int(get_active().get(JOIN_GATHER_CHUNK_ROWS)), 1)
-        if total:
-            _trace.count("join.residual.bytes",
-                         total * self._residual_pair_bytes(sb, build))
         if 0 < total <= limit:
             # under the budget: one launch at today's capacity
             with timed(self.metrics[JOIN_TIME], self):

@@ -27,7 +27,7 @@ from jax import lax
 from ..obs import trace as _obs_trace
 from . import canon
 from .basic import prefix_sum
-from .sort import sorted_words
+from .sort import sorted_words, stable_sort_rows
 
 
 @dataclasses.dataclass
@@ -165,6 +165,85 @@ def join_expand_matches(lo, counts, perm, out_cap: int):
     build_idx = jnp.take(perm, build_pos)
     live = t < jnp.minimum(total, out_cap).astype(jnp.int32)
     return pc, build_idx, live, total
+
+
+def order_word(v):
+    """An integer array (at most 64 bits) as unsigned words in its
+    order: 32-bit words for 32-bit values and narrower, which the chip
+    sorts for a sixth of a 64-bit word's cost."""
+    if v.dtype.itemsize <= 4:
+        return lax.bitcast_convert_type(v.astype(jnp.int32), jnp.uint32) \
+            ^ jnp.uint32(1 << 31)
+    return v.astype(jnp.int64).view(jnp.uint64) ^ jnp.uint64(1 << 63)
+
+
+def sort_within_runs(sorted_words: List[jnp.ndarray], perm, vals, valid):
+    """Each equal-key run of a sorted build (``sorted_words``, ``perm``)
+    ordered by ``vals`` (integers in build row order; NULLs, ``~valid``,
+    last in their run), stably: two (key, row) sorts, the value word
+    first, then the run number beside a NULL flag (32 bits), so the runs
+    keep their positions and a probe's ``lo`` and ``counts`` still name
+    its run.  -> (the reordered ``perm``, the values in that order, each
+    position's count of non-NULL values in its run, and the rounds a
+    binary search over the longest run takes)."""
+    n = perm.shape[0]
+    run = prefix_sum(canon.words_equal_adjacent(sorted_words)
+                     .astype(jnp.int32)) - 1
+    v = jnp.take(vals, perm)
+    ok = jnp.take(valid, perm)
+    _, by_value = stable_sort_rows(order_word(v))
+    runs_key = (run.astype(jnp.uint32) << 1) | (~ok).astype(jnp.uint32)
+    _, by_run = stable_sort_rows(jnp.take(runs_key, by_value))
+    order = jnp.take(by_value, by_run)
+    per_run = jnp.zeros(n, jnp.int32).at[run].add(ok.astype(jnp.int32))
+    longest = jnp.max(jnp.zeros(n, jnp.int32).at[run].add(1))
+    rounds = jnp.int32(32) - lax.clz(longest)
+    return (jnp.take(perm, order), jnp.take(v, order),
+            jnp.take(per_run, run), rounds)
+
+
+def run_bounds(lo, counts, vals_r, nvalid, rounds, s, s_valid,
+               strict: bool, prefix: bool):
+    """Each probe row's surviving sub-range of its run under one
+    comparison ``b OP s`` of the run's values ``b`` (``sort_within_runs``
+    order: ascending, NULLs last) with the row's value ``s``: a binary
+    search of ``rounds`` rounds for the first ``b > s`` (``strict``) or
+    ``b >= s`` among the run's non-NULL values.  ``b < s`` and ``b <=
+    s`` keep the values before it (``prefix``), ``b >= s`` and ``b >
+    s`` those from it on; a NULL ``s`` keeps none.  -> (lo, counts) of
+    the survivors."""
+    n = vals_r.shape[0]
+    nv = jnp.where((counts > 0) & s_valid,
+                   jnp.minimum(jnp.take(nvalid, jnp.clip(lo, 0, n - 1)),
+                               counts), 0)
+
+    def body(_, state):
+        a, b = state
+        mid = (a + b) >> 1
+        bm = jnp.take(vals_r, jnp.clip(lo + mid, 0, n - 1))
+        hit = (bm > s) if strict else (bm >= s)
+        active = a < b
+        return (jnp.where(active & ~hit, mid + 1, a),
+                jnp.where(active & hit, mid, b))
+
+    j, _ = lax.fori_loop(0, rounds, body, (jnp.zeros_like(nv), nv))
+    if prefix:
+        return lo, j
+    return lo + j, nv - j
+
+
+@_obs_trace.launched()
+@functools.partial(jax.jit, static_argnames=("limit",))
+def join_pairs_window(lo, eff, base, limit: int):
+    """The rows' share of the flattened pairs ``[base, base + limit)``
+    (each row's pairs in row order, ``eff`` a row): each row's first
+    pair in the window as a build position, and its pairs there."""
+    incl = prefix_sum(eff.astype(jnp.int64))
+    start = incl - eff
+    n_here = jnp.maximum(jnp.minimum(incl, base + limit) -
+                         jnp.maximum(start, base), 0)
+    lo_w = lo.astype(jnp.int64) + jnp.maximum(base - start, 0)
+    return lo_w.astype(jnp.int32), n_here.astype(jnp.int32)
 
 
 def total_matches(counts) -> int:

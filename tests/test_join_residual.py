@@ -1,10 +1,15 @@
 """Hash joins with a residual (non-equi) condition
-(``exec/tpu_join._residual_batch``): every join type against the
-pyarrow engine on wide inputs, and the semi / anti path's shape: one
+(``exec/tpu_join._residual_batches``): every join type against the
+pyarrow engine on wide inputs; an inner join whose condition is one
+integer or date comparison of a build value with a stream value is
+decided by bounds (``join.residual.range``), every other join and
+condition pair by pair; and the semi / anti path's shape: one
 ``join_residual_core`` launch a stream batch that gathers the
 condition's two columns and nothing else (``lanes.`` = 2 x the pairs'
 capacity), no pair column materialized, no count pulled to the host
 inside the join, the candidate and surviving pairs counted."""
+import datetime
+
 import numpy as np
 import pytest
 
@@ -30,12 +35,26 @@ def _frames(s):
              "q1": [f"q-{i}" for i in range(M)]}
     left["x"][3] = None
     right["y"][5] = None
+    day = datetime.date(1999, 2, 1)
+    left["dt"] = [day + datetime.timedelta(days=int(v))
+                  for v in rng.integers(0, 12, N)]
+    right["du"] = [day + datetime.timedelta(days=int(v))
+                   for v in rng.integers(0, 12, M)]
+    left["dt"][8] = None
+    right["du"][2] = None
     return (s.create_dataframe(left, num_partitions=1),
             s.create_dataframe(right, num_partitions=1))
 
 
+#: decided by bounds in an inner join: one comparison, an integer or
+#: date on each side, with the build's value on either side of it (x and
+#: y hold five values each, so a key's run repeats them, and a NULL each)
+RANGE = {"x_lt_y": "x < y", "y_lt_x": "y < x", "x_le_y": "x <= y",
+         "y_le_x": "y <= x", "x_gt_y": "x > y", "y_gt_x": "y > x",
+         "x_ge_y": "x >= y", "y_ge_x": "y >= x", "expr_lt": "x + 1 < y",
+         "date_gt": "du > dt + interval 3 days"}
 CONDITIONS = {"int_ne": "y <> x", "double_lt": "e > d",
-              "string_ne": "t <> s"}
+              "string_ne": "t <> s", "conj": "y > x and e > d", **RANGE}
 
 
 def _query(how, cond):
@@ -68,12 +87,18 @@ def _device_session():
 @pytest.mark.parametrize("how", ["semi", "anti", "inner", "left outer"])
 def test_residual_join_matches_the_cpu_engine(how, cond):
     """On the device (test mode: no CPU operator) and on the pyarrow
-    engine, whose CpuJoin decides the pairs by its own code."""
+    engine, whose CpuJoin decides the pairs by its own code; by bounds
+    exactly where the join is inner and the condition one comparison."""
     from spark_rapids_tpu.analysis import residency
     cpu = with_cpu_session(lambda s: _query(how, cond)(s).collect())
+    df = _query(how, cond)(_device_session())
+    trace.reset()
     with residency.declared_transfer(site="oracle_compare"):
-        got = _query(how, cond)(_device_session()).collect()
+        got = df.collect()
     assert canon_rows(got) == canon_rows(cpu) and len(got) > 0
+    ranged = sum(t.get("join.residual.range", 0)
+                 for t in trace.coarse_counts().values())
+    assert (ranged > 0) == (how == "inner" and cond in RANGE)
 
 
 def _pairs():

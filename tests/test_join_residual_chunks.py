@@ -7,7 +7,10 @@ With the budget set tiny here, inner, left outer, semi and anti joins
 equal a plain Python join and the same join in one chunk;
 ``join.residual.chunks`` counts the launches; a join under the budget
 keeps the program and cache key it had before chunking existed; a
-chunk's window of stream rows holds every row whose pairs meet it."""
+chunk's window of stream rows holds every row whose pairs meet it.  An
+inner join under ``a < b`` is decided by bounds instead (one launch a
+stream batch, ``join.residual.range``), and its survivors come out in
+batches of the budget; under ``a <> b`` it keeps the chunks."""
 import numpy as np
 import pytest
 
@@ -31,6 +34,7 @@ U["b"][len(U["k2"]) - 1 - U["k2"][::-1].index(1)] = 25
 
 SQL = {
     "inner": "select k, a, k2, b from t join u on k = k2 and a < b",
+    "inner_ne": "select k, a, k2, b from t join u on k = k2 and a <> b",
     "left": "select k, a, k2, b from t left outer join u "
             "on k = k2 and a < b",
     "semi": "select k, a from t left semi join u on k = k2 and a < b",
@@ -42,8 +46,9 @@ def _oracle(how):
     out = []
     for k, a in zip(T["k"], T["a"]):
         hits = [(k2, b) for k2, b in zip(U["k2"], U["b"])
-                if k is not None and k == k2 and a < b]
-        if how == "inner":
+                if k is not None and k == k2 and
+                (a != b if how == "inner_ne" else a < b)]
+        if how in ("inner", "inner_ne"):
             out += [(k, a, k2, b) for k2, b in hits]
         elif how == "left":
             out += [(k, a, k2, b) for k2, b in hits] or [(k, a, None, None)]
@@ -100,7 +105,12 @@ def test_chunked_equals_one_chunk_and_python(how, chunk_rows):
     assert counts["join.residual.pairs"] == one_counts[
         "join.residual.pairs"] == pairs
     assert one_counts["join.residual.chunks"] == 1
-    assert counts["join.residual.chunks"] == -(-pairs // chunk_rows)
+    # an inner a < b: one launch of bounds for the one stream batch
+    ranged = int(how == "inner")
+    assert counts.get("join.residual.range", 0) == \
+        one_counts.get("join.residual.range", 0) == ranged
+    assert counts["join.residual.chunks"] == \
+        (1 if ranged else -(-pairs // chunk_rows))
     assert counts["join.residual.kept"] == one_counts["join.residual.kept"]
     # the pairs' bytes: two int64 columns (8 + 1 bytes each), two int32
     # maps and a flag a pair, whatever the chunking
@@ -117,8 +127,8 @@ def test_an_inner_join_yields_a_batch_a_chunk(monkeypatch):
         sizes.append(None if out is None else out.capacity)
         return out
     monkeypatch.setattr(TpuHashJoinBase, "_chunk_pairs", spy)
-    rows, counts = _run(_session(7), "inner")
-    assert rows == sorted(_oracle("inner"), key=_key)
+    rows, counts = _run(_session(7), "inner_ne")
+    assert rows == sorted(_oracle("inner_ne"), key=_key)
     assert len(sizes) == counts["join.residual.chunks"]
     # each chunk's batch is cut to its survivors' bucket, under the chunk
     assert all(c is None or c <= bucket_capacity(7) for c in sizes)
@@ -146,16 +156,45 @@ def test_a_join_under_the_budget_keeps_its_cache_key():
     budget, beside the one that sorts the build side's condition
     columns once."""
     TpuHashJoinBase._RESIDUAL_JIT.clear()
-    _run(_session(), "inner")
+    _run(_session(), "inner_ne")
     (key,) = TpuHashJoinBase._RESIDUAL_JIT
     assert len(key) == 8 and key[0] == "residual" and key[2] is True
     assert key[3] == bucket_capacity(_pairs())
     assert key[6] == ("bigint", "bigint") and key[7] == 1
     TpuHashJoinBase._RESIDUAL_JIT.clear()
-    _run(_session(7), "inner")
+    _run(_session(7), "inner_ne")
     keys = {k[0]: k for k in TpuHashJoinBase._RESIDUAL_JIT}
     assert sorted(keys) == ["residual_chunk", "residual_sorted"]
     assert keys["residual_chunk"][3] == 7
+
+
+def test_a_range_join_yields_bounded_batches(monkeypatch):
+    """An inner join decided by bounds whose survivors exceed the
+    budget: the equi join's chunks, each at most the budget, the rows
+    the one-launch answer's, ``join.residual.kept`` the survivors; the
+    build's runs sorted by one program and the bounds by another, and
+    no pair decided one by one."""
+    sizes = []
+    real = TpuHashJoinBase._expand_phase
+
+    def spy(self, *args, **kw):
+        out = real(self, *args, **kw)
+        sizes.append(int(out.num_rows))
+        return out
+    one, one_counts = _run(_session(), "inner")
+    TpuHashJoinBase._RESIDUAL_JIT.clear()
+    monkeypatch.setattr(TpuHashJoinBase, "_expand_phase", spy)
+    rows, counts = _run(_session(7), "inner")
+    want = sorted(_oracle("inner"), key=_key)
+    assert rows == one == want and len(want) > 3 * 7
+    assert len(sizes) == -(-len(want) // 7) and max(sizes) == 7
+    assert sum(sizes) == len(want)
+    assert counts["join.residual.kept"] == \
+        one_counts["join.residual.kept"] == len(want)
+    assert counts["join.residual.range"] == counts["join.residual.chunks"] \
+        == 1
+    assert sorted(k[0] for k in TpuHashJoinBase._RESIDUAL_JIT) == \
+        ["residual_range", "residual_range_sorted"]
 
 
 def _wide(kind):
@@ -178,15 +217,20 @@ def _wide(kind):
     return t, u
 
 
-@pytest.mark.parametrize("kind", ["bigint", "double", "string"])
+@pytest.mark.parametrize("kind", ["bigint", "double", "string",
+                                  "bigint_ne"])
 def test_windows_inside_a_large_batch(kind):
-    t, u = _wide(kind)
+    """A bigint ``a < b`` is decided by bounds (one launch); under
+    ``<>`` it takes the chunks' windows like a DOUBLE or a string."""
+    ne = kind.endswith("_ne")
+    t, u = _wide(kind.split("_")[0])
     by_key = {}
     for k2, b in zip(u["k2"], u["b"]):
         by_key.setdefault(k2, []).append(b)
     want = sorted(((k, a, b) for k, a in zip(t["k"], t["a"])
                    for b in by_key.get(k, [])
-                   if a is not None and b is not None and a < b), key=_key)
+                   if a is not None and b is not None and
+                   (a != b if ne else a < b)), key=_key)
     got = []
     for chunk_rows in (None, 1024):
         conf = {"spark.rapids.tpu.sql.enabled": True,
@@ -199,11 +243,14 @@ def test_windows_inside_a_large_batch(kind):
         s.create_dataframe(u, num_partitions=1) \
             .create_or_replace_temp_view("u")
         trace.reset()
-        got.append(sorted(s.sql("select k, a, b from t join u "
-                                "on k = k2 and a < b").collect(), key=_key))
+        got.append(sorted(s.sql(
+            "select k, a, b from t join u on k = k2 and "
+            f"a {'<>' if ne else '<'} b").collect(), key=_key))
         (counts,) = [c for q, c in trace.coarse_counts().items()
                      if q is not None]
         assert counts["join.residual.pairs"] == 6000
+        ranged = int(kind == "bigint")
+        assert counts.get("join.residual.range", 0) == ranged
         assert counts["join.residual.chunks"] == \
-            (1 if chunk_rows is None else -(-6000 // chunk_rows))
+            (1 if chunk_rows is None or ranged else -(-6000 // chunk_rows))
     assert got[0] == want and got[1] == want

@@ -8,7 +8,8 @@ chunked residual join; a rehearsal of the cell by name runs both texts
 through ``TpuSession`` on the device path with no CPU operator and
 answers as the configuration's numpy references do (not the CPU
 engine: it shares the planner); the residual join's counters read the
-pairs, launches, survivors and bytes the data imply; the three metric
+pairs, survivors and bytes the data imply, and q72's two residual joins
+are decided by bounds, a launch a stream batch; the three metric
 readers return what a recorded run holds, and nothing where there is
 nothing to read."""
 import importlib
@@ -248,8 +249,10 @@ def test_a_rehearsal_of_the_cell_is_correct(bench, deployment, tmp_path,
     # both conditions read two 4-byte columns (data + validity: 5 bytes
     # each); two int32 maps and a flag a pair
     assert q72["join.residual.bytes"] == (pairs + reach) * (2 * 5 + 8 + 1)
-    # one launch a stream batch under the budget, one a 2^22 pairs past it
-    assert q72["join.residual.chunks"] >= -(-pairs // (1 << 22)) + 1
+    # both joins are decided by bounds: one launch a stream batch, where
+    # deciding pair by pair took one a 2^22 pairs and one a batch more
+    assert q72["join.residual.range"] == q72["join.residual.chunks"]
+    assert 2 <= q72["join.residual.chunks"] < -(-pairs // (1 << 22))
     # the six WHERE conjuncts, each through both LEFT OUTER joins
     assert q72["plan.pushdown.outer"] == 12
 
